@@ -34,10 +34,18 @@ Key design points:
     to ``evicted`` rather than a cold cache.
 
 Enable per process with ``configure(dir)`` or the
-``PADDLE_TPU_COMPILE_CACHE_DIR`` env var (the launcher / bench can
-stamp one shared directory per fleet); ``PADDLE_TPU_COMPILE_CACHE_MAX_BYTES``
+``PADDLE_TPU_COMPILE_CACHE_DIR`` env var (the launcher stamps one
+shared directory per fleet); ``PADDLE_TPU_COMPILE_CACHE_MAX_BYTES``
 bounds it. Disabled (the default) the executor compiles exactly as
 before — the cache is strictly additive.
+
+``enable()`` is the one place that decides WHERE persistent compile
+state lives, for this store and for JAX's own compilation cache
+together (``resolve_root``): an explicit directory, else
+``JAX_COMPILATION_CACHE_DIR``, else ``.jax_cache/`` in the checkout.
+The path is part of JAX's cache key, so it is never a temp name, a pid
+or a home directory. Entry points (chip_smoke.py, bench.py, the tools,
+the launcher) call it; nothing else sets JAX's cache directory.
 
 See docs/compile.md for the on-disk layout and the provenance record
 schema this feeds.
@@ -51,17 +59,25 @@ import os
 import pickle
 import threading
 import time
+import zlib
 from typing import Optional
 
 from . import observability as _obs
 
 __all__ = ["CompileCache", "CacheHit", "configure", "active",
            "canonical_fingerprint", "cache_key", "stats",
-           "reset_stats"]
+           "reset_stats", "resolve_root", "store_dir", "enable"]
 
 ENV_DIR = "PADDLE_TPU_COMPILE_CACHE_DIR"
 ENV_MAX_BYTES = "PADDLE_TPU_COMPILE_CACHE_MAX_BYTES"
+JAX_ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
 EVICTED_INDEX = "evicted.jsonl"
+# this store's subdirectory of the resolved root (JAX's own cache
+# files sit directly in the root)
+STORE_SUBDIR = "paddle_tpu_executables"
+_CHECKOUT_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 _MU = threading.Lock()
 _ACTIVE: Optional["CompileCache"] = None
@@ -107,8 +123,9 @@ class CacheHit:
 class CompileCache:
     """On-disk store of serialized XLA executables (see module doc).
 
-    Layout under ``dir``: ``<key>.bin`` (pickle of the
-    ``serialize_executable`` triple), ``<key>.json`` (origin + cost
+    Layout under ``dir``: ``<key>.bin`` (deflated pickle of the
+    ``serialize_executable`` triple plus the ids of the devices the
+    executable was compiled for), ``<key>.json`` (origin + cost
     metadata, human-readable), ``evicted.jsonl`` (one key per line,
     append-only memory of LRU evictions)."""
 
@@ -147,10 +164,17 @@ class CompileCache:
                 st = None
             with open(path, "rb") as f:
                 blob = f.read()
-            payload, in_tree, out_tree = pickle.loads(blob)
+            payload, in_tree, out_tree, device_ids = \
+                pickle.loads(zlib.decompress(blob))
+            import jax
             from jax.experimental import serialize_executable as _se
-            loaded = _se.deserialize_and_load(payload, in_tree,
-                                              out_tree)
+            # load onto exactly the devices it was compiled for: the
+            # default is EVERY device of the backend, which breaks a
+            # one-device executable on any multi-device process
+            by_id = {d.id: d for d in jax.devices()}
+            loaded = _se.deserialize_and_load(
+                payload, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids])
         except FileNotFoundError:
             self._m_miss.inc()
             return None
@@ -204,7 +228,12 @@ class CompileCache:
         try:
             from jax.experimental import serialize_executable as _se
             payload, in_tree, out_tree = _se.serialize(compiled)
-            blob = pickle.dumps((payload, in_tree, out_tree))
+            device_ids = [d.id for d in
+                          compiled.runtime_executable().local_devices()]
+            # level 1: a TPU train step serializes to ~300 MB and
+            # deflates ~5x at disk speed
+            blob = zlib.compress(pickle.dumps(
+                (payload, in_tree, out_tree, device_ids)), 1)
         except Exception as e:
             _obs.emit("compile_cache_unserializable", key=key,
                       error=repr(e), entry=meta.get("entry"))
@@ -465,6 +494,35 @@ def active() -> Optional[CompileCache]:
                               error=repr(e))
                     _ACTIVE = None
         return _ACTIVE
+
+
+def resolve_root(explicit: Optional[str] = None) -> str:
+    """Where persistent compile state lives: ``explicit`` if given,
+    else ``JAX_COMPILATION_CACHE_DIR``, else ``.jax_cache/`` in the
+    checkout."""
+    return os.path.abspath(explicit or os.environ.get(JAX_ENV_DIR)
+                           or _CHECKOUT_ROOT)
+
+
+def store_dir() -> str:
+    """This store's default directory: under ``resolve_root()``."""
+    return os.path.join(resolve_root(), STORE_SUBDIR)
+
+
+def enable(dir: Optional[str] = None,
+           max_bytes: Optional[int] = None) -> str:
+    """Turn on both persistent caches under one root and return it:
+    JAX's compilation cache in ``resolve_root(dir)`` and this
+    module's executable store in its ``STORE_SUBDIR``. Call before the
+    first compile — JAX latches its cache directory then. When the
+    root came from ``JAX_COMPILATION_CACHE_DIR`` JAX has already read
+    it, and this sets nothing."""
+    root = resolve_root(dir)
+    if dir or not os.environ.get(JAX_ENV_DIR):
+        import jax
+        jax.config.update("jax_compilation_cache_dir", root)
+    configure(os.path.join(root, STORE_SUBDIR), max_bytes=max_bytes)
+    return root
 
 
 def stats() -> Optional[dict]:

@@ -53,6 +53,22 @@ class CUDAPinnedPlace:
         return "CUDAPinnedPlace"
 
 
+# Published bf16 peak matmul FLOP/s per chip, keyed by PJRT
+# ``device_kind`` (Google Cloud TPU documentation). The chips this
+# framework knows: anything that reports a utilization, or vouches that
+# it ran on a chip, looks the device up here, and a kind that is absent
+# is an error, never a default.
+TPU_PEAK_BF16_FLOPS = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,   # v5e
+    "TPU v5e": 197e12,
+    "TPU v5p": 459e12,
+    "TPU v5": 459e12,
+    "TPU v6 lite": 918e12,   # v6e
+    "TPU v6e": 918e12,
+}
+
+
 def get_devices():
     return jax.devices()
 

@@ -2,7 +2,7 @@
 
 TPU-native analog of the reference's ``PADDLE_ENFORCE`` family
 (reference: paddle/fluid/platform/enforce.h). Errors carry the same
-category taxonomy so user-facing messages are comparable, but raise
+error categories so user-facing messages are comparable, but raise
 normal Python exceptions (there is no C++/Python boundary to marshal
 across in the hot path — the whole step is one compiled XLA program).
 """

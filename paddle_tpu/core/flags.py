@@ -112,12 +112,12 @@ FLAGS.define("communicator_independent_recv_thread", True,
 
 FLAGS.define("sdpa_auto_flash", True,
              "scaled_dot_product_attention's base lowering routes to "
-             "the flash pallas kernel inside its chip-measured win "
-             "envelope (TPU backend, <=2-byte dtype, dropout active, "
-             "single-k-block shapes) — the reference jit/ pool's "
-             "best-impl-at-runtime dispatch. bench.py pins this off "
-             "for its pure-XLA base row. Chip evidence 2026-07-31: "
-             "+12% in-model on transformer-base b64.")
+             "the flash pallas kernel inside its envelope (TPU "
+             "backend, <=2-byte dtype, dropout active, single-k-block "
+             "shapes) — the reference jit/ pool's best-impl-at-runtime "
+             "dispatch. bench.py pins this off for its pure-XLA base "
+             "row. Speed on this installation: not measured "
+             "(ROADMAP D2).")
 
 FLAGS.define("sp_attention", True,
              "scaled_dot_product_attention's base lowering routes "

@@ -9,10 +9,18 @@ TPU redesign: the process unit is a HOST, not a chip — one process
 per host owns all its local chips and `jax.distributed` federates
 hosts into one global device mesh (parallel/multihost.py consumes the
 same PADDLE_* spelling this launcher writes, so reference launch
-scripts port by changing the module name). ``--nproc_per_node`` still
-exists for CPU simulation and forced multi-process-per-host setups;
-each extra process then restricts its visible devices via
-``--selected_devices`` (the FLAGS_selected_gpus analog).
+scripts port by changing the module name). A chip belongs to ONE
+process: ``--nproc_per_node``/``--server_num``/``--serving_replicas``
+setups that put several processes on a node are for
+``JAX_PLATFORMS=cpu`` simulation. On a TPU host the first child to
+initialize JAX takes the chips and every other child dies at backend
+init with JAX's own "Unable to initialize backend 'tpu': ABORTED: The
+TPU is already in use by process with pid N" (or, when two start at
+once, "...accessing libtpu multi-process lockfile"): exit 1 within
+seconds, with ``JAX_PLATFORMS`` set or unset; the launcher then
+terminates the fleet and returns 1 (``--nproc_per_node=2`` on one
+v5e chip: 20 s — chip run, PR 21, libtpu 0.0.34). Nothing hangs and
+no child runs on the CPU unasked.
 
 Usage:
     python -m paddle_tpu.distributed.launch train.py --your --args
@@ -49,11 +57,7 @@ def _parse_args(argv=None):
     parser.add_argument(
         "--nproc_per_node", type=int, default=1,
         help="processes per node (TPU: 1 process owns every local "
-        "chip; >1 is for CPU simulation / forced splits)")
-    parser.add_argument(
-        "--selected_devices", default=None,
-        help="comma-separated per-process device lists separated by "
-        "';' (FLAGS_selected_gpus analog), e.g. '0,1;2,3'")
+        "chip; >1 is for JAX_PLATFORMS=cpu simulation)")
     parser.add_argument(
         "--log_dir", default=None,
         help="redirect each worker's output to <log_dir>/worker.N.log")
@@ -82,11 +86,11 @@ def _parse_args(argv=None):
         "--compile_cache_dir", default=None,
         help="persistent AOT compile-cache directory shared by every "
         "worker (PADDLE_TPU_COMPILE_CACHE_DIR). Default: inherit the "
-        "launcher's env var if set, else <journal_dir|log_dir>/"
-        "compile_cache, else ~/.cache/paddle_tpu/compile_cache — so "
-        "real fleets share one cache and warm restarts perform zero "
-        "XLA compiles (docs/compile.md). Pass an empty string to "
-        "disable stamping.")
+        "launcher's env var if set, else compile_cache.store_dir() "
+        "(under JAX_COMPILATION_CACHE_DIR, else the checkout's "
+        ".jax_cache/) — so fleets share one cache and warm restarts "
+        "perform zero XLA compiles (docs/compile.md). Pass an empty "
+        "string to disable stamping.")
     parser.add_argument(
         "training_script",
         help="the script to launch (followed by its own args)")
@@ -106,12 +110,6 @@ def get_cluster_env(args):
     endpoints = ["%s:%d" % (ip, args.started_port + i)
                  for ip in ips for i in range(nper)]
     node_index = ips.index(args.node_ip)
-    selected = (args.selected_devices.split(";")
-                if args.selected_devices else [None] * nper)
-    if len(selected) != nper:
-        raise ValueError(
-            "--selected_devices must give %d ';'-separated groups, "
-            "got %r" % (nper, args.selected_devices))
     envs = []
     for local_rank in range(nper):
         rank = node_index * nper + local_rank
@@ -123,8 +121,6 @@ def get_cluster_env(args):
             "PADDLE_TRAINING_ROLE": "TRAINER",
         }
         _stamp_role(env, args, "trainer-%d" % rank)
-        if selected[local_rank]:
-            env["FLAGS_selected_devices"] = selected[local_rank]
         envs.append(env)
     return envs
 
@@ -195,14 +191,14 @@ def _journal_dir(args):
 
 
 def default_compile_cache_dir(args=None):
-    """The fleet-shared persistent compile-cache directory
-    (ROADMAP compile-plane follow-up): an explicit
+    """The fleet-shared executable-store directory: an explicit
     ``--compile_cache_dir`` wins; an empty string disables stamping;
     otherwise the launcher's own PADDLE_TPU_COMPILE_CACHE_DIR (every
     child inherits the env anyway — returning it keeps the contract
-    visible), else a ``compile_cache/`` sibling of the fleet's
-    journals/logs, else one stable per-user location so even ad-hoc
-    fleets share warm executables across restarts."""
+    visible), else ``compile_cache.store_dir()`` — under
+    ``JAX_COMPILATION_CACHE_DIR`` when that is set, else under the
+    checkout's ``.jax_cache/`` — so even ad-hoc fleets share warm
+    executables across restarts."""
     explicit = getattr(args, "compile_cache_dir", None) \
         if args is not None else None
     if explicit is not None:
@@ -213,11 +209,8 @@ def default_compile_cache_dir(args=None):
         # (compile_cache.active() reads it as off) — honor it as an
         # explicit opt-out, don't fall through and re-enable
         return env or None
-    jdir = _journal_dir(args) if args is not None else None
-    if jdir:
-        return os.path.join(jdir, "compile_cache")
-    return os.path.join(os.path.expanduser("~"), ".cache",
-                        "paddle_tpu", "compile_cache")
+    from ..compile_cache import store_dir
+    return store_dir()
 
 
 def _stamp_role(env, args, role):

@@ -185,13 +185,13 @@ def _stage_shift(y, direction: int, mesh):
     if _explicit_pp_spmd() and mesh is not None \
             and "pp" in mesh.axis_names \
             and mesh.shape["pp"] == P and P > 1:
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec
         perm = [(i, (i + direction) % P) for i in range(P)]
         return shard_map(
             lambda a: lax.ppermute(a, "pp", perm),
             mesh=mesh, in_specs=PartitionSpec("pp"),
-            out_specs=PartitionSpec("pp"), check_rep=False)(y)
+            out_specs=PartitionSpec("pp"), check_vma=False)(y)
     return jnp.roll(y, direction, axis=0)
 
 

@@ -10,8 +10,8 @@ module is what WATCHES them. Three pieces:
     request completion, prefetcher chunks). The watchdog daemon
     thread checks every armed watch each tick: a beacon that shows NO
     progress for ``deadline_s`` while its ``pending_fn`` reports work
-    outstanding is a **stall** verdict — the "silent 240 s backend
-    hang" class the bench history (BENCH_r03→r05) made expensive.
+    outstanding is a **stall** verdict — the silent-hang class that
+    no exception ever reports.
     Declarative ``HealthRule``s over MetricsRegistry deltas catch the
     softer failures: recompile storms, throughput collapse vs a
     rolling baseline, queue saturation, anomaly-skip burn rate.
@@ -748,8 +748,8 @@ class FlightRecorder:
         default die). Additionally registers ``faulthandler`` on
         SIGTERM writing ``blackbox.<role>.stacks.txt``: the
         C-level dump fires even when the main thread is wedged inside
-        a C call where no Python handler can run (the observed
-        ``jax.devices()`` claim hang). Must be called from the main
+        a C call where no Python handler can run (a backend init that
+        never returns). Must be called from the main
         thread; returns False (and does nothing) elsewhere."""
         import signal
         if threading.current_thread() is not threading.main_thread():

@@ -541,8 +541,8 @@ def batch_norm(x, scale, bias, mean, var, *, epsilon=1e-5, momentum=0.9,
 def _ln_affine(norm, scale, bias):
     """custom-vjp affine tail of layer_norm (FLAGS.mxu_ln_grad): the
     dScale/dBias column reductions over N rows run as ones@M MXU dots
-    with f32 accumulation instead of the convert_reduce fusions the
-    round-4 step anatomy charged ~7.8 ms/step to (BASELINE.md). Same
+    with f32 accumulation instead of convert_reduce fusions (their
+    share of the step on this installation: not measured). Same
     treatment as ops/math_ops._bias_add_vjp, extended to the scale
     product. dX path (through mean/var) stays autodiff. scale/bias
     arrive already broadcast-shaped ([1, ..., D])."""

@@ -33,13 +33,11 @@ matrix in HBM in either direction:
   registers, no m/l scratch, no lane-replicated statistics), and the
   backward is ONE kernel producing dq/dk/dv from a single exp
   recompute with lse and delta derived in-kernel — the only HBM
-  residual is the forward output. Chip-measured 2026-07-31: IN-MODEL
-  this mix wins +12% on transformer-base b64 (13.08 vs 11.69
-  steps/s, MFU 0.374 -> 0.419) — XLA's fused chain pays RNG mask
-  materialization + probs HBM round-trips at all 18 attention sites.
-  The f32 no-dropout micro-benchmark has the kernel 0.94x of XLA:
-  micro-benchmarks do not transfer, in either direction; only
-  in-model numbers decide (BASELINE.md round-4).
+  residual is the forward output. The argument for it in-model:
+  XLA's fused chain pays RNG mask materialization + probs HBM
+  round-trips at all 18 attention sites. Its speed on this
+  installation is not measured (ROADMAP D2/D3 re-measure it through
+  the ledger); only in-model numbers decide.
 
 ``Bias`` is an additive attention mask (0 / -1e9, built from data by the
 models) and is registered non-differentiable: the base lowering and the
@@ -64,7 +62,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register, register_variant
-from .common import CompilerParams, blk, interpret_mode
+from .common import blk, interpret_mode
 
 _NEG_INF = -1e30
 
@@ -85,8 +83,10 @@ def _dropout_keep(seed_ref, i, j, kk, n_q, n_k, shape, rate):
     regenerated in the backward kernels. The (cell, q-block, k-block)
     coordinates are folded into one scalar seed (single-arg prng_seed —
     the multi-arg form doesn't lower on this Mosaic version) with a
-    Knuth-style odd multiplier so nearby blocks decorrelate."""
-    flat = (i * n_q + j) * n_k + kk
+    Knuth-style odd multiplier so nearby blocks decorrelate.
+    ``seed_ref`` = [step seed, this call's first cell]: a shard of a
+    mesh numbers its cells where a single device would (_seed_smem)."""
+    flat = ((seed_ref[1] + i) * n_q + j) * n_k + kk
     pltpu.prng_seed(seed_ref[0] + flat * jnp.int32(-1640531527))
     bits = pltpu.prng_random_bits(shape)
     u = lax.bitcast_convert_type(bits, jnp.uint32)
@@ -162,12 +162,13 @@ def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
                                  dropout_rate=0.0, causal=False,
                                  is_test=False, rng=None):
     """Base lowering: XLA fuses the chain — except inside the flash
-    kernel's chip-measured win envelope, where the base dispatches to
-    it (FLAGS_sdpa_auto_flash, the jit/README.en.md best-impl-wins
-    pool applied at run time). The envelope is exactly what the
-    2026-07-31 in-model A/B measured winning (+12%): TPU execution,
-    low-precision operands, dropout active, single-k-block shapes;
-    everything else keeps the XLA chain, which measured faster there."""
+    kernel's envelope, where the base dispatches to it
+    (FLAGS_sdpa_auto_flash, the jit/README.en.md best-impl-wins pool
+    applied at run time): TPU execution, low-precision operands,
+    dropout active, single-k-block shapes; everything else keeps the
+    XLA chain. Inside the envelope the kernel is THE lowering — a
+    Mosaic compile error propagates, nothing retries with the
+    reference."""
     rate = 0.0 if is_test else float(dropout_rate)
     from ...core.flags import FLAGS
     if FLAGS.sp_attention and rate == 0.0:
@@ -285,20 +286,21 @@ def _1k_applicable(Sq, Sk):
 #     arrays live after Mosaic's buffer reuse. This constant is
 #     ANCHORED on chip evidence, not source-level counting: the
 #     bf16 [8,256,256] backward (5 source-level f32 temps = 20 B/elem
-#     would predict 22 MB) compiled and ran at G=8 in the round-4
-#     headline capture, so Mosaic demonstrably reuses all but ~2.
+#     would predict 22 MB) compiles and runs at G=8 (jax 0.9.0 /
+#     libtpu 0.0.34, PR 21: chip_smoke.py and test_chip_kernels.py),
+#     so Mosaic demonstrably reuses all but ~2.
 # Budget 15 MB of the 16 MB v5e scoped limit; G halves until the
 # modeled row total fits. tests/test_pallas_vmem.py replays this
-# model at every _1k_applicable corner AND pins the chip-measured
-# headline geometry (bf16 256x256 dropout) to G=8.
+# model at every _1k_applicable corner AND pins the headline
+# geometry (bf16 256x256 dropout) to G=8.
 _1K_TEMP_BYTES = 8
 _1K_VMEM_BUDGET = 15 << 20
 
 # Blocked-path tile targets, env-tunable for on-chip sweeps
-# (tools/blocked_sweep.py): PALLAS_BLK_Q / PALLAS_BLK_K. The committed
-# defaults are the round-4 choices; any change must be chip-measured
-# in-model at S>=1024 first (the blocked path never dispatches at the
-# S=256 flagship — _1k_applicable owns that envelope).
+# (tools/blocked_sweep.py): PALLAS_BLK_Q / PALLAS_BLK_K. Any change
+# must be chip-measured in-model at S>=1024 first (the blocked path
+# never dispatches at the S=256 flagship — _1k_applicable owns that
+# envelope).
 _BLK_Q_TARGET = int(os.environ.get("PALLAS_BLK_Q", "256"))
 _BLK_K_TARGET = int(os.environ.get("PALLAS_BLK_K", "512"))
 
@@ -327,7 +329,7 @@ def _1k_bwd_G(H, itemsize, Sq, Sk, Dh, has_bias=False):
 
 def _1k_fwd_G(H, itemsize, rate, Sq, Sk, Dh, has_bias=False):
     """Forward rows per grid cell. With dropout it MUST equal the
-    backward's G (the per-cell PRNG seed mapping — see _pick_G's
+    backward's G (the per-cell PRNG seed mapping — see _blocked_G's
     invariant note); without dropout the forward only needs its own
     streams (q,o + k,v) to fit."""
     if rate > 0.0:
@@ -339,32 +341,33 @@ def _1k_fwd_G(H, itemsize, rate, Sq, Sk, Dh, has_bias=False):
     return blk(H, base)
 
 
-def _bwd_G(H, itemsize):
-    """Backward rows per grid cell: the backward streams six operands
-    + three outputs + the f32 score/prob temporaries, so f32 needs
-    G=4 to fit the 16 MB scoped VMEM (tests/test_pallas_vmem.py).
-    The ONE definition both backward wrappers and _pick_G use — the
-    fwd/bwd dropout-seed consistency invariant hangs off it."""
-    return blk(H, 8 if itemsize <= 2 else 4)
-
-
-def _pick_G(H, itemsize, rate):
-    """Rows per grid cell — ONE choice shared by forward and backward.
+def _blocked_G(H):
+    """(batch, head) rows per grid cell of the blocked kernels — ONE
+    choice shared by forward and both backward kernels.
 
     The in-kernel dropout mask is seeded per grid CELL
     (_dropout_keep), so the (batch, head) -> cell mapping MUST be
     identical in the kernels that generate and regenerate it: a
-    fwd G=8 / bwd G=4 split at f32 silently regenerates different
-    masks for every head the two groupings assign to different
-    cells (caught by round-4 review: f32 H=8 dropout grads diverged
-    from finite differences on heads >= 4). Without dropout the
-    forward may keep G=8 at f32 (it streams fewer operands than the
-    backward, which needs G=4 to fit the 16 MB scoped VMEM —
-    tests/test_pallas_vmem.py), because no PRNG state crosses the
-    kernels."""
-    if rate == 0.0:
-        return blk(H, 8)
-    return _bwd_G(H, itemsize)
+    fwd G=8 / bwd G=4 split silently regenerates different masks for
+    every head the two groupings assign to different cells.
+
+    2 is what Mosaic accepts on a v5e at the 256x512 tiles (chip runs,
+    PR 21): G=8 ran out of the 16 MB scoped VMEM in the f32 forward,
+    the biased bf16 forward and the bf16 backward (Dh<=64 blocks are
+    lane-padded to 128, the statistics ride 128 lanes wide, and the
+    [G, blk_q, blk_k] f32 score temporaries sit beside them); G=2
+    compiled and matched the reference at f32, of which bf16 is the
+    smaller case in every term."""
+    return blk(H, 2)
+
+
+def _seed_smem(seed_f, G):
+    """int32[2] for SMEM from the float32[2] the custom_vjp carries
+    (float32 so no int-cotangent dance): the step's PRNG seed, and
+    this call's first grid cell — ``seed_f[1]`` is its first
+    (batch, head) row in the one-device numbering, G rows to a cell."""
+    s = seed_f.astype(jnp.int32)
+    return jnp.stack([s[0], s[1] // G])
 
 
 def _1k_specs_args(q, k, v, bias, per_head, seed, G, hb):
@@ -399,7 +402,7 @@ def _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate, causal):
     G = _1k_fwd_G(H, q.dtype.itemsize, rate, Sq, Sk, Dh,
                   bias is not None)
     hb = H // G
-    seed = jnp.asarray([seed_f.astype(jnp.int32)], jnp.int32)
+    seed = _seed_smem(seed_f, G)
 
     in_specs, args = _1k_specs_args(q, k, v, bias, per_head, seed, G,
                                     hb)
@@ -416,7 +419,7 @@ def _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate, causal):
         grid=(BH // G,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((G, Sq, Dh), lambda i: (i, 0, 0)),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret_mode(),
     )(*args)
@@ -430,7 +433,7 @@ def _flash_bwd_1k(q, k, v, bias, seed_f, o, g, scale, rate, causal):
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
     G = _1k_bwd_G(H, q.dtype.itemsize, Sq, Sk, Dh, bias is not None)
     hb = H // G
-    seed = jnp.asarray([seed_f.astype(jnp.int32)], jnp.int32)
+    seed = _seed_smem(seed_f, G)
 
     in_specs, args = _1k_specs_args(q, k, v, bias, per_head, seed, G,
                                     hb)
@@ -457,7 +460,7 @@ def _flash_bwd_1k(q, k, v, bias, seed_f, o, g, scale, rate, causal):
             pl.BlockSpec((G, Sk, Dh), lambda i: (i, 0, 0)),
             pl.BlockSpec((G, Sk, Dh), lambda i: (i, 0, 0)),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret_mode(),
     )(*args)
@@ -540,9 +543,7 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal):
     Sk = k.shape[2]
     BH = B * H
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
-    # must match _flash_bwd's grouping when dropout is on (same
-    # per-cell PRNG seeding — see _pick_G)
-    G = _pick_G(H, q.dtype.itemsize, rate)
+    G = _blocked_G(H)
     hb = H // G                    # cells per batch row
     q3 = q.reshape(BH, Sq, Dh)
     k3 = k.reshape(BH, Sk, Dh)
@@ -551,7 +552,7 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal):
     blk_k = blk(Sk, _BLK_K_TARGET)
     n_k = Sk // blk_k
     grid = (BH // G, Sq // blk_q, n_k)
-    seed = jnp.asarray([seed_f.astype(jnp.int32)], jnp.int32)
+    seed = _seed_smem(seed_f, G)
 
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -592,7 +593,7 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal):
             pltpu.VMEM((G, blk_q, 128), jnp.float32),
             pltpu.VMEM((G, blk_q, 128), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(*args)
@@ -702,11 +703,7 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal):
     Sk = k.shape[2]
     BH = B * H
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
-    # the bwd streams 6 (G, blk, Dh) operands + 2 outputs + 2 scratch;
-    # with Dh<=64 lane-padded to 128, G=8 at f32 models ~18 MB and
-    # trips the v5e 16 MB scoped-VMEM limit — halve the (batch,head)
-    # rows per grid cell for 4-byte dtypes (shared _bwd_G definition)
-    G = _bwd_G(H, q.dtype.itemsize)
+    G = _blocked_G(H)
     hb = H // G
     q3 = q.reshape(BH, Sq, Dh)
     k3 = k.reshape(BH, Sk, Dh)
@@ -715,7 +712,7 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal):
     blk_q = blk(Sq, _BLK_Q_TARGET)
     blk_k = blk(Sk, _BLK_K_TARGET)
     n_q, n_k = Sq // blk_q, Sk // blk_k
-    seed = jnp.asarray([seed_f.astype(jnp.int32)], jnp.int32)
+    seed = _seed_smem(seed_f, G)
     # delta_i = rowsum(dO * O): O(S*Dh) elementwise work, XLA fuses it.
     # lse/delta enter the kernels lane-replicated to the 128-lane
     # min-tile (the layout the fwd kernel produced them in).
@@ -773,7 +770,7 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal):
         out_specs=pl.BlockSpec((G, blk_q, Dh),
                                lambda i, j, kk: (i, j, 0)),
         scratch_shapes=[pltpu.VMEM((G, blk_q, Dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(*ar)
@@ -793,7 +790,7 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal):
         ],
         scratch_shapes=[pltpu.VMEM((G, blk_k, Dh), jnp.float32),
                         pltpu.VMEM((G, blk_k, Dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(*ar)
@@ -853,9 +850,60 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
     if rate > 0.0:
         # fold the step key into a scalar TPU PRNG seed; float32 carries
         # it through custom_vjp without an int-cotangent (float0) dance
-        seed_f = jax.random.randint(rng, (), 0, 1 << 23).astype(
+        seed = jax.random.randint(rng, (), 0, 1 << 23).astype(
             jnp.float32)
     else:
-        seed_f = jnp.float32(0)
-    return _sdpa_flash(q, k, v, bias, seed_f, float(scale), rate,
-                       bool(causal))
+        seed = jnp.float32(0)
+    from ...parallel import mesh as mesh_lib
+    from ...parallel.ulysses import in_sp_body
+    mesh = mesh_lib.current_mesh()
+    if mesh is not None and mesh.size > 1 and not in_sp_body():
+        return _flash_over_mesh(mesh, q, k, v, bias, seed, float(scale),
+                                rate, bool(causal))
+    return _sdpa_flash(q, k, v, bias, jnp.stack([seed, jnp.float32(0)]),
+                       float(scale), rate, bool(causal))
+
+
+def _flash_over_mesh(mesh, q, k, v, bias, seed, scale, rate, causal):
+    """The kernel under a multi-device mesh. Mosaic kernels are not
+    partitioned automatically — jax's lowering rule refuses one inside
+    a multi-device jit (jax/_src/tpu_custom_call.py) — so it runs per
+    shard under shard_map: batch over ``dp`` and heads over ``tp``
+    where the mesh has those axes and they divide, every other axis
+    computing replicated. Each shard numbers its dropout cells from
+    its first (batch, head) row, so under dp the masks are the ones a
+    single device draws and the loss trace is the single-device one."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec
+
+    B, H = q.shape[:2]
+
+    def axis(name, n):
+        if name in mesh.axis_names and mesh.shape[name] > 1 \
+                and n % mesh.shape[name] == 0:
+            return name
+        return None
+
+    b_ax, h_ax = axis("dp", B), axis("tp", H)
+    spec = PartitionSpec(b_ax, h_ax, None, None)
+    args, specs = [seed, q, k, v], [PartitionSpec(), spec, spec, spec]
+    if bias is not None:
+        bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
+        args.append(bias)
+        specs.append(PartitionSpec(
+            b_ax if bias.shape[0] == B else None,
+            h_ax if bias.shape[1] == H else None, None, None))
+
+    def body(seed_, q_, k_, v_, bias_=None):
+        shard = jnp.int32(0)
+        for ax in (b_ax, h_ax):
+            if ax is not None:
+                shard = shard * mesh.shape[ax] + lax.axis_index(ax)
+        row0 = shard * (q_.shape[0] * q_.shape[1])
+        return _sdpa_flash(
+            q_, k_, v_, bias_,
+            jnp.stack([seed_, row0.astype(jnp.float32)]),
+            scale, rate, causal)
+
+    return shard_map(body, mesh=mesh, in_specs=tuple(specs),
+                     out_specs=spec, check_vma=False)(*args)

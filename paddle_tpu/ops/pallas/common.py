@@ -19,15 +19,8 @@ operators/jit/test.cc pattern).
 from __future__ import annotations
 
 import jax
-from jax.experimental.pallas import tpu as _pltpu
 
 from ...core.flags import FLAGS
-
-# jax renamed the Mosaic compiler-params dataclass across releases
-# (<=0.4.3x: ``TPUCompilerParams``; newer: ``CompilerParams``). The
-# kernels import this alias so they collect and run on either API.
-CompilerParams = getattr(_pltpu, "CompilerParams", None) or \
-    getattr(_pltpu, "TPUCompilerParams")
 
 
 def interpret_mode() -> bool:

@@ -38,7 +38,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import get, register_variant
-from .common import CompilerParams, blk, interpret_mode
+from .common import blk, interpret_mode
 
 
 def _fwd_kernel(x_ref, w_ref, lab_ref, loss_ref, lse_ref,
@@ -122,7 +122,7 @@ def _fwd_call(x2, w, lab2, eps):
                    pl.BlockSpec((1, bn), lambda j, i: (0, i),
                                 memory_space=pltpu.VMEM)),
         scratch_shapes=[pltpu.VMEM((ni, bn), jnp.float32)] * 4,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=2 * N * D * Vp, transcendentals=N * Vp,

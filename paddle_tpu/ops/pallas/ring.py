@@ -35,7 +35,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import CompilerParams, blk, interpret_mode
+from .common import interpret_mode
 
 _NEG = -1.0e30
 
@@ -64,20 +64,17 @@ def _row_bytes(itemsize, blk_q, Sk, Dh, bwd):
 
 
 def _pick_geometry(BH, Sq, Sk, Dh, itemsize, bwd):
-    """(G, blk_q) fitting the VMEM budget, or None."""
-    blk_q = blk(Sq, 256)
-    G = blk(BH, 8)
-    while True:
-        if G * _row_bytes(itemsize, blk_q, Sk, Dh, bwd) \
-                <= _VMEM_BUDGET:
+    """(G, blk_q) fitting the VMEM budget, or None. The row statistics
+    (m, l, lse, delta) ride as (G, blk_q) blocks of (BH, Sq) arrays,
+    and Mosaic takes a block only when its last two dims divide by
+    (8, 128) or span the array: G is 8 (or all of BH), blk_q a
+    multiple of 128 (or all of Sq) — so what shrinks to fit is the q
+    block, never G below 8 (a (4, 256) block is refused, PR 21)."""
+    G = 8 if BH % 8 == 0 else BH
+    for blk_q in [b for b in (256, 128) if Sq % b == 0] or [Sq]:
+        if G * _row_bytes(itemsize, blk_q, Sk, Dh, bwd) <= _VMEM_BUDGET:
             return G, blk_q
-        if G > 1:
-            G = blk(BH, G // 2)
-            continue
-        if blk_q > 8 and blk(Sq, blk_q // 2) < blk_q:
-            blk_q = blk(Sq, blk_q // 2)
-            continue
-        return None
+    return None
 
 
 def applicable(B, H, Sq, Sk, Dh, itemsize):
@@ -188,7 +185,7 @@ def fwd_block(q, k, v, q_off, k_off, scale, causal):
         out_specs=(pl.BlockSpec((G, blk_q, Dh), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((G, blk_q), lambda i, j: (i, j)),
                    pl.BlockSpec((G, blk_q), lambda i, j: (i, j))),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret_mode(),
     )(offs, q.reshape(BH, Sq, Dh), k.reshape(BH, Sk, Dh),
@@ -234,7 +231,7 @@ def bwd_block(q, k, v, do, lse, delta, q_off, k_off, scale, causal):
             pl.BlockSpec((G, blk_q, Dh), lambda i, j: (i, j, 0)),
             pl.BlockSpec((G, Sk, Dh), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((G, Sk, Dh), lambda i, j: (i, 0, 0))),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(offs, q.reshape(BH, Sq, Dh), k.reshape(BH, Sk, Dh),

@@ -68,7 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from ..core.enforce import (InvalidArgumentError, UnimplementedError,
@@ -258,7 +258,7 @@ def all_reduce_exact(g, mesh, axis: str = "dp"):
         return lax.psum(x / n, axis)
 
     return shard_map(local, mesh=mesh, in_specs=PartitionSpec(),
-                     out_specs=PartitionSpec(), check_rep=False)(g)
+                     out_specs=PartitionSpec(), check_vma=False)(g)
 
 
 def reduce_scatter_gather(g, mesh, axis: str = "dp"):
@@ -279,7 +279,7 @@ def reduce_scatter_gather(g, mesh, axis: str = "dp"):
         return full[:numel].reshape(x.shape)
 
     return shard_map(local, mesh=mesh, in_specs=PartitionSpec(),
-                     out_specs=PartitionSpec(), check_rep=False)(g)
+                     out_specs=PartitionSpec(), check_vma=False)(g)
 
 
 def all_reduce_q8(g, residual, mesh=None, axis: str = "dp",
@@ -335,7 +335,7 @@ def all_reduce_q8(g, residual, mesh=None, axis: str = "dp",
     return shard_map(local, mesh=mesh,
                      in_specs=(PartitionSpec(), PartitionSpec()),
                      out_specs=(PartitionSpec(), PartitionSpec()),
-                     check_rep=False)(g, residual)
+                     check_vma=False)(g, residual)
 
 
 # ---------------------------------------------------------------------------
@@ -367,7 +367,7 @@ def reduce_scatter_shard(g, mesh, axis: str = "dp",
 
     return shard_map(local, mesh=mesh, in_specs=PartitionSpec(),
                      out_specs=PartitionSpec(axis),
-                     check_rep=False)(g)
+                     check_vma=False)(g)
 
 
 def reduce_scatter_shard_q8(g, residual, mesh, axis: str = "dp",
@@ -407,7 +407,7 @@ def reduce_scatter_shard_q8(g, residual, mesh, axis: str = "dp",
     return shard_map(local, mesh=mesh,
                      in_specs=(PartitionSpec(), PartitionSpec()),
                      out_specs=(PartitionSpec(axis), PartitionSpec()),
-                     check_rep=False)(g, residual)
+                     check_vma=False)(g, residual)
 
 
 def all_gather_params(p_shard, mesh, axis: str = "dp"):
@@ -423,7 +423,7 @@ def all_gather_params(p_shard, mesh, axis: str = "dp"):
 
     return shard_map(local, mesh=mesh, in_specs=PartitionSpec(axis),
                      out_specs=PartitionSpec(),
-                     check_rep=False)(p_shard)
+                     check_vma=False)(p_shard)
 
 
 def all_gather_params_q8(p_shard, residual, mesh, axis: str = "dp", *,
@@ -455,7 +455,7 @@ def all_gather_params_q8(p_shard, residual, mesh, axis: str = "dp", *,
     return shard_map(local, mesh=mesh,
                      in_specs=(PartitionSpec(axis), PartitionSpec(axis)),
                      out_specs=(PartitionSpec(), PartitionSpec(axis)),
-                     check_rep=False)(p_shard, residual)
+                     check_vma=False)(p_shard, residual)
 
 
 # ---------------------------------------------------------------------------

@@ -126,7 +126,7 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, mesh=None, axis="ep",
     pressure they legitimately differ. Size capacity_factor for the
     no-drop regime or accept shard-local dropping, as on any ep
     pod."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     if top_k not in (1, 2):
         raise ValueError("top_k must be 1 (Switch) or 2 (GShard), "
@@ -173,7 +173,7 @@ def moe_ffn(x, gate_w, w1, b1, w2, b2, *, mesh=None, axis="ep",
         body, mesh=mesh,
         in_specs=(tok, PartitionSpec(), exp, exp, exp, exp),
         out_specs=(tok, PartitionSpec()),
-        check_rep=False)
+        check_vma=False)
     return f(x, gate_w, w1, b1, w2, b2)
 
 

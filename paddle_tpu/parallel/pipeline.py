@@ -49,7 +49,7 @@ def gpipe_apply(stage_fn, stacked_params, x, *, mesh=None, axis="pp",
     shard_map in_specs). x [B, ...]: the global batch; it is split
     into n_micro microbatches along axis 0 (B % n_micro == 0).
     Returns stage_fn applied through all P stages, [B, ...]."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = mesh or mesh_lib.current_mesh()
     n_params = jax.tree_util.tree_leaves(stacked_params)[0].shape[0]
@@ -97,6 +97,6 @@ def gpipe_apply(stage_fn, stacked_params, x, *, mesh=None, axis="pp",
         body, mesh=mesh,
         in_specs=(p_spec, PartitionSpec()),
         out_specs=PartitionSpec(axis),
-        check_rep=False)
+        check_vma=False)
     out = f(stacked_params, x_micro)          # [P, M, b, ...]
     return out[0].reshape((B,) + x.shape[1:])

@@ -156,7 +156,7 @@ def ring_attention(q, k, v, mesh=None, axis="sp", scale=1.0,
     shard_map in_specs place them on the sp axis). use_flash:
     None = auto (pallas hop kernels when the geometry fits and
     FLAGS.ring_flash is on); False forces the jnp body."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from ..core.flags import FLAGS
     from ..ops.pallas import ring as R
@@ -188,7 +188,7 @@ def ring_attention(q, k, v, mesh=None, axis="sp", scale=1.0,
                                  causal=causal)
     f = shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False)
+        check_vma=False)
     return f(q, k, v)
 
 

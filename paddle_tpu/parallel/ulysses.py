@@ -117,7 +117,7 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", scale=1.0,
     across the axis, exactly once per device, so the per-head math is
     identical to full attention. Falls back to plain fused attention
     when no sp axis is in scope (same contract as ring_attention)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = mesh or mesh_lib.current_mesh()
     if mesh is None or axis not in mesh.axis_names \
@@ -132,12 +132,12 @@ def ulysses_attention(q, k, v, mesh=None, axis="sp", scale=1.0,
                              scale=scale, causal=causal)
     if bias is None:
         f = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                      out_specs=spec, check_rep=False)
+                      out_specs=spec, check_vma=False)
         return f(q, k, v)
     bias = lax.stop_gradient(bias)
     f = shard_map(body, mesh=mesh,
                   in_specs=(spec, spec, spec, PartitionSpec()),
-                  out_specs=spec, check_rep=False)
+                  out_specs=spec, check_vma=False)
     return f(q, k, v, bias)
 
 

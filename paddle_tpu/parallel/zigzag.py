@@ -357,7 +357,7 @@ def zigzag_attention(q, k, v, mesh=None, axis="sp", scale=1.0,
     internal. S must divide by 2*sp. use_flash: None = auto (pallas
     chunk-pair kernels when the geometry fits and FLAGS.ring_flash is
     on); False forces the jnp body."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from ..core.flags import FLAGS
     from ..ops.pallas import ring as R
@@ -392,7 +392,7 @@ def zigzag_attention(q, k, v, mesh=None, axis="sp", scale=1.0,
                                           n_blocks=n, scale=scale)
 
     f = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                  out_specs=spec, check_rep=False)
+                  out_specs=spec, check_vma=False)
     out = f(qz, kz, vz)
     return jnp.take(out, inv, axis=2)
 

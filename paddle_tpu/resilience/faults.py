@@ -35,7 +35,7 @@ import numpy as np
 class InjectedDispatchError(ConnectionError):
     """Stand-in for a transient PJRT dispatch/transfer failure (the
     retry classifier treats it as transient by type AND by its
-    UNAVAILABLE message, mirroring the real tunneled-backend error)."""
+    UNAVAILABLE message, the status a real PJRT failure carries)."""
 
 
 class SimulatedCrash(RuntimeError):

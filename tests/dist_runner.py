@@ -13,7 +13,7 @@ import os
 import sys
 
 if __name__ == "__main__":
-    # subprocess mode: claim a single CPU device before any jax import
+    # subprocess mode: one CPU device, set before any jax import
     # (paddle imports are lazy inside the run_* functions, so this is
     # early enough). Guarded so importing this module for its helpers
     # (test_fleet.py, __graft_entry__._dryrun_ps) does NOT mutate the

@@ -1,0 +1,368 @@
+"""Every registered Pallas variant, COMPILED by Mosaic on the chip at
+the shapes transformer-base presents (B=64, H=8, S=256, Dh=64,
+N=B*S=16384 rows, d_model 512, vocab 30,000), S=1024 for the blocked
+flash path and the per-hop shapes for ops/pallas/ring.py — each against
+its jnp reference at the tolerance tests/test_pallas_kernels.py uses
+for that kernel (bf16 inputs: bf16 tolerance).
+
+The CPU suite only ever runs these kernels with ``interpret=True`` and
+cannot reach the in-kernel PRNG at all; these checks need the real
+backend and skip anywhere else:
+
+    PADDLE_TPU_CHIP_TESTS=1 python -m pytest tests/test_chip_kernels.py -m chip
+"""
+
+import contextlib
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu import ops
+from paddle_tpu.ops.pallas import attention as A
+from paddle_tpu.ops.pallas import ring as R
+
+pytestmark = [
+    pytest.mark.chip,
+    pytest.mark.skipif(jax.default_backend() != "tpu",
+                       reason="needs a TPU: Mosaic compiles only there"),
+]
+
+B, H, S, DH = 64, 8, 256, 64       # transformer-base attention
+N, D, V = B * S, 512, 30000        # rows, d_model, vocab
+F32 = dict(rtol=5e-5, atol=1e-5)
+F32_GRAD = dict(rtol=5e-4, atol=5e-5)
+BF16 = dict(rtol=2e-2, atol=2e-2)
+
+
+def _exact_if_f32(dtype):
+    """f32 comparisons want exact f32 matmuls in kernel and reference
+    alike; bf16 runs as training does — default MXU precision (Mosaic
+    refuses fp32 contract precision on bf16 operands)."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return jax.default_matmul_precision("highest")
+    return contextlib.nullcontext()
+
+
+def _close(got, want, rtol, atol, atol_of_max=False):
+    """``atol_of_max``: atol is a share of each array's largest
+    magnitude — for gradients, whose small entries are differences of
+    large terms."""
+    got = jax.tree_util.tree_leaves(got)
+    want = jax.tree_util.tree_leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        w = np.asarray(w, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), w, rtol=rtol,
+            atol=atol * (np.abs(w).max() if atol_of_max else 1.0))
+
+
+def _qkv(seed, b, h, sq, sk, dtype):
+    r = np.random.RandomState(seed)
+    mk = lambda s: jnp.asarray(  # noqa: E731
+        r.randn(b, h, s, DH).astype(np.float32) * 0.5, dtype)
+    return mk(sq), mk(sk), mk(sk)
+
+
+def _pad_bias(seed, b, sq, sk):
+    """The models' additive pad mask: 0 / -1e9 per key, [b, 1, sq, sk]."""
+    r = np.random.RandomState(seed)
+    keep = r.rand(b, 1, 1, sk) > 0.15
+    keep[..., 0] = True
+    return jnp.broadcast_to(
+        jnp.asarray(np.where(keep, 0.0, -1e9).astype(np.float32)),
+        (b, 1, sq, sk))
+
+
+def _sdpa_fwd_and_grads(q, k, v, bias, causal, fwd_tol, grad_tol):
+    scale = DH ** -0.5
+    kw = dict(scale=scale, causal=causal)
+
+    def ref(q_, k_, v_):
+        return A._sdpa_reference(q_, k_, v_, bias, **kw)
+
+    def pal(q_, k_, v_):
+        return A.sdpa_pallas(q_, k_, v_, bias, is_test=True, **kw)
+
+    _close(jax.jit(pal)(q, k, v), jax.jit(ref)(q, k, v), **fwd_tol)
+    loss = lambda f: lambda *a: jnp.sum(  # noqa: E731
+        jnp.square(f(*a).astype(jnp.float32)))
+    gp = jax.jit(jax.grad(loss(pal), (0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss(ref), (0, 1, 2)))(q, k, v)
+    _close(gp, gr, **grad_tol)
+
+
+# -- the default path: single-k-block flash pair ---------------------------
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("dtype,fwd_tol,grad_tol", [
+    (jnp.bfloat16, BF16, dict(rtol=5e-2, atol=5e-2)),
+    (jnp.float32, F32, F32_GRAD)])
+def test_flash_1k_matches_reference(dtype, fwd_tol, grad_tol, causal):
+    assert A._1k_applicable(S, S)
+    b = B if dtype == jnp.bfloat16 else 8
+    q, k, v = _qkv(0, b, H, S, S, dtype)
+    with _exact_if_f32(dtype):
+        _sdpa_fwd_and_grads(q, k, v, _pad_bias(1, b, S, S), causal,
+                            fwd_tol, grad_tol)
+        _sdpa_fwd_and_grads(q, k, v, None, causal, fwd_tol, grad_tol)
+
+
+def _dropout_checks(sq, sk, b):
+    """What can be said about in-kernel dropout without the mask:
+    deterministic in the seed, different across seeds and grid cells,
+    the kept share near 1-rate, and the backward regenerating exactly
+    the forward's mask — out is linear in V, out = A(mask) V, so dV
+    must be A(mask)^T dOut with the SAME mask."""
+    rate, scale = 0.1, DH ** -0.5
+    q, k, v = _qkv(2, b, H, sq, sk, jnp.bfloat16)
+    seed = jnp.asarray([1234, 0], jnp.float32)
+
+    def fwd(q_, k_, v_, s=seed):
+        return A._sdpa_flash(q_, k_, v_, None, s, scale, rate, False)
+
+    out = jax.jit(fwd)(q, k, v)
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    _close(jax.jit(fwd)(q, k, v), out, rtol=0, atol=0)
+    other = jax.jit(lambda *a: fwd(
+        *a, s=jnp.asarray([99, 0], jnp.float32)))(q, k, v)
+    assert not np.array_equal(np.asarray(out, np.float32),
+                              np.asarray(other, np.float32))
+
+    # uniform probabilities over ones: each output is kept/(sk*(1-rate))
+    zeros = jnp.zeros_like(q)
+    ones = jnp.ones_like(v)
+    share = np.asarray(jax.jit(fwd)(zeros, jnp.zeros_like(k), ones),
+                       np.float32)[..., 0] * (1.0 - rate)
+    assert abs(share.mean() - (1.0 - rate)) < 5e-3, share.mean()
+    assert share.std() > 0.0
+    cells = share.reshape(-1, sq)
+    assert not np.array_equal(cells[0], cells[-1])
+
+    # adjoint identity without cancellation: with cotangent A v',
+    # <dV, v'> = <A^T A v', v'> = |A v'|^2
+    r = np.random.RandomState(3)
+    v2 = jnp.asarray(r.randn(*v.shape).astype(np.float32), jnp.bfloat16)
+    out2 = jax.jit(fwd)(q, k, v2)
+    _, pull = jax.vjp(fwd, q, k, v)
+    dq, dk, dv = jax.jit(pull)(out2)
+    for d in (dq, dk, dv):
+        assert bool(jnp.isfinite(d.astype(jnp.float32)).all())
+    lhs = float(jnp.vdot(dv.astype(jnp.float32), v2.astype(jnp.float32)))
+    rhs = float(jnp.sum(jnp.square(out2.astype(jnp.float32))))
+    assert rhs > 0 and abs(lhs - rhs) <= 2e-2 * rhs, (lhs, rhs)
+
+
+def test_flash_1k_dropout_prng():
+    """The exact configuration the model compiles 18 times: bf16,
+    b64 h8 S=256, dropout 0.1, in-kernel pltpu PRNG."""
+    _dropout_checks(S, S, B)
+
+
+# -- blocked online-softmax path (S=1024) ----------------------------------
+
+@pytest.mark.parametrize("dtype,fwd_tol,grad_tol", [
+    (jnp.bfloat16, BF16, dict(rtol=5e-2, atol=5e-2)),
+    (jnp.float32, F32, F32_GRAD)])
+def test_flash_blocked_matches_reference(dtype, fwd_tol, grad_tol):
+    s = 1024
+    assert not A._1k_applicable(s, s)
+    b = 16 if dtype == jnp.bfloat16 else 2
+    q, k, v = _qkv(4, b, H, s, s, dtype)
+    with _exact_if_f32(dtype):
+        _sdpa_fwd_and_grads(q, k, v, _pad_bias(5, b, s, s), True,
+                            fwd_tol, grad_tol)
+        _sdpa_fwd_and_grads(q, k, v, None, False, fwd_tol, grad_tol)
+
+
+def test_flash_blocked_dropout_prng():
+    _dropout_checks(1024, 1024, 4)
+
+
+# -- ring attention per-hop kernels ----------------------------------------
+
+@pytest.mark.parametrize("dtype,tol", [
+    (jnp.bfloat16, dict(rtol=5e-2, atol=5e-2)),
+    (jnp.float32, dict(rtol=5e-4, atol=2e-4))])
+@pytest.mark.parametrize("causal", [False, True])
+def test_ring_hop_matches_reference(dtype, tol, causal):
+    """One hop of S=1024 over sp=4: 256x256 blocks with global offsets
+    (q shard 2, k shard 1 — fully visible under the causal mask — and
+    the diagonal hop)."""
+    b, sq = 2, 256
+    assert R.applicable(b, H, sq, sq, DH, jnp.dtype(dtype).itemsize)
+    q, k, v = _qkv(6, b, H, sq, sq, dtype)
+    scale = DH ** -0.5
+    hops = ((2 * sq, sq), (sq, sq))
+    with _exact_if_f32(dtype):
+        for q_off, k_off in hops:
+            _ring_hop(q, k, v, q_off, k_off, scale, causal, dtype, tol)
+
+
+def _ring_hop(q, k, v, q_off, k_off, scale, causal, dtype, tol):
+    """One hop equals plain attention over that block pair: partials
+    against jnp, gradients against jnp's vjp fed the same lse/delta."""
+    b, sq = q.shape[0], q.shape[2]
+
+    def ref_parts(q_, k_, v_):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q_.astype(jnp.float32),
+                       k_.astype(jnp.float32)) * scale
+        if causal:
+            qp = q_off + jnp.arange(sq)[:, None]
+            kp = k_off + jnp.arange(sq)[None, :]
+            s = jnp.where(kp <= qp, s, -1e30)
+        m = jnp.max(s, -1)
+        p = jnp.exp(s - m[..., None])
+        return (jnp.einsum("bhqk,bhkd->bhqd", p,
+                           v_.astype(jnp.float32)), m,
+                jnp.sum(p, -1))
+
+    pv, m, l = jax.jit(
+        lambda *a: R.fwd_block(*a, q_off, k_off, scale, causal))(
+            q, k, v)
+    pv_r, m_r, l_r = jax.jit(ref_parts)(q, k, v)
+    _close((pv, m, l), (pv_r, m_r, l_r), **tol)
+
+    def out_of(q_, k_, v_):
+        pv_, _m, l_ = ref_parts(q_, k_, v_)
+        return pv_ / l_[..., None]
+
+    r = np.random.RandomState(7)
+    do = jnp.asarray(r.randn(b, H, sq, DH).astype(np.float32), dtype)
+    out, pull = jax.vjp(out_of, q, k, v)
+    want = pull(do.astype(jnp.float32))
+    lse = m_r + jnp.log(l_r)
+    delta = jnp.sum(do.astype(jnp.float32) * out, -1)
+    got = jax.jit(lambda *a: R.bwd_block(
+        *a, q_off, k_off, scale, causal))(q, k, v, do, lse, delta)
+    _close(got, [w.astype(jnp.float32) for w in want], **tol)
+
+
+@pytest.mark.skipif(jax.device_count() < 4, reason="needs four chips")
+@pytest.mark.parametrize("causal", [False, True])
+def test_ring_attention_flash_over_four_chips(causal):
+    """The hop kernels inside the real ring: S=1024 over an sp=4 mesh,
+    ppermute over ICI, against full attention."""
+    from paddle_tpu.parallel import mesh as mesh_lib
+    from paddle_tpu.parallel.ring_attention import ring_attention
+    from paddle_tpu.parallel.ulysses import _full_attention
+
+    mesh = mesh_lib.make_mesh({"sp": 4}, jax.devices()[:4])
+    q, k, v = _qkv(8, 2, H, 1024, 1024, jnp.float32)
+    scale = DH ** -0.5
+    loss = lambda f: lambda *a: jnp.sum(jnp.square(f(*a)))  # noqa: E731
+    with _exact_if_f32(jnp.float32):
+        want = _full_attention(q, k, v, scale, causal)
+        got = ring_attention(q, k, v, mesh=mesh, scale=scale,
+                             causal=causal, use_flash=True)
+        _close(got, want, **F32_GRAD)
+        gw = jax.grad(loss(lambda *a: _full_attention(
+            *a, scale, causal)), (0, 1, 2))(q, k, v)
+        gg = jax.grad(loss(lambda *a: ring_attention(
+            *a, mesh=mesh, scale=scale, causal=causal, use_flash=True)),
+            (0, 1, 2))(q, k, v)
+        _close(gg, gw, rtol=2e-3, atol=2e-4)
+
+
+@pytest.mark.skipif(jax.device_count() < 4, reason="needs four chips")
+def test_flash_dropout_over_dp_mesh_equals_one_chip():
+    """Under a dp=4 mesh the kernel runs per shard (shard_map) with
+    its dropout cells numbered where one chip numbers them: the same
+    masks, so outputs and gradients equal the one-chip call's."""
+    from paddle_tpu.parallel import mesh as mesh_lib
+
+    q, k, v = _qkv(9, B, H, S, S, jnp.bfloat16)
+    bias = _pad_bias(10, B, 1, S)
+    key = jax.random.key(5)
+
+    def loss(q_, k_, v_):
+        out = A.sdpa_pallas(q_, k_, v_, bias, scale=DH ** -0.5,
+                            dropout_rate=0.1, rng=key)
+        return jnp.sum(jnp.square(out.astype(jnp.float32))), out
+
+    fn = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)
+    want = jax.jit(fn)(q, k, v)
+    mesh = mesh_lib.make_mesh({"dp": 4}, jax.devices()[:4])
+    with mesh_lib.mesh_guard(mesh):
+        got = jax.jit(fn)(q, k, v)
+    _close(got[0][1], want[0][1], rtol=0, atol=0)      # out, bitwise
+    _close(got[1], want[1], rtol=0, atol=0)            # dq, dk, dv
+    _close(got[0][0], want[0][0], rtol=1e-5, atol=0)   # the reduction
+
+
+# -- the other registered variants -----------------------------------------
+
+def _cmp_variant(op_type, args, kwargs, **tol):
+    opdef = ops.get(op_type)
+    _close(jax.jit(lambda *a: opdef.variants["pallas"](*a, **kwargs))(
+        *args), jax.jit(lambda *a: opdef.fn(*a, **kwargs))(*args), **tol)
+
+
+@pytest.mark.parametrize("dtype,tol,gtol", [
+    (jnp.bfloat16, BF16, dict(rtol=5e-2, atol=5e-3, atol_of_max=True)),
+    (jnp.float32, dict(rtol=1e-4, atol=1e-5),
+     dict(rtol=1e-3, atol=1e-5, atol_of_max=True))])
+def test_layer_norm_variant(dtype, tol, gtol):
+    r = np.random.RandomState(10)
+    x = jnp.asarray(r.randn(B, S, D).astype(np.float32), dtype)
+    scale = jnp.asarray(r.rand(D).astype(np.float32) + 0.5)
+    bias = jnp.asarray(r.randn(D).astype(np.float32))
+    kw = {"epsilon": 1e-5, "begin_norm_axis": 2}
+    _cmp_variant("layer_norm", (x, scale, bias), kw, **tol)
+    opdef = ops.get("layer_norm")
+    loss = lambda f: lambda *a: jnp.sum(  # noqa: E731
+        jnp.square(f(*a, **kw)[0].astype(jnp.float32)))
+    _close(jax.jit(jax.grad(loss(opdef.variants["pallas"]), (0, 1, 2)))(
+        x, scale, bias),
+        jax.jit(jax.grad(loss(opdef.fn), (0, 1, 2)))(x, scale, bias),
+        **gtol)
+
+
+def test_softmax_xent_variant():
+    r = np.random.RandomState(11)
+    logits = jnp.asarray(r.randn(N, V).astype(np.float32))
+    label = jnp.asarray(r.randint(0, V, (N, 1)).astype(np.int32))
+    _cmp_variant("softmax_with_cross_entropy", (logits, label), {},
+                 rtol=1e-5, atol=1e-6)
+    opdef = ops.get("softmax_with_cross_entropy")
+    gp = jax.jit(jax.grad(lambda lg: jnp.sum(
+        opdef.variants["pallas"](lg, label)[1])))(logits)
+    gr = jax.jit(jax.grad(lambda lg: jnp.sum(
+        opdef.fn(lg, label)[1])))(logits)
+    _close(gp, gr, rtol=2e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype,tol,gtol", [
+    (jnp.bfloat16, dict(rtol=5e-2, atol=5e-2),
+     dict(rtol=5e-2, atol=5e-2)),
+    (jnp.float32, dict(rtol=2e-5, atol=2e-5),
+     dict(rtol=2e-4, atol=2e-5))])
+def test_fused_linear_xent_variant(dtype, tol, gtol):
+    r = np.random.RandomState(12)
+    x = jnp.asarray(r.randn(N, D).astype(np.float32) * 0.5, dtype)
+    w = jnp.asarray(r.randn(D, V).astype(np.float32) * 0.05, dtype)
+    lab = jnp.asarray(r.randint(0, V, (N, 1)).astype(np.int32))
+    kw = {"epsilon": 0.1}
+    opdef = ops.get("fused_linear_xent")
+    loss = lambda f: lambda a, b: jnp.mean(f(a, b, lab, **kw))  # noqa
+    with _exact_if_f32(dtype):
+        _cmp_variant("fused_linear_xent", (x, w, lab), kw, **tol)
+        _close(jax.jit(jax.grad(loss(opdef.variants["pallas"]),
+                                (0, 1)))(x, w),
+               jax.jit(jax.grad(loss(opdef.fn), (0, 1)))(x, w), **gtol)
+
+
+@pytest.mark.parametrize("shape", [(V, D), (D,), (37, 13)])
+def test_fused_adam_variant(shape):
+    r = np.random.RandomState(13)
+    mk = lambda s=1.0: jnp.asarray(  # noqa: E731
+        r.randn(*shape).astype(np.float32) * s)
+    args = (mk(), mk(), mk(0.1), jnp.abs(mk(0.1)), jnp.float32(0.9),
+            jnp.float32(0.999), jnp.float32(1e-3))
+    _cmp_variant("adam", args,
+                 {"beta1": 0.9, "beta2": 0.999, "epsilon": 1e-8},
+                 rtol=1e-6, atol=1e-7)

@@ -117,6 +117,58 @@ class TestCompileCacheStore:
 # provenance ledger: miss reasons, metrics, telemetry
 # ---------------------------------------------------------------------------
 
+class TestResolver:
+    """``enable()`` is the one place persistent compile state is
+    placed: JAX's cache and the executable store under one root that
+    can be set from outside."""
+
+    @pytest.fixture
+    def jax_updates(self, monkeypatch):
+        calls = []
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: calls.append((k, v)))
+        return calls
+
+    def test_env_places_both_and_jax_is_not_overridden(
+            self, tmp_path, monkeypatch, jax_updates):
+        monkeypatch.setenv(cc.JAX_ENV_DIR, str(tmp_path))
+        assert cc.enable() == str(tmp_path)
+        assert jax_updates == []
+        assert cc.active().dir == str(tmp_path / cc.STORE_SUBDIR)
+
+    def test_unset_is_the_fixed_path_in_the_checkout(
+            self, monkeypatch, jax_updates):
+        monkeypatch.delenv(cc.JAX_ENV_DIR, raising=False)
+        root = os.path.join(ROOT, ".jax_cache")
+        assert cc.enable() == root
+        assert [v for _k, v in jax_updates] == [root]
+        assert cc.active().dir == os.path.join(root, cc.STORE_SUBDIR)
+
+    def test_explicit_dir_wins_over_env(self, tmp_path, monkeypatch,
+                                        jax_updates):
+        monkeypatch.setenv(cc.JAX_ENV_DIR, str(tmp_path / "env"))
+        want = str(tmp_path / "explicit")
+        assert cc.enable(want) == want
+        assert [v for _k, v in jax_updates] == [want]
+        assert cc.active().dir == os.path.join(want, cc.STORE_SUBDIR)
+
+    def test_one_call_site_sets_jax_cache_dir(self):
+        """No entry point, tool or library module places JAX's cache
+        on its own."""
+        name = "jax_compilation_" + "cache_dir"
+        hits = []
+        for base, dirs, files in os.walk(ROOT):
+            dirs[:] = [d for d in dirs if not d.startswith(".")
+                       and d != "chiprun_out"]
+            for f in files:
+                if f.endswith(".py"):
+                    path = os.path.join(base, f)
+                    with open(path) as fh:
+                        if name in fh.read():
+                            hits.append(os.path.relpath(path, ROOT))
+        assert hits == [os.path.join("paddle_tpu", "compile_cache.py")]
+
+
 class TestProvenanceLedger:
     def _events(self, mark):
         return obs.journal_events(kind="executor_compile",

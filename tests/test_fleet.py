@@ -345,13 +345,20 @@ class TestLaunchModule:
 
     def test_compile_cache_env_contract(self, monkeypatch):
         """Every role's env carries ONE shared
-        PADDLE_TPU_COMPILE_CACHE_DIR (the ROADMAP compile-plane
-        follow-up: real fleets share a persistent AOT cache by
-        default), resolved journal-dir > user-cache, explicit flag
+        PADDLE_TPU_COMPILE_CACHE_DIR (real fleets share a persistent
+        AOT cache by default), placed by compile_cache's one resolver
+        — under JAX_COMPILATION_CACHE_DIR if set, else the checkout's
+        .jax_cache/, never a home or journal directory; explicit flag
         wins, empty string opts out."""
+        from paddle_tpu import compile_cache
         from paddle_tpu.distributed import launch as L
         monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE_DIR",
                            raising=False)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(
+            os.path.abspath(L.__file__)))
+        in_checkout = os.path.join(os.path.dirname(repo), ".jax_cache",
+                                   compile_cache.STORE_SUBDIR)
 
         args = L._parse_args(["--nproc_per_node=2",
                               "--server_num=1",
@@ -361,13 +368,15 @@ class TestLaunchModule:
                 + L.get_serving_env(args))
         assert len(envs) == 4
         dirs = {e["PADDLE_TPU_COMPILE_CACHE_DIR"] for e in envs}
-        assert dirs == {os.path.join("/tmp/jd", "compile_cache")}
+        assert dirs == {in_checkout}
 
-        # no journal/log dir: one stable per-user location
+        # placed from outside: the store follows JAX's own cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/tmp/outside")
         args = L._parse_args(["t.py"])
-        env = L.get_cluster_env(args)[0]
-        assert env["PADDLE_TPU_COMPILE_CACHE_DIR"].endswith(
-            os.path.join(".cache", "paddle_tpu", "compile_cache"))
+        assert L.get_cluster_env(args)[0][
+            "PADDLE_TPU_COMPILE_CACHE_DIR"] == os.path.join(
+                "/tmp/outside", compile_cache.STORE_SUBDIR)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
 
         # explicit flag wins over journal dir; "" opts out by
         # stamping an EMPTY value (children inherit the launcher's

@@ -665,14 +665,22 @@ class TestChaosDoctor:
 # ---------------------------------------------------------------------------
 
 class TestBenchDiff:
-    def test_hang_flagged_on_repo_history(self):
-        """The repo's own BENCH_r01..r05 artifacts: the transformer
-        headline measured 65.8k in r1 and degraded to claim-timeout
-        nulls — bench_diff must flag the value->null transition as
-        HANG, loudly."""
+    def test_hang_flagged_on_value_to_null(self, tmp_path):
+        """A headline that measured in round 1 and came back null with
+        an error in every later round — bench_diff must flag the
+        value->null transition as HANG, loudly."""
         import bench_diff
-        files = [os.path.join(ROOT, "BENCH_r%02d.json" % n)
-                 for n in range(1, 6)]
+        files = []
+        for n in range(1, 4):
+            row = {"metric": "transformer_base_train_throughput",
+                   "unit": "tokens/sec/chip",
+                   "value": 65804.0 if n == 1 else None}
+            if n > 1:
+                row["error"] = "watchdog: run exceeded its budget"
+            f = tmp_path / ("BENCH_r%02d.json" % n)
+            f.write_text(json.dumps({"n": n, "rc": 0, "parsed": row,
+                                     "tail": json.dumps(row) + "\n"}))
+            files.append(str(f))
         report = bench_diff.diff(bench_diff.load_rounds(files))
         hangs = [f for f in report["hangs"]
                  if f["metric"] == "transformer_base_train_throughput"]

@@ -299,23 +299,33 @@ def test_fused_linear_xent_3d_and_bf16():
                                rtol=0.05, atol=0.05)
 
 
-def test_attention_dropout_grouping_consistent():
-    """The dropout mask is seeded per grid CELL, so forward and
-    backward must group (batch, head) rows into cells identically
-    whenever dropout is on (round-4 review: a fwd G=8 / bwd G=4 split
-    at f32 regenerated different masks for heads the groupings
-    assigned to different cells — silently wrong gradients)."""
-    from paddle_tpu.ops.pallas.attention import _bwd_G, _pick_G
+@pytest.mark.parametrize("S,dtype", [(256, "float32"),
+                                     (1024, "bfloat16")])
+def test_attention_dropout_grouping_consistent(monkeypatch, S, dtype):
+    """The dropout mask is seeded per grid CELL, so with dropout on the
+    forward and every backward kernel must group (batch, head) rows
+    into cells identically — single-k-block (S=256) and blocked
+    (S=1024) paths alike. A fwd G=8 / bwd G=4 split regenerates
+    different masks for heads the groupings assign to different cells:
+    silently wrong gradients."""
+    from test_pallas_vmem import _capture_calls
 
-    for H in (1, 2, 4, 8, 16):
-        for itemsize in (2, 4):
-            for rate in (0.0, 0.1, 0.5):
-                fwd_G = _pick_G(H, itemsize, rate)
-                bwd_G = _bwd_G(H, itemsize)
-                if rate > 0.0:
-                    assert fwd_G == bwd_G, (H, itemsize, rate)
-                # and the backward grouping always fits scoped VMEM
-                assert bwd_G <= (8 if itemsize <= 2 else 4)
+    from paddle_tpu.ops.pallas import attention as A
+
+    monkeypatch.setattr(A, "interpret_mode", lambda: False)
+    q = jnp.zeros((2, 8, S, 64), dtype)
+    var = ops.get("scaled_dot_product_attention").variants["pallas"]
+
+    def fwd_bwd():
+        jax.grad(lambda q_, k_, v_: jnp.sum(var(
+            q_, k_, v_, None, dropout_rate=0.1,
+            rng=jax.random.key(0)).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, q, q)
+
+    calls = _capture_calls(fwd_bwd)
+    assert len(calls) >= 2                     # fwd + bwd kernel(s)
+    assert len({c["grid"][0] for c in calls}) == 1, \
+        [c["grid"] for c in calls]
 
 
 def test_sdpa_auto_flash_dispatch_envelope(monkeypatch):
@@ -358,3 +368,55 @@ def test_sdpa_auto_flash_dispatch_envelope(monkeypatch):
     assert not run(rate=0.0)                  # no dropout: stays XLA
     assert not run(S=1024)                    # blocked shapes: XLA
     assert not run(auto=False)                # flag off: stays XLA
+
+
+def test_sdpa_auto_flash_failure_propagates(monkeypatch):
+    """Inside the envelope the kernel is THE lowering: when it fails
+    (a Mosaic compile error on the chip) the op fails — nothing
+    catches it and carries on with the jnp reference."""
+    from paddle_tpu.ops.pallas import attention as A
+
+    class KernelRefused(RuntimeError):
+        pass
+
+    def refuse(*a, **kw):
+        raise KernelRefused("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(A, "interpret_mode", lambda: False)
+    monkeypatch.setattr(A, "_flash_fwd_1k", refuse)
+    q = jnp.full((2, 4, 256, 64), 0.1, jnp.bfloat16)
+    with pytest.raises(KernelRefused):
+        A.scaled_dot_product_attention(
+            q, q, q, None, scale=0.125, dropout_rate=0.1,
+            rng=jax.random.key(0))
+
+
+@pytest.mark.parametrize("axes", [{"dp": 4}, {"dp": 2, "tp": 2}])
+def test_sdpa_pallas_under_mesh_runs_per_shard(axes):
+    """Under a multi-device mesh the kernel runs per shard (Mosaic
+    kernels are not auto-partitioned): batch over dp, heads over tp,
+    the pad bias following the batch — same values and gradients as
+    the unsharded call."""
+    from paddle_tpu.ops.pallas import attention as A
+    from paddle_tpu.parallel import mesh as mesh_lib
+
+    r = np.random.RandomState(14)
+    B, H, S, Dh = 4, 4, 128, 16
+    q, k, v = (jnp.asarray(r.randn(B, H, S, Dh).astype(np.float32))
+               for _ in range(3))
+    bias = jnp.asarray(np.where(r.rand(B, 1, 1, S) > 0.2, 0.0, -1e9)
+                       .astype(np.float32))
+
+    def loss(q_, k_, v_):
+        return jnp.sum(jnp.square(A.sdpa_pallas(
+            q_, k_, v_, bias, scale=0.25, causal=True, is_test=True)))
+
+    want = jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
+    n = int(np.prod(list(axes.values())))
+    with mesh_lib.mesh_guard(mesh_lib.make_mesh(axes,
+                                                jax.devices()[:n])):
+        got = jax.jit(jax.value_and_grad(loss, (0, 1, 2)))(q, k, v)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=2e-5, atol=2e-5)

@@ -1,7 +1,7 @@
 """Scoped-VMEM footprint audit for the pallas kernel library.
 
 CPU-testable analog of the TPU compiler's scoped-VMEM check (16 MB on
-v5e): round 4's first on-chip window rejected the fused vocab-xent
+v5e): an early on-chip run rejected the fused vocab-xent
 kernel with "Scoped allocation with size 32.00M ... exceeded scoped
 vmem limit by 16.00M" — its full-length ``[N, 1]`` f32 stats/outputs
 are lane-padded 128x by the (8, 128) VMEM tile. That failure class is
@@ -241,7 +241,7 @@ def test_1k_headline_geometry_pinned():
     assert A._1k_fwd_G(8, 2, 0.1, 256, 256, 64) == 8
     assert A._1k_bwd_G(8, 2, 256, 256, 64) == 8
     # the known f32 constraint: backward needs G=4 at the flagship
-    # shape (pre-existing _bwd_G contract, now reproduced by the model)
+    # shape
     assert A._1k_bwd_G(8, 4, 256, 256, 64) == 4
     # the ADVICE r4 corner: bf16 Sq=256/Sk=512 must NOT run at G=8
     assert A._1k_bwd_G(8, 2, 256, 512, 64) <= 4

@@ -3,11 +3,10 @@
 with loud regression/hang flags — the OFFLINE complement to the
 watchdog's online hang detection.
 
-The repo's own history motivates this: BENCH_r01 measured 65.8k
-tokens/s/chip, and by r05 the same row had silently degraded into a
-240 s "backend hang" claim-timeout null. A value -> null transition is
-exactly the failure a human scanning JSON blobs misses — this tool
-calls it out as ``HANG`` and exits nonzero under ``--strict``.
+A row that measured in one round and silently came back null with a
+timeout error in the next is exactly the failure a human scanning JSON
+blobs misses — this tool calls a value -> null transition out as
+``HANG`` and exits nonzero under ``--strict``.
 
 Each artifact is the driver's wrapper shape ``{"n", "cmd", "rc",
 "tail", "parsed"}``: every JSON line in ``tail`` is one metric row

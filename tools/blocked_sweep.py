@@ -1,7 +1,7 @@
 """On-chip block-size sweep for the BLOCKED flash attention path at
-long sequence (VERDICT r4 #4a: the blocked online-softmax kernels have
-never been in-model measured, and their 256/512 tiles were chosen at
-S=256 scale).
+long sequence (the blocked online-softmax kernels have never been
+in-model measured, and their 256/512 tiles were chosen at S=256
+scale).
 
     python tools/blocked_sweep.py            # default tile grid
     python tools/blocked_sweep.py 256:512 128:512 256:1024

@@ -10,8 +10,8 @@ Run on the real chip:
 
 Each row times the base XLA lowering against the pallas variant
 through the real executor (fwd+bwd where differentiable) at the
-transformer-base flagship shape, and verdicts win/lose. Paste the
-table into BASELINE.md and demote any loser from the default mix.
+transformer-base flagship shape, and verdicts win/lose (op level
+only: ROADMAP D3 decides from in-model rows).
 """
 
 from __future__ import annotations
@@ -90,10 +90,9 @@ def main(argv=None):
     from op_bench import bench_op
 
     def emit(r):
-        # stream each row the moment it's measured: a wedged compile
-        # (observed on-chip round 4: one bad variant hung the remote
-        # compile helper 800s) then costs only the tail of the table,
-        # never the rows already on stdout
+        # stream each row the moment it's measured: a compile that
+        # never ends then costs only the tail of the table, never the
+        # rows already on stdout
         if args.json:
             print(json.dumps(r), flush=True)
         elif "error" in r:

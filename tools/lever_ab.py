@@ -1,8 +1,7 @@
-"""Round-4 perf-lever in-model A/B on the real chip.
+"""Perf-lever in-model A/B on the chip.
 
 Measures transformer-base b64 steps/s for each lever in isolation and
-combined, against the all-off baseline (the round-4 0.377-MFU
-configuration). One fresh program + Executor per config: the executor
+combined, against the all-off baseline. One fresh program + Executor per config: the executor
 jit cache does not key on these trace-time flags.
 
     python tools/lever_ab.py            # all configs
@@ -18,24 +17,18 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import jax  # noqa: E402
 
-jax.config.update("jax_default_prng_impl", "rbg")
-jax.config.update("jax_compilation_cache_dir", os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
-
 import numpy as np  # noqa: E402
 
 import bench  # noqa: E402
+from paddle_tpu import compile_cache  # noqa: E402
 from paddle_tpu.core.flags import FLAGS  # noqa: E402
+
+jax.config.update("jax_default_prng_impl", "rbg")
+compile_cache.enable()
 
 LEVERS = ("lean_xent_grad", "mxu_bias_grad", "multi_tensor_adam",
           "mxu_ln_grad")
 
-# Reproduces the BASELINE.md round-4b table. The historical
-# "multi-tensor adam @ 1M threshold = 1.8 steps/s" row predates the
-# 64k-threshold fix; reproduce it by editing
-# executor._MULTI_ADAM_MAX_NUMEL back to 1 << 20.
 CONFIGS = [
     ("all-off(r4-baseline)", {}, ""),
     ("lean_xent", {"lean_xent_grad": True}, ""),
@@ -72,7 +65,7 @@ def main():
         FLAGS.op_library = mix
         t0 = time.time()
         try:
-            cfg, run, tokens = bench._build_transformer_step(64, 256)
+            cfg, run, tokens, _wire = bench._build_transformer_step(64, 256)
             sps = bench._timed_loop(run, 3, 25)
             mfu = bench._mfu(
                 bench.transformer_flops_per_step(cfg, 64), sps)

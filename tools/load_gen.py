@@ -347,15 +347,22 @@ def spawn_fleet(model_dir, n_replicas, max_batch=32, wait_us=2000,
     child announces ``REPLICA_READY <endpoint>`` on stdout before the
     router is built, so a returned router is immediately usable.
 
+    Replicas are CPU processes by request (``JAX_PLATFORMS=cpu`` in
+    their env): a chip belongs to one process, and this parent may
+    hold it — N replicas on N chips of one process is ROADMAP S9/R8.
+    On a v5e host the parent built the model on the chip and two CPU
+    replicas served 1,617 requests with none failed (chip run, PR 21).
+
     Every replica is stamped with ONE shared persistent compile-cache
     dir (PADDLE_TPU_COMPILE_CACHE_DIR; ROADMAP compile-plane
     follow-up): replica 0's warmup compiles are replicas 1..N's cache
     loads, and a respawned fleet cold-starts with zero XLA compiles.
     ``compile_cache_dir``: explicit dir, or "" to disable stamping;
-    default resolves like launch.py (env var, else the per-user
-    cache location). ``journal_dir``: stamp each replica with its OWN
-    event-journal file + blackbox dir (``events.serving-<k>.jsonl``)
-    so per-replica ledger trails stay separable."""
+    default resolves like launch.py (env var, else
+    ``compile_cache.store_dir()``). ``journal_dir``: stamp each
+    replica with its OWN event-journal file + blackbox dir
+    (``events.serving-<k>.jsonl``) so per-replica ledger trails stay
+    separable."""
     from paddle_tpu.distributed.launch import default_compile_cache_dir
     from paddle_tpu.serving import RouterConfig, ServingRouter
 
