@@ -53,6 +53,13 @@ def _collect_stop_vars(block, no_grad_set) -> Set[str]:
     return stop
 
 
+def _scope_of(fwd) -> dict:
+    """A gradient op carries its forward op's name scope: a layer's
+    backward is charged to that layer (executor.run_block)."""
+    scope = fwd.attrs.get("op_namescope")
+    return {"op_namescope": scope} if scope else {}
+
+
 def _append_sparse_lookup_grad(block, fwd, stop_vars) -> bool:
     """Append a lookup_table_grad op producing a SparseRows table
     gradient (the SelectedRows path of lookup_table_op.cc). Returns
@@ -76,7 +83,7 @@ def _append_sparse_lookup_grad(block, fwd, stop_vars) -> bool:
         outputs={"WGrad": [gn]},
         attrs={"height": int(w.shape[0]),
                "padding_idx": fwd.attrs.get("padding_idx", -1),
-               "op_role": "backward"})
+               "op_role": "backward", **_scope_of(fwd)})
     return True
 
 
@@ -118,7 +125,8 @@ def append_backward(loss: Variable, parameter_list=None, no_grad_set=None,
         type="fill_constant",
         outputs={"Out": [loss_grad]},
         attrs={"shape": tuple(loss.shape), "dtype": loss.dtype,
-               "value": 1.0, "op_role": "backward"})
+               "value": 1.0, "op_role": "backward",
+               **_scope_of(block.ops[target_index])})
 
     # reverse walk, one vjp op per differentiable forward op
     for i in reversed(path):
@@ -184,6 +192,11 @@ def append_backward(loss: Variable, parameter_list=None, no_grad_set=None,
                     "differentiable lax.scan (op #%d)" % i)
 
         out_grad_inputs = [gname(n) for n in fwd.output_arg_names]
+        # the forward op's attrs parameterize its lowering again under
+        # jax.vjp; its name scope is not one of them but the gradient
+        # op's own
+        fwd_attrs = dict(fwd.attrs)
+        fwd_attrs.pop("op_namescope", None)
         block.append_op(
             type="vjp",
             inputs={"FwdIn": fwd.input_arg_names,
@@ -195,11 +208,12 @@ def append_backward(loss: Variable, parameter_list=None, no_grad_set=None,
                 "fwd_inputs": {k: list(v) for k, v in fwd.inputs.items()},
                 "fwd_outputs": {k: list(v)
                                 for k, v in fwd.outputs.items()},
-                "fwd_attrs": dict(fwd.attrs),
+                "fwd_attrs": fwd_attrs,
                 "fwd_op_index": i,
                 "no_grad_vars": tuple(sorted(stop_vars)),
                 "grad_suffix": grad_suffix,
                 "op_role": "backward",
+                **_scope_of(fwd),
             })
 
     # collect (param, grad) pairs

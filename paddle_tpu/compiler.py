@@ -317,7 +317,11 @@ class CompiledProgram:
                     self._verified_version = self.program._version
 
     def run(self, exe, feed, fetch_list, scope, return_numpy,
-            use_program_cache=True, validate_feed=True, donate=True):
+            use_program_cache=True, validate_feed=True, donate=True,
+            *, phases):
+        """Called by ``Executor.run``. ``phases``: its clock of this
+        entry-point call (executor._EntryPhases); what happens here is
+        the call's prepare."""
         from .core.scope import global_scope
         self._prepare_run(scope)
         # ops that are mesh-aware (ring_attention, sp/ep lowerings)
@@ -326,6 +330,6 @@ class CompiledProgram:
             return exe._run_impl(self.program, feed or {},
                                  fetch_list or [],
                                  scope or global_scope(), return_numpy,
-                                 dist=self, donate=donate,
+                                 phases, dist=self, donate=donate,
                                  use_program_cache=use_program_cache,
                                  validate_feed=validate_feed)

@@ -23,7 +23,8 @@ from __future__ import annotations
 
 from ... import layers
 from ...core.enforce import enforce
-from ...framework import default_main_program, default_startup_program
+from ...framework import (default_main_program,
+                          default_startup_program, name_scope)
 from .fp16_lists import AutoMixedPrecisionLists
 from .fp16_utils import rewrite_program
 
@@ -58,10 +59,14 @@ class OptimizerWithMixedPrecision:
         main = default_main_program()
         rewrite_program(main, self._amp_lists, self._dest_dtype)
 
-        self._loss_scaling = layers.create_global_var(
-            shape=[1], value=self._init_loss_scaling, dtype="float32",
-            persistable=True, name="loss_scaling_0")
-        scaled_loss = loss * self._loss_scaling
+        # the loss scaling and its update are, like the casts, AMP's
+        # own layer kind in a device trace (framework.name_scope)
+        with name_scope("amp"):
+            self._loss_scaling = layers.create_global_var(
+                shape=[1], value=self._init_loss_scaling,
+                dtype="float32", persistable=True,
+                name="loss_scaling_0")
+            scaled_loss = loss * self._loss_scaling
 
         params_grads = self._optimizer.backward(
             scaled_loss, startup_program, parameter_list, no_grad_set,
@@ -73,7 +78,7 @@ class OptimizerWithMixedPrecision:
         # clone keeping an isfinite(g) op would dangle on the pruned
         # gradient vars.
         from ...framework import op_role_guard
-        with op_role_guard(main, "optimize"):
+        with op_role_guard(main, "optimize"), name_scope("amp"):
             inv = 1.0 / self._loss_scaling
             if self._use_dynamic_loss_scaling:
                 finite = None
@@ -141,7 +146,8 @@ class OptimizerWithMixedPrecision:
                                       op_role_guard)
             # clip ops read gradient vars: optimize role, or a test
             # clone keeps them dangling (same guard as backward())
-            with op_role_guard(default_main_program(), "optimize"):
+            with op_role_guard(default_main_program(), "optimize"), \
+                    name_scope("clip"):
                 params_grads = append_gradient_clip_ops(params_grads,
                                                         grad_clip)
         optimize_ops = self.apply_gradients(params_grads)

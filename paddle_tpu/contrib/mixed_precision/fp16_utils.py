@@ -89,7 +89,9 @@ def rewrite_program(main_program, amp_lists, dest_dtype="bfloat16"):
                     block, "cast",
                     inputs={"X": [name]},
                     outputs={"Out": [cast_var.name]},
-                    attrs={"dtype": to_dtype}))
+                    attrs={"dtype": to_dtype,
+                           # AMP's own layer kind in a device trace
+                           "op_namescope": "/amp/"}))
                 cache[name] = cast_var.name
             return cache[name]
 

@@ -27,7 +27,6 @@ executor path.
 
 from __future__ import annotations
 
-import collections
 import contextlib
 import hashlib
 import os
@@ -163,6 +162,7 @@ class _VjpParts:
         fwd_type = a["fwd_type"]
         fwd_inputs: Dict[str, List[str]] = a["fwd_inputs"]
         fwd_attrs = dict(a["fwd_attrs"])
+        fwd_attrs.pop("op_namescope", None)
         fwd_index = a["fwd_op_index"]
         self.no_grad_set = set(a.get("no_grad_vars", ()))
         # which inputs participate in differentiation; a second-order
@@ -536,6 +536,29 @@ def _run_adam_group(ops_group, env, step_key, library):
         off += size
 
 
+_PHASE_OF_ROLE = {"backward": "bwd", "optimize": "opt"}
+# the one door every scope of this module goes through
+_named_scope = jax.named_scope
+
+
+def op_scope(op, op_type=None):
+    """``jax.named_scope("<phase>/<layer>/<op type>")`` for one op's
+    lowering: the phase from ``op_role`` (no role is forward; the loss
+    counts as forward, an LR schedule and AMP's scaling update carry
+    the optimize role), the layer kind from the innermost
+    ``op_namescope`` (``-`` where the model named none), and the Fluid
+    op type, for a gradient op its forward op's. The name survives
+    ``jax.vjp``, ``lax.scan`` and XLA's fusion into the optimized
+    HLO's ``op_name``, which is what ``profiler.scope_table`` charges a
+    device trace to. Metadata only: ``lowered.as_text()`` is the same
+    text with and without it."""
+    a = op.attrs
+    return _named_scope("%s/%s/%s" % (
+        _PHASE_OF_ROLE.get(a.get("op_role"), "fwd"),
+        framework.innermost_scope(a.get("op_namescope")),
+        op_type or a.get("fwd_type") or op.type))
+
+
 def run_block(block, env, step_key, library=None, grad_sync=None,
               anomaly_guard=None, pipeline=None):
     """Trace every op of a block into env (the analog of the reference's
@@ -589,21 +612,29 @@ def run_block(block, env, step_key, library=None, grad_sync=None,
                                          grad_sync.boundary)
     sync_end = getattr(grad_sync, "end_boundary", None) \
         if grad_sync is not None else None
+    guard_scope = _named_scope("opt/guard/all_finite")
+    sync_scope = _named_scope(
+        "sync/grad/%s" % getattr(grad_sync, "mode", "-"))
     for i, op in enumerate(block.ops):
         if anomaly_guard is not None and i == anomaly_guard.boundary:
-            anomaly_guard.pre_sync(env)
+            with guard_scope:
+                anomaly_guard.pre_sync(env)
         if grad_sync is not None and i == grad_sync.boundary:
-            grad_sync.apply(env)
+            with sync_scope:
+                grad_sync.apply(env)
         if anomaly_guard is not None \
                 and i == anomaly_guard.post_boundary:
-            anomaly_guard.post_sync(env)
+            with guard_scope:
+                anomaly_guard.post_sync(env)
         if sync_end is not None and i == sync_end:
             # sharded_update: every bracketed param has been written —
             # gather the fresh shards back to full params before
             # anything downstream (EMA, averaging, fetches) reads them
-            grad_sync.finish(env)
+            with sync_scope:
+                grad_sync.finish(env)
         if pipeline is not None and i == pipeline.region_start:
-            pipeline.execute(env, step_key, library=library)
+            with _named_scope("fwd/pipeline/schedule"):
+                pipeline.execute(env, step_key, library=library)
         if i in skip:
             continue
         if i in adam_groups:
@@ -611,8 +642,9 @@ def run_block(block, env, step_key, library=None, grad_sync=None,
             # _run_adam_group._in (a blanket KeyError catch here would
             # misattribute attr/slot lookups as missing variables)
             idxs = adam_groups[i]
-            _run_adam_group([(j, block.ops[j]) for j in idxs],
-                            env, step_key, library)
+            with op_scope(op, "adam"):
+                _run_adam_group([(j, block.ops[j]) for j in idxs],
+                                env, step_key, library)
             skip.update(idxs[1:])
             continue
         if op.type not in ("vjp", "vjp2") and not ops.has(op.type):
@@ -620,13 +652,14 @@ def run_block(block, env, step_key, library=None, grad_sync=None,
                 "op type %r (op #%d) has no registered lowering"
                 % (op.type, i))
         try:
-            if op.type == "vjp":
-                _run_vjp_op(op, env, step_key, library=library)
-            elif op.type == "vjp2":
-                _run_vjp2_op(op, env, step_key, library=library)
-            else:
-                run_op(op, env, step_key, i, library=library,
-                       snapshot=i in vjp_fwd_indices)
+            with op_scope(op):
+                if op.type == "vjp":
+                    _run_vjp_op(op, env, step_key, library=library)
+                elif op.type == "vjp2":
+                    _run_vjp2_op(op, env, step_key, library=library)
+                else:
+                    run_op(op, env, step_key, i, library=library,
+                           snapshot=i in vjp_fwd_indices)
         except KeyError as e:
             missing = e.args[0] if e.args else "?"
             var = block._find_var_recursive(missing) \
@@ -642,7 +675,8 @@ def run_block(block, env, step_key, library=None, grad_sync=None,
                 % (op.type, i, op, missing, hint)) from e
     if sync_end is not None and sync_end >= len(block.ops):
         # the update ops are the block's tail (the usual layout)
-        grad_sync.finish(env)
+        with sync_scope:
+            grad_sync.finish(env)
     return env
 
 
@@ -754,6 +788,73 @@ def _mesh_tag(mesh_fp) -> Optional[str]:
     return hashlib.sha1(repr(mesh_fp).encode()).hexdigest()[:12]
 
 
+class _EntryPhases:
+    """One entry-point call (``run`` / ``run_repeated`` / one
+    ``run_pipelined`` chunk) cut in three on the host clock, always on:
+
+      prepare   feed check, ``feed_h2d``, the persistables gathered
+                from the scope, signature and executable lookup (the
+                first call's build lies here), ``fold_in``
+      dispatch  the enqueue (``_note_dispatch``: what it always timed)
+      settle    write-back to the scope, fetch conversion
+
+    ``with _EntryPhases(exe) as phases`` opens ``executor_entry`` and
+    ``executor_prepare``; ``t0 = phases.dispatch()`` ends prepare;
+    ``phases.settle(t1)`` opens ``executor_settle`` at the dispatch's
+    end. A call that returns books entry / prepare / settle seconds
+    into ``Executor.telemetry()``; each boundary is a ``RecordEvent``
+    too, so a trace holds the same four spans. Five clock reads a
+    call: the three phases share their boundaries, and the entry is
+    read before its spans open, so entry less the three phases is the
+    entry's self time (opening the spans: microseconds) and never
+    negative by rounding."""
+
+    def __init__(self, exe):
+        self._exe = exe
+        self._t_dispatch = self._t_settle = None
+
+    def __enter__(self):
+        self._t_entry = time.perf_counter()
+        self._entry = _profiler.RecordEvent("executor_entry").__enter__()
+        self._phase = _profiler.RecordEvent(
+            "executor_prepare").__enter__()
+        self._t_prepare = time.perf_counter()
+        return self
+
+    def cancel(self):
+        """This call only loops over other entry-point calls, which
+        book themselves."""
+        self._t_entry = None
+
+    def dispatch(self):
+        self._phase.__exit__(None, None, None)
+        self._phase = None
+        self._t_dispatch = time.perf_counter()
+        return self._t_dispatch
+
+    def settle(self, t_dispatched):
+        self._t_settle = t_dispatched
+        self._phase = _profiler.RecordEvent(
+            "executor_settle").__enter__()
+
+    def __exit__(self, *exc):
+        if self._phase is not None:
+            self._phase.__exit__(*exc)
+        self._entry.__exit__(*exc)
+        if exc[0] is None and self._t_entry is not None \
+                and self._t_settle is not None:
+            end = time.perf_counter()
+            self._exe._note_entry(end - self._t_entry,
+                                  self._t_dispatch - self._t_prepare,
+                                  end - self._t_settle)
+        return False
+
+
+_BUILD_PHASES = ("trace_lower_seconds", "key_seconds",
+                 "store_load_seconds", "xla_compile_seconds",
+                 "store_put_seconds")
+
+
 class Executor:
     """Drop-in analog of fluid.Executor (executor.py:292).
 
@@ -830,10 +931,14 @@ class Executor:
         # telemetry: host-observed dispatch wall time (dispatch call ->
         # return; async PJRT dispatch means this is host-side cost plus
         # whatever backpressure the device applies, synced for real at
-        # readbacks) and a ring of per-step estimates (dt / steps, one
-        # entry per dispatch) backing telemetry()'s percentiles
+        # readbacks): ENQUEUE seconds, never a step's time
         self._step_seconds = 0.0
-        self._step_times = collections.deque(maxlen=2048)
+        # the whole entry-point call around it (_EntryPhases), and the
+        # builds' phases summed over every executable built
+        self._entry_seconds = 0.0
+        self._prepare_seconds = 0.0
+        self._settle_seconds = 0.0
+        self._build_phases = dict.fromkeys(_BUILD_PHASES, 0.0)
         # health-plane progress beacon: bumped once per COMPLETED
         # dispatch (_note_dispatch). _dispatch_count increments before
         # the jitted call, so "dispatch_count > dispatches_done" is
@@ -852,6 +957,9 @@ class Executor:
         # (AnalysisPredictor shares one Executor across clones); held
         # only around bookkeeping, never across a dispatch
         self._lock = threading.Lock()
+        # the profiler's device table joins a trace's events to these
+        # executables' optimized HLO (profiler.device_summary_table)
+        _profiler._executors.add(self)
 
     # -- public API --------------------------------------------------------
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
@@ -863,18 +971,20 @@ class Executor:
         sibling thread may still be reading. Training keeps the default
         (in-place HBM updates)."""
         program = program or framework.default_main_program()
-        if getattr(program, "_is_compiled", False):
-            # CompiledProgram (compiler.py) — distributed execution.
-            return program.run(self, feed, fetch_list, scope,
-                               return_numpy,
-                               use_program_cache=use_program_cache,
-                               validate_feed=validate_feed,
-                               donate=donate)
-        return self._run_impl(program, feed or {}, fetch_list or [],
-                              scope or global_scope(), return_numpy,
-                              donate=donate,
-                              use_program_cache=use_program_cache,
-                              validate_feed=validate_feed)
+        with _EntryPhases(self) as phases:
+            if getattr(program, "_is_compiled", False):
+                # CompiledProgram (compiler.py) — distributed execution.
+                return program.run(self, feed, fetch_list, scope,
+                                   return_numpy,
+                                   use_program_cache=use_program_cache,
+                                   validate_feed=validate_feed,
+                                   donate=donate, phases=phases)
+            return self._run_impl(program, feed or {}, fetch_list or [],
+                                  scope or global_scope(), return_numpy,
+                                  donate=donate,
+                                  use_program_cache=use_program_cache,
+                                  validate_feed=validate_feed,
+                                  phases=phases)
 
     @property
     def compile_count(self):
@@ -895,13 +1005,18 @@ class Executor:
         one ran): chunks, steps, stall_s, h2d_s, stall_fraction."""
         return self._last_pipeline_stats
 
-    def _note_dispatch(self, dt, steps):
+    def _note_dispatch(self, dt):
         with self._lock:
             self._step_seconds += dt
-            self._step_times.append(dt / max(1, steps))
             self._dispatches_done += 1
         self._h_dispatch.observe(dt)
         self._beacon.bump()
+
+    def _note_entry(self, entry, prepare, settle):
+        with self._lock:
+            self._entry_seconds += entry
+            self._prepare_seconds += prepare
+            self._settle_seconds += settle
 
     def _note_dispatch_failed(self):
         """A dispatch attempt that RAISED still settled: close the
@@ -1007,7 +1122,7 @@ class Executor:
 
     def _note_provenance(self, entry, shape_sig, reason, fingerprint,
                          mesh_fp, seconds, mode="xla",
-                         xla_seconds=None):
+                         xla_seconds=None, build_phases=None):
         """Registry + journal record for ONE compile — the compile
         plane's provenance ledger (docs/compile.md): every compile is
         an attributable event with a *miss reason*, not a silent perf
@@ -1031,6 +1146,9 @@ class Executor:
                   compile_seconds=round(seconds, 6),
                   xla_compile_seconds=round(xla_seconds, 6)
                   if xla_seconds is not None else None,
+                  build_phases={k: round(v, 6)
+                                for k, v in build_phases.items()}
+                  if build_phases else None,
                   mode=mode, nth=nth)
 
     def _executable_for(self, cache_key, shape_sig, entry, program,
@@ -1103,16 +1221,27 @@ class Executor:
             ctx = compile_ctx if compile_ctx is not None \
                 else contextlib.nullcontext
             t0 = time.perf_counter()
+            # where the build's seconds go (telemetry()["build_phases"],
+            # the artifact record, the journal events): their sum is
+            # build_seconds less the bookkeeping between them
+            phases = dict.fromkeys(_BUILD_PHASES, 0.0)
             with _profiler.RecordEvent("executor_trace_compile"), \
                     ctx():
                 lowered = jitfn.lower(*lower_args())
+                t_lowered = time.perf_counter()
+                phases["trace_lower_seconds"] = t_lowered - t0
                 fp = _ccache.canonical_fingerprint(lowered.as_text())
                 cache = _ccache.active()
                 disk_key = None
                 loaded = compiled = None
                 if cache is not None:
                     disk_key = _ccache.cache_key(fp, mesh_fp)
+                t_keyed = time.perf_counter()
+                phases["key_seconds"] = t_keyed - t_lowered
+                if cache is not None:
                     hit = cache.get(disk_key, entry=entry)
+                    phases["store_load_seconds"] = \
+                        time.perf_counter() - t_keyed
                     if hit is not None:
                         loaded = hit.loaded
                         self._book_prog_sig(cache_key, program,
@@ -1129,7 +1258,9 @@ class Executor:
                             origin_role=hit.meta.get("origin_role"),
                             origin_t_wall=hit.meta.get("origin_t_wall"),
                             compile_seconds_saved=hit.meta.get(
-                                "compile_seconds"))
+                                "compile_seconds"),
+                            build_phases={k: round(v, 6)
+                                          for k, v in phases.items()})
                 if loaded is None:
                     reason = self._classify_miss(cache_key, program,
                                                  shape_sig, mesh_fp,
@@ -1138,17 +1269,21 @@ class Executor:
                                         mesh_fp)
                     t1 = time.perf_counter()
                     compiled = lowered.compile()
-                    xla_s = time.perf_counter() - t1
-                    self._note_provenance(
-                        entry, shape_sig, reason, fp, mesh_fp,
-                        time.perf_counter() - t0, mode="xla",
-                        xla_seconds=xla_s)
+                    t_compiled = time.perf_counter()
+                    xla_s = t_compiled - t1
+                    phases["xla_compile_seconds"] = xla_s
                     if cache is not None:
                         cache.put(disk_key, compiled, {
                             "entry": entry, "fingerprint": fp,
                             "shape_key": _shape_key(shape_sig),
                             "mesh": _mesh_tag(mesh_fp),
                             "compile_seconds": xla_s})
+                        phases["store_put_seconds"] = \
+                            time.perf_counter() - t_compiled
+                    self._note_provenance(
+                        entry, shape_sig, reason, fp, mesh_fp,
+                        t_compiled - t0, mode="xla",
+                        xla_seconds=xla_s, build_phases=phases)
                     loaded = compiled
                 # memoize INSIDE the compile_ctx window: the ctx's
                 # __exit__ may legitimately raise (run_pipelined's
@@ -1162,7 +1297,11 @@ class Executor:
                     "shape_key": _shape_key(shape_sig),
                     "fingerprint": fp, "mode": "xla",
                     "from_cache": compiled is None,
+                    "build_phases": phases,
                     "build_seconds": time.perf_counter() - t0}
+                with self._lock:
+                    for k, v in phases.items():
+                        self._build_phases[k] += v
                 self._executables[ekey] = loaded
             return loaded
 
@@ -1206,45 +1345,36 @@ class Executor:
             return rebuild()(*args)
 
     def telemetry(self, scope=None, program=None):
-        """One observability snapshot of this Executor: throughput
-        (steps/s over host-observed dispatch time), the step-time
-        distribution, compile/dispatch accounting, input-pipeline
-        stall stats of the last *_from_dataset pass, anomaly-guard
-        skip counters read from ``scope``, and (when a distributed
+        """One observability snapshot of this Executor: compile and
+        dispatch accounting; the HOST seconds of every entry-point call
+        in phases (``entry_seconds_total`` = ``prepare`` + ``dispatch``
+        + ``settle`` + the entry's self time: see _EntryPhases; these
+        are enqueue-side costs, never a step's time, which only a
+        readback or a device trace gives); ``build_phases``, the
+        seconds of every executable built by phase (trace+lower, key,
+        store load, XLA compile, store put); input-pipeline stall
+        stats of the last *_from_dataset pass; anomaly-guard skip
+        counters read from ``scope``; and (when a distributed
         ``program`` is passed) the estimated gradient-sync
         bytes-on-wire per step."""
         with self._lock:
-            steps = self._run_counter
-            dispatches = self._dispatch_count
-            compiles = self._compile_count
-            xla_compiles = self._xla_compiles
-            cache_loads = self._cache_loads
-            compile_secs = self._compile_seconds
-            by_entry = dict(self._compiles_by_entry)
-            secs = self._step_seconds
-            times = list(self._step_times)
-        out = {
-            "steps": steps,
-            "dispatches": dispatches,
-            "compiles": compiles,
-            "xla_compiles": xla_compiles,
-            "cache_loads": cache_loads,
-            "compile_seconds_total": round(compile_secs, 6),
-            "compiles_by_entry": by_entry,
-            "compile_cache": _ccache.stats(),
-            "dispatch_seconds_total": round(secs, 6),
-            "steps_per_s": round(steps / secs, 3) if secs > 0 else None,
-        }
-        if times:
-            arr = np.asarray(times) * 1e3
-            out["step_time_ms"] = {
-                "mean": round(float(arr.mean()), 4),
-                "p50": round(float(np.percentile(arr, 50)), 4),
-                "p95": round(float(np.percentile(arr, 95)), 4),
-                "max": round(float(arr.max()), 4),
+            out = {
+                "steps": self._run_counter,
+                "dispatches": self._dispatch_count,
+                "compiles": self._compile_count,
+                "xla_compiles": self._xla_compiles,
+                "cache_loads": self._cache_loads,
+                "compile_seconds_total": round(self._compile_seconds, 6),
+                "compiles_by_entry": dict(self._compiles_by_entry),
+                "entry_seconds_total": round(self._entry_seconds, 6),
+                "prepare_seconds_total": round(self._prepare_seconds,
+                                               6),
+                "dispatch_seconds_total": round(self._step_seconds, 6),
+                "settle_seconds_total": round(self._settle_seconds, 6),
+                "build_phases": {k: round(v, 6) for k, v
+                                 in self._build_phases.items()},
             }
-        else:
-            out["step_time_ms"] = None
+        out["compile_cache"] = _ccache.stats()
         ps = self._last_pipeline_stats
         out["input_pipeline"] = dict(ps) if ps else None
         out["stall_fraction"] = ps.get("stall_fraction") if ps else None
@@ -1292,14 +1422,19 @@ class Executor:
         PRNG: step ``i`` uses ``fold_in(base_key, i)`` so dropout
         masks differ per step like sequential ``run`` calls.
         """
-        program = program or framework.default_main_program()
-        scope = scope or global_scope()
-        feed = feed or {}
-        fetch_list = fetch_list or []
+        with _EntryPhases(self) as phases:
+            return self._run_repeated(
+                phases, program or framework.default_main_program(),
+                feed or {}, fetch_list or [], iters,
+                scope or global_scope(), return_numpy, library)
+
+    def _run_repeated(self, phases, program, feed, fetch_list, iters,
+                      scope, return_numpy, library):
         enforce(iters >= 1, "run_repeated needs iters >= 1, got %s"
                 % iters)
         if getattr(program, "_is_compiled", False) \
                 or _needs_eager(program):
+            phases.cancel()     # each run() below books its own entry
             # dist/interpreted programs: plain loop (correct; per-step
             # dispatch cost applies). Honor an explicit library by
             # scoping the flag, since run() has no such parameter.
@@ -1399,7 +1534,7 @@ class Executor:
         # dispatch_inflight() stuck True forever
         try:
             base_key = jax.random.fold_in(base_key0, counter)
-            t0 = time.perf_counter()
+            t0 = phases.dispatch()
             with _profiler.RecordEvent("executor_run_repeated"):
                 fetches, persist_out = self._call_executable(
                     exe_fn, (cache_key, shape_sig),
@@ -1407,7 +1542,9 @@ class Executor:
         except BaseException:
             self._note_dispatch_failed()
             raise
-        self._note_dispatch(time.perf_counter() - t0, iters)
+        t1 = time.perf_counter()
+        self._note_dispatch(t1 - t0)
+        phases.settle(t1)
         for name, val in persist_out.items():
             scope.set_var(name, val)
         if return_numpy:
@@ -1459,9 +1596,14 @@ class Executor:
         pre-transfers the next chunk on a background thread while this
         chunk runs — ``train_from_dataset`` wires the two together.
         """
-        program = program or framework.default_main_program()
-        scope = scope or global_scope()
-        fetch_list = fetch_list or []
+        with _EntryPhases(self) as phases:
+            return self._run_pipelined(
+                phases, program or framework.default_main_program(),
+                feed_chunk, fetch_list or [], scope or global_scope(),
+                return_numpy, library, stack_fetch_list)
+
+    def _run_pipelined(self, phases, program, feed_chunk, fetch_list,
+                       scope, return_numpy, library, stack_fetch_list):
         enforce(feed_chunk, "run_pipelined needs a non-empty "
                 "feed_chunk (dict name -> [K, ...] array); for "
                 "feed-less programs use run_repeated")
@@ -1488,6 +1630,7 @@ class Executor:
             # chunk and drive per-step run() (correct; per-step
             # dispatch cost applies — same contract as run_repeated's
             # fallback, including the hoisted one-time validation).
+            phases.cancel()     # each run() below books its own entry
             prev = FLAGS.op_library
             if library is not None:
                 FLAGS.op_library = library
@@ -1698,7 +1841,7 @@ class Executor:
             try:
                 idxs = jnp.asarray(np.arange(counter, counter + iters,
                                              dtype=np.int32))
-                t_dispatch = time.perf_counter()
+                t_dispatch = phases.dispatch()
                 with _profiler.RecordEvent("scan_dispatch",
                                            args={"steps": int(iters)}):
                     fetches, stacked, persist_out = \
@@ -1709,7 +1852,9 @@ class Executor:
             except BaseException:
                 self._note_dispatch_failed()
                 raise
-        self._note_dispatch(time.perf_counter() - t_dispatch, iters)
+        t1 = time.perf_counter()
+        self._note_dispatch(t1 - t_dispatch)
+        phases.settle(t1)
         for name, val in persist_out.items():
             scope.set_var(name, val)
         fetches = fetches[:len(fetch_names)]
@@ -1875,7 +2020,7 @@ class Executor:
         return jax.random.key(seed)
 
     def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
-                  dist=None, donate=True, library=None,
+                  phases, dist=None, donate=True, library=None,
                   use_program_cache=True, validate_feed=True):
         fetch_names = [f.name if isinstance(f, framework.Variable) else f
                        for f in fetch_list]
@@ -2010,7 +2155,7 @@ class Executor:
         # dispatch_inflight() stuck True forever
         try:
             step_key = jax.random.fold_in(base_key0, counter)
-            t0 = time.perf_counter()
+            t0 = phases.dispatch()
             with _profiler.RecordEvent("executor_run"):
                 if obtain is not None:
                     fetches, persist_out = self._call_executable(
@@ -2022,7 +2167,9 @@ class Executor:
         except BaseException:
             self._note_dispatch_failed()
             raise
-        self._note_dispatch(time.perf_counter() - t0, 1)
+        t1 = time.perf_counter()
+        self._note_dispatch(t1 - t0)
+        phases.settle(t1)
 
         for name, val in persist_out.items():
             scope.set_var(name, val)

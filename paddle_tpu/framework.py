@@ -313,6 +313,12 @@ class Block:
         if role is not None and (attrs is None
                                  or "op_role" not in attrs):
             attrs = dict(attrs or {}, op_role=role)
+        if _name_scope_stack and (attrs is None
+                                  or "op_namescope" not in attrs):
+            # which layer kind built this op (reference: append_op
+            # stamping _full_name_scope()): metadata only, read by the
+            # executor to name the op's lowering in the device trace
+            attrs = dict(attrs or {}, op_namescope=_full_name_scope())
         op = Operator(self, type, inputs, outputs, attrs)
         if index is None:
             self.ops.append(op)
@@ -876,7 +882,11 @@ def _current_tracing_program() -> Optional["Program"]:
 
 
 # ---------------------------------------------------------------------------
-# name_scope (cosmetic grouping, reference framework.py name_scope)
+# name_scope (reference framework.py name_scope): names the layer kind
+# of the ops built under it. append_op stamps it as ``op_namescope``,
+# backward ops inherit their forward op's, and executor.run_block
+# lowers each op under jax.named_scope("<phase>/<layer>/<op type>"),
+# which is what a device trace is charged to (profiler.scope_table).
 # ---------------------------------------------------------------------------
 
 _name_scope_stack: List[str] = []
@@ -889,6 +899,17 @@ def name_scope(prefix):
         yield
     finally:
         _name_scope_stack.pop()
+
+
+def _full_name_scope() -> str:
+    """``/outer/inner/`` (reference: framework.py _full_name_scope)."""
+    return "/" + "/".join(_name_scope_stack) + "/"
+
+
+def innermost_scope(op_namescope) -> str:
+    """``/optimizer/clip/`` -> ``clip``; ``-`` where an op has none."""
+    parts = [p for p in (op_namescope or "").split("/") if p]
+    return parts[-1] if parts else "-"
 
 
 def cpu_places(device_count=None):

@@ -45,10 +45,12 @@ class _PSTrainerProgram:
         self.program = runtime.program
 
     def run(self, exe, feed, fetch_list, scope, return_numpy,
-            use_program_cache=True, validate_feed=True, donate=True):
-        # validate_feed/donate are accepted for run()-protocol parity;
-        # the PS runtime validates feeds in its own local-step
-        # executor run (which keeps the default donation behavior)
+            use_program_cache=True, validate_feed=True, donate=True,
+            *, phases=None):
+        # validate_feed/donate/phases are accepted for run()-protocol
+        # parity; the PS runtime validates feeds in its own local-step
+        # executor run (which keeps the default donation behavior and
+        # books its own entry-point seconds)
         return self._rt.run_step(exe, feed or {},
                                  fetch_list=fetch_list or [],
                                  return_numpy=return_numpy,

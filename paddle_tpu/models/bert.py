@@ -9,7 +9,11 @@ here TPU-first from this framework's primitives:
   - MLM loss gathers masked positions with a static max_predictions
     slot count (pad + weight, no dynamic shapes under jit);
   - one XLA program per pretrain step; dp sharding via
-    CompiledProgram.with_data_parallel, tp via shard_tp below.
+    CompiledProgram.with_data_parallel, tp via shard_tp below;
+  - every op is built under a ``name_scope`` naming its layer kind
+    (embedding, attention, ffn, residual_norm, pooler, vocab_head,
+    loss), which is what a device trace is charged to
+    (profiler.scope_table).
 """
 
 from __future__ import annotations
@@ -17,6 +21,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import layers
+from ..framework import name_scope
 from ..param_attr import ParamAttr
 
 __all__ = ["BertConfig", "bert_encoder", "bert_pretrain",
@@ -50,6 +55,7 @@ def base():
     return BertConfig()
 
 
+@name_scope("attention")
 def _attention(x, bias, cfg, is_test, prefix):
     d, h = cfg.hidden_size, cfg.num_attention_heads
     dh = d // h
@@ -73,6 +79,7 @@ def _attention(x, bias, cfg, is_test, prefix):
     return layers.fc(ctx, d, num_flatten_dims=2, name=prefix + "_out")
 
 
+@name_scope("residual_norm")
 def _residual_ln(x, residual, cfg, is_test, name):
     if cfg.hidden_dropout_prob and not is_test:
         x = layers.dropout(x, cfg.hidden_dropout_prob,
@@ -83,43 +90,51 @@ def _residual_ln(x, residual, cfg, is_test, name):
 
 def bert_encoder(src_ids, sent_ids, input_mask, cfg, is_test=False):
     """Returns (sequence_output [b,s,d], pooled_output [b,d])."""
-    emb = layers.embedding(
-        src_ids, size=(cfg.vocab_size, cfg.hidden_size),
-        param_attr=ParamAttr(name="word_embedding"))
-    sent = layers.embedding(
-        sent_ids, size=(cfg.type_vocab_size, cfg.hidden_size),
-        param_attr=ParamAttr(name="sent_embedding"))
-    # static position ids 0..s-1 broadcast over the batch
-    s = src_ids.shape[1]
-    pos_ids = layers.assign(np.arange(s, dtype=np.int64))
-    pos = layers.embedding(
-        pos_ids, size=(cfg.max_position_embeddings, cfg.hidden_size),
-        param_attr=ParamAttr(name="pos_embedding"))
-    x = layers.elementwise_add(layers.elementwise_add(emb, sent), pos)
-    x = layers.layer_norm(x, begin_norm_axis=2, name="emb_ln")
-    if cfg.hidden_dropout_prob and not is_test:
-        x = layers.dropout(x, cfg.hidden_dropout_prob,
-                           dropout_implementation="upscale_in_train")
+    with name_scope("embedding"):
+        emb = layers.embedding(
+            src_ids, size=(cfg.vocab_size, cfg.hidden_size),
+            param_attr=ParamAttr(name="word_embedding"))
+        sent = layers.embedding(
+            sent_ids, size=(cfg.type_vocab_size, cfg.hidden_size),
+            param_attr=ParamAttr(name="sent_embedding"))
+        # static position ids 0..s-1 broadcast over the batch
+        s = src_ids.shape[1]
+        pos_ids = layers.assign(np.arange(s, dtype=np.int64))
+        pos = layers.embedding(
+            pos_ids,
+            size=(cfg.max_position_embeddings, cfg.hidden_size),
+            param_attr=ParamAttr(name="pos_embedding"))
+        x = layers.elementwise_add(layers.elementwise_add(emb, sent),
+                                   pos)
+        x = layers.layer_norm(x, begin_norm_axis=2, name="emb_ln")
+        if cfg.hidden_dropout_prob and not is_test:
+            x = layers.dropout(
+                x, cfg.hidden_dropout_prob,
+                dropout_implementation="upscale_in_train")
 
     # [b, s] 1/0 -> additive bias [b, 1, 1, s]
-    bias = layers.scale(input_mask, scale=1e9, bias=-1.0,
-                        bias_after_scale=False)
-    bias = layers.unsqueeze(layers.unsqueeze(bias, [1]), [1])
+    with name_scope("attention"):
+        bias = layers.scale(input_mask, scale=1e9, bias=-1.0,
+                            bias_after_scale=False)
+        bias = layers.unsqueeze(layers.unsqueeze(bias, [1]), [1])
 
     for i in range(cfg.num_hidden_layers):
         p = "layer%d" % i
         att = _attention(x, bias, cfg, is_test, p + "_att")
         x = _residual_ln(att, x, cfg, is_test, p + "_att_ln")
-        ff = layers.fc(x, cfg.intermediate_size, num_flatten_dims=2,
-                       act="gelu", name=p + "_ffn_fc1")
-        ff = layers.fc(ff, cfg.hidden_size, num_flatten_dims=2,
-                       name=p + "_ffn_fc2")
+        with name_scope("ffn"):
+            ff = layers.fc(x, cfg.intermediate_size,
+                           num_flatten_dims=2, act="gelu",
+                           name=p + "_ffn_fc1")
+            ff = layers.fc(ff, cfg.hidden_size, num_flatten_dims=2,
+                           name=p + "_ffn_fc2")
         x = _residual_ln(ff, x, cfg, is_test, p + "_ffn_ln")
 
-    first_tok = layers.slice(x, axes=[1], starts=[0], ends=[1])
-    first_tok = layers.squeeze(first_tok, [1])
-    pooled = layers.fc(first_tok, cfg.hidden_size, act="tanh",
-                       name="pooler")
+    with name_scope("pooler"):
+        first_tok = layers.slice(x, axes=[1], starts=[0], ends=[1])
+        first_tok = layers.squeeze(first_tok, [1])
+        pooled = layers.fc(first_tok, cfg.hidden_size, act="tanh",
+                           name="pooler")
     return x, pooled
 
 
@@ -144,26 +159,31 @@ def bert_pretrain(cfg, is_test=False):
                                    is_test)
 
     # ---- MLM head: gather masked positions from the flattened batch
-    flat = layers.reshape(seq_out, (-1, cfg.hidden_size))
-    gathered = layers.gather(flat, layers.reshape(mask_pos, (-1,)))
-    trans = layers.fc(gathered, cfg.hidden_size, act="gelu",
-                      name="mlm_trans")
-    trans = layers.layer_norm(trans, name="mlm_ln")
-    mlm_logits = layers.fc(trans, cfg.vocab_size, name="mlm_out")
-    mlm_loss_all = layers.softmax_with_cross_entropy(
-        mlm_logits, layers.reshape(mask_label, (-1, 1)))
-    w = layers.reshape(mask_weight, (-1, 1))
-    mlm_sum = layers.reduce_sum(layers.elementwise_mul(mlm_loss_all, w))
-    denom = layers.reduce_sum(w)
-    mlm_loss = layers.elementwise_div(mlm_sum, denom)
+    with name_scope("loss"):
+        flat = layers.reshape(seq_out, (-1, cfg.hidden_size))
+        gathered = layers.gather(flat, layers.reshape(mask_pos, (-1,)))
+        trans = layers.fc(gathered, cfg.hidden_size, act="gelu",
+                          name="mlm_trans")
+        trans = layers.layer_norm(trans, name="mlm_ln")
+    with name_scope("vocab_head"):
+        mlm_logits = layers.fc(trans, cfg.vocab_size, name="mlm_out")
+    with name_scope("loss"):
+        mlm_loss_all = layers.softmax_with_cross_entropy(
+            mlm_logits, layers.reshape(mask_label, (-1, 1)))
+        w = layers.reshape(mask_weight, (-1, 1))
+        mlm_sum = layers.reduce_sum(
+            layers.elementwise_mul(mlm_loss_all, w))
+        denom = layers.reduce_sum(w)
+        mlm_loss = layers.elementwise_div(mlm_sum, denom)
 
-    # ---- NSP head
-    nsp_logits = layers.fc(pooled, 2, name="nsp_out")
-    nsp_loss = layers.mean(layers.softmax_with_cross_entropy(
-        nsp_logits, nsp_label))
-    nsp_acc = layers.accuracy(layers.softmax(nsp_logits), nsp_label)
+        # ---- NSP head
+        nsp_logits = layers.fc(pooled, 2, name="nsp_out")
+        nsp_loss = layers.mean(layers.softmax_with_cross_entropy(
+            nsp_logits, nsp_label))
+        nsp_acc = layers.accuracy(layers.softmax(nsp_logits),
+                                  nsp_label)
 
-    total = layers.elementwise_add(mlm_loss, nsp_loss)
+        total = layers.elementwise_add(mlm_loss, nsp_loss)
     return total, mlm_loss, nsp_acc
 
 
@@ -178,14 +198,16 @@ def bert_classifier(cfg, num_classes, is_test=False):
     label = layers.data("label", shape=[1], dtype="int64")
     _, pooled = bert_encoder(src_ids, sent_ids, input_mask, cfg,
                              is_test)
-    if cfg.hidden_dropout_prob and not is_test:
-        pooled = layers.dropout(
-            pooled, cfg.hidden_dropout_prob,
-            dropout_implementation="upscale_in_train")
-    logits = layers.fc(pooled, num_classes, name="cls_out")
-    probs = layers.softmax(logits)
-    loss = layers.mean(layers.softmax_with_cross_entropy(logits, label))
-    acc = layers.accuracy(probs, label)
+    with name_scope("loss"):
+        if cfg.hidden_dropout_prob and not is_test:
+            pooled = layers.dropout(
+                pooled, cfg.hidden_dropout_prob,
+                dropout_implementation="upscale_in_train")
+        logits = layers.fc(pooled, num_classes, name="cls_out")
+        probs = layers.softmax(logits)
+        loss = layers.mean(
+            layers.softmax_with_cross_entropy(logits, label))
+        acc = layers.accuracy(probs, label)
     return loss, acc, probs
 
 

@@ -12,7 +12,11 @@ machine-translation benchmark (benchmark/fluid/models/machine_translation
   - the whole train step (12 blocks fwd + bwd + Adam) compiles to ONE
     XLA program via the Executor;
   - weights annotated for Megatron-style tp sharding on request
-    (shard_tp) — GSPMD inserts the ICI collectives.
+    (shard_tp) — GSPMD inserts the ICI collectives;
+  - every op is built under a ``name_scope`` naming its layer KIND
+    (embedding, attention, ffn, residual_norm, vocab_head, loss — not
+    its index: six encoder layers share ``attention``), which is what
+    a device trace is charged to (profiler.scope_table).
 """
 
 from __future__ import annotations
@@ -20,6 +24,7 @@ from __future__ import annotations
 import numpy as np
 
 from .. import layers
+from ..framework import name_scope
 from ..initializer import NumpyArrayInitializer
 from ..param_attr import ParamAttr
 
@@ -63,6 +68,7 @@ def _pos_encoding_table(max_len, d_model):
     return table
 
 
+@name_scope("attention")
 def _multi_head_attention(q_in, kv_in, bias, cfg, is_test, prefix):
     """Scaled dot-product attention over n_head heads.
 
@@ -102,6 +108,7 @@ def _multi_head_attention(q_in, kv_in, bias, cfg, is_test, prefix):
                      name=prefix + "_out")
 
 
+@name_scope("ffn")
 def _ffn(x, cfg, prefix):
     hidden = layers.fc(x, cfg.d_ffn, num_flatten_dims=2, act="relu",
                        name=prefix + "_fc1")
@@ -109,6 +116,7 @@ def _ffn(x, cfg, prefix):
                      name=prefix + "_fc2")
 
 
+@name_scope("residual_norm")
 def _post_process(x, residual, cfg, is_test, prefix):
     """residual + dropout, then layer_norm (fluid's "da n" cmd chain)."""
     if cfg.dropout and not is_test:
@@ -119,6 +127,7 @@ def _post_process(x, residual, cfg, is_test, prefix):
                              name=prefix + "_ln")
 
 
+@name_scope("embedding")
 def _embed(ids, vocab, cfg, is_test, name):
     emb = layers.embedding(
         ids, size=(vocab, cfg.d_model),
@@ -134,6 +143,7 @@ def _embed(ids, vocab, cfg, is_test, name):
     return out
 
 
+@name_scope("attention")
 def _pad_bias(pad_mask):
     """[b, s] float 1=token 0=pad -> additive bias [b, 1, 1, s]."""
     bias = layers.scale(pad_mask, scale=1e9, bias=-1.0,
@@ -141,6 +151,7 @@ def _pad_bias(pad_mask):
     return layers.unsqueeze(layers.unsqueeze(bias, [1]), [1])
 
 
+@name_scope("attention")
 def _causal_bias(pad_bias_, seq_len):
     """Combine key-pad bias with a lower-triangular causal bias."""
     causal = np.triu(np.full((seq_len, seq_len), -1e9, np.float32), 1)
@@ -201,15 +212,20 @@ def transformer(cfg: TransformerConfig, is_test=False):
     # and the uniform label smoothing folds into its closed form. The
     # plain logits (for decoding/inference graphs) come from a separate
     # mul on the same weight that XLA dead-code-eliminates whenever
-    # they go unfetched (i.e. every training step).
-    cost, logits = layers.fused_linear_cross_entropy(
-        dec_out, layers.unsqueeze(lbl_ids, [2]), cfg.tgt_vocab,
-        epsilon=cfg.label_smooth_eps, name="proj", return_logits=True)
-    cost = layers.squeeze(cost, [2])            # [b, s]
-    weighted = layers.elementwise_mul(cost, tgt_mask)
-    sum_cost = layers.reduce_sum(weighted)
-    token_num = layers.reduce_sum(tgt_mask)
-    avg_cost = layers.elementwise_div(sum_cost, token_num)
+    # they go unfetched (i.e. every training step). The fused op IS the
+    # pre-softmax matmul with the smoothed cross entropy folded in, so
+    # ``vocab_head`` holds both here; ``loss`` is the masked mean.
+    with name_scope("vocab_head"):
+        cost, logits = layers.fused_linear_cross_entropy(
+            dec_out, layers.unsqueeze(lbl_ids, [2]), cfg.tgt_vocab,
+            epsilon=cfg.label_smooth_eps, name="proj",
+            return_logits=True)
+    with name_scope("loss"):
+        cost = layers.squeeze(cost, [2])            # [b, s]
+        weighted = layers.elementwise_mul(cost, tgt_mask)
+        sum_cost = layers.reduce_sum(weighted)
+        token_num = layers.reduce_sum(tgt_mask)
+        avg_cost = layers.elementwise_div(sum_cost, token_num)
     return avg_cost, token_num, logits
 
 

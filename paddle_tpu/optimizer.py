@@ -153,7 +153,8 @@ class Optimizer:
         # optimize role so clone(for_test=True) prunes it with the
         # backward ops it reads (framework.op_role_guard)
         with framework.op_role_guard(default_main_program(),
-                                     "optimize"):
+                                     "optimize"), \
+                framework.name_scope("optimizer"):
             return self._apply_gradients_impl(params_grads)
 
     def _apply_gradients_impl(self, params_grads):
@@ -207,7 +208,8 @@ class Optimizer:
         if grad_clip is not None:
             from .clip import append_gradient_clip_ops
             with framework.op_role_guard(default_main_program(),
-                                         "optimize"):
+                                         "optimize"), \
+                    framework.name_scope("clip"):
                 params_grads = append_gradient_clip_ops(params_grads,
                                                         grad_clip)
         optimize_ops = self.apply_gradients(params_grads)

@@ -1,4 +1,6 @@
-"""Profiler: host-side event spans + aggregate tables + chrome trace.
+"""Profiler: host-side event spans + aggregate tables + chrome trace,
+and the reduction of a device trace to a table by the program's own
+scopes.
 
 Reference: paddle/fluid/platform/profiler.{h,cc} (RAII ``RecordEvent``
 profiler.h:81, ``EnableProfiler/DisableProfiler`` :166-171 aggregating
@@ -8,28 +10,41 @@ min/max/avg tables from profiler.proto), platform/device_tracer.cc
 and tools/timeline.py (proto -> chrome://tracing JSON).
 
 TPU-native redesign: there is no per-op runtime to instrument — the
-whole step is ONE fused XLA program — so host events cover the step
-pipeline (trace/compile/run/fetch, recorded by the Executor) and any
-user spans, while *device*-side detail comes from the XLA profiler
-(``jax.profiler``, the CUPTI/DeviceTracer analog): pass
-``profile_path`` and a TensorBoard/xprof trace is captured alongside.
-Chrome-trace export works directly from the host events (the
-timeline.py role)."""
+whole step is ONE fused XLA program — so the reference's per-op table
+is rebuilt from two things the program writes while it works:
+
+  - every op is LOWERED under ``jax.named_scope("<phase>/<layer>/<op
+    type>")`` (executor.run_block), which XLA carries into the
+    optimized HLO's ``op_name``; ``scope_table`` charges each device
+    event of a ``jax.profiler`` trace (``trace_path``) to that scope;
+  - every ``RecordEvent`` also enters a ``jax.profiler.TraceAnnotation``,
+    so the host spans lie in the trace's own host plane, on its own
+    clock, whoever started the trace; ``scope_table`` charges each idle
+    gap between dispatches to the span that covers it.
+
+Chrome-trace export works from the same events (the timeline.py
+role)."""
 
 from __future__ import annotations
 
+import bisect
 import contextlib
+import glob
 import json
 import os
+import re
 import threading
 import time
-from dataclasses import dataclass, field
+import weakref
+from dataclasses import dataclass
 from typing import List, Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["RecordEvent", "record_event", "start_profiler",
            "stop_profiler", "reset_profiler", "reset_counters",
-           "profiler", "export_chrome_tracing",
-           "device_summary_table", "bump_counter", "counter_values",
+           "profiler", "export_chrome_tracing", "scope_table",
+           "device_scope_table", "device_summary_table", "bump_counter", "counter_values",
            "cuda_profiler", "npu_profiler"]
 
 _state = threading.local()
@@ -37,11 +52,21 @@ _lock = threading.Lock()
 _enabled = False
 _events: List["_Event"] = []
 _device_trace_dir: Optional[str] = None
-# host perf_counter captured immediately before jax start_trace: the
-# xplane timebase starts there, so host and device events share one
-# timeline (skew is the start_trace call latency, sub-ms)
-_trace_anchor: Optional[float] = None
+# wall time of the ``profiler_clock_sync`` span entered right after
+# jax's start_trace: the span's own timestamp in the trace is the
+# wall<->trace correspondence tools/trace_merge.py needs
+_sync_wall: Optional[float] = None
+# what the one xplane reader returned for the last capture: device
+# ops, and the host plane's spans
 _device_events: List[dict] = []
+_device_table: Optional[dict] = None    # scope_table of them, memoized
+# live Executors, asked for their executables' optimized HLO when a
+# device table is wanted (the join from a TPU event to its op_name)
+_executors: "weakref.WeakSet" = weakref.WeakSet()
+# the stat every RecordEvent's annotation carries: tells the program's
+# spans from the runtime's own host events
+_SPAN_STAT = "span"
+_SYNC_SPAN = "profiler_clock_sync"
 
 
 @dataclass
@@ -66,23 +91,35 @@ def _stack():
 
 class RecordEvent:
     """RAII span (reference: platform/profiler.h:81). Usable as a
-    context manager or via ``record_event``. No-op unless the profiler
-    is enabled — cheap enough to leave in hot paths. ``args`` (a small
-    JSON-able dict, e.g. the serving engine's batch bucket/occupancy)
-    rides into the chrome-trace span's args panel."""
+    context manager or via ``record_event``. Always a
+    ``jax.profiler.TraceAnnotation`` (a flag test and under 2 us when
+    no trace runs), so the span lies in the host plane of ANY running
+    jax.profiler trace; recorded in this module's own list only while
+    the profiler is enabled — cheap enough to leave in hot paths.
+    ``args`` (a small JSON-able dict, e.g. the serving engine's batch
+    bucket/occupancy) rides into the trace event's stats and the
+    chrome-trace span's args panel."""
 
     def __init__(self, name, args=None):
         self.name = name
         self.args = args
         self._t0 = None
+        self._annotation = None
 
     def __enter__(self):
+        # always: a jax.profiler trace may be running that this module
+        # did not start (a benchmark's own start_trace); outside a
+        # trace the annotation is a flag test
+        self._annotation = TraceAnnotation(
+            self.name, **{_SPAN_STAT: "paddle_tpu", **(self.args or {})})
+        self._annotation.__enter__()
         if _enabled:
             self._t0 = time.perf_counter()
             _stack().append(self.name)
         return self
 
     def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
         if self._t0 is not None:
             end = time.perf_counter()
             stack = _stack()
@@ -139,20 +176,21 @@ def start_profiler(state="All", trace_path=None):
     """Reference: profiler.py start_profiler (state CPU/GPU/All; GPU
     maps to the TPU/XLA device trace here). ``trace_path`` starts a
     jax.profiler trace capturing device activity (xprof)."""
-    global _enabled, _device_trace_dir
+    global _enabled, _device_trace_dir, _sync_wall
     if _enabled:
         return
     _enabled = True
     if trace_path and state in ("GPU", "TPU", "All"):
-        global _trace_anchor
         try:
             import jax
-            _trace_anchor = time.perf_counter()
             jax.profiler.start_trace(trace_path)
             _device_trace_dir = trace_path
+            _sync_wall = time.time()
+            with RecordEvent(_SYNC_SPAN):
+                pass
         except Exception:
             _device_trace_dir = None
-            _trace_anchor = None
+            _sync_wall = None
 
 
 def reset_profiler():
@@ -160,14 +198,18 @@ def reset_profiler():
     counters are NOT touched — ``pyreader`` stall accounting and bench
     probes depend on them accumulating across span resets; clear those
     explicitly with ``reset_counters()``."""
+    global _device_table
     with _lock:
         _events.clear()
         _device_events.clear()
+        _device_table = None
 
 
-def stop_profiler(sorted_key=None, profile_path=None):
+def stop_profiler(sorted_key=None, profile_path=None, steps=1):
     """Aggregate + print the event table (reference: DisableProfiler →
-    PrintProfiler, profiler.cc); optionally dump chrome tracing JSON to
+    PrintProfiler, profiler.cc) and, after a device capture, the
+    device time by scope (per step where ``steps`` says how many the
+    capture holds); optionally dump chrome tracing JSON to
     ``profile_path`` (the timeline.py step, no separate tool needed)."""
     global _enabled, _device_trace_dir
     if not _enabled:
@@ -185,7 +227,7 @@ def stop_profiler(sorted_key=None, profile_path=None):
         export_chrome_tracing(profile_path)
     print(summary_table(sorted_key))
     if _device_events:
-        print(device_summary_table())
+        print(device_summary_table(steps=steps))
 
 
 def summary_table(sorted_key=None) -> str:
@@ -224,97 +266,434 @@ def summary_table(sorted_key=None) -> str:
     return "\n".join(lines)
 
 
+# -- from a trace to a table by scope ----------------------------------
+
+OP_LINE, ASYNC_LINE, MODULE_LINE = "XLA Ops", "Async XLA Ops", \
+    "XLA Modules"
+_COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+                "collective-permute", "all-to-all")
+# the vocabulary executor.run_block lowers under; the leftmost match
+# in an op_name is the outermost scope (a gradient op's op_name
+# repeats its scope inside transpose(...) and jvp(...))
+_SCOPE = re.compile(r"(?:^|/)(fwd|bwd|opt|sync)/([^/]+)/"
+                    r"((?:\(xla\) )?[^/()\s]+)")
+UNSCOPED = "unscoped"
+
+
 def _collect_device_events(trace_dir):
-    """Parse the captured xplane files into per-op device events —
-    the DeviceTracer/CUPTI-activity analog (reference:
-    platform/device_tracer.cc:41). Device planes ("/device:TPU:*")
-    carry one line per core stream; on CPU backends the XLA runtime
-    threads ("tf_*" lines of the host plane) play that role."""
-    import glob
-    global _device_events
+    """THE xplane reader: the ``.xplane.pb`` files of a jax.profiler
+    capture into plain events — the DeviceTracer/CUPTI-activity analog
+    (reference: platform/device_tracer.cc:41). An event is a dict:
+    ``name``, ``plane``, ``line``, ``ts_ns``, ``dur_ns``, ``stats`` (the
+    event's own stats) and ``host`` (True for a span of the host
+    plane). Device planes ("/device:TPU:*") carry an op line that nests
+    (``XLA Ops``: a ``while`` holds its body's ops), the in-flight part
+    of asynchronous ops beside it and the programs' intervals (``XLA
+    Modules``); on CPU backends the XLA runtime threads ("tf_*" lines
+    of the host plane) play the device's role. Every other line of the
+    host plane gives host spans: ``TraceAnnotation``s (every
+    ``RecordEvent`` is one) and the runtime's own; the Python tracer's
+    per-call events ("$...") are left out. Returns the events and
+    keeps them for ``device_summary_table`` / ``export_chrome_tracing``."""
+    global _device_events, _device_table
     from jax.profiler import ProfileData
     events = []
     for f in glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                        recursive=True):
-        pd = ProfileData.from_file(f)
-        planes = list(pd.planes)
-        dev_planes = [p for p in planes
-                      if p.name.startswith("/device:")]
-        if dev_planes:
-            selected = [(p.name, line) for p in dev_planes
-                        for line in p.lines]
-        else:
-            selected = [(p.name, line) for p in planes
-                        if p.name.endswith(":CPU")
-                        for line in p.lines
-                        if line.name.startswith("tf_")]
-        for pname, line in selected:
-            for e in line.events:
-                if e.duration_ns <= 0 or \
-                        e.name.startswith(("end: ", "begin: ")):
-                    continue
-                events.append({"name": e.name, "plane": pname,
-                               "line": line.name,
-                               "ts_ns": float(e.start_ns),
-                               "dur_ns": float(e.duration_ns)})
+        data = ProfileData.from_file(f)     # owns what planes point at
+        planes = list(data.planes)
+        on_device = any(p.name.startswith("/device:") for p in planes)
+        for plane in planes:
+            device_plane = plane.name.startswith("/device:")
+            if not device_plane and not plane.name.endswith(":CPU"):
+                continue
+            for line in plane.lines:
+                host = not device_plane and (
+                    on_device or not line.name.startswith("tf_"))
+                for e in line.events:
+                    if e.name.startswith(("end: ", "begin: ", "$")) \
+                            or (e.duration_ns <= 0 and not host):
+                        continue
+                    try:
+                        stats = {k: v for k, v in e.stats}
+                    except Exception:
+                        stats = {}
+                    events.append({"name": e.name, "plane": plane.name,
+                                   "line": line.name, "host": host,
+                                   "ts_ns": float(e.start_ns),
+                                   "dur_ns": float(e.duration_ns),
+                                   "stats": stats})
     with _lock:
-        _device_events = events
+        _device_events, _device_table = events, None
+    return events
 
 
-def device_summary_table(sorted_key=None) -> str:
-    """Per-op DEVICE time table from the xplane capture (reference:
-    the 'GPU' rows of PrintProfiler + tools/timeline.py device
-    tracks)."""
-    with _lock:
-        events = list(_device_events)
-    agg = {}
+def _instruction(ev):
+    """(module, instruction) of a device op event, or None. A TPU trace
+    names an op by its whole HLO line and its program by an event of
+    the ``XLA Modules`` line (looked up by the caller); a CPU trace
+    gives both as stats."""
+    stats = ev.get("stats") or {}
+    if "hlo_op" in stats and "hlo_module" in stats:
+        return stats["hlo_module"], stats["hlo_op"]
+    if ev["line"] in (OP_LINE, ASYNC_LINE):
+        return None, ev["name"].split(" = ", 1)[0].strip().lstrip("%")
+    return None
+
+
+_XLA_MADE = "(xla) "
+
+
+def _opcode(instruction):
+    """``copy-done.66`` -> ``copy-done``."""
+    return re.sub(r"[.\d]+$", "", instruction)
+
+
+def _is_collective(ev):
+    head = ev["name"].split(" = ", 1)[0]
+    return any(w in head for w in _COLLECTIVES)
+
+
+def hlo_op_names(optimized_hlo):
+    """{module: {instruction: op_name}} of optimized HLO text(s) (what
+    ``Executor.aot_artifacts()`` gives as ``optimized_hlo``). What XLA
+    made itself carries no ``op_name``; it is charged by structure,
+    never by guess: a fusion takes the scope of what it fused (the
+    last scoped instruction of the computation it calls), and data
+    movement (a copy between layouts or memory spaces, a slice, the
+    bits of a random generator) the scope of the first scoped
+    instruction that consumes it, marked ``(xla)`` in place of the op
+    type. What has neither stays without."""
+    texts = [optimized_hlo] if isinstance(optimized_hlo, str) \
+        else list(optimized_hlo or ())
+    out = {}
+    instr = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+    comp = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+    op_name = re.compile(r'op_name="([^"]*)"')
+    calls = re.compile(r"calls=%?([\w.\-]+)")
+    operand = re.compile(r"%([\w.\-]+)")
+    for text in texts:
+        if not text:
+            continue
+        head = re.match(r"HloModule ([\w.\-]+)", text)
+        names = out.setdefault(head.group(1) if head else "", {})
+        by_comp, called, current = {}, {}, None
+        bare, users = [], {}
+        for row in text.splitlines():
+            m = instr.match(row)
+            if m is None:
+                c = comp.match(row)
+                if c is not None:
+                    current = by_comp.setdefault(c.group(1), [])
+                    users = {}      # names are a computation's own
+                continue
+            name = m.group(1)
+            n = op_name.search(row)
+            n = n.group(1) if n else ""
+            names.setdefault(name, n)
+            if current is not None:
+                current.append(n)
+            for used in operand.findall(row[m.end():].split(
+                    ", metadata=", 1)[0]):
+                users.setdefault(used, []).append(name)
+            if not _SCOPE.search(n):
+                c = calls.search(row)
+                if c is not None:
+                    called[name] = c.group(1)
+                bare.append((name, users))
+        for name, callee in called.items():
+            for n in reversed(by_comp.get(callee, ())):
+                if _SCOPE.search(n):
+                    names[name] = n
+                    break
+        # consumers come later in a scheduled module: resolve from the
+        # end, so a chain of XLA's own (copy-start -> copy-done ->
+        # bitcast -> fusion) reaches the op it feeds
+        for name, users in reversed(bare):
+            if _SCOPE.search(names[name]):
+                continue
+            for user in users.get(name, ()):
+                m = _SCOPE.search(names.get(user, ""))
+                if m is not None:
+                    names[name] = "%s/%s/%s%s" % (
+                        m.group(1), m.group(2), _XLA_MADE,
+                        _opcode(name))
+                    break
+    return out
+
+
+def _leaves(events):
+    """Events of one op line that contain no other event of it (a
+    ``while`` spans the whole scan and holds its body's ops)."""
+    evs = sorted(events, key=lambda e: (e["ts_ns"], -e["dur_ns"]))
+    out, open_ = [], []          # open_: [event, end, has_child]
+    for ev in evs:
+        end = ev["ts_ns"] + ev["dur_ns"]
+        still = []
+        for rec in open_:
+            if rec[1] <= ev["ts_ns"]:
+                if not rec[2]:
+                    out.append(rec[0])
+            else:
+                if rec[1] >= end:
+                    rec[2] = True
+                still.append(rec)
+        open_ = still + [[ev, end, False]]
+    out.extend(rec[0] for rec in open_ if not rec[2])
+    return sorted(out, key=lambda e: e["ts_ns"])
+
+
+def scope_table(events, optimized_hlo=None):
+    """Device time by the program's own scopes (the reference's
+    ``fluid.profiler`` op table, rebuilt for one fused XLA program).
+
+    ``events`` is what ``_collect_device_events`` returns;
+    ``optimized_hlo`` the optimized HLO text(s) of the executables that
+    ran (``Executor.aot_artifacts()``): a TPU event carries no
+    ``op_name`` of its own, so it is joined by instruction name. Only
+    LEAF events of the op line count; each is charged to the outermost
+    ``<phase>/<layer>/<op type>`` in its ``op_name``, else to
+    ``unscoped``. Times are milliseconds, averaged over the devices
+    traced; every ``by_*`` table sums to ``busy_ms``. The in-flight part
+    of asynchronous collectives overlaps the op line and is listed
+    apart (``async_collectives_by_layer``). Each idle gap between leaf ops is ``inside a
+    program`` or, between two dispatches, charged to the ``RecordEvent``
+    span of the host plane that covers most of it.
+
+    ``note`` says when the table cannot be trusted: an executable
+    loaded from a store written before its program named its ops keeps
+    the old ``op_name``s (the store's key ignores metadata), and most
+    of its time then reads ``unscoped``."""
+    names = hlo_op_names(optimized_hlo)
+    by_module = {m.split("(")[0]: v for m, v in names.items()}
+    planes = {}
     for ev in events:
-        rec = agg.setdefault(ev["name"],
-                             {"calls": 0, "total": 0.0,
-                              "min": float("inf"), "max": 0.0})
-        rec["calls"] += 1
-        d = ev["dur_ns"] / 1e6
-        rec["total"] += d
-        rec["min"] = min(rec["min"], d)
-        rec["max"] = max(rec["max"], d)
-    wall = sum(r["total"] for r in agg.values()) or 1.0
-    rows = [(n, r["calls"], r["total"], r["min"], r["max"],
-             r["total"] / r["calls"], r["total"] / wall)
-            for n, r in agg.items()]
-    rows.sort(key=lambda x: -x[2])
+        if not ev["host"] and _instruction(ev) is not None:
+            planes.setdefault(ev["plane"], []).append(ev)
+    modules = {}
+    for ev in events:
+        if ev["line"] == MODULE_LINE:
+            modules.setdefault(ev["plane"], []).append(
+                (ev["ts_ns"], ev["ts_ns"] + ev["dur_ns"],
+                 ev["name"].split("(")[0]))
+    spans = [ev for ev in events
+             if ev["host"] and _SPAN_STAT in (ev.get("stats") or {})]
+    n = max(1, len(planes))
+    tables = {k: {} for k in ("by_phase", "by_layer", "by_layer_op",
+                              "by_phase_layer", "collectives_by_layer",
+                              "async_collectives_by_layer")}
+    busy = unscoped = collective = window = 0.0
+    idle, gaps = {}, []
+
+    def module_at(plane, t):
+        mods = modules.get(plane)
+        if not mods:
+            return None
+        i = bisect.bisect_right(mods, (t, float("inf"), "")) - 1
+        return mods[i] if i >= 0 and mods[i][1] >= t else None
+
+    def scope_of(ev):
+        module, name = _instruction(ev)
+        if module is None:
+            at = module_at(ev["plane"], ev["ts_ns"])
+            module = at[2] if at else None
+        if module is None and len(by_module) == 1:
+            module = next(iter(by_module))  # no module line to ask
+        m = _SCOPE.search(by_module.get(module, {}).get(name, ""))
+        return m.groups() if m else None
+
+    def add(table, key, ms):
+        tables[table][key] = tables[table].get(key, 0.0) + ms
+
+    for plane, evs in planes.items():
+        if plane in modules:
+            modules[plane].sort()
+        lv = _leaves([e for e in evs if e["line"] != ASYNC_LINE])
+        if not lv:
+            continue
+        window += (max(e["ts_ns"] + e["dur_ns"] for e in lv)
+                   - lv[0]["ts_ns"]) / 1e6 / n
+        end = None
+        for ev in lv:
+            ms = ev["dur_ns"] / 1e6 / n
+            busy += ms
+            scope = scope_of(ev)
+            if _is_collective(ev):
+                # inside the rows below too: GSPMD's all-reduces keep
+                # the op_name of an op whose output they reduce
+                collective += ms
+                add("collectives_by_layer",
+                    scope[1] if scope else UNSCOPED, ms)
+            if scope is None:
+                # what XLA made itself (copies between memory spaces,
+                # a scan's bookkeeping) is told apart by its opcode
+                unscoped += ms
+                scope = (UNSCOPED, UNSCOPED,
+                         _opcode(_instruction(ev)[1]))
+            phase, layer, op = scope
+            add("by_phase", phase, ms)
+            add("by_layer", layer, ms)
+            add("by_layer_op", "%s %s" % (layer, op), ms)
+            add("by_phase_layer", "%s/%s" % (phase, layer), ms)
+            if end is not None and ev["ts_ns"] > end:
+                gaps.append((ev["ts_ns"] - end, plane, end))
+            end = max(end or 0.0, ev["ts_ns"] + ev["dur_ns"])
+        for ev in evs:
+            if ev["line"] == ASYNC_LINE and _is_collective(ev):
+                scope = scope_of(ev)
+                add("async_collectives_by_layer",
+                    scope[1] if scope else UNSCOPED,
+                    ev["dur_ns"] / 1e6 / n)
+    longest = []
+    for dur, plane, start in sorted(gaps, reverse=True):
+        at = module_at(plane, start)
+        if at is not None and at[1] >= start + dur:
+            where = "inside a program"
+        else:
+            # (a CPU trace has no program line: every gap lands here)
+            best, cover = None, 0.0
+            for sp in spans:
+                over = min(start + dur, sp["ts_ns"] + sp["dur_ns"]) \
+                    - max(start, sp["ts_ns"])
+                if over > cover or (over == cover and over > 0 and
+                                    sp["dur_ns"] < best["dur_ns"]):
+                    best, cover = sp, over
+            where = "between dispatches: %s" % (
+                best["name"] if best else "(no span)")
+        idle[where] = idle.get(where, 0.0) + dur / 1e6 / n
+        if len(longest) < 5:
+            longest.append([where, dur / 1e6])
+    note = None
+    if busy and unscoped > 0.5 * busy:
+        note = ("%.0f%% of the device time carries no scope: %s"
+                % (100.0 * unscoped / busy,
+                   "the executable was built before its program named "
+                   "its ops (a store or cache written by an older "
+                   "tree: its key ignores metadata) -- clear it and "
+                   "trace again" if any(by_module.values()) else
+                   "no optimized HLO was given to join the events "
+                   "against"))
+    return dict(tables, devices=len(planes), window_ms=window,
+                busy_ms=busy, unscoped_ms=unscoped,
+                collective_ms=collective, idle_ms=sum(idle.values()),
+                idle_by_cause=idle, longest_gaps=longest, note=note)
+
+
+def format_scope_table(table, steps=1) -> str:
+    """``scope_table``'s result as text; ``steps`` divides every time
+    (the steps the capture holds)."""
+    steps = max(1, steps)
+    unit = "ms/step" if steps > 1 else "ms"
+    busy = table["busy_ms"] or 1.0
     lines = ["------------------------->   Device (XLA) Report   "
-             "<-------------------------", "",
-             "%-40s %8s %12s %10s %10s %8s" %
-             ("Op", "Calls", "Total(ms)", "Min(ms)", "Max(ms)",
-              "Ratio")]
-    for name, calls, total, mn, mx, _ave, ratio in rows[:60]:
-        lines.append("%-40s %8d %12.4f %10.4f %10.4f %7.2f%%"
-                     % (name[:40], calls, total, mn, mx,
-                        ratio * 100.0))
+             "<-------------------------"]
+    if table["note"]:
+        lines.append("!! " + table["note"])
+    lines.append(
+        "%d device(s), %d step(s), %s: busy %.3f of a %.3f window, "
+        "idle %.3f, unscoped %.3f (%.2f%% of busy), collectives %.3f"
+        % (table["devices"], steps, unit, table["busy_ms"] / steps,
+           table["window_ms"] / steps, table["idle_ms"] / steps,
+           table["unscoped_ms"] / steps,
+           100.0 * table["unscoped_ms"] / busy,
+           table["collective_ms"] / steps))
+    for title, key, limit in (
+            ("Phase", "by_phase", None), ("Layer", "by_layer", None),
+            ("Phase/layer", "by_phase_layer", None),
+            ("Layer, op type", "by_layer_op", 30),
+            ("Collectives (inside the rows above), by layer",
+             "collectives_by_layer", None),
+            ("Asynchronous collectives (overlap the rows above)",
+             "async_collectives_by_layer", None),
+            ("Idle, by cause", "idle_by_cause", None)):
+        rows = sorted(table[key].items(), key=lambda kv: -kv[1])
+        if not rows:
+            continue
+        lines += ["", "%-52s %12s %8s" % (title, unit, "of busy")]
+        for name, ms in rows[:limit]:
+            lines.append("%-52s %12.4f %7.2f%%"
+                         % (name[:52], ms / steps, 100.0 * ms / busy))
+        if limit and len(rows) > limit:
+            lines.append("%-52s %12.4f" % (
+                "... %d more" % (len(rows) - limit),
+                sum(ms for _, ms in rows[limit:]) / steps))
+    if table["longest_gaps"]:
+        lines += ["", "Longest idle gaps (ms, whole capture)"]
+        lines += ["%-52s %12.4f" % (w[:52], ms)
+                  for w, ms in table["longest_gaps"]]
     return "\n".join(lines)
 
 
+def _registered_hlo():
+    """Optimized HLO of every executable the live Executors hold."""
+    return [rec["optimized_hlo"] for exe in list(_executors)
+            for rec in exe.aot_artifacts() if rec.get("optimized_hlo")]
+
+
+def device_scope_table():
+    """``scope_table`` of the last capture against the live Executors'
+    executables (computed once a capture)."""
+    global _device_table
+    with _lock:
+        events, table = list(_device_events), _device_table
+    if table is None:
+        table = scope_table(events,
+                            _registered_hlo() if events else None)
+        with _lock:
+            _device_table = table
+    return table
+
+
+def device_summary_table(sorted_key=None, steps=1) -> str:
+    """DEVICE time of the last capture by the program's scopes — phase,
+    layer kind, op type — with what stayed unscoped and what the idle
+    gaps waited for (reference: the 'GPU' rows of PrintProfiler +
+    tools/timeline.py device tracks; rows used to be raw HLO names,
+    which on a TPU are numbered fusions). ``sorted_key`` is accepted
+    for the reference's signature, rows sort by time; ``steps`` divides
+    every time (the steps the capture holds)."""
+    return format_scope_table(device_scope_table(), steps=steps)
+
+
 def export_chrome_tracing(path):
-    """ONE chrome://tracing JSON merging host RecordEvents and the
-    captured device-op events on separate tracks (reference:
-    tools/timeline.py merging profiler.proto host records with
-    device_tracer.cc CUPTI records). Host events are aligned to the
-    device timebase via the anchor captured at start_trace."""
+    """ONE chrome://tracing JSON merging host spans and the captured
+    device-op events on separate tracks (reference: tools/timeline.py
+    merging profiler.proto host records with device_tracer.cc CUPTI
+    records). After a device capture the host spans are the trace's
+    own (every RecordEvent is a TraceAnnotation in its host plane), so
+    both tracks share the capture's clock; without one they are this
+    module's perf_counter spans from their first."""
     with _lock:
         events = list(_events)
-        dev = list(_device_events)
-    if _trace_anchor is not None and dev:
-        base = _trace_anchor
-    elif events:
-        base = min(ev.start for ev in events)
+        captured = list(_device_events)
+    dev = [ev for ev in captured if not ev["host"]]
+    host_spans = [ev for ev in captured if ev["host"]
+                  and _SPAN_STAT in ev["stats"]]
+    if dev and host_spans:
+        lines = sorted({ev["line"] for ev in host_spans})
+        trace_events = [
+            {"name": ev["name"], "cat": "host", "ph": "X",
+             "ts": ev["ts_ns"] / 1e3, "dur": ev["dur_ns"] / 1e3,
+             "pid": 0, "tid": lines.index(ev["line"]),
+             "args": {k: v for k, v in ev["stats"].items()
+                      if k != _SPAN_STAT}}
+            for ev in host_spans if ev["name"] != _SYNC_SPAN]
+        sync = [ev for ev in host_spans if ev["name"] == _SYNC_SPAN]
+        now_wall = _sync_wall if sync and _sync_wall else time.time()
+        now_ts = sync[0]["ts_ns"] / 1e3 if sync else 0.0
     else:
-        base = 0.0
-    trace_events = [
-        {"name": ev.name, "cat": "host", "ph": "X",
-         "ts": (ev.start - base) * 1e6, "dur": ev.dur * 1e6,
-         "pid": 0, "tid": ev.thread % 10000,
-         "args": dict({"depth": ev.depth}, **(ev.args or {}))}
-        for ev in events]
+        base = min(ev.start for ev in events) if events else 0.0
+        trace_events = [
+            {"name": ev.name, "cat": "host", "ph": "X",
+             "ts": (ev.start - base) * 1e6, "dur": ev.dur * 1e6,
+             "pid": 0, "tid": ev.thread % 10000,
+             "args": dict({"depth": ev.depth}, **(ev.args or {}))}
+            for ev in events]
+        # wall-clock anchor: trace ts is perf_counter-based (per-process
+        # arbitrary epoch), so cross-process merge (tools/
+        # trace_merge.py) needs a (wall_time, trace_ts) correspondence
+        now_wall = time.time()
+        now_ts = (time.perf_counter() - base) * 1e6
     tids = {}
     for ev in dev:
         tid = tids.setdefault((ev["plane"], ev["line"]),
@@ -323,11 +702,6 @@ def export_chrome_tracing(path):
             {"name": ev["name"], "cat": "device", "ph": "X",
              "ts": ev["ts_ns"] / 1e3, "dur": ev["dur_ns"] / 1e3,
              "pid": 1, "tid": tid, "args": {"stream": ev["line"]}})
-    # wall-clock anchor: trace ts is perf_counter-based (per-process
-    # arbitrary epoch), so cross-process merge (tools/trace_merge.py)
-    # needs a (wall_time, trace_ts) correspondence to rebase timelines
-    now_wall = time.time()
-    now_ts = (time.perf_counter() - base) * 1e6
     from .observability import journal as _obs_journal
     meta = [{"name": "process_name", "ph": "M", "pid": 0,
              "args": {"name": "host"}},

@@ -192,8 +192,11 @@ def test_device_trace_merged_into_timeline(tmp_path):
              if e.get("cat") == "device"]
     assert any("dot" in n or "fusion" in n or "jit" in n
                for n in names), names[:20]
+    # the device table groups by the program's scopes, not by raw HLO
+    # names; plain jnp work outside any Executor carries none
     table = profiler.device_summary_table()
     assert "Device (XLA) Report" in table
-    assert any(tok in table for tok in ("dot", "fusion", "jit"))
+    assert "unscoped" in table and "Phase" in table
+    assert "carries no scope" in table.splitlines()[1]
     profiler.reset_profiler()
     assert profiler.device_summary_table().count("\n") <= 3

@@ -1,26 +1,35 @@
-"""Profile the flagship transformer-base train step on the current
-backend: capture the XLA device trace over a few scan'd steps and
-print the per-op device-time table (profiler.device_summary_table).
-Usage: python tools/profile_step.py [--iters 20] [--batch 64]
+"""Where a train step's device time goes, by the program's own scopes:
+capture a ``jax.profiler`` trace over a few dispatches and print
+``profiler.device_summary_table`` (phase, layer kind, op type, what
+stayed unscoped, what the idle gaps waited for), with the rate of the
+same dispatches traced and untraced.
+
+    python tools/profile_step.py [--iters 20] [--batch 64]
+    python tools/profile_step.py --workload tfm_base_scan [--out t.json]
+
+Without ``--workload`` the flagship transformer-base step at
+``--batch``; with it, one cell of ``BENCHMARK.json`` exactly as the
+benchmark builds it (its adapter, weights and batch from ``--seed``).
+A table is only as new as the executable: start from an empty compile
+cache (``JAX_COMPILATION_CACHE_DIR`` to a fresh directory) after a
+change to the scopes, or the store hands back an executable that
+still carries the old names (docs/compile.md).
 """
 import argparse
+import json
 import os
 import sys
+import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np  # noqa: E402
 
 
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--iters", type=int, default=20)
-    ap.add_argument("--batch", type=int, default=64)
-    ap.add_argument("--trace-dir", default="/tmp/flagship_trace")
-    args = ap.parse_args()
-
+def flagship(args):
+    """(dispatch, steps a dispatch) of transformer-base under AMP+Adam."""
+    import jax.numpy as jnp
     import paddle_tpu as fluid
-    from paddle_tpu import profiler
     from paddle_tpu.contrib import mixed_precision as amp
     from paddle_tpu.models import transformer as T
 
@@ -35,18 +44,70 @@ def main():
         opt.minimize(avg_cost)
     exe = fluid.Executor()
     exe.run(startup)
-    import jax.numpy as jnp
     feed = {k: jnp.asarray(v)
             for k, v in T.make_fake_batch(cfg, args.batch).items()}
-    run = lambda k: exe.run_repeated(main_p, feed=feed,  # noqa: E731
-                                     fetch_list=[avg_cost], iters=k)
+
+    def dispatch():
+        return exe.run_repeated(main_p, feed=feed,
+                                fetch_list=[avg_cost],
+                                iters=args.iters,
+                                return_numpy=False)[0]
+    return dispatch, args.iters
+
+
+def cell(args):
+    """(dispatch, steps a dispatch) of one benchmark cell."""
+    from benchmark import run as bench
+    c = bench.load_cell(args.workload, args.rehearse_cpu)
+    devices = bench.find_devices(c["chips"], args.rehearse_cpu)
+    system, _batch, _stats = bench.build_system(c, args.seed, devices)
+    return system.dispatch, system.steps_per_dispatch
+
+
+def timed(dispatch, n):
+    """Seconds for n dispatches, two in flight, every loss read."""
+    t0 = time.perf_counter()
+    current = dispatch()
+    for _ in range(n - 1):
+        ahead = dispatch()
+        np.asarray(current)
+        current = ahead
+    np.asarray(current)
+    return time.perf_counter() - t0
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--batch", type=int, default=64)
+    ap.add_argument("--workload")
+    ap.add_argument("--seed", type=int, default=1)
+    ap.add_argument("--dispatches", type=int, default=3)
+    ap.add_argument("--rehearse-cpu", action="store_true")
+    ap.add_argument("--trace-dir", default="/tmp/flagship_trace")
+    ap.add_argument("--out", help="write the table as JSON here too")
+    args = ap.parse_args()
+
+    from paddle_tpu import profiler
+    dispatch, spd = cell(args) if args.workload else flagship(args)
+    n = args.dispatches
     print("compiling + warmup...", file=sys.stderr, flush=True)
-    run(args.iters)
+    timed(dispatch, 2)
+    untraced = timed(dispatch, n)
     print("tracing...", file=sys.stderr, flush=True)
+    profiler.reset_profiler()
     profiler.start_profiler("All", trace_path=args.trace_dir)
-    run(args.iters)
-    profiler.stop_profiler()
-    print(profiler.device_summary_table())
+    traced = timed(dispatch, n)
+    profiler.stop_profiler(steps=n * spd)
+    print("%d dispatches of %d steps: %.4f s untraced, %.4f s traced "
+          "(%.2f%% slower)" % (n, spd, untraced, traced,
+                               100.0 * (traced / untraced - 1.0)))
+    if args.out:
+        table = dict(profiler.device_scope_table(), steps=n * spd,
+                     untraced_s=untraced, traced_s=traced)
+        os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+        with open(args.out, "w") as f:
+            json.dump(table, f, indent=1)
 
 
 if __name__ == "__main__":
