@@ -114,10 +114,11 @@ FLAGS.define("sdpa_auto_flash", True,
              "scaled_dot_product_attention's base lowering routes to "
              "the flash pallas kernel inside its envelope (TPU "
              "backend, <=2-byte dtype, dropout active, single-k-block "
-             "shapes) — the reference jit/ pool's best-impl-at-runtime "
+             "shapes: Sk <= 512, Sq at most 256 or a multiple of it) — "
+             "the reference jit/ pool's best-impl-at-runtime "
              "dispatch. bench.py pins this off for its pure-XLA base "
-             "row. Speed on this installation: not measured "
-             "(ROADMAP D2).")
+             "row. Measured in BERT-base S=512 training (PERF.md, "
+             "PR 27; ROADMAP D2).")
 
 FLAGS.define("sp_attention", True,
              "scaled_dot_product_attention's base lowering routes "

@@ -27,17 +27,25 @@ matrix in HBM in either direction:
   cuts the grid to 64 cells with 8x the work and 8x larger DMA
   transfers. G divides H, so a cell never straddles a batch row and
   per-BATCH bias blocks stay well-defined.
-- **Single-k-block specialization** (``_1k_applicable``: Sq<=256,
-  Sk<=512, natural tiling): when the whole key range fits one block,
-  the online-softmax machinery is dropped (plain softmax in
-  registers, no m/l scratch, no lane-replicated statistics), and the
-  backward is ONE kernel producing dq/dk/dv from a single exp
-  recompute with lse and delta derived in-kernel — the only HBM
-  residual is the forward output. The argument for it in-model:
-  XLA's fused chain pays RNG mask materialization + probs HBM
-  round-trips at all 18 attention sites. Its speed on this
-  installation is not measured (ROADMAP D2/D3 re-measure it through
-  the ledger); only in-model numbers decide.
+- **Single-k-block specialization** (``_1k_applicable``: Sk<=512,
+  and Sq at most 256 or a multiple of 256, natural tiling): when the
+  whole key range fits one block, the online-softmax machinery is
+  dropped (plain softmax in registers, no m/l scratch, no
+  lane-replicated statistics), and the backward is ONE kernel
+  producing dq/dk/dv from a single exp recompute with lse and delta
+  derived in-kernel — the only HBM residual is the forward output.
+  Queries are blocked ``_1K_BLK_Q`` rows to a grid step (a second grid
+  axis; k and v stay resident across it), so a q-block's ``[G, blk_q,
+  Sk]`` score tile is what VMEM holds; the backward sums dk/dv over
+  the q-blocks in float32 scratch. This is what
+  ``FLAGS_sdpa_auto_flash`` dispatches in training: transformer-base
+  (S=256, 18 sites, one q-block) and BERT-base at S=128 and S=512
+  (12 sites, two q-blocks). The argument for it in-model: XLA's fused
+  chain pays RNG mask materialization + probs HBM round-trips at
+  every attention site (BERT S=512: 136 ms of a 253 ms step, ledger
+  PR 26). Everything else — Sk > 512 (S=1024 self-attention), a
+  ragged Sq — takes the blocked kernels below under
+  ``FLAGS_op_library=pallas`` and XLA's chain by default.
 
 ``Bias`` is an additive attention mask (0 / -1e9, built from data by the
 models) and is registered non-differentiable: the base lowering and the
@@ -185,6 +193,7 @@ def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
                                              scale=scale,
                                              causal=causal)
         if routed is not None:
+            _count_lowering("sp")
             return routed
     if (FLAGS.sdpa_auto_flash and rate > 0.0 and rng is not None
             and not interpret_mode()
@@ -193,41 +202,57 @@ def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
         return sdpa_pallas(q, k, v, bias, scale=scale,
                            dropout_rate=dropout_rate, causal=causal,
                            is_test=is_test, rng=rng)
+    _count_lowering("xla")
     return _sdpa_reference(q, k, v, bias, scale=scale,
                            dropout_rate=rate, causal=causal, rng=rng)
 
 
+def _count_lowering(path):
+    """One bump each time an attention op is LOWERED (trace time, so
+    nothing in a step): ``sdpa_lowering.<path>`` with path ``flash_1k``,
+    ``flash_blocked``, ``xla`` or ``sp`` says, in ``counter_values()``,
+    ``/metrics`` and ``obs_dump``, which lowering a program's attention
+    sites took. A differentiated site is lowered once for the forward
+    and once more under ``jax.vjp``."""
+    from ... import profiler
+    profiler.bump_counter("sdpa_lowering." + path)
+
+
 # ---------------------------------------------------------------------------
-# single-k-block specialization (short sequences — the flagship S=256
-# and BERT S=128 shapes). When the whole key range fits one block the
+# single-k-block specialization (the flagship S=256 and BERT's S=128
+# and S=512 shapes). When the whole key range fits one block the
 # online-softmax machinery is pure overhead: no m/l scratch, no alpha
 # rescales, no lane-replicated statistics round-tripping through HBM.
 # The backward is ONE kernel computing dq/dk/dv together from a single
 # exp recompute (the blocked path needs two kernels = two recomputes),
 # with lse and delta = rowsum(dO*O) derived in-kernel so the only HBM
-# residual is the forward output itself.
+# residual is the forward output itself. Grid (cells, q-blocks): k and
+# v keep their block index across the q-blocks of a cell, so they are
+# fetched once a cell.
 # ---------------------------------------------------------------------------
 
 
-def _attn_scores(q_ref, k_ref, b_ref, *, scale, causal):
+def _attn_scores(q_ref, k_ref, b_ref, j, *, scale, causal):
     s = lax.dot_general(q_ref[...], k_ref[...], _QK,
                         preferred_element_type=jnp.float32) * scale
     if b_ref is not None:
         s = s + b_ref[:, 0].astype(jnp.float32)
     if causal:
-        s = _causal_mask(s, 0, 0, s.shape[1], s.shape[2])
-    return s                                        # [G, Sq, Sk] f32
+        s = _causal_mask(s, j, 0, s.shape[1], s.shape[2])
+    return s                                        # [G, blk_q, Sk] f32
 
 
 def _fwd_kernel_1k(seed_ref, q_ref, k_ref, v_ref, b_ref, o_ref, *,
-                   scale, rate, causal):
+                   scale, rate, causal, n_q):
     i = pl.program_id(0)
-    s = _attn_scores(q_ref, k_ref, b_ref, scale=scale, causal=causal)
+    j = pl.program_id(1)
+    s = _attn_scores(q_ref, k_ref, b_ref, j, scale=scale,
+                     causal=causal)
     m = jnp.max(s, -1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, -1, keepdims=True)
     if rate > 0.0:
-        keep = _dropout_keep(seed_ref, i, 0, 0, 1, 1, p.shape, rate)
+        keep = _dropout_keep(seed_ref, i, j, 0, n_q, 1, p.shape, rate)
         p = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
     pv = lax.dot_general(p.astype(v_ref.dtype), v_ref[...], _PV,
                          preferred_element_type=jnp.float32)
@@ -238,57 +263,98 @@ def _fwd_kernel_1k(seed_ref, q_ref, k_ref, v_ref, b_ref, o_ref, *,
 
 
 def _bwd_kernel_1k(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, o_ref,
-                   dq_ref, dk_ref, dv_ref, *, scale, rate, causal):
+                   dq_ref, dk_ref, dv_ref, *acc, scale, rate, causal,
+                   n_q):
     i = pl.program_id(0)
-    s = _attn_scores(q_ref, k_ref, b_ref, scale=scale, causal=causal)
+    j = pl.program_id(1)
+    s = _attn_scores(q_ref, k_ref, b_ref, j, scale=scale,
+                     causal=causal)
     m = jnp.max(s, -1, keepdims=True)
     e = jnp.exp(s - m)
     l = jnp.sum(e, -1, keepdims=True)
-    rl = 1.0 / jnp.where(l == 0.0, 1.0, l)          # [G, Sq, 1]
-    p = e * rl                                      # [G, Sq, Sk] f32
-    do = do_ref[...]                                # [G, Sq, Dh]
+    rl = 1.0 / jnp.where(l == 0.0, 1.0, l)          # [G, blk_q, 1]
+    p = e * rl                                      # [G, blk_q, Sk] f32
+    do = do_ref[...]                                # [G, blk_q, Dh]
     delta = jnp.sum(do.astype(jnp.float32)
                     * o_ref[...].astype(jnp.float32), -1,
-                    keepdims=True)                  # [G, Sq, 1]
+                    keepdims=True)                  # [G, blk_q, 1]
     dp = lax.dot_general(do, v_ref[...], _QK,
                          preferred_element_type=jnp.float32)
     if rate > 0.0:
-        keep = _dropout_keep(seed_ref, i, 0, 0, 1, 1, p.shape, rate)
+        keep = _dropout_keep(seed_ref, i, j, 0, n_q, 1, p.shape, rate)
         inv = 1.0 / (1.0 - rate)
         pd = jnp.where(keep, p * inv, 0.0)
         dp = jnp.where(keep, dp * inv, 0.0)
     else:
         pd = p
-    dv_ref[...] = lax.dot_general(
-        pd.astype(do.dtype), do, _TT,
-        preferred_element_type=jnp.float32).astype(dv_ref.dtype)
+    dv = lax.dot_general(pd.astype(do.dtype), do, _TT,
+                         preferred_element_type=jnp.float32)
     ds = (p * (dp - delta) * scale).astype(q_ref.dtype)
     dq_ref[...] = lax.dot_general(
         ds, k_ref[...], _PV,
         preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk_ref[...] = lax.dot_general(
-        ds, q_ref[...], _TT,
-        preferred_element_type=jnp.float32).astype(dk_ref.dtype)
+    dk = lax.dot_general(ds, q_ref[...], _TT,
+                         preferred_element_type=jnp.float32)
+    if n_q == 1:
+        dk_ref[...] = dk.astype(dk_ref.dtype)
+        dv_ref[...] = dv.astype(dv_ref.dtype)
+        return
+    # dk, dv are sums over the cell's q-blocks: float32 scratch over
+    # the "arbitrary" j axis, written out once at the last block
+    dk_acc, dv_acc = acc
+
+    @pl.when(j == 0)
+    def _first():
+        dk_acc[...] = dk
+        dv_acc[...] = dv
+
+    @pl.when(j > 0)
+    def _rest():
+        dk_acc[...] += dk
+        dv_acc[...] += dv
+
+    @pl.when(j == n_q - 1)
+    def _finish():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+# Query rows to a grid step of the single-k-block kernels: the whole
+# Sq up to 256 (one q-block: the S=256 flagship and BERT's S=128, as
+# before the q axis existed), 256-row blocks above it (BERT's S=512:
+# two). 256 x 512 is the score tile PR 21 compiled on the v5e.
+_1K_BLK_Q = 256
+
+
+def _1k_blk_q(Sq):
+    return min(Sq, _1K_BLK_Q)
 
 
 def _1k_applicable(Sq, Sk):
-    # whole key range in one block, natural TPU tiling (no padding)
-    return (Sq <= 256 and Sk <= 512
-            and Sq % 8 == 0 and Sk % 128 == 0)
+    """The envelope FLAGS_sdpa_auto_flash dispatches, and the switch
+    between the single-k-block and the blocked kernels: the whole key
+    range in one block, whole q-blocks, natural TPU tiling (no
+    padding). Sk > 512 (S=1024 self-attention) and a ragged Sq
+    (384, 520) stay outside."""
+    if Sk > 512 or Sk % 128:
+        return False
+    return Sq % 8 == 0 if Sq <= _1K_BLK_Q else Sq % _1K_BLK_Q == 0
 
 
 # VMEM model for the single-k-block kernels (ADVICE r4: the corner
 # Sq=256/Sk=512 exceeded scoped VMEM at the uncapped G=8). Per grid
 # row the kernels hold:
-#   - streamed blocks, double-buffered: q/do/o/dq rows of Sq, and
+#   - streamed blocks, double-buffered: q/do/o/dq rows of blk_q, and
 #     k/v/dk/dv rows of Sk, each lane-padded to 128 in the minor dim;
-#   - [G,Sq,Sk] f32 score temporaries. 8 bytes/element — ~2 f32
+#   - [G,blk_q,Sk] f32 score temporaries. 8 bytes/element — ~2 f32
 #     arrays live after Mosaic's buffer reuse. This constant is
 #     ANCHORED on chip evidence, not source-level counting: the
 #     bf16 [8,256,256] backward (5 source-level f32 temps = 20 B/elem
 #     would predict 22 MB) compiles and runs at G=8 (jax 0.9.0 /
 #     libtpu 0.0.34, PR 21: chip_smoke.py and test_chip_kernels.py),
-#     so Mosaic demonstrably reuses all but ~2.
+#     so Mosaic demonstrably reuses all but ~2;
+#   - with more than one q-block, the backward's two [G,Sk,Dh] f32
+#     dk/dv accumulators (scratch: resident once, lane-padded).
 # Budget 15 MB of the 16 MB v5e scoped limit; G halves until the
 # modeled row total fits. tests/test_pallas_vmem.py replays this
 # model at every _1k_applicable corner AND pins the headline
@@ -297,31 +363,37 @@ _1K_TEMP_BYTES = 8
 _1K_VMEM_BUDGET = 15 << 20
 
 # Blocked-path tile targets, env-tunable for on-chip sweeps
-# (tools/blocked_sweep.py): PALLAS_BLK_Q / PALLAS_BLK_K. Any change
-# must be chip-measured in-model at S>=1024 first (the blocked path
-# never dispatches at the S=256 flagship — _1k_applicable owns that
-# envelope).
+# (tools/blocked_sweep.py): PALLAS_BLK_Q / PALLAS_BLK_K. The blocked
+# path runs only under FLAGS_op_library=pallas and only outside
+# _1k_applicable (Sk > 512, or a ragged Sq): no default program
+# dispatches it, so any change must be chip-measured in-model at
+# S>=1024 first.
 _BLK_Q_TARGET = int(os.environ.get("PALLAS_BLK_Q", "256"))
 _BLK_K_TARGET = int(os.environ.get("PALLAS_BLK_K", "512"))
 
 
-def _1k_row_bytes(itemsize, Sq, Sk, Dh, n_sq_ops, n_sk_ops, has_bias):
+def _1k_row_bytes(itemsize, Sq, Sk, Dh, n_sq_ops, n_sk_ops, has_bias,
+                  accumulates=False):
     lanes = max(Dh, 128)
-    stream = (n_sq_ops * Sq + n_sk_ops * Sk) * lanes * itemsize * 2
-    temps = Sq * Sk * _1K_TEMP_BYTES
+    blk_q = _1k_blk_q(Sq)
+    stream = (n_sq_ops * blk_q + n_sk_ops * Sk) * lanes * itemsize * 2
+    temps = blk_q * Sk * _1K_TEMP_BYTES
     if has_bias:
         # bias block (streamed, double-buffered; charged per-row even
         # for the shared non-per-head slab — conservative) plus the
         # s + b f32 addend the biased kernel keeps live
-        temps += Sq * Sk * (itemsize * 2 + 4)
+        temps += blk_q * Sk * (itemsize * 2 + 4)
+    if accumulates and Sq > blk_q:
+        temps += 2 * Sk * lanes * 4
     return stream + temps
 
 
 def _1k_bwd_G(H, itemsize, Sq, Sk, Dh, has_bias=False):
     """Backward rows per grid cell, capped by the VMEM model
-    (streams: q,do,o,dq + k,v,dk,dv)."""
+    (streams: q,do,o,dq + k,v,dk,dv; the dk/dv accumulators)."""
     base = 8 if itemsize <= 2 else 4
-    row = _1k_row_bytes(itemsize, Sq, Sk, Dh, 4, 4, has_bias)
+    row = _1k_row_bytes(itemsize, Sq, Sk, Dh, 4, 4, has_bias,
+                        accumulates=True)
     while base > 1 and base * row > _1K_VMEM_BUDGET:
         base //= 2
     return blk(H, base)
@@ -330,8 +402,9 @@ def _1k_bwd_G(H, itemsize, Sq, Sk, Dh, has_bias=False):
 def _1k_fwd_G(H, itemsize, rate, Sq, Sk, Dh, has_bias=False):
     """Forward rows per grid cell. With dropout it MUST equal the
     backward's G (the per-cell PRNG seed mapping — see _blocked_G's
-    invariant note); without dropout the forward only needs its own
-    streams (q,o + k,v) to fit."""
+    invariant note; blk_q is _1k_blk_q(Sq) on both sides); without
+    dropout the forward only needs its own streams (q,o + k,v) to
+    fit."""
     if rate > 0.0:
         return _1k_bwd_G(H, itemsize, Sq, Sk, Dh, has_bias)
     base = 8
@@ -345,11 +418,13 @@ def _blocked_G(H):
     """(batch, head) rows per grid cell of the blocked kernels — ONE
     choice shared by forward and both backward kernels.
 
-    The in-kernel dropout mask is seeded per grid CELL
-    (_dropout_keep), so the (batch, head) -> cell mapping MUST be
-    identical in the kernels that generate and regenerate it: a
-    fwd G=8 / bwd G=4 split silently regenerates different masks for
-    every head the two groupings assign to different cells.
+    The in-kernel dropout mask is seeded per grid CELL and q-/k-block
+    (_dropout_keep), so the (batch, head) -> cell mapping and the
+    block sizes MUST be identical in the kernels that generate and
+    regenerate it: a fwd G=8 / bwd G=4 split silently regenerates
+    different masks for every head the two groupings assign to
+    different cells. (The single-k-block pair keeps the same
+    invariant through _1k_fwd_G / _1k_bwd_G / _1k_blk_q.)
 
     2 is what Mosaic accepts on a v5e at the 256x512 tiles (chip runs,
     PR 21): G=8 ran out of the 16 MB scoped VMEM in the f32 forward,
@@ -371,41 +446,55 @@ def _seed_smem(seed_f, G):
 
 
 def _1k_specs_args(q, k, v, bias, per_head, seed, G, hb):
-    """Shared in_specs/args plumbing for the single-k-block kernels."""
+    """Shared in_specs/args plumbing for the single-k-block kernels:
+    grid (cells i, q-blocks j). Returns (in_specs, args, the spec of
+    a q-side block, the spec of a k-side block)."""
     B, H, Sq, Dh = q.shape
     Sk = k.shape[2]
     BH = B * H
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec((G, Sq, Dh), lambda i: (i, 0, 0)),
-        pl.BlockSpec((G, Sk, Dh), lambda i: (i, 0, 0)),
-        pl.BlockSpec((G, Sk, Dh), lambda i: (i, 0, 0)),
-    ]
+    blk_q = _1k_blk_q(Sq)
+    q_spec = pl.BlockSpec((G, blk_q, Dh), lambda i, j: (i, j, 0))
+    k_spec = pl.BlockSpec((G, Sk, Dh), lambda i, j: (i, 0, 0))
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, k_spec,
+                k_spec]
     args = [seed, q.reshape(BH, Sq, Dh), k.reshape(BH, Sk, Dh),
             v.reshape(BH, Sk, Dh)]
     if bias is not None:
         if per_head:
-            in_specs.append(pl.BlockSpec((G, 1, Sq, Sk),
-                                         lambda i: (i, 0, 0, 0)))
+            in_specs.append(pl.BlockSpec((G, 1, blk_q, Sk),
+                                         lambda i, j: (i, 0, j, 0)))
         else:
-            in_specs.append(pl.BlockSpec((1, 1, Sq, Sk),
-                                         lambda i: (i // hb, 0, 0, 0)))
+            in_specs.append(pl.BlockSpec(
+                (1, 1, blk_q, Sk), lambda i, j: (i // hb, 0, j, 0)))
         args.append(bias)
-    return in_specs, args
+    return in_specs, args, q_spec, k_spec
 
 
+# The two wrappers are jitted so that the sites of a program that
+# present one signature (BERT-base: 12, transformer-base: 6 + 6 + 6)
+# share ONE trace and ONE lowered Mosaic body, called from each site
+# (XLA inlines the calls). Two things hang on it (my chip runs, PR 27):
+# lowered per site, BERT's 36 bodies added 5.5 s to every start of its
+# S=512 step (`trace_lower_s` 16.3 s against 10.8 s); and the executor
+# lowers a differentiated op twice (forward, then again under
+# jax.vjp), whose two bodies, lowered apart, differ in the call-stack
+# locations a Mosaic body serializes, so XLA cannot merge the two
+# forward calls and runs both. One body makes them identical and CSE
+# drops one: 11 ms of BERT's 189 ms step, 8 ms of transformer-base's.
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
 def _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate, causal):
     B, H, Sq, Dh = q.shape
     Sk = k.shape[2]
     BH = B * H
+    n_q = Sq // _1k_blk_q(Sq)
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
     G = _1k_fwd_G(H, q.dtype.itemsize, rate, Sq, Sk, Dh,
                   bias is not None)
     hb = H // G
     seed = _seed_smem(seed_f, G)
 
-    in_specs, args = _1k_specs_args(q, k, v, bias, per_head, seed, G,
-                                    hb)
+    in_specs, args, q_spec, _ = _1k_specs_args(q, k, v, bias, per_head,
+                                               seed, G, hb)
     if bias is not None:
         kernel = _fwd_kernel_1k
     else:
@@ -414,54 +503,56 @@ def _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate, causal):
 
     out = pl.pallas_call(
         functools.partial(kernel, scale=scale, rate=rate,
-                          causal=causal),
+                          causal=causal, n_q=n_q),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
-        grid=(BH // G,),
+        grid=(BH // G, n_q),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((G, Sq, Dh), lambda i: (i, 0, 0)),
+        out_specs=q_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret_mode(),
     )(*args)
     return out.reshape(B, H, Sq, Dh)
 
 
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
 def _flash_bwd_1k(q, k, v, bias, seed_f, o, g, scale, rate, causal):
     B, H, Sq, Dh = q.shape
     Sk = k.shape[2]
     BH = B * H
+    n_q = Sq // _1k_blk_q(Sq)
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
     G = _1k_bwd_G(H, q.dtype.itemsize, Sq, Sk, Dh, bias is not None)
     hb = H // G
     seed = _seed_smem(seed_f, G)
 
-    in_specs, args = _1k_specs_args(q, k, v, bias, per_head, seed, G,
-                                    hb)
+    in_specs, args, q_spec, k_spec = _1k_specs_args(
+        q, k, v, bias, per_head, seed, G, hb)
     if bias is not None:
         kernel = _bwd_kernel_1k
     else:
         kernel = (lambda sr, qr, kr, vr, dor, orf, *outs, **kw:
                   _bwd_kernel_1k(sr, qr, kr, vr, None, dor, orf,
                                  *outs, **kw))
-    in_specs += [pl.BlockSpec((G, Sq, Dh), lambda i: (i, 0, 0)),
-                 pl.BlockSpec((G, Sq, Dh), lambda i: (i, 0, 0))]
+    in_specs += [q_spec, q_spec]
     args += [g.reshape(BH, Sq, Dh), o.reshape(BH, Sq, Dh)]
 
     dq, dk, dv = pl.pallas_call(
         functools.partial(kernel, scale=scale, rate=rate,
-                          causal=causal),
+                          causal=causal, n_q=n_q),
         out_shape=[jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
                    jax.ShapeDtypeStruct((BH, Sk, Dh), k.dtype),
                    jax.ShapeDtypeStruct((BH, Sk, Dh), v.dtype)],
-        grid=(BH // G,),
+        grid=(BH // G, n_q),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((G, Sq, Dh), lambda i: (i, 0, 0)),
-            pl.BlockSpec((G, Sk, Dh), lambda i: (i, 0, 0)),
-            pl.BlockSpec((G, Sk, Dh), lambda i: (i, 0, 0)),
-        ],
+        out_specs=[q_spec, k_spec, k_spec],
+        # one q-block: nothing is carried from one grid step to the
+        # next; more: dk/dv accumulate across j
+        scratch_shapes=[] if n_q == 1 else
+        [pltpu.VMEM((G, Sk, Dh), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=(
+                "parallel", "parallel" if n_q == 1 else "arbitrary")),
         interpret=interpret_mode(),
     )(*args)
     return (dq.reshape(B, H, Sq, Dh), dk.reshape(B, H, Sk, Dh),
@@ -845,8 +936,11 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
     if rate > 0.0 and (rng is None or interpret_mode()):
         # the TPU PRNG has no interpreter emulation; CPU tests take the
         # reference path (dropout masks differ across libraries anyway)
+        _count_lowering("xla")
         return _sdpa_reference(q, k, v, bias, scale=scale,
                                dropout_rate=rate, causal=causal, rng=rng)
+    _count_lowering("flash_1k" if _1k_applicable(q.shape[2], k.shape[2])
+                    else "flash_blocked")
     if rate > 0.0:
         # fold the step key into a scalar TPU PRNG seed; float32 carries
         # it through custom_vjp without an int-cotangent (float0) dance

@@ -1,6 +1,7 @@
 """Every registered Pallas variant, COMPILED by Mosaic on the chip at
 the shapes transformer-base presents (B=64, H=8, S=256, Dh=64,
-N=B*S=16384 rows, d_model 512, vocab 30,000), S=1024 for the blocked
+N=B*S=16384 rows, d_model 512, vocab 30,000), BERT-base's S=512
+attention (b=56, h=12: two q-blocks to a cell), S=1024 for the blocked
 flash path and the per-hop shapes for ops/pallas/ring.py — each against
 its jnp reference at the tolerance tests/test_pallas_kernels.py uses
 for that kernel (bf16 inputs: bf16 tolerance).
@@ -31,6 +32,7 @@ pytestmark = [
 ]
 
 B, H, S, DH = 64, 8, 256, 64       # transformer-base attention
+BERT = (56, 12, 512)               # bert_base_s512_scan's b, h, S
 N, D, V = B * S, 512, 30000        # rows, d_model, vocab
 F32 = dict(rtol=5e-5, atol=1e-5)
 F32_GRAD = dict(rtol=5e-4, atol=5e-5)
@@ -99,31 +101,35 @@ def _sdpa_fwd_and_grads(q, k, v, bias, causal, fwd_tol, grad_tol):
 # -- the default path: single-k-block flash pair ---------------------------
 
 @pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("geom", [(B, H, S), BERT])
 @pytest.mark.parametrize("dtype,fwd_tol,grad_tol", [
     (jnp.bfloat16, BF16, dict(rtol=5e-2, atol=5e-2)),
     (jnp.float32, F32, F32_GRAD)])
-def test_flash_1k_matches_reference(dtype, fwd_tol, grad_tol, causal):
-    assert A._1k_applicable(S, S)
-    b = B if dtype == jnp.bfloat16 else 8
-    q, k, v = _qkv(0, b, H, S, S, dtype)
+def test_flash_1k_matches_reference(dtype, fwd_tol, grad_tol, geom,
+                                    causal):
+    b, h, s = geom
+    assert A._1k_applicable(s, s)
+    if dtype == jnp.float32:
+        b = 8
+    q, k, v = _qkv(0, b, h, s, s, dtype)
     with _exact_if_f32(dtype):
-        _sdpa_fwd_and_grads(q, k, v, _pad_bias(1, b, S, S), causal,
+        _sdpa_fwd_and_grads(q, k, v, _pad_bias(1, b, s, s), causal,
                             fwd_tol, grad_tol)
         _sdpa_fwd_and_grads(q, k, v, None, causal, fwd_tol, grad_tol)
 
 
-def _dropout_checks(sq, sk, b):
+def _dropout_checks(sq, sk, b, h=H, bias=None):
     """What can be said about in-kernel dropout without the mask:
-    deterministic in the seed, different across seeds and grid cells,
-    the kept share near 1-rate, and the backward regenerating exactly
-    the forward's mask — out is linear in V, out = A(mask) V, so dV
-    must be A(mask)^T dOut with the SAME mask."""
+    deterministic in the seed, different across seeds, grid cells and
+    q-blocks, the kept share near 1-rate, and the backward
+    regenerating exactly the forward's mask — out is linear in V,
+    out = A(mask) V, so dV must be A(mask)^T dOut with the SAME mask."""
     rate, scale = 0.1, DH ** -0.5
-    q, k, v = _qkv(2, b, H, sq, sk, jnp.bfloat16)
+    q, k, v = _qkv(2, b, h, sq, sk, jnp.bfloat16)
     seed = jnp.asarray([1234, 0], jnp.float32)
 
     def fwd(q_, k_, v_, s=seed):
-        return A._sdpa_flash(q_, k_, v_, None, s, scale, rate, False)
+        return A._sdpa_flash(q_, k_, v_, bias, s, scale, rate, False)
 
     out = jax.jit(fwd)(q, k, v)
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
@@ -142,6 +148,8 @@ def _dropout_checks(sq, sk, b):
     assert share.std() > 0.0
     cells = share.reshape(-1, sq)
     assert not np.array_equal(cells[0], cells[-1])
+    if sq > 256:                   # q-blocks of one cell draw apart
+        assert not np.array_equal(cells[0][:256], cells[0][256:512])
 
     # adjoint identity without cancellation: with cotangent A v',
     # <dV, v'> = <A^T A v', v'> = |A v'|^2
@@ -161,6 +169,16 @@ def test_flash_1k_dropout_prng():
     """The exact configuration the model compiles 18 times: bf16,
     b64 h8 S=256, dropout 0.1, in-kernel pltpu PRNG."""
     _dropout_checks(S, S, B)
+
+
+def test_flash_1k_dropout_prng_bert_s512():
+    """BERT-base's 12 sites: bf16, b56 h12 S=512, the pad bias, two
+    q-blocks to a cell — the adjoint identity holds only if the
+    backward regenerates the forward's mask in EACH q-block (same G,
+    same blk_q, same (cell, q-block) seed)."""
+    b, h, s = BERT
+    assert A._1k_applicable(s, s) and s > A._1k_blk_q(s)
+    _dropout_checks(s, s, b, h, _pad_bias(11, b, s, s))
 
 
 # -- blocked online-softmax path (S=1024) ----------------------------------

@@ -299,12 +299,60 @@ def test_fused_linear_xent_3d_and_bf16():
                                rtol=0.05, atol=0.05)
 
 
+def _qkv_bias(r, B, H, Sq, Sk, Dh, bias_kind):
+    q = jnp.asarray(r.randn(B, H, Sq, Dh).astype(np.float32))
+    k = jnp.asarray(r.randn(B, H, Sk, Dh).astype(np.float32))
+    v = jnp.asarray(r.randn(B, H, Sk, Dh).astype(np.float32))
+    shape = {None: None, "batch": (B, 1, 1, Sk),
+             "head": (B, H, Sq, Sk)}[bias_kind]
+    bias = None
+    if shape is not None:
+        keep = r.rand(*shape) > 0.2
+        keep[..., 0] = True          # no row wholly masked
+        bias = jnp.asarray(np.where(keep, 0.0, -1e9).astype(np.float32))
+    return q, k, v, bias
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("bias_kind", [None, "batch", "head"])
+@pytest.mark.parametrize("Sq,Sk", [(512, 512), (512, 256)])
+def test_sdpa_flash_1k_q_blocked(Sq, Sk, bias_kind, causal):
+    """The single-k-block pair with more than one q-block (BERT's
+    S=512): forward, and dq / dk / dv — dk and dv are SUMS over the
+    q-blocks of a cell, which a wrong accumulator breaks. H=6 puts two
+    cells in a batch row in the backward (G=3), so a per-batch bias is
+    indexed across cells as well as across q-blocks."""
+    from paddle_tpu.ops.pallas import attention as A
+
+    B, H, Dh = 2, 6, 16
+    assert A._1k_applicable(Sq, Sk) and Sq // A._1k_blk_q(Sq) == 2
+    assert A._1k_bwd_G(H, 4, Sq, Sk, Dh, bias_kind is not None) < H
+    q, k, v, bias = _qkv_bias(np.random.RandomState(21), B, H, Sq, Sk,
+                              Dh, bias_kind)
+    kw = dict(scale=Dh ** -0.5, causal=causal)
+    _cmp("scaled_dot_product_attention", (q, k, v, bias), kw,
+         rtol=5e-5, atol=1e-5)
+    opdef = ops.get("scaled_dot_product_attention")
+
+    def loss(fn):
+        return lambda q_, k_, v_: jnp.sum(jnp.square(
+            fn(q_, k_, v_, bias, **kw)))
+
+    gr = jax.grad(loss(opdef.fn), (0, 1, 2))(q, k, v)
+    gp = jax.grad(loss(opdef.variants["pallas"]), (0, 1, 2))(q, k, v)
+    for a, b in zip(gr, gp):
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a),
+                                   rtol=5e-4, atol=5e-5)
+
+
 @pytest.mark.parametrize("S,dtype", [(256, "float32"),
+                                     (512, "bfloat16"),
                                      (1024, "bfloat16")])
 def test_attention_dropout_grouping_consistent(monkeypatch, S, dtype):
-    """The dropout mask is seeded per grid CELL, so with dropout on the
-    forward and every backward kernel must group (batch, head) rows
-    into cells identically — single-k-block (S=256) and blocked
+    """The dropout mask is seeded per grid CELL and q-block, so with
+    dropout on the forward and every backward kernel must group
+    (batch, head) rows into cells identically and block q alike —
+    single-k-block (S=256: one q-block; S=512: two) and blocked
     (S=1024) paths alike. A fwd G=8 / bwd G=4 split regenerates
     different masks for heads the groupings assign to different cells:
     silently wrong gradients."""
@@ -326,18 +374,57 @@ def test_attention_dropout_grouping_consistent(monkeypatch, S, dtype):
     assert len(calls) >= 2                     # fwd + bwd kernel(s)
     assert len({c["grid"][0] for c in calls}) == 1, \
         [c["grid"] for c in calls]
+    # the q block every kernel streams: same rows, same blk_q
+    assert len({tuple(c["in_specs"][1].block_shape)
+                for c in calls}) == 1
+    if A._1k_applicable(S, S):
+        assert {c["grid"] for c in calls} == {
+            (calls[0]["grid"][0], S // A._1k_blk_q(S))}
 
 
-def test_sdpa_auto_flash_dispatch_envelope(monkeypatch):
+_ENVELOPE_CASES = [
+    # Sq, Sk, dtype, rate, flag, dispatched
+    (256, 256, "bfloat16", 0.1, True, True),
+    (512, 512, "bfloat16", 0.1, True, True),      # BERT phase 2
+    (512, 256, "bfloat16", 0.1, True, True),
+    (256, 512, "bfloat16", 0.1, True, True),
+    (1024, 1024, "bfloat16", 0.1, True, False),   # Sk > 512
+    (384, 512, "bfloat16", 0.1, True, False),     # ragged q-blocks
+    (520, 512, "bfloat16", 0.1, True, False),
+    (256, 256, "float32", 0.1, True, False),      # f32: stays XLA
+    (256, 256, "bfloat16", 0.0, True, False),     # no dropout
+    (256, 256, "bfloat16", 0.1, False, False),    # flag off
+]
+
+
+def _run_base_sdpa(Sq, Sk, dtype, rate, flag):
+    from paddle_tpu.ops.pallas import attention as A
+
+    prev = FLAGS.sdpa_auto_flash
+    FLAGS.sdpa_auto_flash = flag
+    try:
+        # non-degenerate inputs: BOTH paths must run clean — a crash
+        # in either is a real failure (ADVICE r4: a blanket except
+        # here swallowed the dispatched path's errors too)
+        q = jnp.full((2, 4, Sq, 64), 0.1, dtype)
+        k = jnp.full((2, 4, Sk, 64), 0.1, dtype)
+        return A.scaled_dot_product_attention(
+            q, k, k, None, scale=0.125, dropout_rate=rate,
+            rng=jax.random.key(0))
+    finally:
+        FLAGS.sdpa_auto_flash = prev
+
+
+@pytest.mark.parametrize("Sq,Sk,dtype,rate,flag,dispatched",
+                         _ENVELOPE_CASES)
+def test_sdpa_auto_flash_dispatch_envelope(monkeypatch, Sq, Sk, dtype,
+                                           rate, flag, dispatched):
     """FLAGS_sdpa_auto_flash routes the BASE lowering to the flash
     kernel exactly inside the chip-measured win envelope: TPU
-    execution, <=2-byte dtype, dropout active, single-k-block shapes.
-    Everything else (f32, no dropout, long sequences, interpret mode)
+    execution, <=2-byte dtype, dropout active, single-k-block shapes
+    (Sk <= 512; Sq at most 256 or whole 256-row q-blocks). Everything
+    else (f32, no dropout, longer keys, ragged q, interpret mode)
     keeps the XLA chain."""
-    import jax
-    import jax.numpy as jnp
-
-    from paddle_tpu.core.flags import FLAGS
     from paddle_tpu.ops.pallas import attention as A
 
     calls = []
@@ -345,29 +432,68 @@ def test_sdpa_auto_flash_dispatch_envelope(monkeypatch):
     monkeypatch.setattr(
         A, "sdpa_pallas",
         lambda q, k, v, b, **kw: calls.append("flash") or q)
-    rng = jax.random.key(0)
+    _run_base_sdpa(Sq, Sk, dtype, rate, flag)
+    assert calls == (["flash"] if dispatched else [])
 
-    def run(S=256, dtype=jnp.bfloat16, rate=0.1, auto=True):
-        calls.clear()
-        prev = FLAGS.sdpa_auto_flash
-        FLAGS.sdpa_auto_flash = auto
-        try:
-            # non-degenerate inputs: BOTH paths must run clean — a
-            # crash in either is a real failure (ADVICE r4: a blanket
-            # except here swallowed the dispatched path's errors too)
-            q = jnp.full((2, 4, S, 64), 0.1, dtype)
-            A.scaled_dot_product_attention(
-                q, q, q, None, scale=0.125, dropout_rate=rate,
-                rng=rng)
-        finally:
-            FLAGS.sdpa_auto_flash = prev
-        return calls == ["flash"]
 
-    assert run()                              # envelope: dispatches
-    assert not run(dtype=jnp.float32)         # f32: stays XLA
-    assert not run(rate=0.0)                  # no dropout: stays XLA
-    assert not run(S=1024)                    # blocked shapes: XLA
-    assert not run(auto=False)                # flag off: stays XLA
+def _lowerings_counted(fn):
+    """{path: bumps} of the ``sdpa_lowering.*`` counters over fn()."""
+    from paddle_tpu import profiler
+
+    def read():
+        return {k.split(".", 1)[1]: v
+                for k, v in profiler.counter_values().items()
+                if k.startswith("sdpa_lowering.")}
+
+    before = read()
+    fn()
+    after = read()
+    return {k: after[k] - before.get(k, 0.0) for k in after
+            if after[k] != before.get(k, 0.0)}
+
+
+@pytest.mark.parametrize("Sq,Sk,dtype,rate,flag,dispatched",
+                         _ENVELOPE_CASES)
+def test_sdpa_lowering_counter_names_the_path(monkeypatch, Sq, Sk,
+                                              dtype, rate, flag,
+                                              dispatched):
+    """``sdpa_lowering.<path>`` counts each lowering under the path it
+    took: the envelope's cases read flash_1k, the rest xla."""
+    from test_pallas_vmem import _capture_calls
+
+    from paddle_tpu.ops.pallas import attention as A
+
+    monkeypatch.setattr(A, "interpret_mode", lambda: False)
+
+    def lower():
+        _run_base_sdpa(Sq, Sk, dtype, rate, flag)
+
+    moved = _lowerings_counted(
+        (lambda: _capture_calls(lower)) if dispatched else lower)
+    assert moved == {"flash_1k" if dispatched else "xla": 1.0}
+
+
+def test_sdpa_lowering_counter_other_paths(monkeypatch):
+    """The paths the base op's envelope never takes: the pallas
+    library's blocked kernels (S=1024), its reference fallback
+    (dropout in interpret mode), and the sp route."""
+    from paddle_tpu.ops.pallas import attention as A
+    from paddle_tpu.parallel import ulysses
+
+    moved = _lowerings_counted
+    q = jnp.full((1, 2, 1024, 16), 0.1, jnp.float32)
+    assert moved(lambda: A.sdpa_pallas(q, q, q, None, scale=0.25,
+                                       is_test=True)) \
+        == {"flash_blocked": 1.0}
+    s = q[:, :, :128]
+    assert moved(lambda: A.sdpa_pallas(
+        s, s, s, None, scale=0.25, dropout_rate=0.1,
+        rng=jax.random.key(0))) == {"xla": 1.0}
+    monkeypatch.setattr(ulysses, "sequence_parallel_attention",
+                        lambda q_, k_, v_, **kw: q_)
+    monkeypatch.setattr(FLAGS, "sp_attention", True)
+    assert moved(lambda: A.scaled_dot_product_attention(
+        s, s, s, None, scale=0.25, is_test=True)) == {"sp": 1.0}
 
 
 def test_sdpa_auto_flash_failure_propagates(monkeypatch):

@@ -16,9 +16,12 @@ reproduces at 33.6 MB for the old layout):
   - blocks are tiled to (sublane, 128) lanes with the dtype-dependent
     sublane multiple (f32 8, bf16 16, int8 32);
   - streamed input/output blocks are double-buffered (x2);
-  - an OUTPUT whose index map revisits blocks across the grid cannot
-    be flushed incrementally — charge every distinct block (x2),
-    which for a revisited full sweep is the whole padded array;
+  - an OUTPUT whose index map comes BACK to a block after leaving it
+    cannot be flushed incrementally — charge every distinct block
+    (x2), which for a revisited full sweep is the whole padded array.
+    A block revisited only on consecutive grid steps (an accumulator
+    over the innermost axis: the attention backward's dk/dv over
+    q-blocks) is written back when the index moves on: one block;
   - scratch is resident at full padded size (x1).
 
 Reference analog: the jit/ kernel layer's "prove it at the target
@@ -73,10 +76,16 @@ def _block_cost(spec, arr_shape, dtype, grid, is_output):
     shape = getattr(spec, "block_shape", None) or arr_shape
     one = _padded_bytes(shape, dtype)
     if is_output and grid:
-        idx = {spec.index_map(*p) for p in _grid_points(grid)}
-        if len(idx) < len(_grid_points(grid)):
-            # revisited output: every distinct block stays resident
-            return one * len(idx) * 2
+        seen, last = set(), None
+        for p in _grid_points(grid):     # row-major: last axis fastest
+            cur = spec.index_map(*p)
+            if cur != last and cur in seen:
+                # came back to a block it had left: every distinct
+                # block stays resident
+                return one * 2 * len({spec.index_map(*g) for g in
+                                      _grid_points(grid)})
+            seen.add(cur)
+            last = cur
     return one * 2  # streamed + double-buffered
 
 
@@ -87,7 +96,10 @@ class _Recorded(Exception):
 def _capture_calls(fn):
     """Run fn with pl.pallas_call patched to record geometry; fake
     outputs (zeros) keep multi-call kernels (fwd+bwd) traceable
-    without executing anything."""
+    without executing anything. Under ``jax.disable_jit``: a kernel
+    wrapper that is itself jitted (the attention 1k pair) would
+    otherwise keep the trace made with the fake in its cache, and hand
+    zeros to the next real call of the same signature."""
     calls = []
     real = pl.pallas_call
 
@@ -106,7 +118,8 @@ def _capture_calls(fn):
 
     pl.pallas_call = fake
     try:
-        fn()
+        with jax.disable_jit():
+            fn()
     finally:
         pl.pallas_call = real
     assert calls, "kernel never reached pl.pallas_call"
@@ -179,9 +192,10 @@ def test_attention_flagship_fits_vmem(dtype):
 
 
 def _1k_temp_bytes(call):
-    """In-kernel [G,Sq,Sk] f32 temporary model for the single-k-block
-    attention kernels (ADVICE r4: streamed blocks alone under-count
-    them). q block = in_specs[1] (G, Sq, Dh); k block = (G, Sk, Dh).
+    """In-kernel [G,blk_q,Sk] f32 temporary model for the
+    single-k-block attention kernels (ADVICE r4: streamed blocks alone
+    under-count them). q block = in_specs[1] (G, blk_q, Dh); k block =
+    (G, Sk, Dh).
     Bytes/element anchored on the chip accepting the headline bf16
     [8,256,256] backward — see attention._1K_TEMP_BYTES."""
     from paddle_tpu.ops.pallas import attention as A
@@ -196,21 +210,24 @@ def _1k_temp_bytes(call):
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("rate", [0.0, 0.1])
 @pytest.mark.parametrize("with_bias", [False, True])
-def test_attention_1k_corner_fits_vmem(dtype, rate, with_bias):
-    """The Sq=256/Sk=512 corner of _1k_applicable — the largest
-    single-k-block geometry FLAGS_sdpa_auto_flash dispatches by
-    default. Charges streamed blocks AND the in-kernel score
-    temporaries."""
+@pytest.mark.parametrize("Sq,H", [(256, _H), (512, 12)])
+def test_attention_1k_corner_fits_vmem(dtype, rate, with_bias, Sq, H):
+    """The largest score tile of _1k_applicable, 256 x 512, as the
+    largest single-k-block geometries FLAGS_sdpa_auto_flash dispatches
+    by default present it: Sq=256/Sk=512 (one q-block), and BERT-base's
+    Sq=Sk=512 at H=12 (two q-blocks, so the backward also holds its
+    dk/dv accumulators: scratch, charged by _footprint). Charges
+    streamed blocks AND the in-kernel score temporaries."""
     from paddle_tpu.ops.pallas import attention as A
-    Sq, Sk, Dh = 256, 512, 64
+    Sk, Dh = 512, 64
     assert A._1k_applicable(Sq, Sk)
     rs = np.random.RandomState(0)
-    q = jnp.asarray(rs.rand(4, _H, Sq, Dh).astype(dtype))
-    k = jnp.asarray(rs.rand(4, _H, Sk, Dh).astype(dtype))
-    v = jnp.asarray(rs.rand(4, _H, Sk, Dh).astype(dtype))
+    q = jnp.asarray(rs.rand(4, H, Sq, Dh).astype(dtype))
+    k = jnp.asarray(rs.rand(4, H, Sk, Dh).astype(dtype))
+    v = jnp.asarray(rs.rand(4, H, Sk, Dh).astype(dtype))
     var = ops.get("scaled_dot_product_attention").variants["pallas"]
     rng = jax.random.PRNGKey(0) if rate else None
-    bias = (jnp.asarray(rs.rand(4, _H, Sq, Sk).astype("float32"))
+    bias = (jnp.asarray(rs.rand(4, H, Sq, Sk).astype("float32"))
             if with_bias else None)
 
     def fwd_bwd():
@@ -225,6 +242,8 @@ def test_attention_1k_corner_fits_vmem(dtype, rate, with_bias):
         calls = _capture_calls(fwd_bwd)
     finally:
         A.interpret_mode = orig
+    if Sq > A._1k_blk_q(Sq):
+        assert len(calls[-1]["scratch_shapes"]) == 2   # dk, dv sums
     for n, call in enumerate(calls):
         total = _footprint(call) + _1k_temp_bytes(call)
         assert total <= V5E_SCOPED_VMEM, (
@@ -245,6 +264,11 @@ def test_1k_headline_geometry_pinned():
     assert A._1k_bwd_G(8, 4, 256, 256, 64) == 4
     # the ADVICE r4 corner: bf16 Sq=256/Sk=512 must NOT run at G=8
     assert A._1k_bwd_G(8, 2, 256, 512, 64) <= 4
+    # BERT-base S=512 (H=12, pad bias, dropout): the same 256 x 512
+    # tile plus the dk/dv accumulators; forward and backward agree
+    g = A._1k_bwd_G(12, 2, 512, 512, 64, True)
+    assert g == A._1k_fwd_G(12, 2, 0.1, 512, 512, 64, True)
+    assert g <= A._1k_bwd_G(12, 2, 256, 512, 64, True)
 
 
 def test_layer_norm_flagship_fits_vmem():
