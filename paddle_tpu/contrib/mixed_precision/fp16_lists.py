@@ -15,6 +15,11 @@ white_list = {
     # MXU-bound and numerically safe in bf16: all reductions over the
     # vocab axis run in float32 inside the op
     "fused_linear_xent",
+    # the grouped products over the held experts; the routing weights
+    # and the counters stay float32 (fp16_utils.F32_CONTRACT_*). The
+    # router itself (moe_sigmoid_router) is unlisted: its scores are
+    # float32 by the model's own statement
+    "moe_held_experts",
 }
 
 # Numerically sensitive ops that must stay in float32.
@@ -51,6 +56,10 @@ gray_list = {
     # always f32), so the [N, V] logits can stay bf16 — halving the
     # head's HBM traffic on BERT-style models
     "softmax_with_cross_entropy",
+    # rms_norm keeps layer_norm's contract (float32 inside, the input's
+    # type out); the rotation's angles are float32 inside; swish is
+    # elementwise
+    "rms_norm", "rotary_embedding", "swish",
 }
 
 

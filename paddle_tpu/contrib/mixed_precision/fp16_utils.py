@@ -25,6 +25,7 @@ F32_CONTRACT_OUTPUTS = {
     "softmax_with_cross_entropy": ("Loss",),
     "fused_linear_xent": ("Loss",),
     "layer_norm": ("Mean", "Variance"),
+    "moe_held_experts": ("CountersOut",),
 }
 
 # Input slots never cast down when a gray op goes low: training
@@ -34,6 +35,7 @@ F32_CONTRACT_OUTPUTS = {
 F32_CONTRACT_INPUTS = {
     "softmax_with_cross_entropy": ("Label",),
     "fused_linear_xent": ("Label",),
+    "moe_held_experts": ("Weight", "Counters"),
 }
 
 

@@ -55,6 +55,15 @@ def _is_float(x):
     return jnp.issubdtype(jnp.asarray(x).dtype, jnp.floating)
 
 
+def _zero_cotangent(v):
+    """What jax.vjp's pullback takes for an output nothing flows back
+    through: zeros of the output's type, and for an integer output (a
+    router's chosen indices) the ``float0`` zeros jax asks for."""
+    if _is_float(v):
+        return jnp.zeros_like(v)
+    return np.zeros(v.shape, jax.dtypes.float0)
+
+
 def _gather_inputs(opdef, op, env):
     """Collect positional input values for an op from the trace env."""
     vals = []
@@ -250,11 +259,13 @@ class _VjpParts:
             except ValueError as e:
                 raise _augment_vjp_error(e, fwd_type) from e
             flat_out, treedef = jax.tree_util.tree_flatten(primals_out)
-            cots = [c if c is not None else jnp.zeros_like(v)
+            cots = [c if c is not None and _is_float(v)
+                    else _zero_cotangent(v)
                     for v, c in zip(flat_out, cotangents)]
             if len(flat_out) > len(cots):
                 # outputs with no recorded names get zero cotangents
-                cots += [jnp.zeros_like(v) for v in flat_out[len(cots):]]
+                cots += [_zero_cotangent(v)
+                         for v in flat_out[len(cots):]]
             return pullback(
                 jax.tree_util.tree_unflatten(treedef, cots))
 
@@ -1382,6 +1393,11 @@ class Executor:
         skipped, consec = _guard.read_counters(scope or global_scope())
         out["anomaly_skipped_steps"] = skipped
         out["anomaly_consecutive"] = consec
+        # the held-experts layers' own counts (parallel/moe.py
+        # COUNTER_NAMES), totals since the startup program; None where
+        # the scope holds no such layer
+        from .parallel import moe as _moe
+        out["moe"] = _moe.read_counters(scope or global_scope())
         if program is not None and getattr(program, "_is_compiled",
                                            False):
             try:

@@ -371,6 +371,17 @@ def _pick_dyn_dim(avoid):
     return p
 
 
+# True while ``_infer_shapes`` evaluates an op's lowering abstractly at
+# graph-build time (on the variables' declared types, before any AMP
+# rewrite): counters that say which lowering a site TOOK
+# (``sdpa_lowering.*``, ``moe_lowering.*``) leave that pass out.
+_inferring_shapes = False
+
+
+def inferring_shapes() -> bool:
+    return _inferring_shapes
+
+
 def _infer_shapes(block, op):
     """Infer output var shapes/dtypes with jax.eval_shape over the op's
     lowering (the analog of the reference's per-op InferShape,
@@ -452,8 +463,14 @@ def _infer_shapes(block, op):
         else:
             fn = opdef.fn
         attrs.pop("rng", None)
+        global _inferring_shapes
         with _trace_program_guard(block.program):
-            out = jax.eval_shape(lambda *a: fn(*a, **attrs), *arg_structs)
+            _inferring_shapes = True
+            try:
+                out = jax.eval_shape(lambda *a: fn(*a, **attrs),
+                                     *arg_structs)
+            finally:
+                _inferring_shapes = False
     except Exception as e:
         # Best-effort by design (abstract eval can't see runtime-only
         # constructs), but a typo'd op should not fail silently: under

@@ -910,14 +910,17 @@ def label_smooth(label, prior_dist=None, epsilon=0.1, dtype="float32",
 
 def scaled_dot_product_attention(q, k, v, bias=None, scale=1.0,
                                  dropout_rate=0.0, causal=False,
-                                 is_test=False, name=None):
+                                 is_test=False, window=0, name=None):
     """Fused attention core: softmax(q @ k^T * scale + bias) @ v over
     [batch, heads, seq, head_dim] inputs, with optional in-kernel
     attention dropout and causal masking. Lowers to one fused op (pallas
     flash kernel — blocked online softmax, recompute backward — when
     FLAGS_op_library=pallas; XLA-fused composite otherwise). ``bias`` is
     an additive attention *mask* (non-differentiable); add a trainable
-    bias with elementwise_add instead. See ops/pallas/attention.py."""
+    bias with elementwise_add instead. ``k`` and ``v`` may have fewer
+    heads than ``q`` (grouped queries: q head i reads kv head
+    i // (heads // kv heads)); ``window`` > 0, with ``causal``, lets
+    row i read keys i-window+1..i only. See ops/pallas/attention.py."""
     helper = LayerHelper("sdpa", name=name)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
@@ -928,8 +931,32 @@ def scaled_dot_product_attention(q, k, v, bias=None, scale=1.0,
                      attrs={"scale": float(scale),
                             "dropout_rate": float(dropout_rate),
                             "causal": bool(causal),
-                            "is_test": bool(is_test)})
+                            "is_test": bool(is_test),
+                            "window": int(window)})
     return out
+
+
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """RMSNorm over the last axis with a weight (ops/nn_ops.py
+    rms_norm): ``x / sqrt(mean(x^2) + epsilon) * w``; the weight is
+    ``<name>.w_0``, ones by default."""
+    helper = LayerHelper("rms_norm", name=name)
+    w = helper.create_parameter(attr=param_attr,
+                                shape=(int(input.shape[-1]),),
+                                dtype=input.dtype,
+                                default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="rms_norm", inputs={"X": [input], "Scale": [w]},
+                     outputs={"Y": [out]},
+                     attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(x, theta=10000.0, name=None):
+    """Rotary position embedding (rotate-half, the whole head) of
+    [batch, heads, seq, head_dim]; row s is position s."""
+    return _simple("rotary_embedding", x, {"theta": float(theta)},
+                   name=name)
 
 
 def moe_ffn(x, num_experts, d_ffn, capacity_factor=1.25, top_k=1,
@@ -979,6 +1006,103 @@ def moe_ffn(x, num_experts, d_ffn, capacity_factor=1.25, top_k=1,
                      attrs={"capacity_factor": float(capacity_factor),
                             "top_k": int(top_k)})
     return out, aux
+
+
+def _moe_counters(helper):
+    """The one persistable the held-experts ops of a program add their
+    counts to (parallel/moe.py COUNTERS_VAR), zeroed by the startup
+    program."""
+    from ..parallel.moe import COUNTER_NAMES, COUNTERS_VAR
+    block = helper.main_program.global_block()
+    if COUNTERS_VAR in block.vars:
+        return block.vars[COUNTERS_VAR]
+    from .tensor import create_global_var
+    return create_global_var((len(COUNTER_NAMES),), 0.0, "float32",
+                             persistable=True, name=COUNTERS_VAR)
+
+
+def moe_sigmoid_router(x, num_experts, top_k, route_scale=1.0,
+                       route_norm=True, balance_coeff=0.0,
+                       first_held=0, num_held=0, param_attr=None,
+                       name=None):
+    """Sigmoid top-k router over ``num_experts`` (the published width)
+    for ``[tokens, d_model]`` input (parallel/moe.py
+    sigmoid_topk_route): scores in float32, the ``top_k`` chosen on
+    score plus a bias BUFFER (``<name>.bias``, persistable, not a
+    parameter; moved after each step by ``balance_coeff`` towards the
+    mean load, then centred), the chosen scores renormalised
+    (``route_norm``) and times ``route_scale``. ``first_held`` /
+    ``num_held`` only say whose loads the step's counters record.
+    Returns ``(idx [tokens, top_k] int32, weight [tokens, top_k]
+    float32)`` for ``moe_held_experts``."""
+    helper = LayerHelper("moe_router", name=name)
+    enforce(x.shape is not None and len(x.shape) == 2,
+            "moe_sigmoid_router wants [tokens, d_model] input, got "
+            "shape %r" % (x.shape,))
+    E = int(num_experts)
+    w = helper.create_parameter(attr=param_attr,
+                                shape=(int(x.shape[1]), E), dtype=x.dtype)
+    from .tensor import create_global_var
+    bias = create_global_var((E,), 0.0, "float32", persistable=True,
+                             name=helper.name + ".bias")
+    counters = _moe_counters(helper)
+    idx = helper.create_variable_for_type_inference(
+        "int32", stop_gradient=True)
+    weight = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="moe_sigmoid_router",
+        inputs={"X": [x], "W": [w], "Bias": [bias],
+                "Counters": [counters]},
+        outputs={"TopkIdx": [idx], "TopkWeight": [weight],
+                 "BiasOut": [bias], "CountersOut": [counters]},
+        attrs={"top_k": int(top_k), "route_scale": float(route_scale),
+               "route_norm": bool(route_norm),
+               "balance_coeff": float(balance_coeff),
+               "first_held": int(first_held), "n_held": int(num_held)})
+    return idx, weight
+
+
+def moe_held_experts(x, idx, weight, num_held, d_ffn, first_held=0,
+                     row_capacity=None, param_attr=None, name=None):
+    """The part of a top-k expert layer that THIS chip's experts give
+    (parallel/moe.py held_experts_ffn): experts ``first_held ..
+    first_held + num_held - 1`` of the router's width, gated-SiLU MLPs
+    of width ``d_ffn`` (``<name>.w_gate`` / ``.w_up`` [num_held,
+    d_model, d_ffn], ``.w_down`` [num_held, d_ffn, d_model]), over the
+    tokens routed to them: sorted by expert into a static buffer of
+    ``row_capacity`` rows (default: every assignment, so nothing can
+    overflow), three grouped matrix products, scatter-added back times
+    ``weight``. An assignment past the buffer makes the output NaN and
+    is counted (``telemetry()["moe"]``); nothing is dropped in
+    silence."""
+    helper = LayerHelper("moe_experts", name=name)
+    enforce(x.shape is not None and len(x.shape) == 2,
+            "moe_held_experts wants [tokens, d_model] input, got "
+            "shape %r" % (x.shape,))
+    d, n, f = int(x.shape[1]), int(num_held), int(d_ffn)
+    from ..param_attr import ParamAttr
+
+    def param(suffix, shape):
+        attr = ParamAttr._to_attr(param_attr)
+        attr = ParamAttr(name=helper.name + suffix,
+                         initializer=attr.initializer)
+        return helper.create_parameter(attr=attr, shape=shape,
+                                       dtype=x.dtype)
+
+    w_gate = param(".w_gate", (n, d, f))
+    w_up = param(".w_up", (n, d, f))
+    w_down = param(".w_down", (n, f, d))
+    counters = _moe_counters(helper)
+    out = helper.create_variable_for_type_inference(x.dtype)
+    helper.append_op(
+        type="moe_held_experts",
+        inputs={"X": [x], "TopkIdx": [idx], "Weight": [weight],
+                "WGate": [w_gate], "WUp": [w_up], "WDown": [w_down],
+                "Counters": [counters]},
+        outputs={"Out": [out], "CountersOut": [counters]},
+        attrs={"first_held": int(first_held),
+               "row_capacity": int(row_capacity or 0)})
+    return out
 
 
 # ---------------------------------------------------------------------------

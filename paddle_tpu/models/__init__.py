@@ -1,8 +1,10 @@
 """Model zoo mirroring the reference's benchmark + book models
 (reference: benchmark/fluid/models/{mnist,resnet,vgg,
 stacked_dynamic_lstm,machine_translation}.py and
-python/paddle/fluid/tests/book/)."""
+python/paddle/fluid/tests/book/), and ``afmoe``: the decoder block of
+a 2025 sparse model (not in the reference)."""
 
+from . import afmoe  # noqa: F401
 from . import bert  # noqa: F401
 from . import deepfm  # noqa: F401
 from . import mnist  # noqa: F401

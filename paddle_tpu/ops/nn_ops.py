@@ -602,6 +602,36 @@ def layer_norm(x, scale, bias, *, epsilon=1e-5, begin_norm_axis=1):
     return norm.astype(x.dtype), jnp.squeeze(mean), jnp.squeeze(var)
 
 
+@register("rms_norm", ["X", "Scale"], ["Y"])
+def rms_norm(x, scale, *, epsilon=1e-5):
+    """``x / sqrt(mean(x^2) + epsilon) * scale`` over the last axis (no
+    mean subtracted, no bias). The mean square in float32 whatever the
+    input's type, the output back in the INPUT's type: layer_norm's
+    policy, so under AMP the bf16 stream goes on."""
+    xf = x.astype(jnp.float32)
+    inv = lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+                    + epsilon)
+    return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+@register("rotary_embedding", ["X"], ["Out"])
+def rotary_embedding(x, *, theta=10000.0):
+    """Rotary position embedding over the whole head, rotate-half
+    layout: x [B, H, S, Dh], position s of row s; the pair (x[i],
+    x[i + Dh/2]) turns by ``s * theta^(-2i/Dh)``. The angles are
+    float32 constants of the trace; the output keeps x's type."""
+    s, dh = x.shape[-2], x.shape[-1]
+    half = dh // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32)
+                         * (2.0 / dh))
+    ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
 @register("group_norm", ["X", "Scale", "Bias"], ["Y", "Mean", "Variance"])
 def group_norm(x, scale, bias, *, groups, epsilon=1e-5):
     n, c, h, w = x.shape

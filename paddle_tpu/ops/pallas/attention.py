@@ -70,7 +70,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register, register_variant
-from .common import blk, interpret_mode
+from .common import blk, count_lowering, interpret_mode
 
 _NEG_INF = -1e30
 
@@ -80,10 +80,15 @@ _PV = (((2,), (1,)), ((0,), (0,)))     # [G,q,k] x [G,k,d] -> [G,q,d]
 _TT = (((1,), (1,)), ((0,), (0,)))     # [G,q,k] x [G,q,d] -> [G,k,d]
 
 
-def _causal_mask(s, j, kk, blk_q, blk_k):
+def _causal_mask(s, j, kk, blk_q, blk_k, window=0):
+    """Row i reads key c only where c <= i and, with a ``window``,
+    i - c < window."""
     rows = j * blk_q + lax.broadcasted_iota(jnp.int32, s.shape, 1)
     cols = kk * blk_k + lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    return jnp.where(rows >= cols, s, _NEG_INF)
+    keep = rows >= cols
+    if window:
+        keep = jnp.logical_and(keep, rows - cols < window)
+    return jnp.where(keep, s, _NEG_INF)
 
 
 def _dropout_keep(seed_ref, i, j, kk, n_q, n_k, shape, rate):
@@ -133,9 +138,11 @@ def _softmax_save_lowp(dtype_name):
 
 
 def _sdpa_reference(q, k, v, bias, *, scale, dropout_rate=0.0,
-                    causal=False, rng=None):
-    """Pure-jnp composite (the jit/refer/ analog): q,k,v [B,H,S,Dh],
-    bias additive, broadcastable to [B,1_or_H,Sq,Sk].
+                    causal=False, window=0, rng=None):
+    """Pure-jnp composite (the jit/refer/ analog): q [B,H,S,Dh], k and
+    v [B,Hkv,S,Dh] with Hkv dividing H (q head i reads kv head
+    i // (H // Hkv)), bias additive, broadcastable to [B,1_or_H,Sq,Sk];
+    with a ``window`` (causal only) row i reads keys i-window+1..i.
 
     Precision follows standard TPU practice (and the reference's f32
     softmax accumulate): scores and softmax in float32 — the MXU
@@ -144,21 +151,38 @@ def _sdpa_reference(q, k, v, bias, *, scale, dropout_rate=0.0,
     dtype (saving only the low-precision copy for the backward) for
     the dropout mask and the PV matmul, so the [B,H,S,S] traffic
     rides at half width under AMP."""
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
+    B, H, sq, dh = q.shape
+    hkv, sk = k.shape[1], k.shape[2]
+    if hkv != H:
+        # grouped queries: fold the group into the batch of heads, the
+        # kv heads are never repeated
+        q = q.reshape(B, hkv, H // hkv, sq, dh)
+        s = jnp.einsum("bkgqd,bkmd->bkgqm", q, k,
+                       preferred_element_type=jnp.float32
+                       ).reshape(B, H, sq, sk) * scale
+    else:
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       preferred_element_type=jnp.float32) * scale
     if bias is not None:
         s = s + lax.stop_gradient(bias).astype(jnp.float32)
     if causal:
-        sq, sk = s.shape[-2], s.shape[-1]
         rows = lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         cols = lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        s = jnp.where(rows >= cols, s, _NEG_INF)
-    w = _softmax_save_lowp(jnp.dtype(q.dtype).name)(s)
+        keep = rows >= cols
+        if window:
+            keep = jnp.logical_and(keep, rows - cols < window)
+        s = jnp.where(keep, s, _NEG_INF)
+    w = _softmax_save_lowp(jnp.dtype(v.dtype).name)(s)
     if dropout_rate > 0.0:
         from ..nn_ops import _keep_mask
         keep = _keep_mask(rng, dropout_rate, w.shape)
         w = jnp.where(keep, w / (1.0 - dropout_rate),
-                      jnp.zeros((), q.dtype))
+                      jnp.zeros((), v.dtype))
+    if hkv != H:
+        return jnp.einsum(
+            "bkgqm,bkmd->bkgqd", w.reshape(B, hkv, H // hkv, sq, sk), v,
+            preferred_element_type=jnp.float32).reshape(
+                B, H, sq, dh).astype(v.dtype)
     return jnp.einsum("bhqk,bhkd->bhqd", w, v,
                       preferred_element_type=jnp.float32).astype(
         q.dtype)
@@ -168,18 +192,32 @@ def _sdpa_reference(q, k, v, bias, *, scale, dropout_rate=0.0,
           ["Out"], nondiff=("Bias",), needs_rng=True)
 def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
                                  dropout_rate=0.0, causal=False,
-                                 is_test=False, rng=None):
+                                 is_test=False, window=0, rng=None):
     """Base lowering: XLA fuses the chain — except inside the flash
-    kernel's envelope, where the base dispatches to it
+    kernels' envelopes, where the base dispatches to them
     (FLAGS_sdpa_auto_flash, the jit/README.en.md best-impl-wins pool
-    applied at run time): TPU execution, low-precision operands,
-    dropout active, single-k-block shapes; everything else keeps the
-    XLA chain. Inside the envelope the kernel is THE lowering — a
-    Mosaic compile error propagates, nothing retries with the
-    reference."""
+    applied at run time), on the TPU with low-precision operands:
+    with dropout active and single-k-block shapes the 1k pair; with no
+    dropout and keys past that envelope the blocked kernels (at
+    S=8192 XLA's chain would hold a [B,H,S,S] float32 score tensor);
+    everything else keeps the XLA chain. Inside an envelope the kernel
+    is THE lowering — a Mosaic compile error propagates, nothing
+    retries with the reference.
+
+    K and V may carry fewer heads than Q (grouped queries: q head i
+    reads kv head i // (H // Hkv)); ``window`` > 0 (causal only) lets
+    row i read keys i-window+1..i."""
     rate = 0.0 if is_test else float(dropout_rate)
+    window = int(window)
+    if window and not causal:
+        raise ValueError("scaled_dot_product_attention: a window is "
+                         "defined for causal attention only")
+    if q.shape[1] % k.shape[1]:
+        raise ValueError("scaled_dot_product_attention: %d query heads "
+                         "over %d key heads" % (q.shape[1], k.shape[1]))
     from ...core.flags import FLAGS
-    if FLAGS.sp_attention and rate == 0.0:
+    plain = not window and k.shape[1] == q.shape[1]
+    if FLAGS.sp_attention and rate == 0.0 and plain:
         # model-parallel production path: under a mesh with an sp axis
         # (CompiledProgram.with_data_parallel(axes={"dp":d,"sp":s})
         # installs it as the ambient mesh for the whole trace) the one
@@ -195,27 +233,35 @@ def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
         if routed is not None:
             _count_lowering("sp")
             return routed
-    if (FLAGS.sdpa_auto_flash and rate > 0.0 and rng is not None
-            and not interpret_mode()
-            and jnp.dtype(q.dtype).itemsize <= 2
-            and _1k_applicable(q.shape[2], k.shape[2])):
-        return sdpa_pallas(q, k, v, bias, scale=scale,
-                           dropout_rate=dropout_rate, causal=causal,
-                           is_test=is_test, rng=rng)
+    if (FLAGS.sdpa_auto_flash and not interpret_mode()
+            and jnp.dtype(q.dtype).itemsize <= 2):
+        sq, sk = q.shape[2], k.shape[2]
+        if (rate > 0.0 and rng is not None and plain
+                and _1k_applicable(sq, sk)) \
+                or (rate == 0.0 and _blocked_applicable(sq, sk)):
+            return sdpa_pallas(q, k, v, bias, scale=scale,
+                               dropout_rate=dropout_rate, causal=causal,
+                               is_test=is_test, window=window, rng=rng)
     _count_lowering("xla")
     return _sdpa_reference(q, k, v, bias, scale=scale,
-                           dropout_rate=rate, causal=causal, rng=rng)
+                           dropout_rate=rate, causal=causal,
+                           window=window, rng=rng)
+
+
+def _blocked_applicable(Sq, Sk):
+    """The envelope in which a site with no dropout takes the blocked
+    kernels by itself: keys past the single-k-block envelope, whole
+    256 x 512 tiles."""
+    return Sk > 512 and Sk % _BLK_K_TARGET == 0 \
+        and Sq % _BLK_Q_TARGET == 0
 
 
 def _count_lowering(path):
-    """One bump each time an attention op is LOWERED (trace time, so
-    nothing in a step): ``sdpa_lowering.<path>`` with path ``flash_1k``,
-    ``flash_blocked``, ``xla`` or ``sp`` says, in ``counter_values()``,
-    ``/metrics`` and ``obs_dump``, which lowering a program's attention
-    sites took. A differentiated site is lowered once for the forward
-    and once more under ``jax.vjp``."""
-    from ... import profiler
-    profiler.bump_counter("sdpa_lowering." + path)
+    """``sdpa_lowering.<path>`` with path ``flash_1k``,
+    ``flash_blocked``, ``xla`` or ``sp`` (common.count_lowering). A
+    differentiated site is lowered once for the forward and once more
+    under ``jax.vjp``."""
+    count_lowering("sdpa_lowering." + path)
 
 
 # ---------------------------------------------------------------------------
@@ -560,35 +606,126 @@ def _flash_bwd_1k(q, k, v, bias, seed_f, o, g, scale, rate, causal):
 
 
 # ---------------------------------------------------------------------------
-# forward kernel
+# blocked kernels: any Sk, causal k-block skipping, a sliding window,
+# grouped queries
 # ---------------------------------------------------------------------------
+#
+# Geometry shared by the three kernels. A grid cell holds G query heads
+# and the kv rows they read: G kv heads where every query head has its
+# own (group == 1), ONE where ``group`` query heads share a kv head
+# (G divides group, so a cell never straddles two kv heads; the kv
+# block is indexed by ``cell // (group // G)`` and never repeated in
+# HBM). In the shared case the G heads' query rows fold into the
+# matmuls' M dimension ([G*blk_q, Dh] x [Dh, blk_k]), and dk / dv sum
+# over them in the same product.
+#
+# Which k-blocks a q-block reads: causal, the blocks up to the one
+# holding its last row; with a window, from the block holding key
+# ``first row - window + 1`` on. The k axis of the grid is as long as
+# the most any q-block reads (``_n_steps``), step ``kk`` of q-block
+# ``j`` is k-block ``k_lo(j) + kk``, and the index map clamps it to
+# ``k_hi(j)``: a block outside the band is neither computed (pl.when)
+# nor fetched (an unchanged block index is not fetched again). The
+# dk / dv kernel walks the q-blocks of a k-block the same way.
+
+
+def _band(blk_q, blk_k, n_q, n_k, causal, window):
+    """(k_lo, k_hi, j_lo, j_hi): the first and last k-block q-block j
+    reads, the first and last q-block that reads k-block kk; each a
+    function of a (traced) block index."""
+    def k_lo(j):
+        if not window:
+            return 0 * j
+        return jnp.maximum(j * blk_q - (window - 1), 0) // blk_k
+
+    def k_hi(j):
+        if not causal:
+            return 0 * j + (n_k - 1)
+        return jnp.minimum((j * blk_q + blk_q - 1) // blk_k, n_k - 1)
+
+    def j_lo(kk):
+        if not causal:
+            return 0 * kk
+        return jnp.minimum((kk * blk_k) // blk_q, n_q - 1)
+
+    def j_hi(kk):
+        if not window:
+            return 0 * kk + (n_q - 1)
+        return jnp.minimum(
+            (kk * blk_k + blk_k - 1 + window - 1) // blk_q, n_q - 1)
+
+    return k_lo, k_hi, j_lo, j_hi
+
+
+def _n_steps(blk_a, blk_b, n_b, window):
+    """Most blocks of size ``blk_b`` that the band of one block of size
+    ``blk_a`` touches: all ``n_b`` without a window."""
+    if not window:
+        return n_b
+    return min(n_b, (blk_a + window - 2) // blk_b + 2)
+
+
+def _qk(q, k):
+    """[G,bq,Dh] x [Gk,bk,Dh] -> [G,bq,bk] float32; Gk is G or 1."""
+    if k.shape[0] == q.shape[0]:
+        return lax.dot_general(q, k, _QK,
+                               preferred_element_type=jnp.float32)
+    G, bq, dh = q.shape
+    s = lax.dot_general(q.reshape(G * bq, dh), k[0],
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    return s.reshape(G, bq, s.shape[-1])
+
+
+def _pv(p, v):
+    """[G,bq,bk] x [Gk,bk,Dh] -> [G,bq,Dh] float32."""
+    if v.shape[0] == p.shape[0]:
+        return lax.dot_general(p, v, _PV,
+                               preferred_element_type=jnp.float32)
+    G, bq, bk = p.shape
+    o = lax.dot_general(p.reshape(G * bq, bk), v[0],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    return o.reshape(G, bq, o.shape[-1])
+
+
+def _tt(p, x, gk):
+    """[G,bq,bk] x [G,bq,Dh] -> [gk,bk,Dh] float32: per row where gk
+    is G, summed over the G rows where gk is 1."""
+    G, bq, bk = p.shape
+    if gk == G:
+        return lax.dot_general(p, x, _TT,
+                               preferred_element_type=jnp.float32)
+    return lax.dot_general(p.reshape(G * bq, bk),
+                           x.reshape(G * bq, x.shape[-1]),
+                           (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)[None]
+
 
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
                 acc_ref, m_ref, l_ref, *, scale, blk_q, blk_k, n_q,
-                n_k, rate, causal):
+                n_k, n_steps, rate, causal, window):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    kk = pl.program_id(2)
+    step = pl.program_id(2)
+    k_lo, k_hi, _, _ = _band(blk_q, blk_k, n_q, n_k, causal, window)
+    kk = k_lo(j) + step
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    live = (kk * blk_k <= j * blk_q + blk_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(kk <= k_hi(j))
     def _step():
-        q = q_ref[...]                                  # [G, bq, Dh]
-        s = lax.dot_general(q, k_ref[...], _QK,
-                            preferred_element_type=jnp.float32) * scale
+        s = _qk(q_ref[...], k_ref[...]) * scale         # [G, bq, bk]
         if b_ref is not None:
             # per-head: [G,1,bq,bk] -> [G,bq,bk]; per-batch:
             # [1,1,bq,bk] broadcasts over G
             s = s + b_ref[:, 0].astype(jnp.float32)
         if causal:
-            s = _causal_mask(s, j, kk, blk_q, blk_k)
+            s = _causal_mask(s, j, kk, blk_q, blk_k, window)
         m_prev = m_ref[..., :1]                         # [G, bq, 1]
         l_prev = l_ref[..., :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -601,11 +738,10 @@ def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
             keep = _dropout_keep(seed_ref, i, j, kk, n_q, n_k,
                                  p.shape, rate)
             p = jnp.where(keep, p / (1.0 - rate), 0.0)
-        pv = lax.dot_general(p.astype(v_ref.dtype), v_ref[...], _PV,
-                             preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv
+        acc_ref[...] = acc_ref[...] * alpha \
+            + _pv(p.astype(v_ref.dtype), v_ref[...])
 
-    @pl.when(kk == n_k - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
         o_ref[...] = (acc_ref[...] / l_safe[..., :1]).astype(
@@ -629,36 +765,59 @@ def _prep_bias(bias, B, H, Sq, Sk):
     return jnp.broadcast_to(bias, (B, 1, Sq, Sk)), False
 
 
-def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal):
+def _blocked_geometry(q, k):
+    """(G, gk, reps, blk_q, blk_k, n_q, n_k) of the blocked kernels for
+    q [B,H,Sq,Dh] and k [B,Hkv,Sk,Dh]: G query heads and gk kv heads
+    to a cell, ``reps`` cells of query heads to a kv head."""
+    H, Hkv = q.shape[1], k.shape[1]
+    group = H // Hkv
+    G = _blocked_G(H if group == 1 else group)
+    gk = G if group == 1 else 1
+    blk_q = blk(q.shape[2], _BLK_Q_TARGET)
+    blk_k = blk(k.shape[2], _BLK_K_TARGET)
+    return (G, gk, group // G if group > 1 else 1, blk_q, blk_k,
+            q.shape[2] // blk_q, k.shape[2] // blk_k)
+
+
+# Jitted for what the 1k wrappers are jitted for: the sites of one
+# signature share one lowered Mosaic body, so the forward the executor
+# lowers twice (once more under jax.vjp) is one call after XLA's CSE.
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal, window=0):
     B, H, Sq, Dh = q.shape
-    Sk = k.shape[2]
+    Hkv, Sk = k.shape[1], k.shape[2]
     BH = B * H
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
-    G = _blocked_G(H)
+    G, gk, reps, blk_q, blk_k, n_q, n_k = _blocked_geometry(q, k)
     hb = H // G                    # cells per batch row
     q3 = q.reshape(BH, Sq, Dh)
-    k3 = k.reshape(BH, Sk, Dh)
-    v3 = v.reshape(BH, Sk, Dh)
-    blk_q = blk(Sq, _BLK_Q_TARGET)
-    blk_k = blk(Sk, _BLK_K_TARGET)
-    n_k = Sk // blk_k
-    grid = (BH // G, Sq // blk_q, n_k)
+    k3 = k.reshape(B * Hkv, Sk, Dh)
+    v3 = v.reshape(B * Hkv, Sk, Dh)
+    k_lo, k_hi, _, _ = _band(blk_q, blk_k, n_q, n_k, causal, window)
+    n_steps = _n_steps(blk_q, blk_k, n_k, window)
+    grid = (BH // G, n_q, n_steps)
     seed = _seed_smem(seed_f, G)
 
+    def kb(j, step):
+        return jnp.minimum(k_lo(j) + step, k_hi(j))
+
+    kv_spec = pl.BlockSpec((gk, blk_k, Dh),
+                           lambda i, j, st: (i // reps, kb(j, st), 0))
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec((G, blk_q, Dh), lambda i, j, kk: (i, j, 0)),
-        pl.BlockSpec((G, blk_k, Dh), lambda i, j, kk: (i, kk, 0)),
-        pl.BlockSpec((G, blk_k, Dh), lambda i, j, kk: (i, kk, 0)),
+        pl.BlockSpec((G, blk_q, Dh), lambda i, j, st: (i, j, 0)),
+        kv_spec, kv_spec,
     ]
     args = [seed, q3, k3, v3]
     if bias is not None:
         if per_head:
-            bspec = pl.BlockSpec((G, 1, blk_q, blk_k),
-                                 lambda i, j, kk: (i, 0, j, kk))
+            bspec = pl.BlockSpec(
+                (G, 1, blk_q, blk_k),
+                lambda i, j, st: (i, 0, j, kb(j, st)))
         else:
-            bspec = pl.BlockSpec((1, 1, blk_q, blk_k),
-                                 lambda i, j, kk: (i // hb, 0, j, kk))
+            bspec = pl.BlockSpec(
+                (1, 1, blk_q, blk_k),
+                lambda i, j, st: (i // hb, 0, j, kb(j, st)))
         in_specs.append(bspec)
         args.append(bias)
         kernel = _fwd_kernel
@@ -669,15 +828,16 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal):
 
     out, lse = pl.pallas_call(
         functools.partial(kernel, scale=scale, blk_q=blk_q,
-                          blk_k=blk_k, n_q=Sq // blk_q, n_k=n_k,
-                          rate=rate, causal=causal),
+                          blk_k=blk_k, n_q=n_q, n_k=n_k,
+                          n_steps=n_steps, rate=rate, causal=causal,
+                          window=window),
         out_shape=[jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
                    jax.ShapeDtypeStruct((BH, Sq, 128), jnp.float32)],
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((G, blk_q, Dh), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((G, blk_q, 128), lambda i, j, kk: (i, j, 0)),
+            pl.BlockSpec((G, blk_q, Dh), lambda i, j, st: (i, j, 0)),
+            pl.BlockSpec((G, blk_q, 128), lambda i, j, st: (i, j, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, blk_q, Dh), jnp.float32),
@@ -696,71 +856,71 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal):
 # ---------------------------------------------------------------------------
 
 def _recompute_p(q_ref, k_ref, b_ref, lse_ref, *, scale, j, kk, blk_q,
-                 blk_k, causal):
-    s = lax.dot_general(q_ref[...], k_ref[...], _QK,
-                        preferred_element_type=jnp.float32) * scale
+                 blk_k, causal, window):
+    s = _qk(q_ref[...], k_ref[...]) * scale
     if b_ref is not None:
         s = s + b_ref[:, 0].astype(jnp.float32)
     if causal:
-        s = _causal_mask(s, j, kk, blk_q, blk_k)
+        s = _causal_mask(s, j, kk, blk_q, blk_k, window)
     return jnp.exp(s - lse_ref[..., :1])          # [G, blk_q, blk_k]
 
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
                dl_ref, dq_ref, dq_acc, *, scale, blk_q, blk_k, n_q,
-               n_k, rate, causal):
+               n_k, n_steps, rate, causal, window):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    kk = pl.program_id(2)
+    step = pl.program_id(2)
+    k_lo, k_hi, _, _ = _band(blk_q, blk_k, n_q, n_k, causal, window)
+    kk = k_lo(j) + step
 
-    @pl.when(kk == 0)
+    @pl.when(step == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    live = (kk * blk_k <= j * blk_q + blk_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(kk <= k_hi(j))
     def _step():
         p = _recompute_p(q_ref, k_ref, b_ref, lse_ref, scale=scale,
                          j=j, kk=kk, blk_q=blk_q, blk_k=blk_k,
-                         causal=causal)
-        do = do_ref[...]                              # [G, bq, Dh]
-        dp = lax.dot_general(do, v_ref[...], _QK,
-                             preferred_element_type=jnp.float32)
+                         causal=causal, window=window)
+        dp = _qk(do_ref[...], v_ref[...])             # [G, bq, bk]
         if rate > 0.0:
             keep = _dropout_keep(seed_ref, i, j, kk, n_q, n_k,
                                  dp.shape, rate)
             dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
         delta = dl_ref[..., :1]                       # [G, bq, 1]
         ds = (p * (dp - delta) * scale).astype(k_ref.dtype)
-        dq_acc[...] += lax.dot_general(
-            ds, k_ref[...], _PV,
-            preferred_element_type=jnp.float32)
+        dq_acc[...] += _pv(ds, k_ref[...])
 
-    @pl.when(kk == n_k - 1)
+    @pl.when(step == n_steps - 1)
     def _finish():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
                 dl_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                blk_q, blk_k, n_q, n_k, rate, causal):
-    i = pl.program_id(0)
+                blk_q, blk_k, n_q, n_k, n_steps, reps, rate, causal,
+                window):
+    c = pl.program_id(0)
     kk = pl.program_id(1)
-    j = pl.program_id(2)
+    u = pl.program_id(2)
+    # the inner axis walks the kv head's ``reps`` cells of query heads,
+    # and in each the q-blocks of this k-block's band
+    i = c * reps + u // n_steps
+    _, _, j_lo, j_hi = _band(blk_q, blk_k, n_q, n_k, causal, window)
+    j = j_lo(kk) + u % n_steps
+    gk = k_ref.shape[0]
 
-    @pl.when(j == 0)
+    @pl.when(u == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    live = (kk * blk_k <= j * blk_q + blk_q - 1) if causal else True
-
-    @pl.when(live)
+    @pl.when(j <= j_hi(kk))
     def _step():
         p = _recompute_p(q_ref, k_ref, b_ref, lse_ref, scale=scale,
                          j=j, kk=kk, blk_q=blk_q, blk_k=blk_k,
-                         causal=causal)
+                         causal=causal, window=window)
         do = do_ref[...]
         if rate > 0.0:
             keep = _dropout_keep(seed_ref, i, j, kk, n_q, n_k,
@@ -768,41 +928,40 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
             pd = jnp.where(keep, p / (1.0 - rate), 0.0)
         else:
             pd = p
-        # dv += Pd^T @ dO (per row)
-        dv_acc[...] += lax.dot_general(
-            pd.astype(do.dtype), do, _TT,
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v_ref[...], _QK,
-                             preferred_element_type=jnp.float32)
+        # dv += Pd^T @ dO (per row, or over the rows that share the
+        # kv head)
+        dv_acc[...] += _tt(pd.astype(do.dtype), do, gk)
+        dp = _qk(do, v_ref[...])
         if rate > 0.0:
             dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
         delta = dl_ref[..., :1]
         ds = (p * (dp - delta) * scale).astype(q_ref.dtype)
-        # dk += dS^T @ Q (per row)
-        dk_acc[...] += lax.dot_general(
-            ds, q_ref[...], _TT,
-            preferred_element_type=jnp.float32)
+        # dk += dS^T @ Q
+        dk_acc[...] += _tt(ds, q_ref[...], gk)
 
-    @pl.when(j == n_q - 1)
+    @pl.when(u == reps * n_steps - 1)
     def _finish():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal):
+@functools.partial(jax.jit, static_argnums=(8, 9, 10, 11))
+def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal,
+               window=0):
     B, H, Sq, Dh = q.shape
-    Sk = k.shape[2]
-    BH = B * H
+    Hkv, Sk = k.shape[1], k.shape[2]
+    BH, BHkv = B * H, B * Hkv
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
-    G = _blocked_G(H)
+    G, gk, reps, blk_q, blk_k, n_q, n_k = _blocked_geometry(q, k)
     hb = H // G
     q3 = q.reshape(BH, Sq, Dh)
-    k3 = k.reshape(BH, Sk, Dh)
-    v3 = v.reshape(BH, Sk, Dh)
+    k3 = k.reshape(BHkv, Sk, Dh)
+    v3 = v.reshape(BHkv, Sk, Dh)
     do3 = g.reshape(BH, Sq, Dh)
-    blk_q = blk(Sq, _BLK_Q_TARGET)
-    blk_k = blk(Sk, _BLK_K_TARGET)
-    n_q, n_k = Sq // blk_q, Sk // blk_k
+    k_lo, k_hi, j_lo, j_hi = _band(blk_q, blk_k, n_q, n_k, causal,
+                                   window)
+    nk_steps = _n_steps(blk_q, blk_k, n_k, window)
+    nj_steps = _n_steps(blk_k, blk_q, n_q, window)
     seed = _seed_smem(seed_f, G)
     # delta_i = rowsum(dO * O): O(S*Dh) elementwise work, XLA fuses it.
     # lse/delta enter the kernels lane-replicated to the 128-lane
@@ -813,25 +972,34 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal):
     delta128 = jnp.broadcast_to(delta[:, :, None], (BH, Sq, 128))
 
     def specs(order):
-        """order: 'dq' grid (BH/G, n_q, n_k) or 'dkv' (BH/G, n_k, n_q)."""
+        """order: 'dq' grid (BH/G, n_q, k steps) or 'dkv' (kv cells,
+        n_k, reps x q steps). Each index map goes through (q cell, q
+        block, k block) of the grid point."""
         if order == "dq":
-            qi = lambda i, j, kk: (i, j, 0)
-            ki = lambda i, j, kk: (i, kk, 0)
-            if per_head:
-                bi = lambda i, j, kk: (i, 0, j, kk)
-            else:
-                bi = lambda i, j, kk: (i // hb, 0, j, kk)
+            def at(i, j, st):
+                return i, j, jnp.minimum(k_lo(j) + st, k_hi(j))
         else:
-            qi = lambda i, kk, j: (i, j, 0)
-            ki = lambda i, kk, j: (i, kk, 0)
-            if per_head:
-                bi = lambda i, kk, j: (i, 0, j, kk)
-            else:
-                bi = lambda i, kk, j: (i // hb, 0, j, kk)
+            def at(c, kk, u):
+                return (c * reps + u // nj_steps,
+                        jnp.minimum(j_lo(kk) + u % nj_steps, j_hi(kk)),
+                        kk)
+
+        def qi(*g3):
+            i, j, _ = at(*g3)
+            return i, j, 0
+
+        def ki(*g3):
+            i, _, kk = at(*g3)
+            return i // reps, kk, 0
+
+        def bi(*g3):
+            i, j, kk = at(*g3)
+            return (i if per_head else i // hb), 0, j, kk
+
         sp = [pl.BlockSpec(memory_space=pltpu.SMEM),
               pl.BlockSpec((G, blk_q, Dh), qi),
-              pl.BlockSpec((G, blk_k, Dh), ki),
-              pl.BlockSpec((G, blk_k, Dh), ki)]
+              pl.BlockSpec((gk, blk_k, Dh), ki),
+              pl.BlockSpec((gk, blk_k, Dh), ki)]
         ar = [seed, q3, k3, v3]
         if bias is not None:
             gb = G if per_head else 1
@@ -850,16 +1018,17 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal):
             lambda f, sr, qr, kr, vr, *rest, **kw:
             f(sr, qr, kr, vr, None, *rest, **kw), kern)
 
+    common = dict(scale=scale, blk_q=blk_q, blk_k=blk_k, n_q=n_q,
+                  n_k=n_k, rate=rate, causal=causal, window=window)
     sp, ar = specs("dq")
     dq = pl.pallas_call(
-        functools.partial(with_bias(_dq_kernel), scale=scale,
-                          blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k,
-                          rate=rate, causal=causal),
+        functools.partial(with_bias(_dq_kernel), n_steps=nk_steps,
+                          **common),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
-        grid=(BH // G, n_q, n_k),
+        grid=(BH // G, n_q, nk_steps),
         in_specs=sp,
         out_specs=pl.BlockSpec((G, blk_q, Dh),
-                               lambda i, j, kk: (i, j, 0)),
+                               lambda i, j, st: (i, j, 0)),
         scratch_shapes=[pltpu.VMEM((G, blk_q, Dh), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -868,58 +1037,67 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal):
 
     sp, ar = specs("dkv")
     dk, dv = pl.pallas_call(
-        functools.partial(with_bias(_dkv_kernel), scale=scale,
-                          blk_q=blk_q, blk_k=blk_k, n_q=n_q, n_k=n_k,
-                          rate=rate, causal=causal),
-        out_shape=[jax.ShapeDtypeStruct((BH, Sk, Dh), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, Dh), v.dtype)],
-        grid=(BH // G, n_k, n_q),
+        functools.partial(with_bias(_dkv_kernel), n_steps=nj_steps,
+                          reps=reps, **common),
+        out_shape=[jax.ShapeDtypeStruct((BHkv, Sk, Dh), k.dtype),
+                   jax.ShapeDtypeStruct((BHkv, Sk, Dh), v.dtype)],
+        grid=(BHkv // gk, n_k, reps * nj_steps),
         in_specs=sp,
         out_specs=[
-            pl.BlockSpec((G, blk_k, Dh), lambda i, kk, j: (i, kk, 0)),
-            pl.BlockSpec((G, blk_k, Dh), lambda i, kk, j: (i, kk, 0)),
+            pl.BlockSpec((gk, blk_k, Dh), lambda c, kk, u: (c, kk, 0)),
+            pl.BlockSpec((gk, blk_k, Dh), lambda c, kk, u: (c, kk, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((G, blk_k, Dh), jnp.float32),
-                        pltpu.VMEM((G, blk_k, Dh), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((gk, blk_k, Dh), jnp.float32),
+                        pltpu.VMEM((gk, blk_k, Dh), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(*ar)
 
     dq = dq.reshape(B, H, Sq, Dh)
-    dk = dk.reshape(B, H, Sk, Dh)
-    dv = dv.reshape(B, H, Sk, Dh)
+    dk = dk.reshape(B, Hkv, Sk, Dh)
+    dv = dv.reshape(B, Hkv, Sk, Dh)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _sdpa_flash(q, k, v, bias, seed_f, scale, rate, causal):
-    if _1k_applicable(q.shape[2], k.shape[2]):
+def _takes_1k(q, k, window):
+    """The single-k-block pair serves equal q and kv heads with no
+    window inside its envelope; the blocked kernels everything else."""
+    return not window and q.shape[1] == k.shape[1] \
+        and _1k_applicable(q.shape[2], k.shape[2])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _sdpa_flash(q, k, v, bias, seed_f, scale, rate, causal, window=0):
+    if _takes_1k(q, k, window):
         return _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate,
                              causal)
-    out, _lse = _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal)
+    out, _lse = _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal,
+                           window)
     return out
 
 
-def _sdpa_flash_fwd(q, k, v, bias, seed_f, scale, rate, causal):
-    if _1k_applicable(q.shape[2], k.shape[2]):
+def _sdpa_flash_fwd(q, k, v, bias, seed_f, scale, rate, causal,
+                    window=0):
+    if _takes_1k(q, k, window):
         out = _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate,
                             causal)
         # the single-block backward re-derives lse in-kernel: the
         # forward output is the only tensor residual
         return out, (q, k, v, bias, seed_f, out, None)
-    out, lse = _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal)
+    out, lse = _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal,
+                          window)
     return out, (q, k, v, bias, seed_f, out, lse)
 
 
-def _sdpa_flash_bwd(scale, rate, causal, res, g):
+def _sdpa_flash_bwd(scale, rate, causal, window, res, g):
     q, k, v, bias, seed_f, out, lse = res
     if lse is None:
         dq, dk, dv = _flash_bwd_1k(q, k, v, bias, seed_f, out, g,
                                    scale, rate, causal)
     else:
         dq, dk, dv = _flash_bwd(q, k, v, bias, seed_f, out, lse, g,
-                                scale, rate, causal)
+                                scale, rate, causal, window)
     dbias = None if bias is None else jnp.zeros_like(bias)
     return dq, dk, dv, dbias, jnp.zeros_like(seed_f)
 
@@ -929,8 +1107,9 @@ _sdpa_flash.defvjp(_sdpa_flash_fwd, _sdpa_flash_bwd)
 
 @register_variant("scaled_dot_product_attention", "pallas")
 def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
-                causal=False, is_test=False, rng=None):
+                causal=False, is_test=False, window=0, rng=None):
     rate = 0.0 if is_test else float(dropout_rate)
+    window = int(window)
     # per-head bias [B, H, Sq, Sk] is handled natively: _prep_bias
     # flattens it to one slab per (batch, head) grid row
     if rate > 0.0 and (rng is None or interpret_mode()):
@@ -938,8 +1117,9 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
         # reference path (dropout masks differ across libraries anyway)
         _count_lowering("xla")
         return _sdpa_reference(q, k, v, bias, scale=scale,
-                               dropout_rate=rate, causal=causal, rng=rng)
-    _count_lowering("flash_1k" if _1k_applicable(q.shape[2], k.shape[2])
+                               dropout_rate=rate, causal=causal,
+                               window=window, rng=rng)
+    _count_lowering("flash_1k" if _takes_1k(q, k, window)
                     else "flash_blocked")
     if rate > 0.0:
         # fold the step key into a scalar TPU PRNG seed; float32 carries
@@ -953,32 +1133,34 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
     mesh = mesh_lib.current_mesh()
     if mesh is not None and mesh.size > 1 and not in_sp_body():
         return _flash_over_mesh(mesh, q, k, v, bias, seed, float(scale),
-                                rate, bool(causal))
+                                rate, bool(causal), window)
     return _sdpa_flash(q, k, v, bias, jnp.stack([seed, jnp.float32(0)]),
-                       float(scale), rate, bool(causal))
+                       float(scale), rate, bool(causal), window)
 
 
-def _flash_over_mesh(mesh, q, k, v, bias, seed, scale, rate, causal):
+def _flash_over_mesh(mesh, q, k, v, bias, seed, scale, rate, causal,
+                     window=0):
     """The kernel under a multi-device mesh. Mosaic kernels are not
     partitioned automatically — jax's lowering rule refuses one inside
     a multi-device jit (jax/_src/tpu_custom_call.py) — so it runs per
     shard under shard_map: batch over ``dp`` and heads over ``tp``
-    where the mesh has those axes and they divide, every other axis
-    computing replicated. Each shard numbers its dropout cells from
-    its first (batch, head) row, so under dp the masks are the ones a
-    single device draws and the loss trace is the single-device one."""
+    where the mesh has those axes and they divide (the kv heads too,
+    where there are fewer of them), every other axis computing
+    replicated. Each shard numbers its dropout cells from its first
+    (batch, head) row, so under dp the masks are the ones a single
+    device draws and the loss trace is the single-device one."""
     from jax import shard_map
     from jax.sharding import PartitionSpec
 
     B, H = q.shape[:2]
 
-    def axis(name, n):
+    def axis(name, *ns):
         if name in mesh.axis_names and mesh.shape[name] > 1 \
-                and n % mesh.shape[name] == 0:
+                and all(n % mesh.shape[name] == 0 for n in ns):
             return name
         return None
 
-    b_ax, h_ax = axis("dp", B), axis("tp", H)
+    b_ax, h_ax = axis("dp", B), axis("tp", H, k.shape[1])
     spec = PartitionSpec(b_ax, h_ax, None, None)
     args, specs = [seed, q, k, v], [PartitionSpec(), spec, spec, spec]
     if bias is not None:
@@ -997,7 +1179,7 @@ def _flash_over_mesh(mesh, q, k, v, bias, seed, scale, rate, causal):
         return _sdpa_flash(
             q_, k_, v_, bias_,
             jnp.stack([seed_, row0.astype(jnp.float32)]),
-            scale, rate, causal)
+            scale, rate, causal, window)
 
     return shard_map(body, mesh=mesh, in_specs=tuple(specs),
                      out_specs=spec, check_vma=False)(*args)

@@ -14,7 +14,22 @@ same expert. Capacity C = ceil(n * top_k * capacity_factor / E);
 tokens beyond it are DROPPED (zero contribution) — the standard
 static-shape trade; callers size capacity_factor accordingly. The
 aux balancing loss is returned so training can regularize routing
-(Switch Transformer recipe).
+(Switch Transformer recipe). ``moe_ffn`` appears in no model.
+
+**The held experts' part of a sigmoid top-k layer** (below
+``moe_ffn``; what ``models/afmoe.py`` builds through
+``layers.moe_sigmoid_router`` and ``layers.moe_held_experts``) is the
+other kind of expert layer, and shares nothing with the first:
+sigmoid scores over the PUBLISHED width, the top k on score plus a
+load-balancing bias buffer, weights renormalised and scaled
+(``sigmoid_topk_route``, ``balance_bias_update``); then the part of the
+output that the experts HELD HERE give (``held_experts_ffn``): the
+assignments to them stable-sorted by expert into a static row buffer,
+three grouped matrix products that skip the buffer's slack
+(ops/pallas/grouped_matmul.py), scattered back times the weights.
+Nothing is dropped: an assignment past the buffer makes the output NaN
+and is counted. It runs on one chip with no exchange; what the experts
+held elsewhere would add is left out (ROADMAP R1 is the exchange).
 """
 
 from __future__ import annotations
@@ -24,8 +39,36 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec
 
+from ..ops.pallas import grouped_matmul as gmm_lib
 from ..ops.registry import register
 from . import mesh as mesh_lib
+
+# The step's own counts, as one persistable the ops add to. Each is a
+# total since the startup program: assignments (tokens x top_k, every
+# layer), those to held experts, rows the grouped product computed
+# (tile-rounded) and rows that found no room in the buffer, and per
+# layer and step the busiest held expert's tokens and the held mean.
+COUNTERS_VAR = "__moe_counters__"
+COUNTER_NAMES = ("assignments_total", "assignments_held_total",
+                 "rows_computed_total", "rows_over_capacity_total",
+                 "held_load_max_total", "held_load_mean_total")
+
+
+def read_counters(scope):
+    """{name: total} from ``scope``, or None where no program with a
+    held-experts layer has run in it."""
+    import numpy as np
+    v = scope.find_var(COUNTERS_VAR) if scope.has_var(COUNTERS_VAR) \
+        else None
+    if v is None:
+        return None
+    return dict(zip(COUNTER_NAMES, np.asarray(v, np.float64).tolist()))
+
+
+def _add_counts(counters, **counts):
+    add = jnp.stack([jnp.asarray(counts.get(n, 0.0), jnp.float32)
+                     for n in COUNTER_NAMES])
+    return counters + add
 
 
 def _route(x, gate_w, n_experts, capacity, top_k):
@@ -186,3 +229,185 @@ def moe_ffn_op(x, gate_w, w1, b1, w2, b2, *, capacity_factor=1.25,
     ep axis in scope it falls back to the single-device reference."""
     return moe_ffn(x, gate_w, w1, b1, w2, b2, axis=axis,
                    capacity_factor=capacity_factor, top_k=top_k)
+
+
+# ---------------------------------------------------------------------------
+# sigmoid top-k routing over the published width, the held experts' part
+# ---------------------------------------------------------------------------
+
+def sigmoid_topk_route(x, router_w, bias, *, top_k, route_scale=1.0,
+                       route_norm=True):
+    """x [T, D], router_w [D, E], bias [E] -> (sel [T, k] int32,
+    weight [T, k] float32, load [E] float32). Scores are sigmoids in
+    float32 (the product at full precision: a bf16 pass would flip
+    the last of the k where two scores lie close); the k are chosen on
+    ``score + bias`` and weighted by the score alone; ``load`` counts
+    the step's assignments to each expert."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, sel = lax.top_k(scores + lax.stop_gradient(bias), top_k)
+    weight = jnp.take_along_axis(scores, sel, axis=-1)
+    if route_norm:
+        weight = weight / (jnp.sum(weight, -1, keepdims=True) + 1e-20)
+    load = jnp.zeros((router_w.shape[1],), jnp.float32).at[
+        sel.reshape(-1)].add(1.0)
+    return sel.astype(jnp.int32), weight * route_scale, load
+
+
+def balance_bias_update(bias, load, coeff):
+    """The auxiliary-loss-free balancing step on the bias buffer:
+    towards the mean load by ``coeff`` a step, then centred."""
+    bias = bias + coeff * jnp.sign(jnp.mean(load) - load)
+    return bias - jnp.mean(bias)
+
+
+def _gathered_rows(x, tok, live):
+    return jnp.where(live[:, None], x[tok], jnp.zeros((), x.dtype))
+
+
+@jax.custom_vjp
+def _experts(x, wr, w_gate, w_up, w_down, tok, live, sizes):
+    """The sorted rows through their experts and back: x [T, D], wr
+    [C] the weight of each buffer row (0 on the slack), tok [C] its
+    token, sizes [n_held] the rows of each expert -> [T, D] float32.
+
+    Its own backward pass for two reasons. Every array of C rows
+    between the products is C long whatever the step's load, and of
+    those the backward pass keeps the first two products' outputs
+    alone; the gathered rows and the gate's product are made again,
+    which costs elementwise passes and no product. And the forward
+    pass is the same code differentiated or not, so the executor's two
+    lowerings of it (forward op, then under ``jax.vjp``) are one set
+    of kernels after XLA's CSE (under ``jax.checkpoint`` they were
+    not): nine products a layer and step, which is what moves a step's
+    time with its load."""
+    return _experts_fwd(x, wr, w_gate, w_up, w_down, tok, live, sizes)[0]
+
+
+def _experts_fwd(x, wr, w_gate, w_up, w_down, tok, live, sizes):
+    xs = _gathered_rows(x, tok, live)
+    gate = gmm_lib.grouped_matmul(xs, w_gate, sizes)
+    up = gmm_lib.grouped_matmul(xs, w_up, sizes)
+    y = gmm_lib.grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes)
+    out = jnp.zeros(x.shape, jnp.float32).at[tok].add(
+        y.astype(jnp.float32) * wr[:, None])
+    return out, (x, wr, w_gate, w_up, w_down, tok, live, sizes, gate, up)
+
+
+def _experts_bwd(res, d_out):
+    x, wr, w_gate, w_up, w_down, tok, live, sizes, gate, up = res
+    # what is made again must not be merged with the forward pass's
+    # copy, which would then live until here (jax.checkpoint's
+    # prevent_cse, by hand)
+    x, tok, live, gate, up = lax.optimization_barrier(
+        (x, tok, live, gate, up))
+
+    def product_vjp(rows, w, d):
+        # the forward product this traces is dead code: only its
+        # pullback (two products) is used
+        return jax.vjp(lambda a, b: gmm_lib.grouped_matmul(a, b, sizes),
+                       rows, w)[1](d)
+
+    # the output was x's type, so its cotangent holds no more than that
+    d_rows = d_out.astype(x.dtype)[tok]
+    h, gated_vjp = jax.vjp(lambda g, u: jax.nn.silu(g) * u, gate, up)
+    # out = sum over rows of wr x (h @ w_down): with the weight moved
+    # onto h, one pullback gives the matrix's gradient and the
+    # unweighted gradient of h, and the weight's own is a row sum of
+    # that; the product's output is not needed again
+    d_hw, d_w_down = product_vjp(h * wr[:, None].astype(h.dtype),
+                                 w_down, d_rows)
+    d_hw = d_hw.astype(jnp.float32)
+    d_wr = jnp.sum(h.astype(jnp.float32) * d_hw, -1)
+    d_h = (d_hw * wr[:, None]).astype(h.dtype)
+    d_gate, d_up = gated_vjp(d_h)
+    xs = _gathered_rows(x, tok, live)
+    d_xs_gate, d_w_gate = product_vjp(xs, w_gate, d_gate)
+    d_xs_up, d_w_up = product_vjp(xs, w_up, d_up)
+    d_xs = jnp.where(live[:, None], d_xs_gate.astype(jnp.float32)
+                     + d_xs_up.astype(jnp.float32), 0.0)
+    d_x = jnp.zeros(x.shape, jnp.float32).at[tok].add(d_xs)
+    return (d_x.astype(x.dtype), d_wr, d_w_gate, d_w_up, d_w_down, None,
+            None, None)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def held_experts_ffn(x, sel, weight, w_gate, w_up, w_down, *,
+                     first_held=0, row_capacity=0):
+    """The held experts' part of a top-k layer's output.
+
+    x [T, D]; sel [T, k] expert ids over the published width, weight
+    [T, k]; w_gate, w_up [n_held, D, F], w_down [n_held, F, D]: expert
+    ``first_held + e`` is ``w_*[e]``, a gated-SiLU MLP. Returns (out
+    [T, D] in x's type, rows_computed, rows_over): the sum over each
+    token's chosen experts THAT ARE HELD of weight x expert(x); the
+    rows the grouped products computed, tile-rounded; and how many
+    assignments found no room in the ``row_capacity`` rows (0: a row
+    for every assignment), in which case ``out`` is NaN throughout."""
+    T, _ = x.shape
+    k = sel.shape[1]
+    n_held = w_gate.shape[0]
+    n_assign = T * k
+    cap = int(row_capacity) or n_assign
+    local = sel.reshape(-1) - first_held
+    held = jnp.logical_and(local >= 0, local < n_held)
+    key = jnp.where(held, local, n_held)        # the others sort last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+    n_rows = jnp.sum(sizes)
+    if cap > n_assign:
+        order = jnp.pad(order, (0, cap - n_assign))
+    rows = order[:cap]                          # assignment of each row
+    live = jnp.arange(cap) < n_rows
+    tok = rows // k
+    ends = jnp.minimum(jnp.cumsum(sizes), cap)
+    sizes_in = ends - jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                       ends[:-1]])
+    wr = jnp.where(live, weight.reshape(-1)[rows], 0.0)
+    out = _experts(x, wr, w_gate, w_up, w_down, tok, live, sizes_in)
+    over = jnp.maximum(n_rows - cap, 0)
+    out = jnp.where(over > 0, jnp.nan, out)
+    return (out.astype(x.dtype),
+            gmm_lib.tile_rounded_rows(sizes_in).astype(jnp.float32),
+            over.astype(jnp.float32))
+
+
+@register("moe_sigmoid_router", ["X", "W", "Bias", "Counters"],
+          ["TopkIdx", "TopkWeight", "BiasOut", "CountersOut"],
+          nondiff=("Bias", "Counters"))
+def moe_sigmoid_router_op(x, w, bias, counters, *, top_k,
+                          route_scale=1.0, route_norm=True,
+                          balance_coeff=0.0, first_held=0, n_held=0):
+    """Static-graph twin of ``sigmoid_topk_route`` with the bias
+    buffer's update and the step's routing counts. ``BiasOut`` and
+    ``CountersOut`` are the inputs' own variables (written in place,
+    as batch_norm's moving mean is)."""
+    sel, weight, load = sigmoid_topk_route(
+        x, w, bias, top_k=top_k, route_scale=route_scale,
+        route_norm=route_norm)
+    held = lax.dynamic_slice_in_dim(load, first_held, n_held) \
+        if n_held else jnp.zeros((1,), jnp.float32)
+    counters = _add_counts(
+        counters, assignments_total=jnp.sum(load),
+        assignments_held_total=jnp.sum(held),
+        held_load_max_total=jnp.max(held),
+        held_load_mean_total=jnp.mean(held))
+    if balance_coeff:
+        bias = balance_bias_update(bias, load, balance_coeff)
+    return sel, weight, bias, counters
+
+
+@register("moe_held_experts",
+          ["X", "TopkIdx", "Weight", "WGate", "WUp", "WDown",
+           "Counters"],
+          ["Out", "CountersOut"], nondiff=("TopkIdx", "Counters"))
+def moe_held_experts_op(x, sel, weight, w_gate, w_up, w_down, counters,
+                        *, first_held=0, row_capacity=0):
+    out, computed, over = held_experts_ffn(
+        x, sel, weight, w_gate, w_up, w_down, first_held=first_held,
+        row_capacity=row_capacity)
+    return out, _add_counts(counters, rows_computed_total=computed,
+                            rows_over_capacity_total=over)

@@ -201,6 +201,80 @@ def test_flash_blocked_dropout_prng():
     _dropout_checks(1024, 1024, 4)
 
 
+# -- blocked path, sliding window and grouped queries (S=8192) --------------
+
+@pytest.mark.parametrize("window", [2048, 0])
+def test_flash_blocked_window_gqa_matches_reference(window):
+    """trinity_mini_s8k_scan's site (32 q heads over 4 kv heads of 128,
+    S=8192, bf16, causal, a 2048 window in the sliding layers) cut to
+    ONE kv head and its 8 q heads, so that the reference's
+    [1,8,8192,8192] float32 scores fit beside it. The gradient's
+    tolerance is a share of each array's largest entry: dk and dv sum
+    8 heads x up to 8192 rows of bf16 products, and the reference
+    keeps its probabilities in bf16 (row 0, which reads one key, has
+    a dq of exactly nought in the kernel and of 1.5% of the largest
+    entry in the reference: my chip run, PR 28, call 3)."""
+    dh, s = 128, 8192
+    r = np.random.RandomState(20 + window)
+    mk = lambda h: jnp.asarray(                      # noqa: E731
+        r.randn(1, h, s, dh).astype(np.float32) * 0.5, jnp.bfloat16)
+    q, k, v = mk(8), mk(1), mk(1)
+    assert A._blocked_applicable(s, s) and not A._takes_1k(q, k, window)
+    kw = dict(scale=dh ** -0.5, causal=True, window=window)
+    ref = lambda *a: A._sdpa_reference(*a, None, **kw)   # noqa: E731
+    pal = lambda *a: A.sdpa_pallas(*a, None, is_test=True,  # noqa: E731
+                                   **kw)
+    _close(jax.jit(pal)(q, k, v), jax.jit(ref)(q, k, v), **BF16)
+    loss = lambda f: lambda *a: jnp.sum(             # noqa: E731
+        jnp.square(f(*a).astype(jnp.float32)))
+    _close(jax.jit(jax.grad(loss(pal), (0, 1, 2)))(q, k, v),
+           jax.jit(jax.grad(loss(ref), (0, 1, 2)))(q, k, v),
+           rtol=5e-2, atol=3e-2, atol_of_max=True)
+
+
+# -- grouped matrix product (the held experts' three products) -------------
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
+def test_grouped_matmul_matches_ragged_dot(k, n):
+    """ops/pallas/grouped_matmul.py at the cell's widths: 8 groups in a
+    buffer of 8192 rows, one group empty, one boundary inside a tile,
+    slack after the last; the Mosaic kernels (megablox gmm / tgmm)
+    against lax.ragged_dot, values and both gradients, the slack and
+    the empty group's gradient exact zeros."""
+    from paddle_tpu.ops.pallas import grouped_matmul as G
+    sizes = [700, 0, 129, 1024, 37, 512, 900, 450]
+    m, e = 8192, len(sizes)
+    r = np.random.RandomState(k)
+    lhs = jnp.asarray(r.randn(m, k).astype(np.float32) * 0.5,
+                      jnp.bfloat16)
+    rhs = jnp.asarray(r.randn(e, k, n).astype(np.float32) * k ** -0.5,
+                      jnp.bfloat16)
+    t = jnp.asarray(r.randn(m, n).astype(np.float32), jnp.bfloat16)
+    gs = jnp.asarray(sizes, jnp.int32)
+
+    def ref(a, b):
+        out = jax.lax.ragged_dot(a, b, gs,
+                                 preferred_element_type=jnp.float32)
+        return G._zero_slack(out.astype(a.dtype), gs)
+
+    pal = lambda a, b: G.grouped_matmul(a, b, gs)    # noqa: E731
+    got = jax.jit(pal)(lhs, rhs)
+    _close(got, jax.jit(ref)(lhs, rhs), **BF16)
+    assert not np.asarray(got[sum(sizes):], np.float32).any()
+    loss = lambda f: lambda a, b: jnp.sum(           # noqa: E731
+        (f(a, b) * t).astype(jnp.float32))
+    gp = jax.jit(jax.grad(loss(pal), (0, 1)))(lhs, rhs)
+    gr = jax.jit(jax.grad(loss(ref), (0, 1)))(lhs, rhs)
+    # ragged_dot leaves the slack rows of ITS lhs gradient as it found
+    # them (NaN and stale numbers: my chip run, PR 28, call 3): the
+    # real rows are compared, the wrapper's slack is exact zeros
+    live = sum(sizes)
+    _close((gp[0][:live], gp[1]), (gr[0][:live], gr[1]), rtol=5e-2,
+           atol=1e-2, atol_of_max=True)
+    assert not np.asarray(gp[0][live:], np.float32).any()
+    assert not np.asarray(gp[1][1], np.float32).any()
+
+
 # -- ring attention per-hop kernels ----------------------------------------
 
 @pytest.mark.parametrize("dtype,tol", [

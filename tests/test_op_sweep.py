@@ -377,6 +377,29 @@ spec("batch_norm", {"X": sgn((3, 2, 2, 2), 98),
 spec("layer_norm", {"X": sgn((3, 4), 101), "Scale": u((4,), 102),
                     "Bias": sgn((4,), 103)},
      grad=["X", "Scale", "Bias"], max_rel=0.02)
+spec("rms_norm", {"X": sgn((3, 4), 131), "Scale": u((4,), 132)},
+     {"epsilon": 1e-5}, max_rel=0.02,
+     ref=lambda ins: [ins["X"] / np.sqrt(
+         np.mean(np.square(ins["X"]), -1, keepdims=True) + 1e-5)
+         * ins["Scale"]])
+
+
+def _rotary_ref(ins):
+    """Rotate-half over the whole head: pair (i, i + Dh/2) of row s
+    turns by s * theta^(-2i/Dh)."""
+    x = ins["X"]
+    s, dh = x.shape[-2], x.shape[-1]
+    ang = np.arange(s)[:, None] \
+        * 100.0 ** (-np.arange(dh // 2) * 2.0 / dh)[None, :]
+    x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
+    return [np.concatenate([x1 * np.cos(ang) - x2 * np.sin(ang),
+                            x2 * np.cos(ang) + x1 * np.sin(ang)],
+                           -1).astype(np.float32)]
+
+
+spec("rotary_embedding", {"X": sgn((1, 2, 5, 4), 133)},
+     {"theta": 100.0}, ref=_rotary_ref,
+     loss_weight=_rs(203).uniform(0.5, 1.5, (1, 2, 5, 4)))
 spec("instance_norm", {"X": sgn((2, 2, 3, 3), 104),
                        "Scale": u((2,), 105),
                        "Bias": sgn((2,), 106)}, max_rel=0.02,
@@ -2176,6 +2199,16 @@ spec("fusion_lstm",
      {"use_peepholes": False}, ref=_fusion_lstm_ref, max_rel=0.01)
 
 EXEMPT = {
+    # discrete routing over persistable buffers (a bias buffer and the
+    # step's counters written in place; top-k flips under a finite
+    # difference)
+    "moe_sigmoid_router":
+        "test_afmoe_model.py (choice, weights and counters by hand; "
+        "loss and every gradient against the float32 reference)",
+    "moe_held_experts":
+        "test_afmoe_model.py (every gradient against the reference, "
+        "the 16 shares against the uncut layer, overflow), "
+        "test_grouped_matmul.py",
     # host callbacks
     "print": "test_misc_parity.py (host callback, pass-through)",
     "py_func": "test_new_ops.py (host callback + custom backward)",

@@ -1,0 +1,90 @@
+"""The Mosaic kernels of the Trinity-Mini cell, COMPILED for a v5e
+that is described and not attached, at the cell's own shapes: what the
+chip's compiler would refuse (a tile that does not align, more fast
+memory than a kernel may use) is refused here, at no chip time.
+Nothing runs, so nothing is said about results or times: the values
+are tests/test_sdpa_window_gqa.py's and tests/test_grouped_matmul.py's
+(interpreted), and tests/test_chip_kernels.py's on the chip.
+
+The topology is described inside a fixture (never at import: one
+process at a time may load the TPU's library), and everything that
+compiles is in this one file.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas import attention as A
+from paddle_tpu.ops.pallas import grouped_matmul as G
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_the_chip(monkeypatch):
+    """The kernels' TPU lowering, which they choose from
+    ``interpret_mode()``: steered here, not by an option of theirs."""
+    monkeypatch.setattr(A, "interpret_mode", lambda: False)
+    monkeypatch.setattr(G, "interpret_mode", lambda: False)
+
+
+def compiled(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    # as training runs: conftest's exact float32 products are for the
+    # CPU, and Mosaic refuses that precision on bf16 operands
+    with jax.default_matmul_precision("default"):
+        return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("window", [2048, 0])
+def test_blocked_flash_at_the_cells_site(one_chip, for_the_chip, window):
+    """32 q heads over 4 kv heads of 128, 8192 positions, bf16: the
+    forward and both backward kernels."""
+    bf = jnp.bfloat16
+    q, kv = (1, 32, 8192, 128), (1, 4, 8192, 128)
+
+    def site(q_, k_, v_, g_):
+        seed = jnp.zeros((2,), jnp.float32)
+        out, pull = jax.vjp(
+            lambda a, b, c: A._sdpa_flash(a, b, c, None, seed,
+                                          128 ** -0.5, 0.0, True,
+                                          window), q_, k_, v_)
+        return out, pull(g_)
+
+    c = compiled(site, one_chip, (q, bf), (kv, bf), (kv, bf), (q, bf))
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    # out, lse, delta and the three gradients: nothing of S x S
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
+def test_grouped_products_at_the_cells_widths(one_chip, for_the_chip,
+                                              k, n):
+    """8 held experts, a row for every assignment (65,536): gmm
+    forward, gmm for the rows' gradient, tgmm for the matrices'."""
+    bf = jnp.bfloat16
+
+    def product(lhs, rhs, sizes, g):
+        out, pull = jax.vjp(
+            lambda a, b: G.grouped_matmul(a, b, sizes), lhs, rhs)
+        return out, pull(g)
+
+    c = compiled(product, one_chip, ((65536, k), bf), ((8, k, n), bf),
+                 ((8,), jnp.int32), ((65536, n), bf))
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
