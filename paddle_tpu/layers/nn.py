@@ -910,17 +910,29 @@ def label_smooth(label, prior_dist=None, epsilon=0.1, dtype="float32",
 
 def scaled_dot_product_attention(q, k, v, bias=None, scale=1.0,
                                  dropout_rate=0.0, causal=False,
-                                 is_test=False, window=0, name=None):
-    """Fused attention core: softmax(q @ k^T * scale + bias) @ v over
-    [batch, heads, seq, head_dim] inputs, with optional in-kernel
-    attention dropout and causal masking. Lowers to one fused op (pallas
-    flash kernel — blocked online softmax, recompute backward — when
+                                 is_test=False, window=0, name=None,
+                                 num_heads=None):
+    """Fused attention core: softmax(q @ k^T * scale + bias) @ v, with
+    optional in-kernel attention dropout and causal masking. Two input
+    layouts: [batch, heads, seq, head_dim], or, with ``num_heads``,
+    [batch, seq, heads * head_dim] as a projection produces it and the
+    output projection reads it (no head split or merge to build: the
+    flash pair picks the heads inside the kernel); the output has
+    ``q``'s shape. Lowers to one fused op (pallas flash kernel —
+    blocked online softmax, recompute backward — when
     FLAGS_op_library=pallas; XLA-fused composite otherwise). ``bias`` is
-    an additive attention *mask* (non-differentiable); add a trainable
+    an additive attention *mask* (non-differentiable), broadcastable to
+    [batch, 1 or heads, q_len, k_len] in either layout; add a trainable
     bias with elementwise_add instead. ``k`` and ``v`` may have fewer
     heads than ``q`` (grouped queries: q head i reads kv head
     i // (heads // kv heads)); ``window`` > 0, with ``causal``, lets
     row i read keys i-window+1..i only. See ops/pallas/attention.py."""
+    if (len(q.shape) == 3) != bool(num_heads):
+        raise ValueError(
+            "scaled_dot_product_attention: num_heads goes with rank-3 "
+            "[batch, seq, heads * head_dim] inputs and with nothing "
+            "else; got rank %d and num_heads=%r"
+            % (len(q.shape), num_heads))
     helper = LayerHelper("sdpa", name=name)
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if bias is not None:
@@ -932,7 +944,8 @@ def scaled_dot_product_attention(q, k, v, bias=None, scale=1.0,
                             "dropout_rate": float(dropout_rate),
                             "causal": bool(causal),
                             "is_test": bool(is_test),
-                            "window": int(window)})
+                            "window": int(window),
+                            "num_heads": int(num_heads or 0)})
     return out
 
 

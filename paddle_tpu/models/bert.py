@@ -62,20 +62,14 @@ def _attention(x, bias, cfg, is_test, prefix):
     q = layers.fc(x, d, num_flatten_dims=2, name=prefix + "_q")
     k = layers.fc(x, d, num_flatten_dims=2, name=prefix + "_k")
     v = layers.fc(x, d, num_flatten_dims=2, name=prefix + "_v")
-    s = x.shape[1]
-
-    def split(t):
-        t = layers.reshape(t, (-1, s, h, dh))
-        return layers.transpose(t, (0, 2, 1, 3))
-
-    q, k, v = split(q), split(k), split(v)
-    # fused attention (pallas flash kernel when enabled); attention
-    # dropout runs in-kernel so scores never materialize in HBM
+    # fused attention (pallas flash kernel when enabled) on the
+    # projections' own [b, s, h * dh] layout: no head split or merge;
+    # attention dropout runs in-kernel so scores never materialize in
+    # HBM
     ctx = layers.scaled_dot_product_attention(
         q, k, v, bias=bias, scale=dh ** -0.5,
-        dropout_rate=cfg.attention_probs_dropout_prob, is_test=is_test)
-    ctx = layers.transpose(ctx, (0, 2, 1, 3))
-    ctx = layers.reshape(ctx, (-1, s, d))
+        dropout_rate=cfg.attention_probs_dropout_prob, is_test=is_test,
+        num_heads=h)
     return layers.fc(ctx, d, num_flatten_dims=2, name=prefix + "_out")
 
 

@@ -86,24 +86,14 @@ def _multi_head_attention(q_in, kv_in, bias, cfg, is_test, prefix):
     v = layers.fc(kv_in, d, num_flatten_dims=2, bias_attr=False,
                   name=prefix + "_v")
 
-    def split_heads(x, slen):
-        x = layers.reshape(x, (-1, slen, h, dh))
-        return layers.transpose(x, (0, 2, 1, 3))  # [b, h, s, dh]
-
-    q_len = q_in.shape[1]
-    k_len = kv_in.shape[1]
-    q = split_heads(q, q_len)
-    k = split_heads(k, k_len)
-    v = split_heads(v, k_len)
-
-    # fused attention core (pallas flash kernel when enabled) —
-    # attention dropout runs in-kernel (TPU PRNG), so the score matrix
+    # fused attention core (pallas flash kernel when enabled), on the
+    # projections' own [b, s, h * dh] layout: the kernel picks the
+    # heads out of the lanes, so no head split or merge is built.
+    # Attention dropout runs in-kernel (TPU PRNG), so the score matrix
     # never materializes in HBM even when training with dropout
     ctx = layers.scaled_dot_product_attention(
         q, k, v, bias=bias, scale=dh ** -0.5,
-        dropout_rate=cfg.dropout, is_test=is_test)
-    ctx = layers.transpose(ctx, (0, 2, 1, 3))
-    ctx = layers.reshape(ctx, (-1, q_len, d))
+        dropout_rate=cfg.dropout, is_test=is_test, num_heads=h)
     return layers.fc(ctx, d, num_flatten_dims=2, bias_attr=False,
                      name=prefix + "_out")
 

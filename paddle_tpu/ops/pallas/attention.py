@@ -20,13 +20,10 @@ matrix in HBM in either direction:
   forward mask without storing it.
 - **Causal** masking skips fully-masked k-blocks (roughly halves the
   decoder self-attention work).
-- **Short-sequence batching**: each grid cell processes ``G``
-  (batch, head) rows at once (batched dot_generals over the leading
-  dim). At flagship shape (B=64 H=8 S=256) the naive per-row grid is
-  512 cells of ~0.3us of MXU work each — pure per-cell overhead; G=8
-  cuts the grid to 64 cells with 8x the work and 8x larger DMA
-  transfers. G divides H, so a cell never straddles a batch row and
-  per-BATCH bias blocks stay well-defined.
+- **Short-sequence batching** (blocked kernels): each grid cell
+  processes ``G`` (batch, head) rows at once (batched dot_generals
+  over the leading dim). G divides H, so a cell never straddles a
+  batch row and per-BATCH bias blocks stay well-defined.
 - **Single-k-block specialization** (``_1k_applicable``: Sk<=512,
   and Sq at most 256 or a multiple of 256, natural tiling): when the
   whole key range fits one block, the online-softmax machinery is
@@ -34,18 +31,35 @@ matrix in HBM in either direction:
   lane-replicated statistics), and the backward is ONE kernel
   producing dq/dk/dv from a single exp recompute with lse and delta
   derived in-kernel — the only HBM residual is the forward output.
-  Queries are blocked ``_1K_BLK_Q`` rows to a grid step (a second grid
-  axis; k and v stay resident across it), so a q-block's ``[G, blk_q,
-  Sk]`` score tile is what VMEM holds; the backward sums dk/dv over
-  the q-blocks in float32 scratch. This is what
-  ``FLAGS_sdpa_auto_flash`` dispatches in training: transformer-base
-  (S=256, 18 sites, one q-block) and BERT-base at S=128 and S=512
-  (12 sites, two q-blocks). The argument for it in-model: XLA's fused
-  chain pays RNG mask materialization + probs HBM round-trips at
-  every attention site (BERT S=512: 136 ms of a 253 ms step, ledger
-  PR 26). Everything else — Sk > 512 (S=1024 self-attention), a
-  ragged Sq — takes the blocked kernels below under
-  ``FLAGS_op_library=pallas`` and XLA's chain by default.
+  The pair reads q, k, v and the output's gradient, and writes o, dq,
+  dk, dv, **in the layout the projections produce and consume,
+  ``[B, S, H*Dh]``**: a grid cell is a batch row's ``G`` heads
+  (``G*Dh`` lanes: a multiple of 128, or the whole width), and a
+  loop over the cell's heads picks each out of the lanes
+  (``_for_lane_groups``). No ``[B,H,S,Dh]`` array, and none of the eight
+  materialised transposes a site that built and undid it (16.8 ms of
+  transformer-base's 164 ms step, 16.3 of BERT's 178: ledger, PR 28),
+  exists in a training step of either model. Queries are blocked
+  ``_1K_BLK_Q`` rows to a grid step (the last grid axis; k and v stay
+  resident across it), so one head's ``[blk_q, Sk]`` score tile is
+  what VMEM holds beside the blocks; the backward sums dk/dv over the
+  q-blocks in float32 scratch. This is what ``FLAGS_sdpa_auto_flash``
+  dispatches in training: transformer-base (S=256, 18 sites, one
+  q-block, G=8) and BERT-base at S=128 and S=512 (12 sites, two
+  q-blocks, G=6). The argument for it in-model: XLA's fused chain
+  pays RNG mask materialization + probs HBM round-trips at every
+  attention site (BERT S=512: 136 ms of a 253 ms step, ledger PR 26).
+  Everything else — Sk > 512 (S=1024 self-attention), a ragged Sq —
+  takes the blocked kernels below under ``FLAGS_op_library=pallas``
+  and XLA's chain by default.
+- **Two entry layouts, one pair.** The op takes rank 4
+  ``[B,H,S,Dh]`` or rank 3 ``[B,S,H*Dh]`` with ``num_heads``; the
+  rank of Q is all the lowering looks at. The 1k pair has the rank-3
+  layout only, the blocked kernels and the sp schedules the rank-4
+  one only: a caller in the other layout is adapted by a transpose in
+  the lowering (``sdpa_pallas``), which is what a model's own head
+  split costs. ``sdpa_lowering.flash_1k_transposed`` counts the sites
+  that reached the pair that way.
 
 ``Bias`` is an additive attention mask (0 / -1e9, built from data by the
 models) and is registered non-differentiable: the base lowering and the
@@ -83,17 +97,18 @@ _TT = (((1,), (1,)), ((0,), (0,)))     # [G,q,k] x [G,q,d] -> [G,k,d]
 def _causal_mask(s, j, kk, blk_q, blk_k, window=0):
     """Row i reads key c only where c <= i and, with a ``window``,
     i - c < window."""
-    rows = j * blk_q + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    cols = kk * blk_k + lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    rows = j * blk_q + lax.broadcasted_iota(jnp.int32, s.shape,
+                                            s.ndim - 2)
+    cols = kk * blk_k + lax.broadcasted_iota(jnp.int32, s.shape,
+                                             s.ndim - 1)
     keep = rows >= cols
     if window:
         keep = jnp.logical_and(keep, rows - cols < window)
     return jnp.where(keep, s, _NEG_INF)
 
 
-def _dropout_keep(seed_ref, i, j, kk, n_q, n_k, shape, rate):
-    """Deterministic per-block dropout mask; identical bits are
-    regenerated in the backward kernels. The (cell, q-block, k-block)
+def _seed_block(seed_ref, i, j, kk, n_q, n_k):
+    """Seed the TPU's generator for one (cell, q-block, k-block): the
     coordinates are folded into one scalar seed (single-arg prng_seed —
     the multi-arg form doesn't lower on this Mosaic version) with a
     Knuth-style odd multiplier so nearby blocks decorrelate.
@@ -101,10 +116,22 @@ def _dropout_keep(seed_ref, i, j, kk, n_q, n_k, shape, rate):
     mesh numbers its cells where a single device would (_seed_smem)."""
     flat = ((seed_ref[1] + i) * n_q + j) * n_k + kk
     pltpu.prng_seed(seed_ref[0] + flat * jnp.int32(-1640531527))
+
+
+def _keep_bits(shape, rate):
+    """The next ``shape`` of keep / drop bits of the seeded generator:
+    the backward kernels seed alike and draw in the same order, so they
+    regenerate the forward's mask without storing it."""
     bits = pltpu.prng_random_bits(shape)
     u = lax.bitcast_convert_type(bits, jnp.uint32)
     thresh = jnp.uint32(min(int(rate * (1 << 32)), (1 << 32) - 1))
     return u >= thresh
+
+
+def _dropout_keep(seed_ref, i, j, kk, n_q, n_k, shape, rate):
+    """Deterministic per-block dropout mask of the blocked kernels."""
+    _seed_block(seed_ref, i, j, kk, n_q, n_k)
+    return _keep_bits(shape, rate)
 
 
 @functools.lru_cache(maxsize=None)
@@ -137,12 +164,43 @@ def _softmax_save_lowp(dtype_name):
     return f
 
 
+def _geometry(q, k, num_heads):
+    """(B, H, Hkv, Sq, Sk, Dh) of either layout the op takes: rank 4,
+    heads leading, q [B,H,Sq,Dh] and k [B,Hkv,Sk,Dh]; or rank 3, as
+    the projections produce it, q [B,Sq,H*Dh] and k [B,Sk,Hkv*Dh] with
+    ``num_heads`` query heads (a head's Dh lanes are contiguous)."""
+    if q.ndim == 4:
+        B, H, Sq, Dh = q.shape
+        return B, H, k.shape[1], Sq, k.shape[2], Dh
+    B, Sq, width = q.shape
+    if num_heads <= 0 or width % num_heads:
+        raise ValueError(
+            "scaled_dot_product_attention: rank-3 Q of width %d needs "
+            "num_heads dividing it, got %d" % (width, num_heads))
+    Dh = width // num_heads
+    return B, num_heads, k.shape[2] // Dh, Sq, k.shape[1], Dh
+
+
+def _split_heads(x, Dh):
+    """[B,S,H*Dh] -> [B,H,S,Dh]: a materialised transpose."""
+    B, S, width = x.shape
+    return x.reshape(B, S, width // Dh, Dh).transpose(0, 2, 1, 3)
+
+
+def _merge_heads(x):
+    """[B,H,S,Dh] -> [B,S,H*Dh]."""
+    B, H, S, Dh = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, S, H * Dh)
+
+
 def _sdpa_reference(q, k, v, bias, *, scale, dropout_rate=0.0,
-                    causal=False, window=0, rng=None):
+                    causal=False, window=0, num_heads=0, rng=None):
     """Pure-jnp composite (the jit/refer/ analog): q [B,H,S,Dh], k and
     v [B,Hkv,S,Dh] with Hkv dividing H (q head i reads kv head
-    i // (H // Hkv)), bias additive, broadcastable to [B,1_or_H,Sq,Sk];
-    with a ``window`` (causal only) row i reads keys i-window+1..i.
+    i // (H // Hkv)), or all three rank 3 (_geometry: a free reshape
+    to [B,S,H,Dh], the einsums pick the heads and XLA the layouts);
+    bias additive, broadcastable to [B,1_or_H,Sq,Sk]; with a
+    ``window`` (causal only) row i reads keys i-window+1..i.
 
     Precision follows standard TPU practice (and the reference's f32
     softmax accumulate): scores and softmax in float32 — the MXU
@@ -151,18 +209,25 @@ def _sdpa_reference(q, k, v, bias, *, scale, dropout_rate=0.0,
     dtype (saving only the low-precision copy for the backward) for
     the dropout mask and the PV matmul, so the [B,H,S,S] traffic
     rides at half width under AMP."""
-    B, H, sq, dh = q.shape
-    hkv, sk = k.shape[1], k.shape[2]
-    if hkv != H:
-        # grouped queries: fold the group into the batch of heads, the
-        # kv heads are never repeated
-        q = q.reshape(B, hkv, H // hkv, sq, dh)
-        s = jnp.einsum("bkgqd,bkmd->bkgqm", q, k,
-                       preferred_element_type=jnp.float32
-                       ).reshape(B, H, sq, sk) * scale
+    B, H, hkv, sq, sk, dh = _geometry(q, k, num_heads)
+    heads_last = q.ndim == 3
+    group = H // hkv
+    # q as [.., hkv, group, ..] where kv heads are shared: the group
+    # folds into the batch of heads, the kv heads are never repeated
+    if heads_last:
+        q = q.reshape((B, sq, H, dh) if group == 1
+                      else (B, sq, hkv, group, dh))
+        k = k.reshape(B, sk, hkv, dh)
+        v = v.reshape(B, sk, hkv, dh)
+        qs, ks = ("bqhd", "bkhd") if group == 1 else ("bqhgd", "bkhd")
     else:
-        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) * scale
+        if group > 1:
+            q = q.reshape(B, hkv, group, sq, dh)
+        qs, ks = ("bhqd", "bhkd") if group == 1 else ("bhgqd", "bhkd")
+    ps = "bhqk" if group == 1 else "bhgqk"
+    s = jnp.einsum("%s,%s->%s" % (qs, ks, ps), q, k,
+                   preferred_element_type=jnp.float32
+                   ).reshape(B, H, sq, sk) * scale
     if bias is not None:
         s = s + lax.stop_gradient(bias).astype(jnp.float32)
     if causal:
@@ -178,21 +243,21 @@ def _sdpa_reference(q, k, v, bias, *, scale, dropout_rate=0.0,
         keep = _keep_mask(rng, dropout_rate, w.shape)
         w = jnp.where(keep, w / (1.0 - dropout_rate),
                       jnp.zeros((), v.dtype))
-    if hkv != H:
-        return jnp.einsum(
-            "bkgqm,bkmd->bkgqd", w.reshape(B, hkv, H // hkv, sq, sk), v,
-            preferred_element_type=jnp.float32).reshape(
-                B, H, sq, dh).astype(v.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", w, v,
-                      preferred_element_type=jnp.float32).astype(
-        q.dtype)
+    if group > 1:
+        w = w.reshape(B, hkv, group, sq, sk)
+    out = jnp.einsum("%s,%s->%s" % (ps, ks, qs), w, v,
+                     preferred_element_type=jnp.float32)
+    out_dtype = v.dtype if group > 1 else q.dtype
+    return out.reshape((B, sq, H * dh) if heads_last
+                       else (B, H, sq, dh)).astype(out_dtype)
 
 
 @register("scaled_dot_product_attention", ["Q", "K", "V", "Bias"],
           ["Out"], nondiff=("Bias",), needs_rng=True)
 def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
                                  dropout_rate=0.0, causal=False,
-                                 is_test=False, window=0, rng=None):
+                                 is_test=False, window=0, num_heads=0,
+                                 rng=None):
     """Base lowering: XLA fuses the chain — except inside the flash
     kernels' envelopes, where the base dispatches to them
     (FLAGS_sdpa_auto_flash, the jit/README.en.md best-impl-wins pool
@@ -204,19 +269,30 @@ def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
     is THE lowering — a Mosaic compile error propagates, nothing
     retries with the reference.
 
+    Two entry layouts, told apart by Q's rank (_geometry): rank 4 with
+    heads leading, or rank 3 ``[B, S, H*Dh]`` with ``num_heads``, which
+    is what the projections produce and the output projection reads;
+    ``Out`` has Q's shape. The 1k pair reads rank 3 in place; whatever
+    wants the other layout than the caller's (the pair for a rank-4
+    caller; the blocked kernels and the sp route for a rank-3 one)
+    gets it by a transpose here, which is what a model's own head
+    split costs.
+
     K and V may carry fewer heads than Q (grouped queries: q head i
     reads kv head i // (H // Hkv)); ``window`` > 0 (causal only) lets
     row i read keys i-window+1..i."""
     rate = 0.0 if is_test else float(dropout_rate)
     window = int(window)
+    num_heads = int(num_heads)
     if window and not causal:
         raise ValueError("scaled_dot_product_attention: a window is "
                          "defined for causal attention only")
-    if q.shape[1] % k.shape[1]:
+    _, H, hkv, sq, sk, dh = _geometry(q, k, num_heads)
+    if H % hkv:
         raise ValueError("scaled_dot_product_attention: %d query heads "
-                         "over %d key heads" % (q.shape[1], k.shape[1]))
+                         "over %d key heads" % (H, hkv))
     from ...core.flags import FLAGS
-    plain = not window and k.shape[1] == q.shape[1]
+    plain = not window and hkv == H
     if FLAGS.sp_attention and rate == 0.0 and plain:
         # model-parallel production path: under a mesh with an sp axis
         # (CompiledProgram.with_data_parallel(axes={"dp":d,"sp":s})
@@ -227,25 +303,28 @@ def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
         # axis is in scope or the geometry doesn't admit a schedule,
         # in which case the replicated lowerings below stay in charge.
         from ...parallel.ulysses import sequence_parallel_attention
-        routed = sequence_parallel_attention(q, k, v, bias=bias,
+        heads_last = q.ndim == 3
+        q4, k4, v4 = ((_split_heads(x, dh) for x in (q, k, v))
+                      if heads_last else (q, k, v))
+        routed = sequence_parallel_attention(q4, k4, v4, bias=bias,
                                              scale=scale,
                                              causal=causal)
         if routed is not None:
             _count_lowering("sp")
-            return routed
+            return _merge_heads(routed) if heads_last else routed
     if (FLAGS.sdpa_auto_flash and not interpret_mode()
             and jnp.dtype(q.dtype).itemsize <= 2):
-        sq, sk = q.shape[2], k.shape[2]
         if (rate > 0.0 and rng is not None and plain
                 and _1k_applicable(sq, sk)) \
                 or (rate == 0.0 and _blocked_applicable(sq, sk)):
             return sdpa_pallas(q, k, v, bias, scale=scale,
                                dropout_rate=dropout_rate, causal=causal,
-                               is_test=is_test, window=window, rng=rng)
+                               is_test=is_test, window=window,
+                               num_heads=num_heads, rng=rng)
     _count_lowering("xla")
     return _sdpa_reference(q, k, v, bias, scale=scale,
                            dropout_rate=rate, causal=causal,
-                           window=window, rng=rng)
+                           window=window, num_heads=num_heads, rng=rng)
 
 
 def _blocked_applicable(Sq, Sk):
@@ -256,12 +335,14 @@ def _blocked_applicable(Sq, Sk):
         and Sq % _BLK_Q_TARGET == 0
 
 
-def _count_lowering(path):
+def _count_lowering(path, by=1.0):
     """``sdpa_lowering.<path>`` with path ``flash_1k``,
-    ``flash_blocked``, ``xla`` or ``sp`` (common.count_lowering). A
+    ``flash_blocked``, ``xla`` or ``sp`` (common.count_lowering), and
+    ``flash_1k_transposed`` beside ``flash_1k`` where the pair was
+    reached from rank 4, behind the lowering's transposes. A
     differentiated site is lowered once for the forward and once more
     under ``jax.vjp``."""
-    count_lowering("sdpa_lowering." + path)
+    count_lowering("sdpa_lowering." + path, by)
 
 
 # ---------------------------------------------------------------------------
@@ -272,97 +353,191 @@ def _count_lowering(path):
 # The backward is ONE kernel computing dq/dk/dv together from a single
 # exp recompute (the blocked path needs two kernels = two recomputes),
 # with lse and delta = rowsum(dO*O) derived in-kernel so the only HBM
-# residual is the forward output itself. Grid (cells, q-blocks): k and
-# v keep their block index across the q-blocks of a cell, so they are
-# fetched once a cell.
+# residual is the forward output itself.
+#
+# Layout: q / o / do / dq are [B, Sq, H*Dh] and k / v / dk / dv
+# [B, Sk, H*Dh], as the projections produce and consume them: no
+# [B,H,S,Dh] array exists around the pair. Grid (batch rows, cells of
+# G heads, q-blocks); a cell's blocks are (1, blk_q, G*Dh) and
+# (1, Sk, G*Dh), lane-dense (G*Dh a multiple of 128, or the whole
+# width), and k and v keep their block index across the q-blocks of a
+# cell, so they are fetched once a cell. Inside, a loop over
+# the cell's heads picks each head's Dh lanes out of the block
+# (_for_lane_groups).
 # ---------------------------------------------------------------------------
 
+# one head's 2-D tiles
+_NT = (((1,), (1,)), ((), ()))         # [q,d] x [k,d] -> [q,k]
+_NN = (((1,), (0,)), ((), ()))         # [q,k] x [k,d] -> [q,d]
+_TN = (((0,), (0,)), ((), ()))         # [q,k] x [q,d] -> [k,d]
 
-def _attn_scores(q_ref, k_ref, b_ref, j, *, scale, causal):
-    s = lax.dot_general(q_ref[...], k_ref[...], _QK,
+
+def _for_lane_groups(G, Dh, body):
+    """``body(lanes of the block, first head, heads)`` for the cell's G
+    heads in the groups that are loaded and stored together, as many
+    as fill a 128-lane tile (two at Dh=64), so that every load and
+    store is lane-aligned. A head is then picked by noughts, not by a
+    slice: the other heads' lanes are zeroed in one operand of a
+    contraction over the group's lanes, and a product that writes them
+    is kept in the head's own lanes only (_own_lanes). On a 128 x 128
+    matrix unit a contraction or an output of 64 fills half the array
+    already, so this costs no extra pass; a static 64-lane slice cost
+    a relayout of every operand (one transformer-base site, forward +
+    backward: 1.69 ms sliced, 1.06 ms this way; my chip run, PR 29,
+    call 1).
+
+    Whole tiles are walked by a ``fori_loop`` over a dynamic, aligned
+    lane offset, so the Mosaic body holds one group's code and not
+    G / 2 copies of it: unrolled over the transformer's eight heads
+    the pair added 2.3 s of lowering and 2.1 s of loading to every
+    start of its step (my chip run, PR 29, call 3). Lanes that do not
+    tile (a test's 96-lane width) are walked statically."""
+    per = max(1, 128 // Dh)
+    width = per * Dh
+    if width % 128 == 0 and G % per == 0 and G > per:
+        def group(i, carry):
+            body(pl.ds(pl.multiple_of(i * width, width), width),
+                 i * per, per)
+            return carry
+
+        lax.fori_loop(0, G // per, group, 0)
+        return
+    for g0 in range(0, G, per):
+        body(slice(g0 * Dh, min(g0 + per, G) * Dh), g0, min(per, G - g0))
+
+
+def _own_lanes(x, t, n, Dh, other=None):
+    """``x`` [rows, n*Dh] in head t's lanes of its group of n, and
+    ``other`` (noughts by default) in the rest."""
+    if n == 1:
+        return x
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    own = jnp.logical_and(lane >= t * Dh, lane < (t + 1) * Dh)
+    return jnp.where(own, x, jnp.zeros((), x.dtype)
+                     if other is None else other)
+
+
+def _head_scores(q, k, b_ref, g, j, *, scale, causal):
+    """One head's [blk_q, Sk] float32 scores; head ``g`` of the cell
+    reads its own bias slab where the bias is per head."""
+    s = lax.dot_general(q, k, _NT,
                         preferred_element_type=jnp.float32) * scale
     if b_ref is not None:
-        s = s + b_ref[:, 0].astype(jnp.float32)
+        s = s + b_ref[g if b_ref.shape[0] > 1 else 0, 0].astype(
+            jnp.float32)
     if causal:
-        s = _causal_mask(s, j, 0, s.shape[1], s.shape[2])
-    return s                                        # [G, blk_q, Sk] f32
+        s = _causal_mask(s, j, 0, s.shape[0], s.shape[1])
+    return s
 
 
-def _fwd_kernel_1k(seed_ref, q_ref, k_ref, v_ref, b_ref, o_ref, *,
+def _seed_cell_1k(seed_ref, n_q):
+    """One seed a (cell, q-block); the cell's heads then draw their
+    [blk_q, Sk] masks one after another, forward and backward in the
+    same order. Cells are numbered batch row by batch row."""
+    cell = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
+    _seed_block(seed_ref, cell, pl.program_id(2), 0, n_q, 1)
+
+
+def _fwd_kernel_1k(seed_ref, q_ref, k_ref, v_ref, b_ref, o_ref, *, Dh,
                    scale, rate, causal, n_q):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    s = _attn_scores(q_ref, k_ref, b_ref, j, scale=scale,
-                     causal=causal)
-    m = jnp.max(s, -1, keepdims=True)
-    p = jnp.exp(s - m)
-    l = jnp.sum(p, -1, keepdims=True)
+    j = pl.program_id(2)
     if rate > 0.0:
-        keep = _dropout_keep(seed_ref, i, j, 0, n_q, 1, p.shape, rate)
-        p = jnp.where(keep, p * (1.0 / (1.0 - rate)), 0.0)
-    pv = lax.dot_general(p.astype(v_ref.dtype), v_ref[...], _PV,
-                         preferred_element_type=jnp.float32)
-    # reciprocal-multiply: a [G,Sq,1]-broadcast divide on the [G,Sq,Dh]
-    # tile costs ~4x a multiply on the VPU
-    rl = 1.0 / jnp.where(l == 0.0, 1.0, l)
-    o_ref[...] = (pv * rl).astype(o_ref.dtype)
+        _seed_cell_1k(seed_ref, n_q)
+
+    def group(lanes, g0, n):
+        q, k, v = q_ref[0, :, lanes], k_ref[0, :, lanes], \
+            v_ref[0, :, lanes]
+        out = None
+        for t in range(n):
+            s = _head_scores(_own_lanes(q, t, n, Dh), k, b_ref, g0 + t,
+                             j, scale=scale, causal=causal)
+            m = jnp.max(s, -1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, -1, keepdims=True)
+            if rate > 0.0:
+                p = jnp.where(_keep_bits(p.shape, rate),
+                              p * (1.0 / (1.0 - rate)), 0.0)
+            pv = lax.dot_general(p.astype(v.dtype), v, _NN,
+                                 preferred_element_type=jnp.float32)
+            # reciprocal-multiply: a [Sq,1]-broadcast divide on the
+            # [Sq,Dh] tile costs ~4x a multiply on the VPU
+            rl = 1.0 / jnp.where(l == 0.0, 1.0, l)
+            out = _own_lanes(pv * rl, t, n, Dh, out)
+        o_ref[0, :, lanes] = out.astype(o_ref.dtype)
+
+    _for_lane_groups(q_ref.shape[2] // Dh, Dh, group)
 
 
 def _bwd_kernel_1k(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, o_ref,
-                   dq_ref, dk_ref, dv_ref, *acc, scale, rate, causal,
-                   n_q):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    s = _attn_scores(q_ref, k_ref, b_ref, j, scale=scale,
-                     causal=causal)
-    m = jnp.max(s, -1, keepdims=True)
-    e = jnp.exp(s - m)
-    l = jnp.sum(e, -1, keepdims=True)
-    rl = 1.0 / jnp.where(l == 0.0, 1.0, l)          # [G, blk_q, 1]
-    p = e * rl                                      # [G, blk_q, Sk] f32
-    do = do_ref[...]                                # [G, blk_q, Dh]
-    delta = jnp.sum(do.astype(jnp.float32)
-                    * o_ref[...].astype(jnp.float32), -1,
-                    keepdims=True)                  # [G, blk_q, 1]
-    dp = lax.dot_general(do, v_ref[...], _QK,
-                         preferred_element_type=jnp.float32)
+                   dq_ref, dk_ref, dv_ref, *acc, Dh, scale, rate,
+                   causal, n_q):
+    j = pl.program_id(2)
     if rate > 0.0:
-        keep = _dropout_keep(seed_ref, i, j, 0, n_q, 1, p.shape, rate)
-        inv = 1.0 / (1.0 - rate)
-        pd = jnp.where(keep, p * inv, 0.0)
-        dp = jnp.where(keep, dp * inv, 0.0)
-    else:
-        pd = p
-    dv = lax.dot_general(pd.astype(do.dtype), do, _TT,
-                         preferred_element_type=jnp.float32)
-    ds = (p * (dp - delta) * scale).astype(q_ref.dtype)
-    dq_ref[...] = lax.dot_general(
-        ds, k_ref[...], _PV,
-        preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-    dk = lax.dot_general(ds, q_ref[...], _TT,
-                         preferred_element_type=jnp.float32)
-    if n_q == 1:
-        dk_ref[...] = dk.astype(dk_ref.dtype)
-        dv_ref[...] = dv.astype(dv_ref.dtype)
-        return
-    # dk, dv are sums over the cell's q-blocks: float32 scratch over
-    # the "arbitrary" j axis, written out once at the last block
-    dk_acc, dv_acc = acc
+        _seed_cell_1k(seed_ref, n_q)
 
-    @pl.when(j == 0)
-    def _first():
-        dk_acc[...] = dk
-        dv_acc[...] = dv
+    def group(lanes, g0, n):
+        k, v = k_ref[0, :, lanes], v_ref[0, :, lanes]
+        q_all, do_all = q_ref[0, :, lanes], do_ref[0, :, lanes]
+        o = o_ref[0, :, lanes].astype(jnp.float32)
+        dq = dk = dv = None
+        for t in range(n):
+            # with the other heads' lanes of q and do at nought, the
+            # contractions over the group's lanes are this head's, and
+            # dk and dv (which q and do write) are nought outside them
+            q = _own_lanes(q_all, t, n, Dh)
+            do = _own_lanes(do_all, t, n, Dh)
+            s = _head_scores(q, k, b_ref, g0 + t, j, scale=scale,
+                             causal=causal)
+            m = jnp.max(s, -1, keepdims=True)
+            e = jnp.exp(s - m)
+            l = jnp.sum(e, -1, keepdims=True)
+            p = e * (1.0 / jnp.where(l == 0.0, 1.0, l))  # [blk_q, Sk]
+            delta = jnp.sum(do.astype(jnp.float32) * o, -1,
+                            keepdims=True)               # [blk_q, 1]
+            dp = lax.dot_general(do, v, _NT,
+                                 preferred_element_type=jnp.float32)
+            if rate > 0.0:
+                keep = _keep_bits(p.shape, rate)
+                inv = 1.0 / (1.0 - rate)
+                pd = jnp.where(keep, p * inv, 0.0)
+                dp = jnp.where(keep, dp * inv, 0.0)
+            else:
+                pd = p
+            dv_t = lax.dot_general(pd.astype(do.dtype), do, _TN,
+                                   preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta) * scale).astype(q.dtype)
+            dq = _own_lanes(lax.dot_general(
+                ds, k, _NN, preferred_element_type=jnp.float32),
+                t, n, Dh, dq)
+            dk_t = lax.dot_general(ds, q, _TN,
+                                   preferred_element_type=jnp.float32)
+            dk = dk_t if dk is None else dk + dk_t
+            dv = dv_t if dv is None else dv + dv_t
+        dq_ref[0, :, lanes] = dq.astype(dq_ref.dtype)
+        if n_q == 1:
+            dk_ref[0, :, lanes] = dk.astype(dk_ref.dtype)
+            dv_ref[0, :, lanes] = dv.astype(dv_ref.dtype)
+            return
+        # dk, dv are sums over the cell's q-blocks: float32 scratch
+        # over the "arbitrary" j axis, written out at the last block
+        dk_acc, dv_acc = acc
 
-    @pl.when(j > 0)
-    def _rest():
-        dk_acc[...] += dk
-        dv_acc[...] += dv
+        @pl.when(j == 0)
+        def _first():
+            dk_acc[:, lanes] = dk
+            dv_acc[:, lanes] = dv
 
-    @pl.when(j == n_q - 1)
-    def _finish():
-        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        @pl.when(j > 0)
+        def _rest():
+            dk_acc[:, lanes] += dk
+            dv_acc[:, lanes] += dv
+
+    _for_lane_groups(q_ref.shape[2] // Dh, Dh, group)
+    if n_q > 1:
+        @pl.when(j == n_q - 1)
+        def _finish():
+            dk_ref[0] = acc[0][...].astype(dk_ref.dtype)
+            dv_ref[0] = acc[1][...].astype(dv_ref.dtype)
 
 
 # Query rows to a grid step of the single-k-block kernels: the whole
@@ -387,26 +562,28 @@ def _1k_applicable(Sq, Sk):
     return Sq % 8 == 0 if Sq <= _1K_BLK_Q else Sq % _1K_BLK_Q == 0
 
 
-# VMEM model for the single-k-block kernels (ADVICE r4: the corner
-# Sq=256/Sk=512 exceeded scoped VMEM at the uncapped G=8). Per grid
-# row the kernels hold:
-#   - streamed blocks, double-buffered: q/do/o/dq rows of blk_q, and
-#     k/v/dk/dv rows of Sk, each lane-padded to 128 in the minor dim;
-#   - [G,blk_q,Sk] f32 score temporaries. 8 bytes/element — ~2 f32
-#     arrays live after Mosaic's buffer reuse. This constant is
-#     ANCHORED on chip evidence, not source-level counting: the
-#     bf16 [8,256,256] backward (5 source-level f32 temps = 20 B/elem
-#     would predict 22 MB) compiles and runs at G=8 (jax 0.9.0 /
-#     libtpu 0.0.34, PR 21: chip_smoke.py and test_chip_kernels.py),
-#     so Mosaic demonstrably reuses all but ~2;
-#   - with more than one q-block, the backward's two [G,Sk,Dh] f32
-#     dk/dv accumulators (scratch: resident once, lane-padded).
-# Budget 15 MB of the 16 MB v5e scoped limit; G halves until the
-# modeled row total fits. tests/test_pallas_vmem.py replays this
-# model at every _1k_applicable corner AND pins the headline
-# geometry (bf16 256x256 dropout) to G=8.
-_1K_TEMP_BYTES = 8
+# VMEM model for the single-k-block kernels. A cell of G heads holds:
+#   - streamed blocks, double-buffered: q/do/o/dq rows of blk_q and
+#     k/v/dk/dv rows of Sk, G*Dh lanes wide (lane-dense: nothing is
+#     padded where G*Dh is a multiple of 128);
+#   - ONE head's [blk_q,Sk] score temporaries at a time (the loop over
+#     the cell's heads is unrolled, a head's temporaries die with its
+#     iteration): 24 bytes an element, the backward's six
+#     source-level arrays (s, e/p, dp, the generator's bits, pd, ds)
+#     with no reuse assumed; twice that for float32 operands, whose
+#     exact products split every operand into bf16 parts (BERT's
+#     S=512 at G=6 in float32: 13.5 MB by the single count, 17 MB by
+#     the compiler for the described v5e; PR 29);
+#   - the bias block(s), double-buffered, and the s + b f32 addend;
+#   - with more than one q-block, the backward's two [Sk, G*Dh] f32
+#     dk/dv accumulators (scratch: resident once).
+# Budget 15 MB of the 16 MB v5e scoped limit. tests/test_pallas_vmem.py
+# replays this model at every _1k_applicable corner and pins the two
+# benchmark geometries' G; tests/test_tpu_aot_kernels.py has the
+# chip's compiler accept them.
+_1K_TEMP_BYTES = 24
 _1K_VMEM_BUDGET = 15 << 20
+_1K_MAX_G = 8
 
 # Blocked-path tile targets, env-tunable for on-chip sweeps
 # (tools/blocked_sweep.py): PALLAS_BLK_Q / PALLAS_BLK_K. The blocked
@@ -418,46 +595,55 @@ _BLK_Q_TARGET = int(os.environ.get("PALLAS_BLK_Q", "256"))
 _BLK_K_TARGET = int(os.environ.get("PALLAS_BLK_K", "512"))
 
 
-def _1k_row_bytes(itemsize, Sq, Sk, Dh, n_sq_ops, n_sk_ops, has_bias,
-                  accumulates=False):
-    lanes = max(Dh, 128)
+def _1k_cell_bytes(G, itemsize, Sq, Sk, Dh, n_sq_ops, n_sk_ops,
+                   bias_itemsize=0, per_head=False, accumulates=False):
+    """Modeled VMEM of one grid cell of G heads; ``bias_itemsize`` 0
+    without a bias, whose slabs are one a cell or, ``per_head``, G."""
+    lanes = -(-G * Dh // 128) * 128
     blk_q = _1k_blk_q(Sq)
-    stream = (n_sq_ops * blk_q + n_sk_ops * Sk) * lanes * itemsize * 2
-    temps = blk_q * Sk * _1K_TEMP_BYTES
-    if has_bias:
-        # bias block (streamed, double-buffered; charged per-row even
-        # for the shared non-per-head slab — conservative) plus the
-        # s + b f32 addend the biased kernel keeps live
-        temps += blk_q * Sk * (itemsize * 2 + 4)
+    total = (n_sq_ops * blk_q + n_sk_ops * Sk) * lanes * itemsize * 2
+    total += blk_q * Sk * _1K_TEMP_BYTES * (2 if itemsize > 2 else 1)
+    if bias_itemsize:
+        slabs = G if per_head else 1
+        total += blk_q * Sk * (slabs * bias_itemsize * 2 + 4)
     if accumulates and Sq > blk_q:
-        temps += 2 * Sk * lanes * 4
-    return stream + temps
+        total += 2 * Sk * lanes * 4
+    return total
 
 
-def _1k_bwd_G(H, itemsize, Sq, Sk, Dh, has_bias=False):
-    """Backward rows per grid cell, capped by the VMEM model
+def _1k_G(H, Dh, *model, **kw):
+    """Heads to a cell: the most (up to _1K_MAX_G) whose
+    ``_1k_cell_bytes(G, *model, **kw)`` fit the budget, of the divisors
+    of H whose lanes tile: G*Dh a multiple of 128, or the whole width.
+    Nothing fitting, the fewest that tile."""
+    tiling = [g for g in range(H, 0, -1)
+              if H % g == 0 and (g == H or g * Dh % 128 == 0)]
+    capped = [g for g in tiling if g <= _1K_MAX_G] or tiling[-1:]
+    for g in capped:
+        if _1k_cell_bytes(g, *model, **kw) <= _1K_VMEM_BUDGET:
+            return g
+    return capped[-1]
+
+
+def _1k_bwd_G(H, itemsize, Sq, Sk, Dh, bias_itemsize=0, per_head=False):
+    """The backward's heads per grid cell, capped by the VMEM model
     (streams: q,do,o,dq + k,v,dk,dv; the dk/dv accumulators)."""
-    base = 8 if itemsize <= 2 else 4
-    row = _1k_row_bytes(itemsize, Sq, Sk, Dh, 4, 4, has_bias,
-                        accumulates=True)
-    while base > 1 and base * row > _1K_VMEM_BUDGET:
-        base //= 2
-    return blk(H, base)
+    return _1k_G(H, Dh, itemsize, Sq, Sk, Dh, 4, 4, bias_itemsize,
+                 per_head, accumulates=True)
 
 
-def _1k_fwd_G(H, itemsize, rate, Sq, Sk, Dh, has_bias=False):
-    """Forward rows per grid cell. With dropout it MUST equal the
-    backward's G (the per-cell PRNG seed mapping — see _blocked_G's
-    invariant note; blk_q is _1k_blk_q(Sq) on both sides); without
-    dropout the forward only needs its own streams (q,o + k,v) to
-    fit."""
+def _1k_fwd_G(H, itemsize, rate, Sq, Sk, Dh, bias_itemsize=0,
+              per_head=False):
+    """The forward's heads per grid cell. With dropout it MUST equal
+    the backward's G (a cell's heads draw their masks one after
+    another from the cell's seed; blk_q is _1k_blk_q(Sq) on both
+    sides); without dropout the forward only needs its own streams
+    (q,o + k,v) to fit."""
     if rate > 0.0:
-        return _1k_bwd_G(H, itemsize, Sq, Sk, Dh, has_bias)
-    base = 8
-    row = _1k_row_bytes(itemsize, Sq, Sk, Dh, 2, 2, has_bias)
-    while base > 1 and base * row > _1K_VMEM_BUDGET:
-        base //= 2
-    return blk(H, base)
+        return _1k_bwd_G(H, itemsize, Sq, Sk, Dh, bias_itemsize,
+                         per_head)
+    return _1k_G(H, Dh, itemsize, Sq, Sk, Dh, 2, 2, bias_itemsize,
+                 per_head)
 
 
 def _blocked_G(H):
@@ -491,27 +677,27 @@ def _seed_smem(seed_f, G):
     return jnp.stack([s[0], s[1] // G])
 
 
-def _1k_specs_args(q, k, v, bias, per_head, seed, G, hb):
+def _1k_specs_args(q, k, v, bias, per_head, seed, G, H):
     """Shared in_specs/args plumbing for the single-k-block kernels:
-    grid (cells i, q-blocks j). Returns (in_specs, args, the spec of
-    a q-side block, the spec of a k-side block)."""
-    B, H, Sq, Dh = q.shape
-    Sk = k.shape[2]
-    BH = B * H
+    grid (batch rows b, cells c of G heads, q-blocks j). Returns
+    (in_specs, args, the spec of a q-side block, the spec of a k-side
+    block)."""
+    Sq, Sk = q.shape[1], k.shape[1]
+    width = G * (q.shape[2] // H)
+    hb = H // G                    # cells per batch row
     blk_q = _1k_blk_q(Sq)
-    q_spec = pl.BlockSpec((G, blk_q, Dh), lambda i, j: (i, j, 0))
-    k_spec = pl.BlockSpec((G, Sk, Dh), lambda i, j: (i, 0, 0))
+    q_spec = pl.BlockSpec((1, blk_q, width), lambda b, c, j: (b, j, c))
+    k_spec = pl.BlockSpec((1, Sk, width), lambda b, c, j: (b, 0, c))
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, k_spec,
                 k_spec]
-    args = [seed, q.reshape(BH, Sq, Dh), k.reshape(BH, Sk, Dh),
-            v.reshape(BH, Sk, Dh)]
+    args = [seed, q, k, v]
     if bias is not None:
         if per_head:
-            in_specs.append(pl.BlockSpec((G, 1, blk_q, Sk),
-                                         lambda i, j: (i, 0, j, 0)))
+            in_specs.append(pl.BlockSpec(
+                (G, 1, blk_q, Sk), lambda b, c, j: (b * hb + c, 0, j, 0)))
         else:
             in_specs.append(pl.BlockSpec(
-                (1, 1, blk_q, Sk), lambda i, j: (i // hb, 0, j, 0)))
+                (1, 1, blk_q, Sk), lambda b, c, j: (b, 0, j, 0)))
         args.append(bias)
     return in_specs, args, q_spec, k_spec
 
@@ -527,53 +713,52 @@ def _1k_specs_args(q, k, v, bias, per_head, seed, G, hb):
 # locations a Mosaic body serializes, so XLA cannot merge the two
 # forward calls and runs both. One body makes them identical and CSE
 # drops one: 11 ms of BERT's 189 ms step, 8 ms of transformer-base's.
-@functools.partial(jax.jit, static_argnums=(5, 6, 7))
-def _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate, causal):
-    B, H, Sq, Dh = q.shape
-    Sk = k.shape[2]
-    BH = B * H
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+def _flash_fwd_1k(q, k, v, bias, seed_f, H, scale, rate, causal):
+    """q [B,Sq,H*Dh], k and v [B,Sk,H*Dh] -> out [B,Sq,H*Dh]."""
+    B, Sq, width = q.shape
+    Sk, Dh = k.shape[1], width // H
     n_q = Sq // _1k_blk_q(Sq)
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
     G = _1k_fwd_G(H, q.dtype.itemsize, rate, Sq, Sk, Dh,
-                  bias is not None)
-    hb = H // G
+                  bias.dtype.itemsize if bias is not None else 0,
+                  per_head)
     seed = _seed_smem(seed_f, G)
 
     in_specs, args, q_spec, _ = _1k_specs_args(q, k, v, bias, per_head,
-                                               seed, G, hb)
+                                               seed, G, H)
     if bias is not None:
         kernel = _fwd_kernel_1k
     else:
         kernel = (lambda sr, qr, kr, vr, orf, **kw:
                   _fwd_kernel_1k(sr, qr, kr, vr, None, orf, **kw))
 
-    out = pl.pallas_call(
-        functools.partial(kernel, scale=scale, rate=rate,
+    return pl.pallas_call(
+        functools.partial(kernel, Dh=Dh, scale=scale, rate=rate,
                           causal=causal, n_q=n_q),
-        out_shape=jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
-        grid=(BH // G, n_q),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        grid=(B, H // G, n_q),
         in_specs=in_specs,
         out_specs=q_spec,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel",) * 3),
         interpret=interpret_mode(),
     )(*args)
-    return out.reshape(B, H, Sq, Dh)
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8, 9))
-def _flash_bwd_1k(q, k, v, bias, seed_f, o, g, scale, rate, causal):
-    B, H, Sq, Dh = q.shape
-    Sk = k.shape[2]
-    BH = B * H
+@functools.partial(jax.jit, static_argnums=(7, 8, 9, 10))
+def _flash_bwd_1k(q, k, v, bias, seed_f, o, g, H, scale, rate, causal):
+    B, Sq, width = q.shape
+    Sk, Dh = k.shape[1], width // H
     n_q = Sq // _1k_blk_q(Sq)
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
-    G = _1k_bwd_G(H, q.dtype.itemsize, Sq, Sk, Dh, bias is not None)
-    hb = H // G
+    G = _1k_bwd_G(H, q.dtype.itemsize, Sq, Sk, Dh,
+                  bias.dtype.itemsize if bias is not None else 0,
+                  per_head)
     seed = _seed_smem(seed_f, G)
 
     in_specs, args, q_spec, k_spec = _1k_specs_args(
-        q, k, v, bias, per_head, seed, G, hb)
+        q, k, v, bias, per_head, seed, G, H)
     if bias is not None:
         kernel = _bwd_kernel_1k
     else:
@@ -581,28 +766,27 @@ def _flash_bwd_1k(q, k, v, bias, seed_f, o, g, scale, rate, causal):
                   _bwd_kernel_1k(sr, qr, kr, vr, None, dor, orf,
                                  *outs, **kw))
     in_specs += [q_spec, q_spec]
-    args += [g.reshape(BH, Sq, Dh), o.reshape(BH, Sq, Dh)]
+    args += [g, o]
 
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(kernel, scale=scale, rate=rate,
+    return pl.pallas_call(
+        functools.partial(kernel, Dh=Dh, scale=scale, rate=rate,
                           causal=causal, n_q=n_q),
-        out_shape=[jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, Dh), k.dtype),
-                   jax.ShapeDtypeStruct((BH, Sk, Dh), v.dtype)],
-        grid=(BH // G, n_q),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        grid=(B, H // G, n_q),
         in_specs=in_specs,
         out_specs=[q_spec, k_spec, k_spec],
         # one q-block: nothing is carried from one grid step to the
         # next; more: dk/dv accumulate across j
         scratch_shapes=[] if n_q == 1 else
-        [pltpu.VMEM((G, Sk, Dh), jnp.float32)] * 2,
+        [pltpu.VMEM((Sk, G * Dh), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
-                "parallel", "parallel" if n_q == 1 else "arbitrary")),
+                "parallel", "parallel",
+                "parallel" if n_q == 1 else "arbitrary")),
         interpret=interpret_mode(),
     )(*args)
-    return (dq.reshape(B, H, Sq, Dh), dk.reshape(B, H, Sk, Dh),
-            dv.reshape(B, H, Sk, Dh))
 
 
 # ---------------------------------------------------------------------------
@@ -1060,28 +1244,32 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal,
     return dq, dk, dv
 
 
-def _takes_1k(q, k, window):
+def _takes_1k(H, Hkv, Sq, Sk, window):
     """The single-k-block pair serves equal q and kv heads with no
     window inside its envelope; the blocked kernels everything else."""
-    return not window and q.shape[1] == k.shape[1] \
-        and _1k_applicable(q.shape[2], k.shape[2])
+    return not window and H == Hkv and _1k_applicable(Sq, Sk)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _sdpa_flash(q, k, v, bias, seed_f, scale, rate, causal, window=0):
-    if _takes_1k(q, k, window):
-        return _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate,
-                             causal)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _sdpa_flash(q, k, v, bias, seed_f, scale, rate, causal, window=0,
+                num_heads=0):
+    """The kernels behind one differentiable call. The layout names
+    the family: rank 3 ``[B,S,H*Dh]`` with ``num_heads`` is the 1k
+    pair's, rank 4 ``[B,H,S,Dh]`` the blocked kernels' (sdpa_pallas
+    hands each the layout it reads)."""
+    if q.ndim == 3:
+        return _flash_fwd_1k(q, k, v, bias, seed_f, num_heads, scale,
+                             rate, causal)
     out, _lse = _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal,
                            window)
     return out
 
 
 def _sdpa_flash_fwd(q, k, v, bias, seed_f, scale, rate, causal,
-                    window=0):
-    if _takes_1k(q, k, window):
-        out = _flash_fwd_1k(q, k, v, bias, seed_f, scale, rate,
-                            causal)
+                    window=0, num_heads=0):
+    if q.ndim == 3:
+        out = _flash_fwd_1k(q, k, v, bias, seed_f, num_heads, scale,
+                            rate, causal)
         # the single-block backward re-derives lse in-kernel: the
         # forward output is the only tensor residual
         return out, (q, k, v, bias, seed_f, out, None)
@@ -1090,11 +1278,11 @@ def _sdpa_flash_fwd(q, k, v, bias, seed_f, scale, rate, causal,
     return out, (q, k, v, bias, seed_f, out, lse)
 
 
-def _sdpa_flash_bwd(scale, rate, causal, window, res, g):
+def _sdpa_flash_bwd(scale, rate, causal, window, num_heads, res, g):
     q, k, v, bias, seed_f, out, lse = res
     if lse is None:
         dq, dk, dv = _flash_bwd_1k(q, k, v, bias, seed_f, out, g,
-                                   scale, rate, causal)
+                                   num_heads, scale, rate, causal)
     else:
         dq, dk, dv = _flash_bwd(q, k, v, bias, seed_f, out, lse, g,
                                 scale, rate, causal, window)
@@ -1107,9 +1295,11 @@ _sdpa_flash.defvjp(_sdpa_flash_fwd, _sdpa_flash_bwd)
 
 @register_variant("scaled_dot_product_attention", "pallas")
 def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
-                causal=False, is_test=False, window=0, rng=None):
+                causal=False, is_test=False, window=0, num_heads=0,
+                rng=None):
     rate = 0.0 if is_test else float(dropout_rate)
     window = int(window)
+    num_heads = int(num_heads)
     # per-head bias [B, H, Sq, Sk] is handled natively: _prep_bias
     # flattens it to one slab per (batch, head) grid row
     if rate > 0.0 and (rng is None or interpret_mode()):
@@ -1118,9 +1308,20 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
         _count_lowering("xla")
         return _sdpa_reference(q, k, v, bias, scale=scale,
                                dropout_rate=rate, causal=causal,
-                               window=window, rng=rng)
-    _count_lowering("flash_1k" if _takes_1k(q, k, window)
-                    else "flash_blocked")
+                               window=window, num_heads=num_heads,
+                               rng=rng)
+    _, H, hkv, sq, sk, dh = _geometry(q, k, num_heads)
+    heads_last = q.ndim == 3
+    takes_1k = _takes_1k(H, hkv, sq, sk, window)
+    _count_lowering("flash_1k" if takes_1k else "flash_blocked")
+    # each family reads one layout; the other entry layout is adapted
+    # by the transposes its model would otherwise have built
+    if takes_1k:
+        _count_lowering("flash_1k_transposed", 0.0 if heads_last else 1.0)
+        if not heads_last:
+            q, k, v = (_merge_heads(x) for x in (q, k, v))
+    elif heads_last:
+        q, k, v = (_split_heads(x, dh) for x in (q, k, v))
     if rate > 0.0:
         # fold the step key into a scalar TPU PRNG seed; float32 carries
         # it through custom_vjp without an int-cotangent (float0) dance
@@ -1132,27 +1333,34 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
     from ...parallel.ulysses import in_sp_body
     mesh = mesh_lib.current_mesh()
     if mesh is not None and mesh.size > 1 and not in_sp_body():
-        return _flash_over_mesh(mesh, q, k, v, bias, seed, float(scale),
-                                rate, bool(causal), window)
-    return _sdpa_flash(q, k, v, bias, jnp.stack([seed, jnp.float32(0)]),
-                       float(scale), rate, bool(causal), window)
+        out = _flash_over_mesh(mesh, q, k, v, bias, seed, H, hkv,
+                               float(scale), rate, bool(causal), window)
+    else:
+        out = _sdpa_flash(q, k, v, bias,
+                          jnp.stack([seed, jnp.float32(0)]),
+                          float(scale), rate, bool(causal), window, H)
+    if takes_1k == heads_last:
+        return out
+    return _split_heads(out, dh) if takes_1k else _merge_heads(out)
 
 
-def _flash_over_mesh(mesh, q, k, v, bias, seed, scale, rate, causal,
-                     window=0):
+def _flash_over_mesh(mesh, q, k, v, bias, seed, H, Hkv, scale, rate,
+                     causal, window=0):
     """The kernel under a multi-device mesh. Mosaic kernels are not
     partitioned automatically — jax's lowering rule refuses one inside
     a multi-device jit (jax/_src/tpu_custom_call.py) — so it runs per
     shard under shard_map: batch over ``dp`` and heads over ``tp``
     where the mesh has those axes and they divide (the kv heads too,
     where there are fewer of them), every other axis computing
-    replicated. Each shard numbers its dropout cells from its first
-    (batch, head) row, so under dp the masks are the ones a single
-    device draws and the loss trace is the single-device one."""
+    replicated. Heads are axis 1 of the blocked kernels' rank 4 and
+    contiguous in the last axis of the pair's rank 3. Each shard
+    numbers its dropout cells from its first (batch, head) row, so
+    under dp the masks are the ones a single device draws and the loss
+    trace is the single-device one."""
     from jax import shard_map
     from jax.sharding import PartitionSpec
 
-    B, H = q.shape[:2]
+    B = q.shape[0]
 
     def axis(name, *ns):
         if name in mesh.axis_names and mesh.shape[name] > 1 \
@@ -1160,8 +1368,10 @@ def _flash_over_mesh(mesh, q, k, v, bias, seed, scale, rate, causal,
             return name
         return None
 
-    b_ax, h_ax = axis("dp", B), axis("tp", H, k.shape[1])
-    spec = PartitionSpec(b_ax, h_ax, None, None)
+    b_ax, h_ax = axis("dp", B), axis("tp", H, Hkv)
+    h_loc = H // mesh.shape[h_ax] if h_ax else H
+    spec = PartitionSpec(b_ax, None, h_ax) if q.ndim == 3 \
+        else PartitionSpec(b_ax, h_ax, None, None)
     args, specs = [seed, q, k, v], [PartitionSpec(), spec, spec, spec]
     if bias is not None:
         bias = bias.reshape((1,) * (4 - bias.ndim) + bias.shape)
@@ -1175,11 +1385,11 @@ def _flash_over_mesh(mesh, q, k, v, bias, seed, scale, rate, causal,
         for ax in (b_ax, h_ax):
             if ax is not None:
                 shard = shard * mesh.shape[ax] + lax.axis_index(ax)
-        row0 = shard * (q_.shape[0] * q_.shape[1])
+        row0 = shard * (q_.shape[0] * h_loc)
         return _sdpa_flash(
             q_, k_, v_, bias_,
             jnp.stack([seed_, row0.astype(jnp.float32)]),
-            scale, rate, causal, window)
+            scale, rate, causal, window, h_loc)
 
     return shard_map(body, mesh=mesh, in_specs=tuple(specs),
                      out_specs=spec, check_vma=False)(*args)

@@ -1,7 +1,8 @@
 """Every registered Pallas variant, COMPILED by Mosaic on the chip at
 the shapes transformer-base presents (B=64, H=8, S=256, Dh=64,
 N=B*S=16384 rows, d_model 512, vocab 30,000), BERT-base's S=512
-attention (b=56, h=12: two q-blocks to a cell), S=1024 for the blocked
+attention (b=56, h=12: two q-blocks to a cell; the flash 1k pair on
+[b, s, h * Dh] as the models hand it over), S=1024 for the blocked
 flash path and the per-hop shapes for ops/pallas/ring.py — each against
 its jnp reference at the tolerance tests/test_pallas_kernels.py uses
 for that kernel (bf16 inputs: bf16 tolerance).
@@ -63,10 +64,12 @@ def _close(got, want, rtol, atol, atol_of_max=False):
             atol=atol * (np.abs(w).max() if atol_of_max else 1.0))
 
 
-def _qkv(seed, b, h, sq, sk, dtype):
+def _qkv(seed, b, h, sq, sk, dtype, heads_last=False):
+    """[b, h, s, DH], or [b, s, h * DH] with ``heads_last``."""
     r = np.random.RandomState(seed)
     mk = lambda s: jnp.asarray(  # noqa: E731
-        r.randn(b, h, s, DH).astype(np.float32) * 0.5, dtype)
+        r.randn(*((b, s, h * DH) if heads_last else (b, h, s, DH)))
+        .astype(np.float32) * 0.5, dtype)
     return mk(sq), mk(sk), mk(sk)
 
 
@@ -80,9 +83,10 @@ def _pad_bias(seed, b, sq, sk):
         (b, 1, sq, sk))
 
 
-def _sdpa_fwd_and_grads(q, k, v, bias, causal, fwd_tol, grad_tol):
+def _sdpa_fwd_and_grads(q, k, v, bias, causal, fwd_tol, grad_tol,
+                        num_heads=0):
     scale = DH ** -0.5
-    kw = dict(scale=scale, causal=causal)
+    kw = dict(scale=scale, causal=causal, num_heads=num_heads)
 
     def ref(q_, k_, v_):
         return A._sdpa_reference(q_, k_, v_, bias, **kw)
@@ -107,29 +111,49 @@ def _sdpa_fwd_and_grads(q, k, v, bias, causal, fwd_tol, grad_tol):
     (jnp.float32, F32, F32_GRAD)])
 def test_flash_1k_matches_reference(dtype, fwd_tol, grad_tol, geom,
                                     causal):
+    """The pair where the models call it: q, k, v [b, s, h * DH]."""
     b, h, s = geom
     assert A._1k_applicable(s, s)
     if dtype == jnp.float32:
         b = 8
-    q, k, v = _qkv(0, b, h, s, s, dtype)
+    q, k, v = _qkv(0, b, h, s, s, dtype, heads_last=True)
     with _exact_if_f32(dtype):
         _sdpa_fwd_and_grads(q, k, v, _pad_bias(1, b, s, s), causal,
-                            fwd_tol, grad_tol)
-        _sdpa_fwd_and_grads(q, k, v, None, causal, fwd_tol, grad_tol)
+                            fwd_tol, grad_tol, h)
+        _sdpa_fwd_and_grads(q, k, v, None, causal, fwd_tol, grad_tol, h)
 
 
-def _dropout_checks(sq, sk, b, h=H, bias=None):
+@pytest.mark.parametrize("geom", [(B, H, S), BERT])
+def test_flash_1k_behind_a_rank4_caller(geom):
+    """A caller with heads leading is adapted by the lowering's own
+    transposes and runs the same pair; a per-head bias with it."""
+    b, h, s = geom
+    q, k, v = _qkv(12, b, h, s, s, jnp.bfloat16)
+    r = np.random.RandomState(13)
+    keep = r.rand(1, h, s, s) > 0.15
+    keep[..., 0] = True
+    per_head = jnp.asarray(np.where(keep, 0.0, -1e9).astype(np.float32))
+    grad_tol = dict(rtol=5e-2, atol=5e-2)
+    _sdpa_fwd_and_grads(q, k, v, _pad_bias(14, b, s, s), True, BF16,
+                        grad_tol)
+    _sdpa_fwd_and_grads(q[:4], k[:4], v[:4], per_head, False, BF16,
+                        grad_tol)
+
+
+def _dropout_checks(sq, sk, b, h=H, bias=None, heads_last=False):
     """What can be said about in-kernel dropout without the mask:
-    deterministic in the seed, different across seeds, grid cells and
-    q-blocks, the kept share near 1-rate, and the backward
+    deterministic in the seed, different across seeds, grid cells,
+    heads and q-blocks, the kept share near 1-rate, and the backward
     regenerating exactly the forward's mask — out is linear in V,
-    out = A(mask) V, so dV must be A(mask)^T dOut with the SAME mask."""
+    out = A(mask) V, so dV must be A(mask)^T dOut with the SAME mask.
+    ``heads_last``: the 1k pair's layout, [b, s, h * DH]."""
     rate, scale = 0.1, DH ** -0.5
-    q, k, v = _qkv(2, b, h, sq, sk, jnp.bfloat16)
+    q, k, v = _qkv(2, b, h, sq, sk, jnp.bfloat16, heads_last)
     seed = jnp.asarray([1234, 0], jnp.float32)
 
     def fwd(q_, k_, v_, s=seed):
-        return A._sdpa_flash(q_, k_, v_, bias, s, scale, rate, False)
+        return A._sdpa_flash(q_, k_, v_, bias, s, scale, rate, False, 0,
+                             h if heads_last else 0)
 
     out = jax.jit(fwd)(q, k, v)
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
@@ -143,11 +167,15 @@ def _dropout_checks(sq, sk, b, h=H, bias=None):
     zeros = jnp.zeros_like(q)
     ones = jnp.ones_like(v)
     share = np.asarray(jax.jit(fwd)(zeros, jnp.zeros_like(k), ones),
-                       np.float32)[..., 0] * (1.0 - rate)
+                       np.float32) * (1.0 - rate)
+    # one lane of each head: [b, h, sq]
+    share = share[..., ::DH].transpose(0, 2, 1) if heads_last \
+        else share[..., 0]
     assert abs(share.mean() - (1.0 - rate)) < 5e-3, share.mean()
     assert share.std() > 0.0
     cells = share.reshape(-1, sq)
     assert not np.array_equal(cells[0], cells[-1])
+    assert not np.array_equal(cells[0], cells[1])      # two heads
     if sq > 256:                   # q-blocks of one cell draw apart
         assert not np.array_equal(cells[0][:256], cells[0][256:512])
 
@@ -167,8 +195,9 @@ def _dropout_checks(sq, sk, b, h=H, bias=None):
 
 def test_flash_1k_dropout_prng():
     """The exact configuration the model compiles 18 times: bf16,
-    b64 h8 S=256, dropout 0.1, in-kernel pltpu PRNG."""
-    _dropout_checks(S, S, B)
+    b64 h8 S=256 on [b, s, 512], dropout 0.1, in-kernel pltpu PRNG:
+    one seed a cell, its eight heads drawing one after another."""
+    _dropout_checks(S, S, B, heads_last=True)
 
 
 def test_flash_1k_dropout_prng_bert_s512():
@@ -178,7 +207,7 @@ def test_flash_1k_dropout_prng_bert_s512():
     same blk_q, same (cell, q-block) seed)."""
     b, h, s = BERT
     assert A._1k_applicable(s, s) and s > A._1k_blk_q(s)
-    _dropout_checks(s, s, b, h, _pad_bias(11, b, s, s))
+    _dropout_checks(s, s, b, h, _pad_bias(11, b, s, s), heads_last=True)
 
 
 # -- blocked online-softmax path (S=1024) ----------------------------------
@@ -219,7 +248,8 @@ def test_flash_blocked_window_gqa_matches_reference(window):
     mk = lambda h: jnp.asarray(                      # noqa: E731
         r.randn(1, h, s, dh).astype(np.float32) * 0.5, jnp.bfloat16)
     q, k, v = mk(8), mk(1), mk(1)
-    assert A._blocked_applicable(s, s) and not A._takes_1k(q, k, window)
+    assert A._blocked_applicable(s, s) \
+        and not A._takes_1k(8, 1, s, s, window)
     kw = dict(scale=dh ** -0.5, causal=True, window=window)
     ref = lambda *a: A._sdpa_reference(*a, None, **kw)   # noqa: E731
     pal = lambda *a: A.sdpa_pallas(*a, None, is_test=True,  # noqa: E731
@@ -367,13 +397,13 @@ def test_flash_dropout_over_dp_mesh_equals_one_chip():
     masks, so outputs and gradients equal the one-chip call's."""
     from paddle_tpu.parallel import mesh as mesh_lib
 
-    q, k, v = _qkv(9, B, H, S, S, jnp.bfloat16)
+    q, k, v = _qkv(9, B, H, S, S, jnp.bfloat16, heads_last=True)
     bias = _pad_bias(10, B, 1, S)
     key = jax.random.key(5)
 
     def loss(q_, k_, v_):
         out = A.sdpa_pallas(q_, k_, v_, bias, scale=DH ** -0.5,
-                            dropout_rate=0.1, rng=key)
+                            dropout_rate=0.1, num_heads=H, rng=key)
         return jnp.sum(jnp.square(out.astype(jnp.float32))), out
 
     fn = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)

@@ -299,11 +299,15 @@ def test_fused_linear_xent_3d_and_bf16():
                                rtol=0.05, atol=0.05)
 
 
-def _qkv_bias(r, B, H, Sq, Sk, Dh, bias_kind):
-    q = jnp.asarray(r.randn(B, H, Sq, Dh).astype(np.float32))
-    k = jnp.asarray(r.randn(B, H, Sk, Dh).astype(np.float32))
-    v = jnp.asarray(r.randn(B, H, Sk, Dh).astype(np.float32))
-    shape = {None: None, "batch": (B, 1, 1, Sk),
+def _qkv_bias(r, B, H, Sq, Sk, Dh, bias_kind, heads_last=False):
+    """q, k, v as [B,H,S,Dh], or as [B,S,H*Dh] with ``heads_last``;
+    the additive mask by kind: per key, per batch row, per head."""
+    def mk(S):
+        shape = (B, S, H * Dh) if heads_last else (B, H, S, Dh)
+        return jnp.asarray(r.randn(*shape).astype(np.float32))
+
+    q, k, v = mk(Sq), mk(Sk), mk(Sk)
+    shape = {None: None, "key": (B, 1, 1, Sk), "batch": (B, 1, Sq, Sk),
              "head": (B, H, Sq, Sk)}[bias_kind]
     bias = None
     if shape is not None:
@@ -313,23 +317,9 @@ def _qkv_bias(r, B, H, Sq, Sk, Dh, bias_kind):
     return q, k, v, bias
 
 
-@pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("bias_kind", [None, "batch", "head"])
-@pytest.mark.parametrize("Sq,Sk", [(512, 512), (512, 256)])
-def test_sdpa_flash_1k_q_blocked(Sq, Sk, bias_kind, causal):
-    """The single-k-block pair with more than one q-block (BERT's
-    S=512): forward, and dq / dk / dv — dk and dv are SUMS over the
-    q-blocks of a cell, which a wrong accumulator breaks. H=6 puts two
-    cells in a batch row in the backward (G=3), so a per-batch bias is
-    indexed across cells as well as across q-blocks."""
-    from paddle_tpu.ops.pallas import attention as A
-
-    B, H, Dh = 2, 6, 16
-    assert A._1k_applicable(Sq, Sk) and Sq // A._1k_blk_q(Sq) == 2
-    assert A._1k_bwd_G(H, 4, Sq, Sk, Dh, bias_kind is not None) < H
-    q, k, v, bias = _qkv_bias(np.random.RandomState(21), B, H, Sq, Sk,
-                              Dh, bias_kind)
-    kw = dict(scale=Dh ** -0.5, causal=causal)
+def _pair_matches_reference(q, k, v, bias, **kw):
+    """The pallas variant against the base lowering's reference: the
+    output and the gradients of all three inputs."""
     _cmp("scaled_dot_product_attention", (q, k, v, bias), kw,
          rtol=5e-5, atol=1e-5)
     opdef = ops.get("scaled_dot_product_attention")
@@ -343,6 +333,171 @@ def test_sdpa_flash_1k_q_blocked(Sq, Sk, bias_kind, causal):
     for a, b in zip(gr, gp):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                    rtol=5e-4, atol=5e-5)
+
+
+@pytest.fixture
+def heads_to_a_cell(monkeypatch):
+    """Cap the 1k pair's heads per grid cell, so that a batch row
+    spans several cells at test sizes (every size fits the VMEM model
+    whole). The jitted wrappers choose G while they trace: their
+    caches are dropped on both sides of the test."""
+    from paddle_tpu.ops.pallas import attention as A
+
+    def cap(n):
+        monkeypatch.setattr(A, "_1K_MAX_G", n)
+        jax.clear_caches()
+
+    yield cap
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("bias_kind", [None, "batch", "head"])
+@pytest.mark.parametrize("Sq,Sk", [(512, 512), (512, 256)])
+def test_sdpa_flash_1k_q_blocked(Sq, Sk, bias_kind, causal):
+    """The single-k-block pair with more than one q-block (BERT's
+    S=512) behind a rank-4 caller, which the lowering adapts by a
+    transpose: forward, and dq / dk / dv — dk and dv are SUMS over the
+    q-blocks of a cell, which a wrong accumulator breaks. H=6 at
+    Dh=16 is one cell of 96 lanes, the whole width."""
+    from paddle_tpu.ops.pallas import attention as A
+
+    B, H, Dh = 1, 6, 16
+    assert A._1k_applicable(Sq, Sk) and Sq // A._1k_blk_q(Sq) == 2
+    assert A._1k_bwd_G(H, 4, Sq, Sk, Dh, 4 if bias_kind else 0,
+                       bias_kind == "head") == H
+    q, k, v, bias = _qkv_bias(np.random.RandomState(21), B, H, Sq, Sk,
+                              Dh, bias_kind)
+    _pair_matches_reference(q, k, v, bias, scale=Dh ** -0.5,
+                            causal=causal)
+
+
+# Sq, Sk, H, Dh, bias, causal: the two models' sites (H=8 and H=12 at
+# Dh=64; one, two and two-over-half-the-keys q-blocks), every kind of
+# bias and the causal mask on each, and a head of one lane tile and of
+# a quarter of one
+_RANK3_CASES = [
+    (256, 256, 8, 64, None, False),
+    (256, 256, 8, 64, "key", True),
+    (256, 256, 8, 64, "batch", False),
+    (256, 256, 8, 64, "head", True),
+    (256, 256, 12, 64, "batch", True),
+    (512, 512, 12, 64, "key", False),
+    (512, 512, 12, 64, None, True),
+    (512, 512, 8, 64, "head", False),
+    (512, 512, 8, 64, "batch", True),
+    (512, 256, 12, 64, "batch", False),
+    (512, 256, 8, 64, "key", True),
+    (512, 256, 8, 64, "head", False),
+    (256, 256, 2, 128, "key", True),
+    (256, 256, 4, 32, "batch", False),
+]
+
+
+@pytest.mark.parametrize("Sq,Sk,H,Dh,bias_kind,causal", _RANK3_CASES)
+def test_sdpa_flash_1k_heads_in_place(Sq, Sk, H, Dh, bias_kind, causal):
+    """The pair on the projections' own layout, q [B,Sq,H*Dh] and k, v
+    [B,Sk,H*Dh]: each head picked out of the lanes inside the kernel,
+    against the reference's einsums over the same arrays."""
+    q, k, v, bias = _qkv_bias(np.random.RandomState(31), 1, H, Sq, Sk,
+                              Dh, bias_kind, heads_last=True)
+    _pair_matches_reference(q, k, v, bias, scale=Dh ** -0.5,
+                            causal=causal, num_heads=H)
+
+
+@pytest.mark.parametrize("Sq,Sk,H,G,bias_kind,causal", [
+    (256, 256, 8, 2, "batch", True),
+    (512, 512, 12, 4, "key", False),
+    (512, 256, 12, 6, "head", True),
+])
+def test_sdpa_flash_1k_cells_across_a_batch_row(heads_to_a_cell, Sq, Sk,
+                                                H, G, bias_kind,
+                                                causal):
+    """Several cells to a batch row (what the VMEM model does to
+    BERT's twelve heads): the lanes of cell c start at c*G*Dh, a
+    per-batch bias is shared by the row's cells, a per-head one
+    indexed b*H/G + c, and each cell sums its own dk / dv."""
+    from paddle_tpu.ops.pallas import attention as A
+
+    Dh = 64
+    heads_to_a_cell(G)
+    assert A._1k_bwd_G(H, 4, Sq, Sk, Dh, 4, bias_kind == "head") == G
+    q, k, v, bias = _qkv_bias(np.random.RandomState(32), 2, H, Sq, Sk,
+                              Dh, bias_kind, heads_last=True)
+    _pair_matches_reference(q, k, v, bias, scale=Dh ** -0.5,
+                            causal=causal, num_heads=H)
+
+
+@pytest.mark.parametrize("rate", [0.0, 0.1])
+@pytest.mark.parametrize("Sq,Sk,H,bias_kind,causal", [
+    (256, 256, 8, "batch", False),
+    (512, 256, 12, "head", True),
+])
+def test_sdpa_reference_rank3_equals_rank4(Sq, Sk, H, bias_kind, causal,
+                                           rate):
+    """The reference over [B,S,H*Dh] is the reference over the same
+    data as [B,H,S,Dh]: the output to the last bit, with dropout too
+    (the mask is drawn at [B,H,Sq,Sk] in both); the gradients to
+    float32 rounding (their contractions run over the rows, which the
+    two layouts hand the CPU's products in another order)."""
+    from paddle_tpu.ops.pallas import attention as A
+
+    Dh = 64
+    q, k, v, bias = _qkv_bias(np.random.RandomState(33), 2, H, Sq, Sk,
+                              Dh, bias_kind, heads_last=True)
+    kw = dict(scale=Dh ** -0.5, causal=causal, dropout_rate=rate,
+              rng=jax.random.key(3))
+
+    def rank3(q_, k_, v_):
+        return A._sdpa_reference(q_, k_, v_, bias, num_heads=H, **kw)
+
+    def rank4(q_, k_, v_):
+        return A._merge_heads(A._sdpa_reference(
+            *(A._split_heads(x, Dh) for x in (q_, k_, v_)), bias, **kw))
+
+    def loss(fn):
+        return lambda *a: jnp.sum(jnp.square(fn(*a)))
+
+    np.testing.assert_array_equal(np.asarray(rank3(q, k, v)),
+                                  np.asarray(rank4(q, k, v)))
+    for a, b in zip(jax.grad(loss(rank3), (0, 1, 2))(q, k, v),
+                    jax.grad(loss(rank4), (0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5,
+            atol=1e-5 * float(jnp.abs(b).max()))
+
+
+def test_sdpa_rank3_outside_the_envelope_and_gqa():
+    """Rank 3 where the pair does not serve: S=1024 takes the blocked
+    kernels behind the lowering's own head split, and fewer kv heads
+    with a window the reference's grouped einsums; both against the
+    rank-4 reference of the same data."""
+    from paddle_tpu.ops.pallas import attention as A
+
+    r = np.random.RandomState(34)
+    H, Dh, S = 2, 32, 1024
+    q, k, v, _ = _qkv_bias(r, 1, H, S, S, Dh, None, heads_last=True)
+    want = A._merge_heads(A._sdpa_reference(
+        *(A._split_heads(x, Dh) for x in (q, k, v)), None,
+        scale=Dh ** -0.5, causal=True))
+    got = A.sdpa_pallas(q, k, v, None, scale=Dh ** -0.5, causal=True,
+                        is_test=True, num_heads=H)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=5e-5, atol=1e-5)
+    H, hkv, S = 4, 2, 64
+    q = jnp.asarray(r.randn(1, S, H * Dh).astype(np.float32))
+    k, v = (jnp.asarray(r.randn(1, S, hkv * Dh).astype(np.float32))
+            for _ in range(2))
+    kw = dict(scale=Dh ** -0.5, causal=True, window=16)
+    want = A._merge_heads(A._sdpa_reference(
+        *(A._split_heads(x, Dh) for x in (q, k, v)), None, **kw))
+    got = A.scaled_dot_product_attention(q, k, v, None, is_test=True,
+                                         num_heads=H, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-6)
+    with pytest.raises(ValueError, match="num_heads"):
+        A.scaled_dot_product_attention(q, k, v, None, scale=1.0)
 
 
 @pytest.mark.parametrize("S,dtype", [(256, "float32"),
@@ -372,14 +527,16 @@ def test_attention_dropout_grouping_consistent(monkeypatch, S, dtype):
 
     calls = _capture_calls(fwd_bwd)
     assert len(calls) >= 2                     # fwd + bwd kernel(s)
-    assert len({c["grid"][0] for c in calls}) == 1, \
-        [c["grid"] for c in calls]
     # the q block every kernel streams: same rows, same blk_q
     assert len({tuple(c["in_specs"][1].block_shape)
                 for c in calls}) == 1
     if A._1k_applicable(S, S):
+        # (batch rows, cells of G heads, q-blocks), the pair's one grid
         assert {c["grid"] for c in calls} == {
-            (calls[0]["grid"][0], S // A._1k_blk_q(S))}
+            (2, calls[0]["grid"][1], S // A._1k_blk_q(S))}
+    else:
+        assert len({c["grid"][0] for c in calls}) == 1, \
+            [c["grid"] for c in calls]
 
 
 _ENVELOPE_CASES = [
@@ -397,7 +554,8 @@ _ENVELOPE_CASES = [
 ]
 
 
-def _run_base_sdpa(Sq, Sk, dtype, rate, flag):
+def _run_base_sdpa(Sq, Sk, dtype, rate, flag, rank=4):
+    """The base lowering over 4 heads of 64 in either entry layout."""
     from paddle_tpu.ops.pallas import attention as A
 
     prev = FLAGS.sdpa_auto_flash
@@ -406,25 +564,29 @@ def _run_base_sdpa(Sq, Sk, dtype, rate, flag):
         # non-degenerate inputs: BOTH paths must run clean — a crash
         # in either is a real failure (ADVICE r4: a blanket except
         # here swallowed the dispatched path's errors too)
-        q = jnp.full((2, 4, Sq, 64), 0.1, dtype)
-        k = jnp.full((2, 4, Sk, 64), 0.1, dtype)
+        q = jnp.full((2, 4, Sq, 64) if rank == 4 else (2, Sq, 256),
+                     0.1, dtype)
+        k = jnp.full((2, 4, Sk, 64) if rank == 4 else (2, Sk, 256),
+                     0.1, dtype)
         return A.scaled_dot_product_attention(
             q, k, k, None, scale=0.125, dropout_rate=rate,
-            rng=jax.random.key(0))
+            num_heads=0 if rank == 4 else 4, rng=jax.random.key(0))
     finally:
         FLAGS.sdpa_auto_flash = prev
 
 
+@pytest.mark.parametrize("rank", [4, 3])
 @pytest.mark.parametrize("Sq,Sk,dtype,rate,flag,dispatched",
                          _ENVELOPE_CASES)
 def test_sdpa_auto_flash_dispatch_envelope(monkeypatch, Sq, Sk, dtype,
-                                           rate, flag, dispatched):
+                                           rate, flag, dispatched, rank):
     """FLAGS_sdpa_auto_flash routes the BASE lowering to the flash
     kernel exactly inside the chip-measured win envelope: TPU
     execution, <=2-byte dtype, dropout active, single-k-block shapes
     (Sk <= 512; Sq at most 256 or whole 256-row q-blocks). Everything
     else (f32, no dropout, longer keys, ragged q, interpret mode)
-    keeps the XLA chain."""
+    keeps the XLA chain. The envelope is of the sequence lengths: the
+    same in both entry layouts."""
     from paddle_tpu.ops.pallas import attention as A
 
     calls = []
@@ -432,7 +594,7 @@ def test_sdpa_auto_flash_dispatch_envelope(monkeypatch, Sq, Sk, dtype,
     monkeypatch.setattr(
         A, "sdpa_pallas",
         lambda q, k, v, b, **kw: calls.append("flash") or q)
-    _run_base_sdpa(Sq, Sk, dtype, rate, flag)
+    _run_base_sdpa(Sq, Sk, dtype, rate, flag, rank)
     assert calls == (["flash"] if dispatched else [])
 
 
@@ -452,13 +614,16 @@ def _lowerings_counted(fn):
             if after[k] != before.get(k, 0.0)}
 
 
+@pytest.mark.parametrize("rank", [4, 3])
 @pytest.mark.parametrize("Sq,Sk,dtype,rate,flag,dispatched",
                          _ENVELOPE_CASES)
 def test_sdpa_lowering_counter_names_the_path(monkeypatch, Sq, Sk,
                                               dtype, rate, flag,
-                                              dispatched):
+                                              dispatched, rank):
     """``sdpa_lowering.<path>`` counts each lowering under the path it
-    took: the envelope's cases read flash_1k, the rest xla."""
+    took: the envelope's cases read flash_1k, the rest xla; and
+    flash_1k_transposed beside flash_1k where the pair was reached
+    from rank 4, so the lowering built the transposes."""
     from test_pallas_vmem import _capture_calls
 
     from paddle_tpu.ops.pallas import attention as A
@@ -466,11 +631,16 @@ def test_sdpa_lowering_counter_names_the_path(monkeypatch, Sq, Sk,
     monkeypatch.setattr(A, "interpret_mode", lambda: False)
 
     def lower():
-        _run_base_sdpa(Sq, Sk, dtype, rate, flag)
+        _run_base_sdpa(Sq, Sk, dtype, rate, flag, rank)
 
     moved = _lowerings_counted(
         (lambda: _capture_calls(lower)) if dispatched else lower)
-    assert moved == {"flash_1k" if dispatched else "xla": 1.0}
+    want = {"xla": 1.0}
+    if dispatched:
+        want = {"flash_1k": 1.0}
+        if rank == 4:
+            want["flash_1k_transposed"] = 1.0
+    assert moved == want
 
 
 def test_sdpa_lowering_counter_other_paths(monkeypatch):
@@ -485,6 +655,10 @@ def test_sdpa_lowering_counter_other_paths(monkeypatch):
     assert moved(lambda: A.sdpa_pallas(q, q, q, None, scale=0.25,
                                        is_test=True)) \
         == {"flash_blocked": 1.0}
+    q3 = A._merge_heads(q)      # the blocked kernels behind rank 3
+    assert moved(lambda: A.sdpa_pallas(q3, q3, q3, None, scale=0.25,
+                                       is_test=True, num_heads=2)) \
+        == {"flash_blocked": 1.0}
     s = q[:, :, :128]
     assert moved(lambda: A.sdpa_pallas(
         s, s, s, None, scale=0.25, dropout_rate=0.1,
@@ -494,6 +668,19 @@ def test_sdpa_lowering_counter_other_paths(monkeypatch):
     monkeypatch.setattr(FLAGS, "sp_attention", True)
     assert moved(lambda: A.scaled_dot_product_attention(
         s, s, s, None, scale=0.25, is_test=True)) == {"sp": 1.0}
+    # the sp schedules read heads leading: a rank-3 caller is split
+    # for them and their output merged back
+    s3 = A._merge_heads(s) + jnp.arange(32.0)
+    seen = []
+    monkeypatch.setattr(
+        ulysses, "sequence_parallel_attention",
+        lambda q_, k_, v_, **kw: seen.append(q_.shape) or q_)
+    out = {}
+    assert moved(lambda: out.update(o=A.scaled_dot_product_attention(
+        s3, s3, s3, None, scale=0.25, is_test=True, num_heads=2))) \
+        == {"sp": 1.0}
+    assert seen == [(1, 2, 128, 16)]
+    np.testing.assert_array_equal(np.asarray(out["o"]), np.asarray(s3))
 
 
 def test_sdpa_auto_flash_failure_propagates(monkeypatch):
@@ -510,32 +697,35 @@ def test_sdpa_auto_flash_failure_propagates(monkeypatch):
 
     monkeypatch.setattr(A, "interpret_mode", lambda: False)
     monkeypatch.setattr(A, "_flash_fwd_1k", refuse)
-    q = jnp.full((2, 4, 256, 64), 0.1, jnp.bfloat16)
+    q = jnp.full((2, 256, 256), 0.1, jnp.bfloat16)
     with pytest.raises(KernelRefused):
         A.scaled_dot_product_attention(
-            q, q, q, None, scale=0.125, dropout_rate=0.1,
+            q, q, q, None, scale=0.125, dropout_rate=0.1, num_heads=4,
             rng=jax.random.key(0))
 
 
+@pytest.mark.parametrize("rank", [4, 3])
 @pytest.mark.parametrize("axes", [{"dp": 4}, {"dp": 2, "tp": 2}])
-def test_sdpa_pallas_under_mesh_runs_per_shard(axes):
+def test_sdpa_pallas_under_mesh_runs_per_shard(axes, rank):
     """Under a multi-device mesh the kernel runs per shard (Mosaic
-    kernels are not auto-partitioned): batch over dp, heads over tp,
-    the pad bias following the batch — same values and gradients as
-    the unsharded call."""
+    kernels are not auto-partitioned): batch over dp, heads over tp
+    (axis 1 of rank 4, contiguous lanes of rank 3's last axis), the
+    pad bias following the batch — same values and gradients as the
+    unsharded call."""
     from paddle_tpu.ops.pallas import attention as A
     from paddle_tpu.parallel import mesh as mesh_lib
 
     r = np.random.RandomState(14)
-    B, H, S, Dh = 4, 4, 128, 16
-    q, k, v = (jnp.asarray(r.randn(B, H, S, Dh).astype(np.float32))
-               for _ in range(3))
+    B, H, S, Dh = 4, 4, 128, 64
+    q, k, v, _ = _qkv_bias(r, B, H, S, S, Dh, None,
+                           heads_last=rank == 3)
     bias = jnp.asarray(np.where(r.rand(B, 1, 1, S) > 0.2, 0.0, -1e9)
                        .astype(np.float32))
 
     def loss(q_, k_, v_):
         return jnp.sum(jnp.square(A.sdpa_pallas(
-            q_, k_, v_, bias, scale=0.25, causal=True, is_test=True)))
+            q_, k_, v_, bias, scale=0.125, causal=True, is_test=True,
+            num_heads=H if rank == 3 else 0)))
 
     want = jax.value_and_grad(loss, (0, 1, 2))(q, k, v)
     n = int(np.prod(list(axes.values())))

@@ -192,48 +192,55 @@ def test_attention_flagship_fits_vmem(dtype):
 
 
 def _1k_temp_bytes(call):
-    """In-kernel [G,blk_q,Sk] f32 temporary model for the
-    single-k-block attention kernels (ADVICE r4: streamed blocks alone
-    under-count them). q block = in_specs[1] (G, blk_q, Dh); k block =
-    (G, Sk, Dh).
-    Bytes/element anchored on the chip accepting the headline bf16
-    [8,256,256] backward — see attention._1K_TEMP_BYTES."""
+    """In-kernel score temporaries of the single-k-block attention
+    kernels (ADVICE r4: streamed blocks alone under-count them): ONE
+    head's [blk_q, Sk] at a time, the loop over the cell's heads being
+    unrolled. q block = in_specs[1] (1, blk_q, G*Dh); k block =
+    (1, Sk, G*Dh). Bytes/element: attention._1K_TEMP_BYTES (twice for
+    float32 operands), and the s + b float32 addend where there is a
+    bias."""
     from paddle_tpu.ops.pallas import attention as A
     blocks = [getattr(s, "block_shape", None) for s in call["in_specs"]]
     if len(blocks) < 3 or blocks[1] is None or len(blocks[1]) != 3:
         return 0
-    G, Sq, _ = blocks[1]
-    Sk = blocks[2][1]
-    return int(G) * int(Sq) * int(Sk) * A._1K_TEMP_BYTES
+    blk_q, Sk = int(blocks[1][1]), int(blocks[2][1])
+    has_bias = any(b is not None and len(b) == 4 for b in blocks)
+    wide = np.dtype(call["args"][1][1]).itemsize > 2
+    return blk_q * Sk * (A._1K_TEMP_BYTES * (2 if wide else 1)
+                         + (4 if has_bias else 0))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("rate", [0.0, 0.1])
-@pytest.mark.parametrize("with_bias", [False, True])
-@pytest.mark.parametrize("Sq,H", [(256, _H), (512, 12)])
-def test_attention_1k_corner_fits_vmem(dtype, rate, with_bias, Sq, H):
+@pytest.mark.parametrize("bias_kind", [None, "batch", "head"])
+@pytest.mark.parametrize("Sq,H", [(256, _H), (512, 12), (512, _H)])
+def test_attention_1k_corner_fits_vmem(dtype, rate, bias_kind, Sq, H):
     """The largest score tile of _1k_applicable, 256 x 512, as the
     largest single-k-block geometries FLAGS_sdpa_auto_flash dispatches
-    by default present it: Sq=256/Sk=512 (one q-block), and BERT-base's
-    Sq=Sk=512 at H=12 (two q-blocks, so the backward also holds its
-    dk/dv accumulators: scratch, charged by _footprint). Charges
-    streamed blocks AND the in-kernel score temporaries."""
+    by default present it, on the pair's own [B,S,H*Dh] layout:
+    Sq=256/Sk=512 (one q-block), and Sq=Sk=512 at BERT-base's H=12
+    and at H=8 (two q-blocks, so the backward also holds its dk/dv
+    accumulators: scratch, charged by _footprint). Charges the
+    lane-dense streamed blocks as the kernels declare them AND the
+    in-kernel score temporaries, whatever G the model chose."""
     from paddle_tpu.ops.pallas import attention as A
     Sk, Dh = 512, 64
     assert A._1k_applicable(Sq, Sk)
     rs = np.random.RandomState(0)
-    q = jnp.asarray(rs.rand(4, H, Sq, Dh).astype(dtype))
-    k = jnp.asarray(rs.rand(4, H, Sk, Dh).astype(dtype))
-    v = jnp.asarray(rs.rand(4, H, Sk, Dh).astype(dtype))
+    q = jnp.asarray(rs.rand(4, Sq, H * Dh).astype(dtype))
+    k = jnp.asarray(rs.rand(4, Sk, H * Dh).astype(dtype))
+    v = jnp.asarray(rs.rand(4, Sk, H * Dh).astype(dtype))
     var = ops.get("scaled_dot_product_attention").variants["pallas"]
     rng = jax.random.PRNGKey(0) if rate else None
-    bias = (jnp.asarray(rs.rand(4, H, Sq, Sk).astype("float32"))
-            if with_bias else None)
+    bias = None
+    if bias_kind:
+        bias = jnp.asarray(rs.rand(
+            4, H if bias_kind == "head" else 1, Sq, Sk).astype("float32"))
 
     def fwd_bwd():
         def loss(q_, k_, v_):
             return jnp.sum(var(q_, k_, v_, bias, dropout_rate=rate,
-                               causal=False, rng=rng))
+                               causal=False, num_heads=H, rng=rng))
         jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     orig = A.interpret_mode
@@ -245,6 +252,10 @@ def test_attention_1k_corner_fits_vmem(dtype, rate, with_bias, Sq, H):
     if Sq > A._1k_blk_q(Sq):
         assert len(calls[-1]["scratch_shapes"]) == 2   # dk, dv sums
     for n, call in enumerate(calls):
+        width = call["in_specs"][1].block_shape[2]
+        assert width == H * Dh or width % 128 == 0     # lane-dense
+        assert call["grid"] == (4, H * Dh // width,
+                                Sq // A._1k_blk_q(Sq))
         total = _footprint(call) + _1k_temp_bytes(call)
         assert total <= V5E_SCOPED_VMEM, (
             "1k[%s,rate=%s] call %d modeled VMEM %.1f MB exceeds the "
@@ -252,23 +263,24 @@ def test_attention_1k_corner_fits_vmem(dtype, rate, with_bias, Sq, H):
 
 
 def test_1k_headline_geometry_pinned():
-    """The round-4 chip-measured winner (bf16, Sq=Sk=256, dropout,
-    H=8) ran at G=8 fwd AND bwd. Any VMEM-model change that silently
-    shrinks this G regresses the measured +12% — fail loudly here
-    instead."""
+    """The two benchmark geometries' heads per cell, forward AND
+    backward (bf16, dropout, a bf16 bias per batch row): the
+    transformer's H=8 at Sq=Sk=256 takes the whole 512 lanes in one
+    cell (G=8, chip-measured since round 4), BERT-base's H=12 at
+    Sq=Sk=512 six heads (384 lanes). Any VMEM-model change that
+    silently moves either fails loudly here instead."""
     from paddle_tpu.ops.pallas import attention as A
-    assert A._1k_fwd_G(8, 2, 0.1, 256, 256, 64) == 8
-    assert A._1k_bwd_G(8, 2, 256, 256, 64) == 8
-    # the known f32 constraint: backward needs G=4 at the flagship
-    # shape
-    assert A._1k_bwd_G(8, 4, 256, 256, 64) == 4
-    # the ADVICE r4 corner: bf16 Sq=256/Sk=512 must NOT run at G=8
-    assert A._1k_bwd_G(8, 2, 256, 512, 64) <= 4
-    # BERT-base S=512 (H=12, pad bias, dropout): the same 256 x 512
-    # tile plus the dk/dv accumulators; forward and backward agree
-    g = A._1k_bwd_G(12, 2, 512, 512, 64, True)
-    assert g == A._1k_fwd_G(12, 2, 0.1, 512, 512, 64, True)
-    assert g <= A._1k_bwd_G(12, 2, 256, 512, 64, True)
+    assert A._1k_fwd_G(8, 2, 0.1, 256, 256, 64, 2) == 8
+    assert A._1k_bwd_G(8, 2, 256, 256, 64, 2) == 8
+    g = A._1k_bwd_G(12, 2, 512, 512, 64, 2)
+    assert g == A._1k_fwd_G(12, 2, 0.1, 512, 512, 64, 2) == 6
+    # without dropout the forward holds half the streams
+    assert A._1k_fwd_G(12, 2, 0.0, 512, 512, 64, 2) >= g
+    # a cell is lane-dense or the whole width, and divides the heads
+    for H, Dh in ((8, 64), (12, 64), (6, 16), (5, 64), (16, 128),
+                  (12, 32)):
+        g = A._1k_bwd_G(H, 4, 512, 512, Dh, 4, True)
+        assert H % g == 0 and (g == H or g * Dh % 128 == 0), (H, Dh, g)
 
 
 def test_layer_norm_flagship_fits_vmem():

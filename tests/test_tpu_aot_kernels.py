@@ -1,5 +1,7 @@
-"""The Mosaic kernels of the Trinity-Mini cell, COMPILED for a v5e
-that is described and not attached, at the cell's own shapes: what the
+"""The Mosaic kernels of the benchmark's cells (the flash 1k pair at
+transformer-base's and BERT-base's sites, the blocked flash kernels
+and the grouped products of the Trinity-Mini cell), COMPILED for a v5e
+that is described and not attached, at the cells' own shapes: what the
 chip's compiler would refuse (a tile that does not align, more fast
 memory than a kernel may use) is refused here, at no chip time.
 Nothing runs, so nothing is said about results or times: the values
@@ -50,6 +52,38 @@ def compiled(fn, one_chip, *shapes):
     # CPU, and Mosaic refuses that precision on bf16 operands
     with jax.default_matmul_precision("default"):
         return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("b,s,h,bias,causal", [
+    (128, 256, 8, (128, 1, 256, 256), False),   # tfm_base_*: encoder
+    (128, 256, 8, (128, 1, 256, 256), True),    # decoder self-attention
+    (56, 512, 12, (56, 1, 1, 512), False),      # bert_base_s512_scan
+])
+def test_flash_1k_pair_at_the_cells_sites(one_chip, for_the_chip, b, s,
+                                          h, bias, causal):
+    """q, k, v and the output's gradient as the projections hold them,
+    [b, s, h * 64] in bf16, the pad bias, dropout 0.1 from the TPU's
+    generator: the forward and the one backward kernel, the heads
+    picked out of the lanes inside (G = 8, the whole 512 lanes; G = 6
+    of BERT's twelve heads in two q-blocks)."""
+    bf = jnp.bfloat16
+    x = ((b, s, h * 64), bf)
+
+    def site(q_, k_, v_, g_, bias_):
+        seed = jnp.asarray([3.0, 0.0], jnp.float32)
+        out, pull = jax.vjp(
+            lambda a, b_, c: A._sdpa_flash(a, b_, c, bias_, seed,
+                                           64 ** -0.5, 0.1, causal, 0,
+                                           h), q_, k_, v_)
+        return out, pull(g_)
+
+    c = compiled(site, one_chip, x, x, x, x, (bias, bf))
+    text = c.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    # nothing of [b,h,s,64] around the calls: no head split or merge
+    # is compiled
+    assert "dimensions={0,2,1,3}" not in text
+    assert c.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 @pytest.mark.parametrize("window", [2048, 0])
