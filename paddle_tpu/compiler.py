@@ -319,17 +319,15 @@ class CompiledProgram:
     def run(self, exe, feed, fetch_list, scope, return_numpy,
             use_program_cache=True, validate_feed=True, donate=True,
             *, phases):
-        """Called by ``Executor.run``. ``phases``: its clock of this
-        entry-point call (executor._EntryPhases); what happens here is
-        the call's prepare."""
+        """Called by ``Executor.run``: hands itself over as the ``dist``
+        of the executor's one body (``Executor._run_impl``), which
+        prepares this strategy's state (``_prepare_run``) and traces
+        and dispatches under its mesh. ``phases``: the executor's
+        clock of this entry-point call (executor._EntryPhases).
+        ``use_program_cache`` is accepted for parity, as in
+        ``Executor.run``."""
         from .core.scope import global_scope
-        self._prepare_run(scope)
-        # ops that are mesh-aware (ring_attention, sp/ep lowerings)
-        # read the ambient mesh during tracing
-        with mesh_lib.mesh_guard(self._mesh):
-            return exe._run_impl(self.program, feed or {},
-                                 fetch_list or [],
-                                 scope or global_scope(), return_numpy,
-                                 phases, dist=self, donate=donate,
-                                 use_program_cache=use_program_cache,
-                                 validate_feed=validate_feed)
+        return exe._run_impl(self.program, feed or {}, fetch_list or [],
+                             scope or global_scope(), return_numpy,
+                             phases, dist=self, donate=donate,
+                             validate_feed=validate_feed)

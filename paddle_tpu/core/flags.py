@@ -70,7 +70,7 @@ class _Flags:
 FLAGS = _Flags()
 
 # Execution / debugging (reference: operator.cc FLAGS_check_nan_inf :950,
-# FLAGS_benchmark :946; executor eager deletion FLAGS_eager_delete_tensor_gb).
+# FLAGS_benchmark :946).
 FLAGS.define("check_nan_inf", False,
              "After each step, scan fetched outputs for NaN/Inf and raise.")
 FLAGS.define("benchmark", False,
@@ -87,8 +87,6 @@ FLAGS.define("deterministic", True,
 # XLA-managed so these only gate host staging buffers).
 FLAGS.define("host_pinned_pool_mb", 256,
              "Host staging pool for infeed, in MB.")
-FLAGS.define("eager_delete_tensor_gb", 0.0,
-             "Kept for API parity; XLA manages HBM lifetimes.")
 
 # Tracing / profiling.
 FLAGS.define("profile_dir", "", "If set, xprof traces are written here.")
@@ -107,8 +105,6 @@ FLAGS.define("communicator_max_merge_var_num", 20,
              "Max queued grads merged into one PS send.")
 FLAGS.define("communicator_send_queue_size", 20,
              "Trainer-side send queue depth.")
-FLAGS.define("communicator_independent_recv_thread", True,
-             "Kept for API parity (recv is pull-on-demand here).")
 
 FLAGS.define("sdpa_auto_flash", True,
              "scaled_dot_product_attention's base lowering routes to "
@@ -162,22 +158,7 @@ FLAGS.define("mxu_ln_grad", False,
              "ones@M MXU dots with f32 accumulation (the "
              "mxu_bias_grad treatment extended to the layer-norm "
              "affine tail — ops/nn_ops._ln_affine). Default OFF "
-             "until chip-measured in-model (tools/lever_ab.py).")
-
-FLAGS.define("multi_tensor_adam", False,
-             "Trace consecutive dense adam/adamw ops over SMALL "
-             "parameters as one concatenated multi-tensor update "
-             "(the reference's fuse_adam_op_pass analog; "
-             "framework/ir/fuse_optimizer_ops_pass). The update math "
-             "is identical element-for-element; results match the "
-             "per-op path to f32 ulp (XLA fusion grouping may "
-             "contract FMAs differently). DEFAULT OFF: chip-measured "
-             "2026-07-31 on transformer-base, the batch LOSES "
-             "in-model at every tried threshold (11.42 vs 11.69 "
-             "steps/s at 64k-numel; 1.8 at 1M) — XLA's per-param "
-             "fusions already schedule well and the concat/slice "
-             "copies only add traffic. Kept as the parity analog and "
-             "for param-heavy models with many tiny tensors.")
+             "until chip-measured in-model.")
 
 FLAGS.define("verify_rewrites", False,
              "Run the static program verifier (paddle_tpu/analysis) "

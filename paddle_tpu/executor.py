@@ -32,11 +32,12 @@ import hashlib
 import os
 import threading
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from . import compile_cache as _ccache
 from . import framework, ops
@@ -382,177 +383,12 @@ def _augment_vjp_error(e, fwd_type):
     return e
 
 
-# --------------------------------------------------------------------
-# Multi-tensor adam: the trace-time analog of the reference's
-# fuse_optimizer_ops_pass (framework/ir/fuse_optimizer_ops_pass/
-# fuse_adam_op_pass.cc) — N per-parameter adam updates become one
-# elementwise update over a concatenated vector. Only SMALL dense f32
-# parameters batch (for large tensors the per-op fusion is already
-# bandwidth-bound and the concat copies would add traffic); numerics
-# are bit-identical because the update is purely elementwise and each
-# parameter's lr_t scalar is computed exactly as the per-op lowering
-# does.
-
-_MULTI_ADAM_TYPES = ("adam", "adamw")
-# Biases/scales only: a 1<<20 threshold swept the 512x512 and
-# 512x2048 matrices into the concat and measured 1.8 steps/s vs 11.7
-# on transformer-base (chip, 2026-07-31) — the concat copies plus the
-# per-element lr repeat-gather on ~44M elements dwarf the saved
-# per-fusion overhead. At <=64k elements the batch is ~100 KB total
-# and the gather is noise.
-_MULTI_ADAM_MAX_NUMEL = 1 << 16
-
-
-def _adam_group_sig(op):
-    return (op.type, tuple(sorted(
-        (k, repr(v)) for k, v in op.attrs.items()
-        if k not in ("op_role", "op_namescope"))))
-
-
-def _adam_library_overridden(library):
-    """True when the active op-library mix would pick a non-base
-    lowering for adam/adamw — the batched path runs the inline base
-    update, so batching must stand aside or the requested variant
-    (e.g. the pallas fused adam) would be silently bypassed."""
-    if not library:
-        return False
-    for t in _MULTI_ADAM_TYPES:
-        if ops.get(t).pick(library) is not ops.get(t).fn:
-            return True
-    return False
-
-
-def _adam_batch_groups(block):
-    """Maximal runs of consecutive dense adam/adamw ops with identical
-    attrs: {start_index: [indices]} (len >= 2 only). Gated ops (anomaly
-    guard, gradient accumulation) batch together when they share the
-    same gate — _adam_group_sig includes the gate attr, and
-    _run_adam_group applies the select on its batched writes."""
-    groups = {}
-    ops_l = block.ops
-    i = 0
-    while i < len(ops_l):
-        op = ops_l[i]
-        if op.type in _MULTI_ADAM_TYPES:
-            sig = _adam_group_sig(op)
-            idxs = [i]
-            j = i + 1
-            while (j < len(ops_l)
-                   and ops_l[j].type == op.type
-                   and _adam_group_sig(ops_l[j]) == sig):
-                idxs.append(j)
-                j += 1
-            if len(idxs) > 1:
-                groups[i] = idxs
-            i = j
-        else:
-            i += 1
-    return groups
-
-
-def _run_adam_group(ops_group, env, step_key, library):
-    from .core.selected_rows import SparseRows
-
-    def _in(op, slot):
-        name = op.inputs[slot][0]
-        try:
-            return env[name]
-        except KeyError:
-            raise InvalidArgumentError(
-                "op %s (%r) needs variable %r which has no value — "
-                "persistable optimizer state missing; did you run "
-                "the startup program first?" % (op.type, op, name)) \
-                from None
-
-    small, rest = [], []
-    for idx, op in ops_group:
-        p = _in(op, "Param")
-        g = _in(op, "Grad")
-        if (not isinstance(g, SparseRows)
-                and p.size <= _MULTI_ADAM_MAX_NUMEL
-                and p.dtype == jnp.float32
-                and not isinstance(_in(op, "Moment1"), SparseRows)
-                and jnp.asarray(g).dtype == jnp.float32):
-            small.append((idx, op))
-        else:
-            rest.append((idx, op))
-    for idx, op in rest:
-        run_op(op, env, step_key, idx, library=library)
-    if len(small) < 2:
-        for idx, op in small:
-            run_op(op, env, step_key, idx, library=library)
-        return
-
-    op0 = small[0][1]
-    a = op0.attrs
-    # gated group (anomaly guard / grad accumulation — identical gate
-    # across the group by _adam_group_sig): batched writes select old
-    # vs new exactly like _gate_result does per-op
-    gate_name = a.get("gate")
-    gate = env[gate_name] if gate_name is not None else None
-
-    def _sel(new, old):
-        return new if gate is None else jnp.where(gate, new, old)
-    # defaults mirror the op lowerings' signatures
-    # (ops/optimizer_ops.py adam/adamw) so an op relying on an attr
-    # default gets the identical value on the batched path
-    b1 = float(a.get("beta1", 0.9))
-    b2 = float(a.get("beta2", 0.999))
-    eps = float(a.get("epsilon", 1e-8))
-    wd = float(a.get("weight_decay", 0.01)) if op0.type == "adamw" \
-        else 0.0
-
-    ps = [_in(op, "Param") for _, op in small]
-    gs = [_in(op, "Grad") for _, op in small]
-    m1s = [_in(op, "Moment1") for _, op in small]
-    m2s = [_in(op, "Moment2") for _, op in small]
-    b1ps = [_in(op, "Beta1Pow") for _, op in small]
-    b2ps = [_in(op, "Beta2Pow") for _, op in small]
-    lrs = [_in(op, "LearningRate") for _, op in small]
-
-    sizes = np.asarray([p.size for p in ps])
-    total = int(sizes.sum())
-    pc = jnp.concatenate([p.reshape(-1) for p in ps])
-    gc = jnp.concatenate([g.reshape(-1).astype(jnp.float32)
-                          for g in gs])
-    m1c = jnp.concatenate([m.reshape(-1) for m in m1s])
-    m2c = jnp.concatenate([m.reshape(-1) for m in m2s])
-    # per-parameter scalars, identical math to the per-op lowering
-    lr_t = jnp.stack([
-        jnp.reshape(lr * jnp.sqrt(1.0 - b2p) / (1.0 - b1p), ())
-        for lr, b1p, b2p in zip(lrs, b1ps, b2ps)])
-    lrv = jnp.repeat(lr_t, sizes, total_repeat_length=total)
-    m1n = b1 * m1c + (1.0 - b1) * gc
-    m2n = b2 * m2c + (1.0 - b2) * jnp.square(gc)
-    pn = pc - lrv * m1n / (jnp.sqrt(m2n) + eps)
-    if wd:
-        lr_raw = jnp.repeat(
-            jnp.stack([jnp.reshape(lr, ()) for lr in lrs]),
-            sizes, total_repeat_length=total)
-        pn = pn - lr_raw * wd * pc
-
-    off = 0
-    for (idx, op), p, m1, m2, b1p, b2p in zip(small, ps, m1s, m2s,
-                                              b1ps, b2ps):
-        size = int(p.size)
-        sl = slice(off, off + size)
-        env[op.outputs["ParamOut"][0]] = _sel(
-            pn[sl].reshape(p.shape), p)
-        env[op.outputs["Moment1Out"][0]] = _sel(
-            m1n[sl].reshape(p.shape), m1)
-        env[op.outputs["Moment2Out"][0]] = _sel(
-            m2n[sl].reshape(p.shape), m2)
-        env[op.outputs["Beta1PowOut"][0]] = _sel(b1p * b1, b1p)
-        env[op.outputs["Beta2PowOut"][0]] = _sel(b2p * b2, b2p)
-        off += size
-
-
 _PHASE_OF_ROLE = {"backward": "bwd", "optimize": "opt"}
 # the one door every scope of this module goes through
 _named_scope = jax.named_scope
 
 
-def op_scope(op, op_type=None):
+def op_scope(op):
     """``jax.named_scope("<phase>/<layer>/<op type>")`` for one op's
     lowering: the phase from ``op_role`` (no role is forward; the loss
     counts as forward, an LR schedule and AMP's scaling update carry
@@ -567,7 +403,7 @@ def op_scope(op, op_type=None):
     return _named_scope("%s/%s/%s" % (
         _PHASE_OF_ROLE.get(a.get("op_role"), "fwd"),
         framework.innermost_scope(a.get("op_namescope")),
-        op_type or a.get("fwd_type") or op.type))
+        a.get("fwd_type") or op.type))
 
 
 def run_block(block, env, step_key, library=None, grad_sync=None,
@@ -602,9 +438,6 @@ def run_block(block, env, step_key, library=None, grad_sync=None,
     unchanged."""
     vjp_fwd_indices = {op.attrs.get("fwd_op_index")
                        for op in block.ops if op.type in ("vjp", "vjp2")}
-    adam_groups = _adam_batch_groups(block) \
-        if (FLAGS.multi_tensor_adam
-            and not _adam_library_overridden(library)) else {}
     skip = set()
     if pipeline is not None:
         skip.update(pipeline.skip)
@@ -647,16 +480,6 @@ def run_block(block, env, step_key, library=None, grad_sync=None,
             with _named_scope("fwd/pipeline/schedule"):
                 pipeline.execute(env, step_key, library=library)
         if i in skip:
-            continue
-        if i in adam_groups:
-            # variable misses raise a proper InvalidArgumentError from
-            # _run_adam_group._in (a blanket KeyError catch here would
-            # misattribute attr/slot lookups as missing variables)
-            idxs = adam_groups[i]
-            with op_scope(op, "adam"):
-                _run_adam_group([(j, block.ops[j]) for j in idxs],
-                                env, step_key, library)
-            skip.update(idxs[1:])
             continue
         if op.type not in ("vjp", "vjp2") and not ops.has(op.type):
             raise UnimplementedError(
@@ -797,6 +620,119 @@ def _mesh_tag(mesh_fp) -> Optional[str]:
     if mesh_fp is None:
         return None
     return hashlib.sha1(repr(mesh_fp).encode()).hexdigest()[:12]
+
+
+class _Entry(NamedTuple):
+    """What one entry point dispatches. ``Executor._run_impl`` is the
+    ONE control flow from a call to its executable and back; ``run``,
+    ``run_repeated`` and ``run_pipelined`` differ by this record."""
+    name: str       # the provenance ledger's entry; heads the cache key
+    span: str       # the dispatch's span
+    steps: int      # steps one dispatch takes: the run counter's advance
+    wrap: Callable  # the traced step -> the function jit compiles
+    # the feed is [steps, *batch]: checked and sharded per slice and
+    # donated with the carry; the step counters ride beside the key;
+    # the executable returns (fetches, stacked, persist)
+    chunk: bool = False
+
+
+_RUN = _Entry("run", "executor_run", 1, lambda step: step)
+
+
+def _var_names(fetch_list):
+    return [f.name if isinstance(f, framework.Variable) else f
+            for f in fetch_list]
+
+
+def _feed_to_device(feed, dist, chunk):
+    """The feed as the executable takes it: device arrays, laid out on
+    the strategy's mesh where there is one. A chunk's per-step slices
+    are batch-sharded exactly as ``run()`` would shard them, with the
+    chunk axis replicated in front: dp shards the batch dim (now dim
+    1), sp the sequence dim."""
+    if dist is None:
+        return {k: v if isinstance(v, jax.Array) else jnp.asarray(v)
+                for k, v in feed.items()}
+    out = {}
+    for k, v in feed.items():
+        shape = tuple(np.shape(v))
+        if chunk:
+            per_step = dist.feed_sharding(shape[1:], k)
+            sharding = NamedSharding(
+                dist._mesh, PartitionSpec(None, *per_step.spec))
+        else:
+            sharding = dist.feed_sharding(shape, k)
+        out[k] = jax.device_put(v, sharding)
+    return out
+
+
+def _step_keys(entry, base_key, counter):
+    """The PRNG argument(s) of one dispatch taken at run counter
+    ``counter`` — the ONE place that folds. Step ``i`` of the
+    dispatch draws from:
+
+      run            fold_in(base, counter)
+      run_pipelined  fold_in(base, counter + i): the scan folds
+                     the step's own counter in, so a chunk draws
+                     what K sequential run() calls would
+      run_repeated   fold_in(fold_in(base, counter), i): the scan
+                     folds i into the key folded here
+
+    Lowering asks with counter 0: only the avals matter there."""
+    if entry.chunk:
+        return (jnp.asarray(np.arange(counter, counter + entry.steps,
+                                      dtype=np.int32)), base_key)
+    return (jax.random.fold_in(base_key, counter),)
+
+
+@contextlib.contextmanager
+def _chunk_donation_filter(chunk_vals, persist_in):
+    """Around the lower+compile of a chunk scan, which donates the
+    feed chunk with the carry (the chunk's device buffers are dead
+    once its scan consumed them). The chunk rarely aliases an output
+    (fetches are scalars), so XLA warns its donation "was not usable"
+    at compile time — expected, and it would noise up every data-fed
+    run. The PERSIST CARRY shares the donate list though, and a carry
+    that stops aliasing (param buffers silently duplicated each chunk)
+    must stay loud: suppress only when every buffer the warning names
+    is a chunk aval AND no persistable shares that aval (ambiguity
+    stays loud). catch_warnings mutates process-global state, so the
+    window is confined to the one-off lower+compile — steady-state
+    dispatches touch no warning machinery."""
+    import re
+    import warnings
+
+    def avals(vals):
+        # XLA names donated buffers by their PER-SHARD aval on a mesh
+        # (global aval on one device) — match both
+        out = set()
+        for v in vals:
+            if not (hasattr(v, "shape") and hasattr(v, "dtype")):
+                continue
+            out.add(_aval_str(v))
+            sharding = getattr(v, "sharding", None)
+            if sharding is not None:
+                try:
+                    out.add(_fmt_aval(v.dtype,
+                                      sharding.shard_shape(v.shape)))
+                except Exception:
+                    pass
+        return out
+
+    chunk_avals = avals(chunk_vals.values())
+    persist_avals = avals(persist_in.values())
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        yield
+    for w in caught:
+        msg = str(w.message)
+        if "donated buffers were not usable" in msg:
+            named = set(re.findall(r"ShapedArray\(([^)]+)\)", msg))
+            if named and named <= chunk_avals \
+                    and not named & persist_avals:
+                continue  # feed-chunk-only: expected
+        warnings.warn_explicit(w.message, w.category, w.filename,
+                               w.lineno)
 
 
 class _EntryPhases:
@@ -980,11 +916,16 @@ class Executor:
         the call — required for CONCURRENT runs sharing one scope
         (inference clones): donation invalidates the param buffers a
         sibling thread may still be reading. Training keeps the default
-        (in-place HBM updates)."""
+        (in-place HBM updates). ``use_program_cache`` is accepted for
+        parity with the reference's signature and selects nothing:
+        every executable is built once per (program, feed shapes) and
+        kept."""
         program = program or framework.default_main_program()
         with _EntryPhases(self) as phases:
             if getattr(program, "_is_compiled", False):
-                # CompiledProgram (compiler.py) — distributed execution.
+                # a CompiledProgram (compiler.py) hands itself back to
+                # _run_impl as ``dist``; the PS trainer's wrapper runs
+                # its own step
                 return program.run(self, feed, fetch_list, scope,
                                    return_numpy,
                                    use_program_cache=use_program_cache,
@@ -992,10 +933,8 @@ class Executor:
                                    donate=donate, phases=phases)
             return self._run_impl(program, feed or {}, fetch_list or [],
                                   scope or global_scope(), return_numpy,
-                                  donate=donate,
-                                  use_program_cache=use_program_cache,
-                                  validate_feed=validate_feed,
-                                  phases=phases)
+                                  phases, donate=donate,
+                                  validate_feed=validate_feed)
 
     @property
     def compile_count(self):
@@ -1162,6 +1101,24 @@ class Executor:
                   if build_phases else None,
                   mode=mode, nth=nth)
 
+    @contextlib.contextmanager
+    def _building(self, ekey):
+        """The window of one executable's build: under its per-key gate
+        (concurrent first-compiles of clones sharing this Executor
+        serialize, and the loser finds the executable), and counted
+        into dispatch_inflight() for the whole of it, the time parked
+        on a sibling's gate included: a wedged compile must still trip
+        the hang watch."""
+        with self._lock:
+            gate = self._exe_gates.setdefault(ekey, threading.Lock())
+            self._builds_inflight += 1
+        try:
+            with gate:
+                yield
+        finally:
+            with self._lock:
+                self._builds_inflight -= 1
+
     def _executable_for(self, cache_key, shape_sig, entry, program,
                         make_fn, lower_args, mesh_fp=None,
                         compile_ctx=None):
@@ -1186,25 +1143,7 @@ class Executor:
         fn = self._executables.get(ekey)
         if fn is not None:
             return fn
-        with self._lock:
-            gate = self._exe_gates.setdefault(ekey, threading.Lock())
-            # visible to dispatch_inflight() for the whole build
-            # (including time parked on a sibling's gate): a wedged
-            # compile must still trip the hang watch
-            self._builds_inflight += 1
-        try:
-            return self._build_executable(ekey, gate, cache_key,
-                                          shape_sig, entry, program,
-                                          make_fn, lower_args, mesh_fp,
-                                          compile_ctx)
-        finally:
-            with self._lock:
-                self._builds_inflight -= 1
-
-    def _build_executable(self, ekey, gate, cache_key, shape_sig,
-                          entry, program, make_fn, lower_args, mesh_fp,
-                          compile_ctx):
-        with gate:
+        with self._building(ekey):
             fn = self._executables.get(ekey)
             if fn is not None:
                 return fn
@@ -1435,137 +1374,25 @@ class Executor:
         of device work with the per-step host dispatch cost paid once.
         The reference times a host loop (fluid_benchmark.py:296).
 
+        The scan carries a FIXED structure: exactly the persistables
+        the scope holds when tracing starts (vars a step newly creates
+        cannot join the carry — run the startup program / one warmup
+        ``run()`` first). Interpreted programs, and for now a
+        CompiledProgram, run as a loop of ``run`` calls instead.
+
         PRNG: step ``i`` uses ``fold_in(base_key, i)`` so dropout
         masks differ per step like sequential ``run`` calls.
         """
-        with _EntryPhases(self) as phases:
-            return self._run_repeated(
-                phases, program or framework.default_main_program(),
-                feed or {}, fetch_list or [], iters,
-                scope or global_scope(), return_numpy, library)
-
-    def _run_repeated(self, phases, program, feed, fetch_list, iters,
-                      scope, return_numpy, library):
         enforce(iters >= 1, "run_repeated needs iters >= 1, got %s"
                 % iters)
-        if getattr(program, "_is_compiled", False) \
-                or _needs_eager(program):
-            phases.cancel()     # each run() below books its own entry
-            # dist/interpreted programs: plain loop (correct; per-step
-            # dispatch cost applies). Honor an explicit library by
-            # scoping the flag, since run() has no such parameter.
-            # The SAME feed dict repeats every iteration, so validation
-            # and feed->jnp conversion are hoisted out of the loop:
-            # validate once here, convert once, then every run() call
-            # sees ready device arrays and skips re-validation.
-            if not getattr(program, "_is_compiled", False):
-                _check_feed_shape_type(program.global_block(), feed)
-                feed = {k: jnp.asarray(v)
-                        if not isinstance(v, jax.Array) else v
-                        for k, v in feed.items()}
-            prev = FLAGS.op_library
-            if library is not None:
-                FLAGS.op_library = library
-            try:
-                out = None
-                for i in range(iters):
-                    # compiled programs validate on the first pass only
-                    # (their feed check also derives shardings, which
-                    # must still happen once)
-                    out = self.run(program, feed=feed,
-                                   fetch_list=fetch_list, scope=scope,
-                                   return_numpy=return_numpy,
-                                   validate_feed=i == 0 and
-                                   getattr(program, "_is_compiled",
-                                           False))
-            finally:
-                FLAGS.op_library = prev
-            return out
-
-        block = program.global_block()
-        if library is None and FLAGS.op_library:
-            library = FLAGS.op_library
-        fetch_names = [f.name if isinstance(f, framework.Variable)
-                       else f for f in fetch_list]
-        persist_in = {}
-        for name, var in block.vars.items():
-            if var.persistable and scope.has_var(name) \
-                    and scope.find_var(name) is not None:
-                persist_in[name] = scope.find_var(name)
-        _check_feed_shape_type(block, feed)
-        # program._uid, NOT id(program): ids are reused after GC, and a
-        # recycled id with a matching version would return a stale
-        # compiled scan belonging to a dead program
-        cache_key = ("repeat", iters, program._uid, program._version,
-                     tuple(sorted(feed)), tuple(fetch_names),
-                     tuple(sorted(persist_in)), library)
-        # convert the feed BEFORE compile accounting so the shape
-        # signature reflects the dtypes XLA actually sees (asarray
-        # canonicalizes int64 -> int32 etc.)
-        with _profiler.RecordEvent("feed_h2d"):
-            feed_vals = {k: jnp.asarray(v)
-                         if not isinstance(v, jax.Array) else v
-                         for k, v in feed.items()}
-        shape_sig = tuple((k, tuple(feed_vals[k].shape),
-                           _dtype_tag(feed_vals[k]))
-                          for k in sorted(feed_vals))
-        self._book_fresh_sig(cache_key, shape_sig)
-
-        def make_fn():
-            self._check_sharded_layout(block)
-            # scan carries a FIXED structure: exactly the persistables
-            # present when tracing started (vars a step newly creates
-            # cannot join the carry — run the startup program / one
-            # warmup run() first). Step assembly and the O(1)-memory
-            # fetches-in-carry scan both live in the engine.
-            from .engine import build_repeat_fn, build_step
-            step = build_step(program, block, fetch_names,
-                              library=library,
-                              guard_plan=self._guard_plan(program,
-                                                          block),
-                              carried=frozenset(persist_in))
-            return jax.jit(build_repeat_fn(step, iters),
-                           donate_argnums=(0,))
-
-        base_key0 = self._base_key(program)
-
-        def obtain():
-            # the fold_in value is irrelevant to lowering (only the
-            # key's aval matters); the dispatch below folds the real
-            # run counter in. Thunked: only a build miss pays it.
-            return self._executable_for(
-                cache_key, shape_sig, "run_repeated", program, make_fn,
-                lambda: (persist_in, feed_vals,
-                         jax.random.fold_in(base_key0, 0)))
-
-        exe_fn = obtain()
-        with self._lock:
-            counter = self._run_counter
-            self._run_counter += iters
-            self._dispatch_count += 1
-        self._m_dispatch.inc()
-        self._m_steps.inc(iters)
-        # the failed-settlement guard covers EVERYTHING after the
-        # count increment, or an exception in between leaves
-        # dispatch_inflight() stuck True forever
-        try:
-            base_key = jax.random.fold_in(base_key0, counter)
-            t0 = phases.dispatch()
-            with _profiler.RecordEvent("executor_run_repeated"):
-                fetches, persist_out = self._call_executable(
-                    exe_fn, (cache_key, shape_sig),
-                    (persist_in, feed_vals, base_key), obtain)
-        except BaseException:
-            self._note_dispatch_failed()
-            raise
-        t1 = time.perf_counter()
-        self._note_dispatch(t1 - t0)
-        phases.settle(t1)
-        for name, val in persist_out.items():
-            scope.set_var(name, val)
-        if return_numpy:
-            fetches = [np.asarray(f) for f in fetches]
-        return fetches
+        from .engine import build_repeat_fn
+        with _EntryPhases(self) as phases:
+            return self._run_impl(
+                program or framework.default_main_program(), feed or {},
+                fetch_list or [], scope or global_scope(), return_numpy,
+                phases, library=library, entry=_Entry(
+                    "run_repeated", "executor_run_repeated", iters,
+                    lambda step: build_repeat_fn(step, iters)))
 
     def run_pipelined(self, program=None, feed_chunk=None,
                       fetch_list=None, scope=None, return_numpy=True,
@@ -1580,10 +1407,9 @@ class Executor:
         ``program`` may be a CompiledProgram: the gradient-sync plan
         (exact/rs_ag/q8 and the sharded-update bracket) then splices
         INSIDE the scanned step — guard × collective × bracket × K-step
-        chunk compose into one dispatch on the strategy's mesh, the
-        composition the per-step fallback used to pay K host
-        round-trips for. Only interpreted (eager) programs still
-        unstack to the per-step loop.
+        chunk compose into one dispatch on the strategy's mesh. Only
+        interpreted (eager) programs unstack to a loop of ``run``
+        calls.
 
         ``stack_fetch_list`` names fetches whose PER-STEP values are
         additionally returned stacked ``[K, ...]`` (they ride the scan
@@ -1612,14 +1438,6 @@ class Executor:
         pre-transfers the next chunk on a background thread while this
         chunk runs — ``train_from_dataset`` wires the two together.
         """
-        with _EntryPhases(self) as phases:
-            return self._run_pipelined(
-                phases, program or framework.default_main_program(),
-                feed_chunk, fetch_list or [], scope or global_scope(),
-                return_numpy, library, stack_fetch_list)
-
-    def _run_pipelined(self, phases, program, feed_chunk, fetch_list,
-                       scope, return_numpy, library, stack_fetch_list):
         enforce(feed_chunk, "run_pipelined needs a non-empty "
                 "feed_chunk (dict name -> [K, ...] array); for "
                 "feed-less programs use run_repeated")
@@ -1633,252 +1451,19 @@ class Executor:
                     "expected %s", name, shape[0], iters)
             iters = shape[0]
         enforce(iters >= 1, "feed_chunk must hold >= 1 batches")
-
-        want_stacked = stack_fetch_list is not None
-        stack_names = [f.name if isinstance(f, framework.Variable)
-                       else f for f in (stack_fetch_list or [])]
-        dist = program if getattr(program, "_is_compiled", False) \
-            else None
-        base = dist.program if dist is not None else program
-
-        if _needs_eager(base):
-            # interpreted programs can't scan the block: unstack the
-            # chunk and drive per-step run() (correct; per-step
-            # dispatch cost applies — same contract as run_repeated's
-            # fallback, including the hoisted one-time validation).
-            phases.cancel()     # each run() below books its own entry
-            prev = FLAGS.op_library
-            if library is not None:
-                FLAGS.op_library = library
-            try:
-                out = None
-                rows = [[] for _ in stack_names]
-                for i in range(iters):
-                    feed_i = {k: v[i] for k, v in feed_chunk.items()}
-                    vals = self.run(
-                        program, feed=feed_i,
-                        fetch_list=list(fetch_list) + stack_names,
-                        scope=scope, return_numpy=return_numpy,
-                        validate_feed=i == 0)
-                    out = vals[:len(fetch_list)]
-                    for r, v in zip(rows, vals[len(fetch_list):]):
-                        r.append(np.asarray(v))
-            finally:
-                FLAGS.op_library = prev
-            if want_stacked:
-                return out, [np.stack(r) for r in rows]
-            return out
-
-        block = base.global_block()
-        if library is None and FLAGS.op_library:
-            library = FLAGS.op_library
-        fetch_names = [f.name if isinstance(f, framework.Variable)
-                       else f for f in fetch_list]
-        all_fetch_names = fetch_names + stack_names
-        if dist is not None:
-            # fuse pass + sharded/residual state conversion + verify
-            # memo must run BEFORE the persistable snapshot below —
-            # ensure_sharded_state rewrites block shapes AND scope
-            # values (same ordering contract as CompiledProgram.run)
-            dist._prepare_run(scope)
-        persist_in = {}
-        for name, var in block.vars.items():
-            if var.persistable and scope.has_var(name) \
-                    and scope.find_var(name) is not None:
-                persist_in[name] = scope.find_var(name)
-        if dist is not None:
-            # lay the carry out on the mesh per the strategy (see
-            # _run_impl — a sharded device_put, no-op when already
-            # correctly placed)
-            for name, val in persist_in.items():
-                want = dist.persist_sharding(block.vars[name])
-                if getattr(val, "sharding", None) != want:
-                    persist_in[name] = jax.device_put(val, want)
-        # validate the PER-STEP slice (shape/dtype only — no device
-        # readback: ShapeDtypeStructs stand in for the sliced values)
-        _check_feed_shape_type(block, {
-            k: jax.ShapeDtypeStruct(tuple(v.shape[1:]), v.dtype)
-            for k, v in feed_chunk.items()})
-        feed_names = tuple(sorted(feed_chunk))
-        mesh_fp = dist._fingerprint() if dist is not None else None
-        # stack_names key the cache SEPARATELY from all_fetch_names:
-        # the user/stacked split is baked into the compiled scan (which
-        # fetch positions ride the ys), so two calls with the same
-        # union but a different split must not share an executable
-        pplan = getattr(dist._build_strategy, "pipeline", None) \
-            if dist is not None \
-            else getattr(base, "_pipeline_plan", None)
-        cache_key = ("pipelined", base._uid, base._version,
-                     feed_names, tuple(all_fetch_names),
-                     tuple(stack_names), tuple(sorted(persist_in)),
-                     library, mesh_fp,
-                     pplan.signature() if pplan is not None else None)
-        with _profiler.RecordEvent("feed_h2d"):
-            if dist is not None:
-                # batch-shard each per-step slice exactly as run()
-                # would, with the chunk axis replicated in front: dp
-                # shards the batch dim (now dim 1), sp the sequence dim
-                from jax.sharding import NamedSharding, PartitionSpec
-                chunk_vals = {}
-                for k, v in feed_chunk.items():
-                    per_step = dist.feed_sharding(
-                        tuple(np.shape(v))[1:], k)
-                    chunk_vals[k] = jax.device_put(
-                        v, NamedSharding(
-                            dist._mesh,
-                            PartitionSpec(None, *per_step.spec)))
-            else:
-                chunk_vals = {k: jnp.asarray(v)
-                              if not isinstance(v, jax.Array) else v
-                              for k, v in feed_chunk.items()}
-        # per-shape compile accounting, on the CONVERTED chunk — the
-        # dtypes XLA actually sees (asarray canonicalizes int64
-        # labels to int32, so the raw feed dtype would book phantom
-        # compiles). K is part of the shape: the ragged tail chunk
-        # legitimately counts as one extra compile.
-        shape_sig = tuple((k, tuple(chunk_vals[k].shape),
-                           _dtype_tag(chunk_vals[k]))
-                          for k in feed_names)
-        self._book_fresh_sig(cache_key, shape_sig)
-
-        def make_fn():
-            # trace-time only (see _run_impl): the grad-sync plan, the
-            # guard splice, and the chunk scan all assemble in the ONE
-            # step factory (engine/step_engine.py) — collective ×
-            # bracket × guard × K-step chunk compose inside the scan
-            sync_plan = dist.grad_sync_plan(block) if dist is not None \
-                else None
-            self._check_sharded_layout(block, sync_plan)
-            guard_plan = self._guard_plan(base, block)
-            from .engine import build_chunk_fn, build_step
-            step = build_step(base, block, all_fetch_names,
-                              library=library, sync_plan=sync_plan,
-                              guard_plan=guard_plan,
-                              carried=frozenset(persist_in),
-                              warn_dropped=True,
-                              pipeline_plan=pplan,
-                              mesh=dist._mesh if dist is not None
-                              else None)
-            pipelined = build_chunk_fn(
-                step, range(len(fetch_names), len(all_fetch_names)),
-                pipeline_plan=pplan)
-            # donate the carry AND the feed chunk: the chunk's device
-            # buffers are dead once its scan consumed them
-            jit_kwargs = {"donate_argnums": (0, 1)}
-            if dist is not None:
-                # pin persistable outputs to their input shardings so
-                # parameters keep a stable layout across chunks
-                # (donation then reuses the buffers in place)
-                jit_kwargs["out_shardings"] = (None, None, {
-                    n: dist.persist_sharding(block.vars[n])
-                    for n in persist_in})
-            return jax.jit(pipelined, **jit_kwargs)
-
-        @contextlib.contextmanager
-        def donation_warning_filter():
-            # The feed chunk rarely aliases an output (fetches are
-            # scalars), so XLA warns its donation "was not usable" at
-            # compile time — expected, and it would noise up every
-            # data-fed run. The PERSIST CARRY shares the donate list
-            # though, and a carry that stops aliasing (param buffers
-            # silently duplicated each chunk) must stay loud: suppress
-            # only when every buffer the warning names is a chunk aval
-            # AND no persistable shares that aval (ambiguity stays
-            # loud). catch_warnings mutates process-global state, so
-            # the window is confined to the one-off lower+compile —
-            # steady-state dispatches touch no warning machinery.
-            import re
-            import warnings
-
-            def avals(vals):
-                # XLA names donated buffers by their PER-SHARD aval on
-                # a mesh (global aval on one device) — match both
-                out = set()
-                for v in vals:
-                    if not (hasattr(v, "shape") and hasattr(v, "dtype")):
-                        continue
-                    out.add(_aval_str(v))
-                    sharding = getattr(v, "sharding", None)
-                    if sharding is not None:
-                        try:
-                            out.add(_fmt_aval(
-                                v.dtype,
-                                sharding.shard_shape(v.shape)))
-                        except Exception:
-                            pass
-                return out
-
-            chunk_avals = avals(chunk_vals.values())
-            persist_avals = avals(persist_in.values())
-            with warnings.catch_warnings(record=True) as caught:
-                warnings.simplefilter("always")
-                yield
-            for w in caught:
-                msg = str(w.message)
-                if "donated buffers were not usable" in msg:
-                    named = set(re.findall(
-                        r"ShapedArray\(([^)]+)\)", msg))
-                    if named and named <= chunk_avals \
-                            and not named & persist_avals:
-                        continue  # feed-chunk-only: expected
-                warnings.warn_explicit(w.message, w.category,
-                                       w.filename, w.lineno)
-
-        base_key0 = self._base_key(base)
-
-        def obtain():
-            return self._executable_for(
-                cache_key, shape_sig, "run_pipelined", base,
-                make_fn,
-                lambda: (persist_in, chunk_vals,
-                         jnp.asarray(np.arange(iters, dtype=np.int32)),
-                         base_key0),
-                mesh_fp=mesh_fp,
-                compile_ctx=donation_warning_filter)
-
-        if dist is not None:
-            # mesh-aware ops (ring_attention, sp/ep lowerings) read the
-            # ambient mesh during tracing
-            from .parallel import mesh as mesh_lib
-            mesh_ctx = mesh_lib.mesh_guard(dist._mesh)
-        else:
-            mesh_ctx = contextlib.nullcontext()
-        with mesh_ctx:
-            exe_fn = obtain()
-            with self._lock:
-                counter = self._run_counter
-                self._run_counter += iters
-                self._dispatch_count += 1
-            self._m_dispatch.inc()
-            self._m_steps.inc(iters)
-            # the failed-settlement guard covers everything between the
-            # count increment and the dispatch settling (see
-            # _note_dispatch_failed)
-            try:
-                idxs = jnp.asarray(np.arange(counter, counter + iters,
-                                             dtype=np.int32))
-                t_dispatch = phases.dispatch()
-                with _profiler.RecordEvent("scan_dispatch",
-                                           args={"steps": int(iters)}):
-                    fetches, stacked, persist_out = \
-                        self._call_executable(
-                            exe_fn, (cache_key, shape_sig),
-                            (persist_in, chunk_vals, idxs, base_key0),
-                            obtain)
-            except BaseException:
-                self._note_dispatch_failed()
-                raise
-        t1 = time.perf_counter()
-        self._note_dispatch(t1 - t_dispatch)
-        phases.settle(t1)
-        for name, val in persist_out.items():
-            scope.set_var(name, val)
-        fetches = fetches[:len(fetch_names)]
-        if return_numpy:
-            fetches = [np.asarray(f) for f in fetches]
-        if want_stacked:
-            return fetches, [np.asarray(s) for s in stacked]
-        return fetches
+        from .engine import build_chunk_fn
+        n_user = len(fetch_list or [])
+        # the stacked fetches follow the user's in the step's fetches
+        stacked_idx = range(n_user, n_user + len(stack_fetch_list or []))
+        with _EntryPhases(self) as phases:
+            return self._run_impl(
+                program or framework.default_main_program(), feed_chunk,
+                fetch_list or [], scope or global_scope(), return_numpy,
+                phases, library=library,
+                stack_fetch_list=stack_fetch_list, entry=_Entry(
+                    "run_pipelined", "scan_dispatch", iters,
+                    lambda step: build_chunk_fn(step, stacked_idx),
+                    chunk=True))
 
     def train_from_dataset(self, program=None, dataset=None, scope=None,
                            thread=0, debug=False, fetch_list=None,
@@ -2035,22 +1620,83 @@ class Executor:
             program.random_seed = seed  # stable within this program's life
         return jax.random.key(seed)
 
+    def _run_per_step(self, program, entry, feed, fetch_list,
+                      stack_names, scope, return_numpy, library):
+        """What a scan entry does with a program it cannot scan: a loop
+        of ``run()`` calls, each booking its own entry (correct; the
+        per-step dispatch cost applies). ``run()`` has no ``library``
+        parameter, so an explicit one is honoured by scoping the flag.
+        The feeds are homogeneous, so only the first is validated."""
+        if entry.chunk:
+            feeds = ({k: v[i] for k, v in feed.items()}
+                     for i in range(entry.steps))
+        else:
+            # the SAME feed every step: convert it once (a compiled
+            # program places it on its mesh itself)
+            if not getattr(program, "_is_compiled", False):
+                feed = _feed_to_device(feed, None, False)
+            feeds = [feed] * entry.steps
+        prev = FLAGS.op_library
+        if library is not None:
+            FLAGS.op_library = library
+        try:
+            out = None
+            rows = [[] for _ in stack_names]
+            for i, feed_i in enumerate(feeds):
+                vals = self.run(program, feed=feed_i,
+                                fetch_list=list(fetch_list) + stack_names,
+                                scope=scope, return_numpy=return_numpy,
+                                validate_feed=i == 0)
+                out = vals[:len(fetch_list)]
+                for r, v in zip(rows, vals[len(fetch_list):]):
+                    r.append(np.asarray(v))
+        finally:
+            FLAGS.op_library = prev
+        return out, [np.stack(r) for r in rows]
+
     def _run_impl(self, program, feed, fetch_list, scope, return_numpy,
-                  phases, dist=None, donate=True, library=None,
-                  use_program_cache=True, validate_feed=True):
-        fetch_names = [f.name if isinstance(f, framework.Variable) else f
-                       for f in fetch_list]
-        block = program.global_block()
+                  phases, entry=_RUN, dist=None, donate=True,
+                  library=None, validate_feed=True,
+                  stack_fetch_list=None):
+        """The body of every entry point: prepare (state, feed, key,
+        executable), dispatch, settle (write-back, fetches). ``entry``
+        says what is dispatched; ``dist`` is the CompiledProgram whose
+        strategy lays ``program`` out on a mesh (the scans hand one
+        over as ``program``); ``feed`` is the whole feed or, for a
+        chunk entry, the chunk. Returns the fetches, and ``(fetches,
+        stacked)`` when ``stack_fetch_list`` is given."""
+        if dist is None and getattr(program, "_is_compiled", False):
+            dist, program = program, program.program
         if library is None and FLAGS.op_library:
             library = FLAGS.op_library
+        fetch_names = _var_names(fetch_list)
+        stack_names = _var_names(stack_fetch_list or [])
+        all_fetch_names = fetch_names + stack_names
+        if entry is not _RUN and (
+                _needs_eager(program)
+                # run_repeated over a mesh: the scan below would take
+                # it as it stands; it moves tfm_base_dp4-like traffic,
+                # so it waits for a perf_opt of its own (ROADMAP S-new)
+                or (dist is not None and not entry.chunk)):
+            phases.cancel()     # each run() of the loop books its own
+            out, stacked = self._run_per_step(
+                dist if dist is not None else program, entry, feed,
+                fetch_list, stack_names, scope, return_numpy, library)
+            return out if stack_fetch_list is None else (out, stacked)
 
-        # persistable vars the program touches and the scope already holds
+        block = program.global_block()
+        if dist is not None:
+            # fuse pass, sharded/residual state conversion and the
+            # verify memo run BEFORE the persistable snapshot below:
+            # ensure_sharded_state rewrites block shapes AND scope
+            # values
+            dist._prepare_run(scope)
+        # persistable vars the program touches and the scope holds
         persist_in = {}
         for name, var in block.vars.items():
             if var.persistable and scope.has_var(name) \
                     and scope.find_var(name) is not None:
                 persist_in[name] = scope.find_var(name)
-
         if dist is not None:
             # Lay persistable vars out on the mesh per the strategy
             # (the analog of ParallelExecutor's BCastParamsToDevices,
@@ -2060,136 +1706,137 @@ class Executor:
                 want = dist.persist_sharding(block.vars[name])
                 if getattr(val, "sharding", None) != want:
                     persist_in[name] = jax.device_put(val, want)
-
         if validate_feed:
-            _check_feed_shape_type(block, feed)
+            # a chunk is checked as its PER-STEP slice (shape/dtype
+            # only: ShapeDtypeStructs stand in for the sliced values,
+            # no device readback)
+            _check_feed_shape_type(block, {
+                k: jax.ShapeDtypeStruct(tuple(v.shape[1:]), v.dtype)
+                for k, v in feed.items()} if entry.chunk else feed)
         feed_names = tuple(sorted(feed))
         mesh_fp = dist._fingerprint() if dist is not None else None
-        # program._uid, NOT id(program) — see run_repeated's cache key
-        # donate is baked into the jitted fn (donate_argnums), so it
-        # must key the cache: a donate=False caller handed a donating
-        # executable would have its param buffers invalidated mid-call
         pplan = getattr(dist._build_strategy, "pipeline", None) \
             if dist is not None \
             else getattr(program, "_pipeline_plan", None)
-        cache_key = (program._uid, program._version, feed_names,
-                     tuple(fetch_names), tuple(sorted(persist_in)),
-                     library, donate, mesh_fp,
+        # What a traceable bakes in keys it. program._uid, NOT
+        # id(program): ids are reused after GC, and a recycled id with
+        # a matching version would return a stale executable of a dead
+        # program. ``steps`` where the scan length is baked (a chunk's
+        # K is its feed's shape). stack_names SEPARATELY from the
+        # fetches' union: which fetch positions ride the scan ys is
+        # baked, so the same union split otherwise must not share.
+        # ``donate`` (donate_argnums): a donate=False caller handed a
+        # donating executable would have its param buffers invalidated
+        # mid-call.
+        cache_key = (entry.name, None if entry.chunk else entry.steps,
+                     program._uid, program._version, feed_names,
+                     tuple(all_fetch_names), tuple(stack_names),
+                     tuple(sorted(persist_in)), library, donate, mesh_fp,
                      pplan.signature() if pplan is not None else None)
         # convert the feed BEFORE the per-SHAPE compile accounting:
         # the signature must reflect the dtypes XLA actually sees
         # (asarray canonicalizes int64 labels to int32, so the raw
         # feed dtype would book phantom compiles), and the AOT
-        # executable keyed on it is called with exactly these values
+        # executable keyed on it is called with exactly these values.
+        # A chunk's K is part of its shape: a ragged tail chunk
+        # legitimately counts as one extra compile.
         with _profiler.RecordEvent("feed_h2d"):
-            if dist is not None:
-                feed_vals = {
-                    k: jax.device_put(
-                        v, dist.feed_sharding(np.shape(v), k))
-                    for k, v in feed.items()}
-            else:
-                feed_vals = {k: jnp.asarray(v)
-                             if not isinstance(v, jax.Array)
-                             else v
-                             for k, v in feed.items()}
+            feed_vals = _feed_to_device(feed, dist, entry.chunk)
         shape_sig = tuple((k, tuple(feed_vals[k].shape),
                            _dtype_tag(feed_vals[k]))
                           for k in feed_names)
-        fresh_sig = self._book_fresh_sig(cache_key, shape_sig)
+        self._book_fresh_sig(cache_key, shape_sig)
 
         def make_fn():
             # trace-time only (the closure bakes it into the compiled
-            # step), so the block scan stays off the per-step hot path
+            # step), so the block scan stays off the per-step hot path.
+            # Guard, collective, sharded bracket, pipeline schedule and
+            # the K-step scans all assemble in the ONE step factory
+            # (engine/step_engine.py).
             sync_plan = dist.grad_sync_plan(block) if dist is not None \
                 else None
             self._check_sharded_layout(block, sync_plan)
-            guard_plan = self._guard_plan(program, block)
-            # the ONE step assembly (engine/step_engine.py): guard,
-            # collective, and sharded-bracket splices all live there
             from .engine import build_step
-            step = build_step(program, block, fetch_names,
-                              library=library, sync_plan=sync_plan,
-                              guard_plan=guard_plan,
-                              pipeline_plan=pplan,
-                              mesh=dist._mesh if dist is not None
-                              else None)
-
+            step = build_step(
+                program, block, all_fetch_names, library=library,
+                sync_plan=sync_plan,
+                guard_plan=self._guard_plan(program, block),
+                # a scan carries a FIXED structure: exactly the
+                # persistables present when tracing starts
+                carried=None if entry is _RUN else frozenset(persist_in),
+                warn_dropped=entry.chunk, pipeline_plan=pplan,
+                mesh=dist._mesh if dist is not None else None)
             if _needs_eager(program):
-                # Interpreted mode: programs with While loops / tensor
-                # arrays have data-dependent Python control flow; run
-                # the ops' lowerings eagerly, op by op — the analog of
-                # the reference's single-threaded interpreter
+                # Interpreted mode: programs with tensor arrays have
+                # data-dependent Python control flow; run the ops'
+                # lowerings eagerly, op by op — the analog of the
+                # reference's single-threaded interpreter
                 # (executor.cc:415). Compiled recurrence goes through
                 # static_rnn/dynamic_rnn/beam-search instead.
                 return step
             jit_kwargs = {}
             if donate:
-                jit_kwargs["donate_argnums"] = (0,)
+                jit_kwargs["donate_argnums"] = (0, 1) if entry.chunk \
+                    else (0,)
             if dist is not None:
                 # Pin persistable outputs to their input shardings so
-                # parameters keep a stable layout across steps
+                # parameters keep a stable layout across dispatches
                 # (donation then reuses the buffers in place).
-                persist_sharding = {
-                    n: dist.persist_sharding(block.vars[n])
-                    for n in persist_in}
-                jit_kwargs["out_shardings"] = (None, persist_sharding)
-            return jax.jit(step, **jit_kwargs)
+                jit_kwargs["out_shardings"] = \
+                    (None,) * (2 if entry.chunk else 1) + ({
+                        n: dist.persist_sharding(block.vars[n])
+                        for n in persist_in},)
+            return jax.jit(entry.wrap(step), **jit_kwargs)
 
         base_key0 = self._base_key(program)
-        if use_program_cache:
-            def obtain():
-                return self._executable_for(
-                    cache_key, shape_sig, "run", program, make_fn,
-                    lambda: (persist_in, feed_vals,
-                             jax.random.fold_in(base_key0, 0)),
-                    mesh_fp=mesh_fp)
 
-            exe_fn = obtain()
+        def obtain():
+            # the args are a thunk: only a build miss pays for them
+            return self._executable_for(
+                cache_key, shape_sig, entry.name, program, make_fn,
+                lambda: (persist_in, feed_vals)
+                + _step_keys(entry, base_key0, 0),
+                mesh_fp=mesh_fp,
+                compile_ctx=(lambda: _chunk_donation_filter(
+                    feed_vals, persist_in)) if entry.chunk else None)
+
+        # mesh-aware ops (ring_attention, sp/ep lowerings) read the
+        # ambient mesh during tracing
+        if dist is not None:
+            from .parallel import mesh as mesh_lib
+            mesh_ctx = mesh_lib.mesh_guard(dist._mesh)
         else:
-            # explicit no-caching contract: fresh traceable each call,
-            # jit-dispatched (jit compiles internally, invisibly to
-            # the AOT ledger beyond this booking)
-            obtain = None
-            exe_fn = make_fn()
-            if fresh_sig:
-                reason = self._classify_miss(cache_key, program,
-                                             shape_sig, mesh_fp,
-                                             None, None)
-                self._book_prog_sig(cache_key, program, shape_sig,
-                                    mesh_fp)
-                self._note_provenance("run", shape_sig, reason, None,
-                                      mesh_fp, 0.0, mode="uncached")
-
-        with self._lock:
-            counter = self._run_counter
-            self._run_counter += 1
-            self._dispatch_count += 1
-        self._m_dispatch.inc()
-        self._m_steps.inc()
-        # the failed-settlement guard covers EVERYTHING after the
-        # count increment, or an exception in between leaves
-        # dispatch_inflight() stuck True forever
-        try:
-            step_key = jax.random.fold_in(base_key0, counter)
-            t0 = phases.dispatch()
-            with _profiler.RecordEvent("executor_run"):
-                if obtain is not None:
-                    fetches, persist_out = self._call_executable(
-                        exe_fn, (cache_key, shape_sig),
-                        (persist_in, feed_vals, step_key), obtain)
-                else:
-                    fetches, persist_out = exe_fn(persist_in,
-                                                  feed_vals, step_key)
-        except BaseException:
-            self._note_dispatch_failed()
-            raise
+            mesh_ctx = contextlib.nullcontext()
+        with mesh_ctx:
+            exe_fn = obtain()
+            with self._lock:
+                counter = self._run_counter
+                self._run_counter += entry.steps
+                self._dispatch_count += 1
+            self._m_dispatch.inc()
+            self._m_steps.inc(entry.steps)
+            # the failed-settlement guard covers EVERYTHING after the
+            # count increment, or an exception in between leaves
+            # dispatch_inflight() stuck True forever
+            try:
+                args = (persist_in, feed_vals) \
+                    + _step_keys(entry, base_key0, counter)
+                t0 = phases.dispatch()
+                with _profiler.RecordEvent(
+                        entry.span, args={"steps": int(entry.steps)}
+                        if entry.chunk else None):
+                    *outs, persist_out = self._call_executable(
+                        exe_fn, (cache_key, shape_sig), args, obtain)
+            except BaseException:
+                self._note_dispatch_failed()
+                raise
         t1 = time.perf_counter()
         self._note_dispatch(t1 - t0)
         phases.settle(t1)
 
         for name, val in persist_out.items():
             scope.set_var(name, val)
-
+        fetches = outs[0][:len(fetch_names)]
         if FLAGS.benchmark:
             jax.block_until_ready(fetches)
         if return_numpy:
@@ -2201,7 +1848,9 @@ class Executor:
                         not np.all(np.isfinite(arr)):
                     raise FloatingPointError(
                         "NaN/Inf in fetched var %r" % name)
-        return fetches
+        if stack_fetch_list is None:
+            return fetches
+        return fetches, [np.asarray(s) for s in outs[1]]
 
 
 # Convenience mirroring fluid's module-level scope helpers.

@@ -703,10 +703,9 @@ class ShardedUpdatePlan:
     swap the param env entry to its flat shard — the master shard when
     the param gather quantizes, a free local slice of the full param
     otherwise. Every op inside the bracket (regularizer, clip,
-    accumulation, update — including the batched multi_tensor_adam
-    path) then runs on 1/n-laid-out flats; global reductions (norm
-    clip, lamb trust ratios) still see the full global value, with
-    GSPMD reducing the sharded operand.
+    accumulation, update) then runs on 1/n-laid-out flats; global
+    reductions (norm clip, lamb trust ratios) still see the full
+    global value, with GSPMD reducing the sharded operand.
 
     ``finish`` (at ``end_boundary``): carry the updated shard into the
     master slot, all-gather the fresh params (fp32, or int8 + scales

@@ -886,6 +886,6 @@ def test_program_uid_not_id_in_executor_cache():
     r2 = exe.run_repeated(m2, feed=feed, fetch_list=[y2.name], iters=2)
     assert float(np.ravel(r1[0])[0]) == 2.0
     assert float(np.ravel(r2[0])[0]) == 3.0
-    repeat_keys = [k for k in exe._cache if k[0] == "repeat"]
+    repeat_keys = [k for k in exe._cache if k[0] == "run_repeated"]
     assert sorted(k[2] for k in repeat_keys) == sorted(
         [m1._uid, m2._uid])

@@ -7,7 +7,7 @@ param-gather variants within an rtol budget with both error-feedback
 residual families live, ~1/n per-chip optimizer-slot bytes,
 save -> restore -> continue bit-exactness, and composition with the
 anomaly guard (a gated step leaves shards, residuals, and params
-bit-identical) and with the batched multi_tensor_adam path.
+bit-identical).
 """
 
 import tempfile
@@ -18,7 +18,6 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import io, layers, optimizer, unique_name
-from paddle_tpu.core.flags import FLAGS
 from paddle_tpu.parallel import collectives as C
 from paddle_tpu.parallel import make_mesh
 
@@ -343,25 +342,6 @@ def test_guard_gated_step_leaves_sharded_state_bit_identical():
                          fetch_list=[loss])
         assert np.isfinite(lv2)
         assert read_counters(scope) == (1.0, 0.0)
-
-
-def test_multi_tensor_adam_batched_path_composes():
-    """FLAGS.multi_tensor_adam batches the (shard-shaped) adam updates
-    through one concatenated elementwise update — bit-identical to the
-    per-op sharded path."""
-    old = FLAGS.multi_tensor_adam
-    try:
-        FLAGS.multi_tensor_adam = False
-        _, per_op, p1, _ = _train("sharded_update", world=4, steps=6,
-                                  clip=None, opt="adam")
-        FLAGS.multi_tensor_adam = True
-        _, batched, p2, _ = _train("sharded_update", world=4, steps=6,
-                                   clip=None, opt="adam")
-    finally:
-        FLAGS.multi_tensor_adam = old
-    assert per_op == batched
-    for n in p1:
-        np.testing.assert_array_equal(p1[n], p2[n], err_msg=n)
 
 
 def test_ema_reads_full_params_after_gather():
