@@ -1084,8 +1084,8 @@ def moe_held_experts(x, idx, weight, num_held, d_ffn, first_held=0,
     d_model, d_ffn], ``.w_down`` [num_held, d_ffn, d_model]), over the
     tokens routed to them: sorted by expert into a static buffer of
     ``row_capacity`` rows (default: every assignment, so nothing can
-    overflow), three grouped matrix products, scatter-added back times
-    ``weight``. An assignment past the buffer makes the output NaN and
+    overflow) walked in chunks up to the rows in use, three grouped
+    matrix products a chunk, scatter-added back times ``weight``. An assignment past the buffer makes the output NaN and
     is counted (``telemetry()["moe"]``); nothing is dropped in
     silence."""
     helper = LayerHelper("moe_experts", name=name)

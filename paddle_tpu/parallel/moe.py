@@ -25,8 +25,10 @@ load-balancing bias buffer, weights renormalised and scaled
 (``sigmoid_topk_route``, ``balance_bias_update``); then the part of the
 output that the experts HELD HERE give (``held_experts_ffn``): the
 assignments to them stable-sorted by expert into a static row buffer,
-three grouped matrix products that skip the buffer's slack
-(ops/pallas/grouped_matmul.py), scattered back times the weights.
+the buffer walked in chunks up to the rows the router sent (three
+grouped matrix products a chunk, ops/pallas/grouped_matmul.py),
+scattered back times the weights: the buffer's size is what may be
+addressed, the step's load is what is computed and moved.
 Nothing is dropped: an assignment past the buffer makes the output NaN
 and is counted. It runs on one chip with no exchange; what the experts
 held elsewhere would add is left out (ROADMAP R1 is the exchange).
@@ -46,12 +48,14 @@ from . import mesh as mesh_lib
 # The step's own counts, as one persistable the ops add to. Each is a
 # total since the startup program: assignments (tokens x top_k, every
 # layer), those to held experts, rows the grouped product computed
-# (tile-rounded) and rows that found no room in the buffer, and per
-# layer and step the busiest held expert's tokens and the held mean.
+# (tile-rounded) and rows that found no room in the buffer, per layer
+# and step the busiest held expert's tokens and the held mean, and the
+# rows of the buffer that the held experts' chunk loops walked.
 COUNTERS_VAR = "__moe_counters__"
 COUNTER_NAMES = ("assignments_total", "assignments_held_total",
                  "rows_computed_total", "rows_over_capacity_total",
-                 "held_load_max_total", "held_load_mean_total")
+                 "held_load_max_total", "held_load_mean_total",
+                 "rows_passed_total")
 
 
 def read_counters(scope):
@@ -262,74 +266,127 @@ def balance_bias_update(bias, load, coeff):
     return bias - jnp.mean(bias)
 
 
-def _gathered_rows(x, tok, live):
-    return jnp.where(live[:, None], x[tok], jnp.zeros((), x.dtype))
+def _chunk_rows(n_tokens, capacity):
+    """Rows a chunk of the sorted buffer, from the shapes alone: three
+    for every two tokens of the step (the whole buffer where that is
+    less), in whole row tiles.
+
+    Swept on the v5e at the Trinity-Mini cell's shapes (T = 8,192
+    tokens, 65,536 rows, 8 experts of 2048 x 1024; PERF.md section 6,
+    PR 31). A row of slack in a chunk costs 0.18 us of passes, a real
+    row 0.45 us, and a chunk of its own about 1 ms more (the held
+    matrices fetched again, the float32 sums of their gradients read
+    and written), so a chunk should take a layer's load whole nearly
+    always and not be much longer: the cell's layers hold 0.7 to 1.5
+    rows a token, and a step read 255.0 / 258.1 ms at 8,192 rows a
+    chunk (two chunks in half the layers), 251.7 / 252.8 at 12,288 and
+    255.6 / 256.6 at 16,384 (two seeds); one layer forward and
+    backward at 7,100 rows 9.3, 7.6 and 8.3 ms at 4,096, 8,192 and
+    12,288, and 12.4, 12.1 and 8.6 at 8,320 rows."""
+    rows = min(n_tokens + n_tokens // 2, capacity)
+    return -(-rows // gmm_lib.TILE_M) * gmm_lib.TILE_M
+
+
+def _chunk(i, x, weight, rows, ends):
+    """Chunk ``i`` of the sorted rows: its tokens' activations and its
+    weights [R], the tokens themselves, and how many of its rows each
+    expert has (a group that straddles a chunk's end is split there: R
+    is whole row tiles, so at a tile's end, and the tiles the products
+    visit are the unchunked ones). The slack of the last chunk needs
+    no mask: the products ignore what the rows past the sizes' sum
+    hold and zero what they give."""
+    n = rows.shape[1]
+    rows_c = lax.dynamic_index_in_dim(rows, i, keepdims=False)
+    tok = rows_c // weight.shape[1]
+    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    sizes = jnp.clip(ends, i * n, (i + 1) * n) \
+        - jnp.clip(starts, i * n, (i + 1) * n)
+    return x[tok], weight.reshape(-1)[rows_c], rows_c, tok, sizes
+
+
+def _n_chunks(ends, rows):
+    return (ends[-1] + rows - 1) // rows
 
 
 @jax.custom_vjp
-def _experts(x, wr, w_gate, w_up, w_down, tok, live, sizes):
-    """The sorted rows through their experts and back: x [T, D], wr
-    [C] the weight of each buffer row (0 on the slack), tok [C] its
-    token, sizes [n_held] the rows of each expert -> [T, D] float32.
+def _experts(x, weight, w_gate, w_up, w_down, rows, sizes):
+    """The sorted rows through their experts and back: x [T, D];
+    weight [T, k]; rows [chunks, R] the assignment (token x k + choice)
+    of each buffer row; sizes [n_held] the rows of each expert -> [T,
+    D] float32.
 
-    Its own backward pass for two reasons. Every array of C rows
-    between the products is C long whatever the step's load, and of
-    those the backward pass keeps the first two products' outputs
-    alone; the gathered rows and the gate's product are made again,
-    which costs elementwise passes and no product. And the forward
-    pass is the same code differentiated or not, so the executor's two
-    lowerings of it (forward op, then under ``jax.vjp``) are one set
-    of kernels after XLA's CSE (under ``jax.checkpoint`` they were
-    not): nine products a layer and step, which is what moves a step's
-    time with its load."""
-    return _experts_fwd(x, wr, w_gate, w_up, w_down, tok, live, sizes)[0]
+    The buffer is walked in chunks of R rows by a loop whose trip count
+    is the step's own ``ceil(sum(sizes) / R)``: every array between the
+    sort and the combine is R rows long, so the gathers, the
+    elementwise passes, the scatter-adds and the products alike cost
+    what the step's load costs and not what the buffer could hold.
+
+    Its own backward pass, the same loop over the same chunks, which
+    keeps the op's inputs ALONE: the gathered rows and the first two
+    products are made again a chunk at a time (two products more a
+    chunk), so no array of the buffer's length lives between the
+    passes, and the executor's second lowering of the forward pass
+    (under ``jax.vjp``) feeds nothing and is dropped as dead code,
+    whatever XLA's CSE makes of two loops. The matrices' gradients are
+    summed across chunks in float32 and rounded once."""
+    return _experts_fwd(x, weight, w_gate, w_up, w_down, rows, sizes)[0]
 
 
-def _experts_fwd(x, wr, w_gate, w_up, w_down, tok, live, sizes):
-    xs = _gathered_rows(x, tok, live)
-    gate = gmm_lib.grouped_matmul(xs, w_gate, sizes)
-    up = gmm_lib.grouped_matmul(xs, w_up, sizes)
-    y = gmm_lib.grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes)
-    out = jnp.zeros(x.shape, jnp.float32).at[tok].add(
-        y.astype(jnp.float32) * wr[:, None])
-    return out, (x, wr, w_gate, w_up, w_down, tok, live, sizes, gate, up)
+def _experts_fwd(x, weight, w_gate, w_up, w_down, rows, sizes):
+    ends = jnp.cumsum(sizes)
+
+    def chunk(i, out):
+        xs, wr, _, tok, sizes_c = _chunk(i, x, weight, rows, ends)
+        gate = gmm_lib.grouped_matmul(xs, w_gate, sizes_c)
+        up = gmm_lib.grouped_matmul(xs, w_up, sizes_c)
+        y = gmm_lib.grouped_matmul(jax.nn.silu(gate) * up, w_down, sizes_c)
+        return out.at[tok].add(y.astype(jnp.float32) * wr[:, None])
+
+    out = lax.fori_loop(0, _n_chunks(ends, rows.shape[1]), chunk,
+                        jnp.zeros(x.shape, jnp.float32))
+    return out, (x, weight, w_gate, w_up, w_down, rows, sizes)
 
 
 def _experts_bwd(res, d_out):
-    x, wr, w_gate, w_up, w_down, tok, live, sizes, gate, up = res
-    # what is made again must not be merged with the forward pass's
-    # copy, which would then live until here (jax.checkpoint's
-    # prevent_cse, by hand)
-    x, tok, live, gate, up = lax.optimization_barrier(
-        (x, tok, live, gate, up))
-
-    def product_vjp(rows, w, d):
-        # the forward product this traces is dead code: only its
-        # pullback (two products) is used
-        return jax.vjp(lambda a, b: gmm_lib.grouped_matmul(a, b, sizes),
-                       rows, w)[1](d)
-
+    x, weight, w_gate, w_up, w_down, rows, sizes = res
+    ends = jnp.cumsum(sizes)
     # the output was x's type, so its cotangent holds no more than that
-    d_rows = d_out.astype(x.dtype)[tok]
-    h, gated_vjp = jax.vjp(lambda g, u: jax.nn.silu(g) * u, gate, up)
-    # out = sum over rows of wr x (h @ w_down): with the weight moved
-    # onto h, one pullback gives the matrix's gradient and the
-    # unweighted gradient of h, and the weight's own is a row sum of
-    # that; the product's output is not needed again
-    d_hw, d_w_down = product_vjp(h * wr[:, None].astype(h.dtype),
-                                 w_down, d_rows)
-    d_hw = d_hw.astype(jnp.float32)
-    d_wr = jnp.sum(h.astype(jnp.float32) * d_hw, -1)
-    d_h = (d_hw * wr[:, None]).astype(h.dtype)
-    d_gate, d_up = gated_vjp(d_h)
-    xs = _gathered_rows(x, tok, live)
-    d_xs_gate, d_w_gate = product_vjp(xs, w_gate, d_gate)
-    d_xs_up, d_w_up = product_vjp(xs, w_up, d_up)
-    d_xs = jnp.where(live[:, None], d_xs_gate.astype(jnp.float32)
-                     + d_xs_up.astype(jnp.float32), 0.0)
-    d_x = jnp.zeros(x.shape, jnp.float32).at[tok].add(d_xs)
-    return (d_x.astype(x.dtype), d_wr, d_w_gate, d_w_up, d_w_down, None,
-            None, None)
+    d_out = d_out.astype(x.dtype)
+
+    def chunk(i, carry):
+        d_x, d_weight, d_w_gate, d_w_up, d_w_down = carry
+        xs, wr, rows_c, tok, sizes_c = _chunk(i, x, weight, rows, ends)
+        gate = gmm_lib.grouped_matmul(xs, w_gate, sizes_c)
+        up = gmm_lib.grouped_matmul(xs, w_up, sizes_c)
+        h, gated_vjp = jax.vjp(lambda g, u: jax.nn.silu(g) * u, gate, up)
+        # out = sum over rows of wr x (h @ w_down): with the weight
+        # moved onto h, one pullback gives the matrix's gradient and
+        # the unweighted gradient of h, and the weight's own is a row
+        # sum of that; the product's output is not needed again
+        d_hw, d_w_down = gmm_lib.grouped_matmul_pullback(
+            h * wr[:, None].astype(h.dtype), w_down, sizes_c, d_out[tok],
+            d_w_down)
+        d_hw = d_hw.astype(jnp.float32)
+        d_weight = d_weight.at[rows_c].add(
+            jnp.sum(h.astype(jnp.float32) * d_hw, -1))
+        d_gate, d_up = gated_vjp((d_hw * wr[:, None]).astype(h.dtype))
+        d_xs_gate, d_w_gate = gmm_lib.grouped_matmul_pullback(
+            xs, w_gate, sizes_c, d_gate, d_w_gate)
+        d_xs_up, d_w_up = gmm_lib.grouped_matmul_pullback(
+            xs, w_up, sizes_c, d_up, d_w_up)
+        d_x = d_x.at[tok].add(d_xs_gate.astype(jnp.float32)
+                              + d_xs_up.astype(jnp.float32))
+        return d_x, d_weight, d_w_gate, d_w_up, d_w_down
+
+    zeros = lambda like: jnp.zeros(like.shape, jnp.float32)  # noqa: E731
+    d_x, d_weight, d_w_gate, d_w_up, d_w_down = lax.fori_loop(
+        0, _n_chunks(ends, rows.shape[1]), chunk,
+        (zeros(x), jnp.zeros((weight.size,), jnp.float32), zeros(w_gate),
+         zeros(w_up), zeros(w_down)))
+    return (d_x.astype(x.dtype),
+            d_weight.reshape(weight.shape).astype(weight.dtype),
+            d_w_gate.astype(w_gate.dtype), d_w_up.astype(w_up.dtype),
+            d_w_down.astype(w_down.dtype), None, None)
 
 
 _experts.defvjp(_experts_fwd, _experts_bwd)
@@ -342,36 +399,40 @@ def held_experts_ffn(x, sel, weight, w_gate, w_up, w_down, *,
     x [T, D]; sel [T, k] expert ids over the published width, weight
     [T, k]; w_gate, w_up [n_held, D, F], w_down [n_held, F, D]: expert
     ``first_held + e`` is ``w_*[e]``, a gated-SiLU MLP. Returns (out
-    [T, D] in x's type, rows_computed, rows_over): the sum over each
-    token's chosen experts THAT ARE HELD of weight x expert(x); the
-    rows the grouped products computed, tile-rounded; and how many
+    [T, D] in x's type, rows_computed, rows_passed, rows_over): the
+    sum over each token's chosen experts THAT ARE HELD of weight x
+    expert(x); the rows the grouped products computed, tile-rounded;
+    the rows the chunk loop walked (``_experts``); and how many
     assignments found no room in the ``row_capacity`` rows (0: a row
-    for every assignment), in which case ``out`` is NaN throughout."""
+    for every assignment), in which case ``out`` is NaN throughout.
+    ``row_capacity`` is what may be addressed; what is computed and
+    moved follows the rows the router sent."""
     T, _ = x.shape
     k = sel.shape[1]
     n_held = w_gate.shape[0]
     n_assign = T * k
     cap = int(row_capacity) or n_assign
+    chunk = _chunk_rows(T, cap)
+    padded = -(-cap // chunk) * chunk           # whole chunks
     local = sel.reshape(-1) - first_held
     held = jnp.logical_and(local >= 0, local < n_held)
     key = jnp.where(held, local, n_held)        # the others sort last
     order = jnp.argsort(key, stable=True)
-    sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+    sizes = jnp.sum(key[None, :] == jnp.arange(n_held)[:, None], axis=1,
+                    dtype=jnp.int32)
     n_rows = jnp.sum(sizes)
-    if cap > n_assign:
-        order = jnp.pad(order, (0, cap - n_assign))
-    rows = order[:cap]                          # assignment of each row
-    live = jnp.arange(cap) < n_rows
-    tok = rows // k
+    if padded > n_assign:
+        order = jnp.pad(order, (0, padded - n_assign))
     ends = jnp.minimum(jnp.cumsum(sizes), cap)
     sizes_in = ends - jnp.concatenate([jnp.zeros((1,), jnp.int32),
                                        ends[:-1]])
-    wr = jnp.where(live, weight.reshape(-1)[rows], 0.0)
-    out = _experts(x, wr, w_gate, w_up, w_down, tok, live, sizes_in)
+    out = _experts(x, weight, w_gate, w_up, w_down,
+                   order[:padded].reshape(-1, chunk), sizes_in)
     over = jnp.maximum(n_rows - cap, 0)
     out = jnp.where(over > 0, jnp.nan, out)
     return (out.astype(x.dtype),
             gmm_lib.tile_rounded_rows(sizes_in).astype(jnp.float32),
+            (_n_chunks(ends, chunk) * chunk).astype(jnp.float32),
             over.astype(jnp.float32))
 
 
@@ -406,8 +467,9 @@ def moe_sigmoid_router_op(x, w, bias, counters, *, top_k,
           ["Out", "CountersOut"], nondiff=("TopkIdx", "Counters"))
 def moe_held_experts_op(x, sel, weight, w_gate, w_up, w_down, counters,
                         *, first_held=0, row_capacity=0):
-    out, computed, over = held_experts_ffn(
+    out, computed, passed, over = held_experts_ffn(
         x, sel, weight, w_gate, w_up, w_down, first_held=first_held,
         row_capacity=row_capacity)
     return out, _add_counts(counters, rows_computed_total=computed,
+                            rows_passed_total=passed,
                             rows_over_capacity_total=over)

@@ -158,11 +158,18 @@ def test_telemetry_moe_against_counts_made_by_hand():
     from paddle_tpu.ops.pallas import grouped_matmul as gmm
     assert m["rows_computed_total"] >= m["assignments_held_total"]
     assert m["rows_computed_total"] % gmm.TILE_M == 0
+    # whole chunks of the buffer, enough for every held row, and per
+    # layer and step under a chunk more than that layer's rows
+    chunk = moe_lib._chunk_rows(tokens, tokens * k)
+    assert m["rows_passed_total"] % chunk == 0
+    assert m["assignments_held_total"] <= m["rows_passed_total"] \
+        < m["assignments_held_total"] + 2 * n_moe * chunk
     assert fluid.Executor().telemetry(scope=fluid.Scope())["moe"] is None
 
 
 def test_one_layer_counts_by_hand():
-    """The router's counters for one call against numpy's own count."""
+    """The router's and the held experts' counters for one call each
+    against numpy's own count."""
     rs = np.random.RandomState(3)
     x = jnp.asarray(rs.randn(40, 8), jnp.float32)
     w = jnp.asarray(rs.randn(8, 16), jnp.float32)
@@ -181,6 +188,17 @@ def test_one_layer_counts_by_hand():
     assert c["held_load_max_total"] == load[4:8].max()
     assert c["held_load_mean_total"] == pytest.approx(load[4:8].mean())
     assert (np.asarray(bias) == 0).all()    # balance_coeff 0: unmoved
+    mats = [jnp.asarray(rs.randn(*s), jnp.float32)
+            for s in [(4, 8, 6), (4, 8, 6), (4, 6, 8)]]
+    _, counters = moe_lib.moe_held_experts_op(
+        x, sel, weight, *mats, zeros, first_held=4, row_capacity=0)
+    c = dict(zip(moe_lib.COUNTER_NAMES, np.asarray(counters)))
+    from paddle_tpu.ops.pallas import grouped_matmul as gmm
+    chunk = moe_lib._chunk_rows(40, 120)
+    assert c["rows_passed_total"] == -(-load[4:8].sum() // chunk) * chunk
+    assert c["rows_computed_total"] == int(
+        gmm.tile_rounded_rows(jnp.asarray(load[4:8])))
+    assert c["rows_over_capacity_total"] == 0
 
 
 def test_overflow_of_the_row_buffer_is_nan_and_counted():

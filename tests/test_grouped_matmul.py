@@ -68,9 +68,10 @@ def test_cpu_lowering_matches_a_loop_over_the_groups():
 
 
 def test_tpu_wrapper_matches_a_loop_over_the_groups(monkeypatch):
-    """``_gmm_tpu``'s own custom_vjp (which product is transposed,
-    tgmm's operand order, the slack zeroed) with megablox's kernels
-    interpreted: the chip's path, minus Mosaic."""
+    """The TPU lowering of the product and its pullback (which product
+    is transposed, tgmm's operand order and ``existing_out``, the slack
+    zeroed) with megablox's kernels interpreted: the chip's path,
+    minus Mosaic."""
     mb = importlib.import_module(
         "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
@@ -84,7 +85,36 @@ def test_tpu_wrapper_matches_a_loop_over_the_groups(monkeypatch):
             return mb.tgmm(*a, interpret=True, **kw)
 
     monkeypatch.setattr(G, "_megablox", lambda: Interpreted)
-    check(lambda a, b, s: G._gmm_tpu(a, b, s, False))
+    monkeypatch.setattr(G, "interpret_mode", lambda: False)
+    check(G.grouped_matmul)
+    check_pullback_adds()
+
+
+def check_pullback_adds():
+    """The matrices' gradient is ADDED to the float32 sum handed in:
+    two halves of the rows, one after the other, give the whole."""
+    lhs, rhs, sizes, t = operands(1)
+    half = M // 2
+    ends = np.cumsum(SIZES)
+    starts = ends - SIZES
+    acc = jnp.zeros(rhs.shape, jnp.float32)
+    d_lhs = []
+    for lo in (0, half):
+        part = jnp.asarray(np.clip(ends, lo, lo + half)
+                           - np.clip(starts, lo, lo + half), jnp.int32)
+        d, acc = jax.jit(G.grouped_matmul_pullback)(
+            lhs[lo:lo + half], rhs, part, t[lo:lo + half], acc)
+        d_lhs.append(d)
+    whole = jax.jit(G.grouped_matmul_pullback)(
+        lhs, rhs, sizes, t, jnp.zeros(rhs.shape, jnp.float32))
+    np.testing.assert_allclose(jnp.concatenate(d_lhs), whole[0],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(acc, whole[1], rtol=1e-5, atol=1e-4)
+    assert acc.dtype == jnp.float32
+
+
+def test_cpu_pullback_adds_to_the_sum_handed_in():
+    check_pullback_adds()
 
 
 def test_tile_rounded_rows_by_hand():

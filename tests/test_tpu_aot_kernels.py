@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas import attention as A
 from paddle_tpu.ops.pallas import grouped_matmul as G
+from paddle_tpu.parallel import moe as M
 
 
 @pytest.fixture(scope="module")
@@ -110,15 +111,44 @@ def test_blocked_flash_at_the_cells_site(one_chip, for_the_chip, window):
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
 def test_grouped_products_at_the_cells_widths(one_chip, for_the_chip,
                                               k, n):
-    """8 held experts, a row for every assignment (65,536): gmm
-    forward, gmm for the rows' gradient, tgmm for the matrices'."""
+    """8 held experts, one chunk of the cell's row buffer: gmm forward,
+    gmm for the rows' gradient, tgmm adding the matrices' to a float32
+    sum (its output block and the sum's, both float32, in VMEM)."""
     bf = jnp.bfloat16
+    rows = M._chunk_rows(8192, 65536)
 
     def product(lhs, rhs, sizes, g):
         out, pull = jax.vjp(
             lambda a, b: G.grouped_matmul(a, b, sizes), lhs, rhs)
         return out, pull(g)
 
-    c = compiled(product, one_chip, ((65536, k), bf), ((8, k, n), bf),
-                 ((8,), jnp.int32), ((65536, n), bf))
+    c = compiled(product, one_chip, ((rows, k), bf), ((8, k, n), bf),
+                 ((8,), jnp.int32), ((rows, n), bf))
     assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
+
+
+def test_held_experts_layer_at_the_cells_widths(one_chip, for_the_chip):
+    """The cell's expert layer as the executor lowers it (forward op,
+    then the op under ``jax.vjp``): three products in the forward
+    chunk loop, eight in the backward one (two made again, three
+    pullbacks of two), and no third loop: the differentiated forward
+    is gone from what the chip would run."""
+    bf, t, d, f, e, k = jnp.bfloat16, 8192, 2048, 1024, 8, 8
+
+    def layer(x, sel, w, w_gate, w_up, w_down, g):
+        def held(x, w, w_gate, w_up, w_down):
+            return M.held_experts_ffn(x, sel, w, w_gate, w_up, w_down,
+                                      first_held=8, row_capacity=65536)[0]
+        out = held(x, w, w_gate, w_up, w_down)
+        return out, jax.vjp(held, x, w, w_gate, w_up, w_down)[1](g)
+
+    c = compiled(layer, one_chip, ((t, d), bf), ((t, k), jnp.int32),
+                 ((t, k), jnp.float32), ((e, d, f), bf), ((e, d, f), bf),
+                 ((e, f, d), bf), ((t, d), bf))
+    text = c.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 11
+    loops = [ln for ln in text.splitlines() if " while(" in ln
+             and "f32[%d,%d]" % (t, d) in ln.split(" while(")[0]]
+    assert len(loops) == 2
+    # nothing of the buffer's 65,536 rows times a width
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -84,7 +84,10 @@ def main():
     ap.add_argument("--seed", type=int, default=1)
     ap.add_argument("--dispatches", type=int, default=3)
     ap.add_argument("--rehearse-cpu", action="store_true")
-    ap.add_argument("--trace-dir", default="/tmp/flagship_trace")
+    # a directory of this run's own: the reader takes every trace it
+    # finds under it, so two runs sharing one read as one capture
+    ap.add_argument("--trace-dir",
+                    default="/tmp/flagship_trace.%d" % os.getpid())
     ap.add_argument("--out", help="write the table as JSON here too")
     args = ap.parse_args()
 
