@@ -20,6 +20,11 @@ white_list = {
     # router itself (moe_sigmoid_router) is unlisted: its scores are
     # float32 by the model's own statement
     "moe_held_experts",
+    # the chunked delta rule's matrix products; the log decay, its
+    # cumulative sums, the chunk's triangular inverse and the carried
+    # state stay float32 inside (ops/kda_ops.py; fp16_utils.
+    # F32_CONTRACT_*)
+    "kda_attention",
 }
 
 # Numerically sensitive ops that must stay in float32.
@@ -30,6 +35,9 @@ black_list = {
     "group_norm", "instance_norm", "reduce_sum", "reduce_mean", "sum",
     "cumsum", "logsumexp", "l2_normalize", "norm", "p_norm",
     "frobenius_norm",
+    # the per-channel log decay: exp and softplus of a few thousandths,
+    # summed over a chunk by its consumer
+    "kda_gate",
 }
 
 # Everything else: runs in whatever dtype its inputs arrive in
@@ -60,6 +68,8 @@ gray_list = {
     # type out); the rotation's angles are float32 inside; swish is
     # elementwise
     "rms_norm", "rotary_embedding", "swish",
+    # float32 inside, the input's type out
+    "short_conv", "gated_rms_norm",
 }
 
 

@@ -26,6 +26,7 @@ F32_CONTRACT_OUTPUTS = {
     "fused_linear_xent": ("Loss",),
     "layer_norm": ("Mean", "Variance"),
     "moe_held_experts": ("CountersOut",),
+    "kda_attention": ("CountersOut",),
 }
 
 # Input slots never cast down when a gray op goes low: training
@@ -36,6 +37,7 @@ F32_CONTRACT_INPUTS = {
     "softmax_with_cross_entropy": ("Label",),
     "fused_linear_xent": ("Label",),
     "moe_held_experts": ("Weight", "Counters"),
+    "kda_attention": ("G", "Counters"),
 }
 
 

@@ -1332,11 +1332,11 @@ class Executor:
         skipped, consec = _guard.read_counters(scope or global_scope())
         out["anomaly_skipped_steps"] = skipped
         out["anomaly_consecutive"] = consec
-        # the held-experts layers' own counts (parallel/moe.py
-        # COUNTER_NAMES), totals since the startup program; None where
-        # the scope holds no such layer
-        from .parallel import moe as _moe
-        out["moe"] = _moe.read_counters(scope or global_scope())
+        # the held-experts and the KDA layers' own counts (parallel/
+        # moe.py, ops/kda_ops.py COUNTER_NAMES), totals since the
+        # startup program; None where the scope holds no such layer
+        out["moe"], out["kda"] = _step_counters(
+            scope or global_scope())
         if program is not None and getattr(program, "_is_compiled",
                                            False):
             try:
@@ -1868,3 +1868,14 @@ def scope_guard(scope):
             scope_mod._global_scope = old
 
     return _guard()
+
+
+def _step_counters(scope):
+    """(``telemetry()["moe"]``, ``telemetry()["kda"]``). It stands at
+    the file's end for the compile cache's sake: a Mosaic kernel's
+    lowered body names the lines of the frames it was traced under
+    (``_run_impl`` among them), so a line added above them moves every
+    cell's key (PERF.md section 6, PR 26)."""
+    from .ops import kda_ops
+    from .parallel import moe
+    return moe.read_counters(scope), kda_ops.read_counters(scope)

@@ -926,7 +926,16 @@ def scaled_dot_product_attention(q, k, v, bias=None, scale=1.0,
     bias with elementwise_add instead. ``k`` and ``v`` may have fewer
     heads than ``q`` (grouped queries: q head i reads kv head
     i // (heads // kv heads)); ``window`` > 0, with ``causal``, lets
-    row i read keys i-window+1..i only. See ops/pallas/attention.py."""
+    row i read keys i-window+1..i only. In rank 4 ``v`` (and the
+    output) may have another head width than ``q`` and ``k`` (latent
+    attention: 192-wide keys beside 128-wide values). See
+    ops/pallas/attention.py."""
+    if len(q.shape) == 3 and k.shape[-1] != v.shape[-1]:
+        raise ValueError(
+            "scaled_dot_product_attention: values of another width "
+            "than the keys (%r beside %r) want rank 4 "
+            "[batch, heads, seq, head_dim] inputs"
+            % (v.shape[-1], k.shape[-1]))
     if (len(q.shape) == 3) != bool(num_heads):
         raise ValueError(
             "scaled_dot_product_attention: num_heads goes with rank-3 "
@@ -1021,17 +1030,22 @@ def moe_ffn(x, num_experts, d_ffn, capacity_factor=1.25, top_k=1,
     return out, aux
 
 
-def _moe_counters(helper):
-    """The one persistable the held-experts ops of a program add their
-    counts to (parallel/moe.py COUNTERS_VAR), zeroed by the startup
-    program."""
-    from ..parallel.moe import COUNTER_NAMES, COUNTERS_VAR
+def _step_counters(helper, lib):
+    """The one persistable that a program's ops of one kind add their
+    counts to (``lib.COUNTERS_VAR``, a float32 a name of
+    ``lib.COUNTER_NAMES``), zeroed by the startup program."""
     block = helper.main_program.global_block()
-    if COUNTERS_VAR in block.vars:
-        return block.vars[COUNTERS_VAR]
+    if lib.COUNTERS_VAR in block.vars:
+        return block.vars[lib.COUNTERS_VAR]
     from .tensor import create_global_var
-    return create_global_var((len(COUNTER_NAMES),), 0.0, "float32",
-                             persistable=True, name=COUNTERS_VAR)
+    return create_global_var((len(lib.COUNTER_NAMES),), 0.0, "float32",
+                             persistable=True, name=lib.COUNTERS_VAR)
+
+
+def _moe_counters(helper):
+    """The held-experts ops' (parallel/moe.py COUNTERS_VAR)."""
+    from ..parallel import moe
+    return _step_counters(helper, moe)
 
 
 def moe_sigmoid_router(x, num_experts, top_k, route_scale=1.0,
@@ -1115,6 +1129,80 @@ def moe_held_experts(x, idx, weight, num_held, d_ffn, first_held=0,
         outputs={"Out": [out], "CountersOut": [counters]},
         attrs={"first_held": int(first_held),
                "row_capacity": int(row_capacity or 0)})
+    return out
+
+
+def short_conv(input, kernel_size=4, param_attr=None, name=None):
+    """Causal depthwise convolution over the positions of
+    ``[batch, seq, channels]``, then SiLU (ops/kda_ops.py short_conv):
+    channel c of row t reads rows t-kernel_size+1..t of channel c
+    alone, through ``<name>.w_0`` [channels, kernel_size] (no bias)."""
+    helper = LayerHelper("short_conv", name=name)
+    enforce(input.shape is not None and len(input.shape) == 3,
+            "short_conv wants [batch, seq, channels] input, got shape "
+            "%r" % (input.shape,))
+    w = helper.create_parameter(
+        attr=param_attr, shape=(int(input.shape[-1]), int(kernel_size)),
+        dtype=input.dtype)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="short_conv", inputs={"X": [input], "W": [w]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def gated_rms_norm(input, gate, group_size, epsilon=1e-5, param_attr=None,
+                   name=None):
+    """RMSNorm over each group of ``group_size`` lanes of the last axis
+    (a head) with one weight ``<name>.w_0`` [group_size], times
+    ``sigmoid(gate)`` (ops/kda_ops.py gated_rms_norm)."""
+    helper = LayerHelper("gated_rms_norm", name=name)
+    w = helper.create_parameter(attr=param_attr,
+                                shape=(int(group_size),),
+                                dtype=input.dtype,
+                                default_initializer=Constant(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="gated_rms_norm",
+                     inputs={"X": [input], "Gate": [gate], "Scale": [w]},
+                     outputs={"Y": [out]},
+                     attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def kda_gate(x, a_log, dt_bias):
+    """The per-channel log decay of Kimi Delta Attention (ops/kda_ops.py
+    kda_gate): ``-exp(a_log_h) * softplus(x + dt_bias)`` in float32 for
+    x [batch, seq, heads * head_dim], ``a_log`` [heads] one scalar a
+    head and ``dt_bias`` [heads * head_dim]."""
+    helper = LayerHelper("kda_gate")
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(type="kda_gate",
+                     inputs={"X": [x], "ALog": [a_log],
+                             "DtBias": [dt_bias]},
+                     outputs={"Out": [out]})
+    return out
+
+
+def kda_attention(q, k, v, g, beta, scale=1.0, name=None):
+    """The core of Kimi Delta Attention (ops/kda_ops.py): a gated
+    delta-rule linear attention with a per-channel decay. ``q``, ``k``
+    [batch, seq, heads * dk], ``v`` [batch, seq, heads * dv], the log
+    decay ``g`` (float32, <= 0, ``kda_gate``) of k's shape and ``beta``
+    [batch, seq, heads] in (0, 1); a head's state ``S`` [dk, dv] starts
+    at nought at the row's first position and
+    ``S_t = (I - beta_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + beta_t k_t
+    v_t^T``, ``o_t = S_t^T q_t``, with q and k L2-normalised per head
+    and q times ``scale``. Runs chunk-parallel; the backward pass keeps
+    these inputs alone. Returns [batch, seq, heads * dv] in v's type."""
+    helper = LayerHelper("kda", name=name)
+    from ..ops import kda_ops
+    counters = _step_counters(helper, kda_ops)
+    out = helper.create_variable_for_type_inference(v.dtype)
+    helper.append_op(
+        type="kda_attention",
+        inputs={"Q": [q], "K": [k], "V": [v], "G": [g], "Beta": [beta],
+                "Counters": [counters]},
+        outputs={"Out": [out], "CountersOut": [counters]},
+        attrs={"scale": float(scale)})
     return out
 
 

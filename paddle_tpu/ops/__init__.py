@@ -38,3 +38,4 @@ from . import fused_ops  # noqa: F401,E402
 from . import collective_ops  # noqa: F401,E402
 from . import py_func_op  # noqa: F401,E402
 from . import pallas  # noqa: F401,E402
+from . import kda_ops  # noqa: F401,E402

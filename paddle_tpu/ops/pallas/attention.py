@@ -249,7 +249,7 @@ def _sdpa_reference(q, k, v, bias, *, scale, dropout_rate=0.0,
                      preferred_element_type=jnp.float32)
     out_dtype = v.dtype if group > 1 else q.dtype
     return out.reshape((B, sq, H * dh) if heads_last
-                       else (B, H, sq, dh)).astype(out_dtype)
+                       else (B, H, sq, v.shape[-1])).astype(out_dtype)
 
 
 @register("scaled_dot_product_attention", ["Q", "K", "V", "Bias"],
@@ -969,14 +969,14 @@ def _blocked_geometry(q, k):
 @functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
 def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal, window=0):
     B, H, Sq, Dh = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     BH = B * H
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
     G, gk, reps, blk_q, blk_k, n_q, n_k = _blocked_geometry(q, k)
     hb = H // G                    # cells per batch row
     q3 = q.reshape(BH, Sq, Dh)
     k3 = k.reshape(B * Hkv, Sk, Dh)
-    v3 = v.reshape(B * Hkv, Sk, Dh)
+    v3 = v.reshape(B * Hkv, Sk, Dv)
     k_lo, k_hi, _, _ = _band(blk_q, blk_k, n_q, n_k, causal, window)
     n_steps = _n_steps(blk_q, blk_k, n_k, window)
     grid = (BH // G, n_q, n_steps)
@@ -990,7 +990,7 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal, window=0):
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
         pl.BlockSpec((G, blk_q, Dh), lambda i, j, st: (i, j, 0)),
-        kv_spec, kv_spec,
+        kv_spec, pl.BlockSpec((gk, blk_k, Dv), kv_spec.index_map),
     ]
     args = [seed, q3, k3, v3]
     if bias is not None:
@@ -1015,16 +1015,16 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal, window=0):
                           blk_k=blk_k, n_q=n_q, n_k=n_k,
                           n_steps=n_steps, rate=rate, causal=causal,
                           window=window),
-        out_shape=[jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
                    jax.ShapeDtypeStruct((BH, Sq, 128), jnp.float32)],
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((G, blk_q, Dh), lambda i, j, st: (i, j, 0)),
+            pl.BlockSpec((G, blk_q, Dv), lambda i, j, st: (i, j, 0)),
             pl.BlockSpec((G, blk_q, 128), lambda i, j, st: (i, j, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, blk_q, Dh), jnp.float32),
+            pltpu.VMEM((G, blk_q, Dv), jnp.float32),
             pltpu.VMEM((G, blk_q, 128), jnp.float32),
             pltpu.VMEM((G, blk_q, 128), jnp.float32),
         ],
@@ -1032,7 +1032,7 @@ def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal, window=0):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
     )(*args)
-    return out.reshape(B, H, Sq, Dh), lse[:, :, 0]
+    return out.reshape(B, H, Sq, Dv), lse[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -1133,15 +1133,15 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
 def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal,
                window=0):
     B, H, Sq, Dh = q.shape
-    Hkv, Sk = k.shape[1], k.shape[2]
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     BH, BHkv = B * H, B * Hkv
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
     G, gk, reps, blk_q, blk_k, n_q, n_k = _blocked_geometry(q, k)
     hb = H // G
     q3 = q.reshape(BH, Sq, Dh)
     k3 = k.reshape(BHkv, Sk, Dh)
-    v3 = v.reshape(BHkv, Sk, Dh)
-    do3 = g.reshape(BH, Sq, Dh)
+    v3 = v.reshape(BHkv, Sk, Dv)
+    do3 = g.reshape(BH, Sq, Dv)
     k_lo, k_hi, j_lo, j_hi = _band(blk_q, blk_k, n_q, n_k, causal,
                                    window)
     nk_steps = _n_steps(blk_q, blk_k, n_k, window)
@@ -1150,7 +1150,7 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal,
     # delta_i = rowsum(dO * O): O(S*Dh) elementwise work, XLA fuses it.
     # lse/delta enter the kernels lane-replicated to the 128-lane
     # min-tile (the layout the fwd kernel produced them in).
-    delta = jnp.sum(do3.astype(jnp.float32) * o.reshape(BH, Sq, Dh)
+    delta = jnp.sum(do3.astype(jnp.float32) * o.reshape(BH, Sq, Dv)
                     .astype(jnp.float32), axis=-1)
     lse128 = jnp.broadcast_to(lse[:, :, None], (BH, Sq, 128))
     delta128 = jnp.broadcast_to(delta[:, :, None], (BH, Sq, 128))
@@ -1183,13 +1183,13 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal,
         sp = [pl.BlockSpec(memory_space=pltpu.SMEM),
               pl.BlockSpec((G, blk_q, Dh), qi),
               pl.BlockSpec((gk, blk_k, Dh), ki),
-              pl.BlockSpec((gk, blk_k, Dh), ki)]
+              pl.BlockSpec((gk, blk_k, Dv), ki)]
         ar = [seed, q3, k3, v3]
         if bias is not None:
             gb = G if per_head else 1
             sp.append(pl.BlockSpec((gb, 1, blk_q, blk_k), bi))
             ar.append(bias)
-        sp += [pl.BlockSpec((G, blk_q, Dh), qi),
+        sp += [pl.BlockSpec((G, blk_q, Dv), qi),
                pl.BlockSpec((G, blk_q, 128), qi),
                pl.BlockSpec((G, blk_q, 128), qi)]
         ar += [do3, lse128, delta128]
@@ -1224,15 +1224,15 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal,
         functools.partial(with_bias(_dkv_kernel), n_steps=nj_steps,
                           reps=reps, **common),
         out_shape=[jax.ShapeDtypeStruct((BHkv, Sk, Dh), k.dtype),
-                   jax.ShapeDtypeStruct((BHkv, Sk, Dh), v.dtype)],
+                   jax.ShapeDtypeStruct((BHkv, Sk, Dv), v.dtype)],
         grid=(BHkv // gk, n_k, reps * nj_steps),
         in_specs=sp,
         out_specs=[
             pl.BlockSpec((gk, blk_k, Dh), lambda c, kk, u: (c, kk, 0)),
-            pl.BlockSpec((gk, blk_k, Dh), lambda c, kk, u: (c, kk, 0)),
+            pl.BlockSpec((gk, blk_k, Dv), lambda c, kk, u: (c, kk, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((gk, blk_k, Dh), jnp.float32),
-                        pltpu.VMEM((gk, blk_k, Dh), jnp.float32)],
+                        pltpu.VMEM((gk, blk_k, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret_mode(),
@@ -1240,7 +1240,7 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal,
 
     dq = dq.reshape(B, H, Sq, Dh)
     dk = dk.reshape(B, Hkv, Sk, Dh)
-    dv = dv.reshape(B, Hkv, Sk, Dh)
+    dv = dv.reshape(B, Hkv, Sk, Dv)
     return dq, dk, dv
 
 
@@ -1312,8 +1312,8 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
                                rng=rng)
     _, H, hkv, sq, sk, dh = _geometry(q, k, num_heads)
     heads_last = q.ndim == 3
-    takes_1k = _takes_1k(H, hkv, sq, sk, window)
-    _count_lowering("flash_1k" if takes_1k else "flash_blocked")
+    takes_1k = _takes_1k(H, hkv, sq, sk, window) and _same_widths(k, v)
+    _count_lowering("flash_1k" if takes_1k else _blocked_name(k, v))
     # each family reads one layout; the other entry layout is adapted
     # by the transposes its model would otherwise have built
     if takes_1k:
@@ -1393,3 +1393,18 @@ def _flash_over_mesh(mesh, q, k, v, bias, seed, H, Hkv, scale, rate,
 
     return shard_map(body, mesh=mesh, in_specs=tuple(specs),
                      out_specs=spec, check_vma=False)(*args)
+
+
+def _same_widths(k, v):
+    """Whether values are as wide as keys: both are [B,Hkv,Sk,D] or
+    both [B,Sk,Hkv*D], so their last axes tell. Latent attention's keys
+    (and queries) are 192 wide beside 128-wide values; rank 4 only."""
+    return k.shape[-1] == v.shape[-1]
+
+
+def _blocked_name(k, v):
+    """The blocked kernels' counter: ``flash_blocked``, and
+    ``flash_blocked_mla`` where the qk width differs from v's (the
+    kernels take the two widths as they come: q, k, dq, dk blocks and
+    accumulators at one, v, o, do, dv at the other)."""
+    return "flash_blocked" if _same_widths(k, v) else "flash_blocked_mla"

@@ -262,6 +262,36 @@ def test_flash_blocked_window_gqa_matches_reference(window):
            rtol=5e-2, atol=3e-2, atol_of_max=True)
 
 
+# -- blocked path, keys wider than values (S=8192) --------------------------
+
+def test_flash_blocked_mla_widths_matches_reference():
+    """kimi_linear_s8k_scan's latent-attention site (32 heads, queries
+    and keys 192 wide -- 128 lanes a head's own, 64 shared by every
+    head --, values 128 wide, S=8192, bf16, causal, no window) cut to 4
+    heads so that the reference's [1,4,8192,8192] float32 scores fit
+    beside it. The same tolerances as the windowed GQA site above."""
+    s = 8192
+    r = np.random.RandomState(31)
+    mk = lambda h, d: jnp.asarray(                    # noqa: E731
+        r.randn(1, h, s, d).astype(np.float32) * 0.5, jnp.bfloat16)
+    q, own, v = mk(4, 192), mk(4, 128), mk(4, 128)
+    k = jnp.concatenate([own, jnp.broadcast_to(mk(1, 64),
+                                               (1, 4, s, 64))], -1)
+    assert A._blocked_applicable(s, s) and not A._same_widths(k, v)
+    kw = dict(scale=192 ** -0.5, causal=True)
+    ref = lambda *a: A._sdpa_reference(*a, None, **kw)   # noqa: E731
+    pal = lambda *a: A.sdpa_pallas(*a, None, is_test=True,  # noqa: E731
+                                   **kw)
+    out = jax.jit(pal)(q, k, v)
+    assert out.shape == (1, 4, s, 128)
+    _close(out, jax.jit(ref)(q, k, v), **BF16)
+    loss = lambda f: lambda *a: jnp.sum(             # noqa: E731
+        jnp.square(f(*a).astype(jnp.float32)))
+    _close(jax.jit(jax.grad(loss(pal), (0, 1, 2)))(q, k, v),
+           jax.jit(jax.grad(loss(ref), (0, 1, 2)))(q, k, v),
+           rtol=5e-2, atol=3e-2, atol_of_max=True)
+
+
 # -- grouped matrix product (the held experts' three products) -------------
 
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])

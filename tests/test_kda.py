@@ -1,0 +1,213 @@
+"""Kimi Delta Attention (ops/kda_ops.py): the chunked form the op
+lowers to against the token-by-token recurrence that defines it --
+forward and every input's gradient, at a length that is no multiple of
+the chunk, across several chunks (the carried state), under gates so
+strong that a decay factorised about the chunk's start would leave
+float32 --, the two limits written out by hand, the short convolution's
+causality, what the custom VJP keeps, and the op's counters."""
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu import layers, profiler
+from paddle_tpu.ops import kda_ops as K
+
+
+def inputs(seed, b, s, h, dk, dv, g_scale=1.0):
+    r = np.random.RandomState(seed)
+    f = lambda *sh: jnp.asarray(r.randn(*sh), jnp.float32)  # noqa: E731
+    g = -jnp.asarray(r.uniform(0.0, g_scale, (b, s, h * dk)),
+                     jnp.float32)
+    beta = jnp.asarray(r.uniform(0.1, 0.9, (b, s, h)), jnp.float32)
+    return f(b, s, h * dk), f(b, s, h * dk), f(b, s, h * dv), g, beta
+
+
+def unit(x):
+    """The op's L2 norm over the last axis, in numpy."""
+    return x / np.sqrt(np.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+def both(args, ct, scale):
+    def rec(*a):
+        return K.kda_recurrence(*a, scale=scale)
+
+    def chk(*a):
+        return K.kda_chunked(*a, scale)[0]
+    loss = lambda f: lambda *a: jnp.sum(f(*a) * ct)     # noqa: E731
+    every = tuple(range(5))
+    return (rec(*args), chk(*args),
+            jax.grad(loss(rec), every)(*args),
+            jax.jit(jax.grad(loss(chk), every))(*args))
+
+
+@pytest.mark.parametrize("s,strong", [(150, False), (150, True),
+                                      (64, True), (700, True)])
+def test_chunked_form_is_the_recurrence(s, strong):
+    """150 = two chunks and 22 tokens; 700 spans two blocks of the
+    stateless part. ``strong``: A = 16 and a step near 5 for a run of
+    70 tokens, so the in-chunk cumulative log decay reaches -3000 and
+    ``exp(-G)`` about the chunk's start would be inf."""
+    b, h, dk, dv = 2, 3, 8, 16
+    q, k, v, g, beta = inputs(s, b, s, h, dk, dv, 3.0)
+    if strong:
+        g = g.at[:, 30:100].multiply(16.0 * 2.0)
+        assert float(jnp.min(jnp.cumsum(g[:, :64], axis=1))) < -1000
+    ct = jnp.asarray(np.random.RandomState(1).randn(b, s, h * dv),
+                     jnp.float32)
+    o_rec, o_chk, g_rec, g_chk = both((q, k, v, g, beta), ct, dk ** -0.5)
+    assert o_chk.shape == (b, s, h * dv)
+    np.testing.assert_allclose(o_chk, o_rec, rtol=2e-4, atol=2e-5)
+    for name, a, w in zip(("q", "k", "v", "g", "beta"), g_chk, g_rec):
+        assert bool(jnp.all(jnp.isfinite(a))), name
+        np.testing.assert_allclose(a, w, rtol=2e-3, atol=2e-4,
+                                   err_msg="d" + name)
+
+
+def test_no_decay_is_the_delta_rule():
+    """g = 0: S_t = (I - beta k k^T) S_{t-1} + beta k v^T, in numpy."""
+    b, s, h, dk, dv = 1, 90, 2, 4, 4
+    q, k, v, g, beta = inputs(5, b, s, h, dk, dv)
+    got = np.asarray(K.kda_chunked(q, k, v, 0.0 * g, beta, 1.0)[0])
+    qn, kn, vn = (np.asarray(x, np.float64).reshape(s, h, -1)
+                  for x in (q, k, v))
+    qn, kn = unit(qn), unit(kn)
+    bn = np.asarray(beta, np.float64)[0]
+    want = np.zeros((s, h, dv))
+    for head in range(h):
+        S = np.zeros((dk, dv))
+        for t in range(s):
+            kt = kn[t, head]
+            S = S - bn[t, head] * np.outer(kt, kt @ S) \
+                + bn[t, head] * np.outer(kt, vn[t, head])
+            want[t, head] = S.T @ qn[t, head]
+    np.testing.assert_allclose(got[0], want.reshape(s, -1), rtol=1e-3,
+                               atol=1e-4)
+
+
+def test_small_beta_is_decayed_linear_attention():
+    """beta -> 0: the k k^T term is second order, so o / beta tends to
+    sum_{j<=t} (q_t . (k_j * exp(G_t - G_j))) v_j with G the cumulative
+    log decay over the whole row (q and k the normalised ones)."""
+    b, s, h, dk, dv = 1, 100, 1, 4, 4
+    q, k, v, g, _ = inputs(6, b, s, h, dk, dv)
+    eps = 1e-4
+    beta = jnp.full((b, s, h), eps, jnp.float32)
+    got = np.asarray(K.kda_chunked(q, k, v, g, beta, 1.0)[0])[0] / eps
+    qn, kn, vn, gn = (np.asarray(x, np.float64)[0] for x in (q, k, v, g))
+    qn, kn = unit(qn), unit(kn)
+    G = np.cumsum(gn, axis=0)
+    want = np.zeros((s, dv))
+    for t in range(s):
+        w = np.einsum("c,jc->j", qn[t], kn[:t + 1]
+                      * np.exp(G[t] - G[:t + 1]))
+        want[t] = w @ vn[:t + 1]
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-3)
+
+
+def test_custom_vjp_keeps_the_inputs_alone():
+    args = inputs(7, 1, 130, 2, 4, 4)
+    out, res = K._kda_fwd(*args, 0.5)
+    assert len(res) == 5 and all(r is a for r, a in zip(res, args))
+    from jax._src.ad_checkpoint import saved_residuals
+    saved = saved_residuals(lambda *a: K.kda_chunked(*a, 0.5)[0],
+                            *args)
+    kept = sum(int(np.prod(aval.shape)) for aval, _ in saved)
+    assert kept <= sum(int(a.size) for a in args), saved
+
+
+def test_short_conv_is_causal_and_exact():
+    op = fluid.ops.get("short_conv").fn
+    r = np.random.RandomState(8)
+    x = r.randn(2, 12, 6).astype(np.float32)
+    w = r.randn(6, 4).astype(np.float32)
+    y1 = np.asarray(op(jnp.asarray(x), jnp.asarray(w)))
+    want = np.zeros_like(x)
+    for t in range(12):
+        for i in range(4):
+            if t - 3 + i >= 0:
+                want[:, t] += w[:, i] * x[:, t - 3 + i]
+    np.testing.assert_allclose(y1, want / (1 + np.exp(-want)),
+                               rtol=1e-5, atol=1e-6)
+    x2 = x.copy()
+    x2[:, 7] += 5.0                 # a future token for rows 0..6
+    y2 = np.asarray(op(jnp.asarray(x2), jnp.asarray(w)))
+    assert np.array_equal(y1[:, :7], y2[:, :7])
+    assert not np.allclose(y1[:, 7:11], y2[:, 7:11])
+    assert np.array_equal(y1[:, 11:], y2[:, 11:])   # four taps reach 10
+
+
+def test_gate_and_gated_norm_by_hand():
+    r = np.random.RandomState(9)
+    x = r.randn(1, 5, 8).astype(np.float32)
+    a_log = r.uniform(0, 2.7, (2,)).astype(np.float32)
+    dt = r.randn(8).astype(np.float32)
+    gate = fluid.ops.get("kda_gate").fn
+    got = np.asarray(gate(jnp.asarray(x), jnp.asarray(a_log),
+                          jnp.asarray(dt)))
+    want = -np.repeat(np.exp(a_log), 4) * np.log1p(np.exp(x + dt))
+    np.testing.assert_allclose(got, want, rtol=1e-5)
+    assert got.dtype == np.float32 and (got < 0).all()
+    norm = fluid.ops.get("gated_rms_norm").fn
+    o, g = r.randn(1, 5, 8).astype(np.float32), r.randn(1, 5, 8)
+    w = r.uniform(0.5, 1.5, (4,)).astype(np.float32)
+    got = np.asarray(norm(jnp.asarray(o), jnp.asarray(g, jnp.float32),
+                          jnp.asarray(w), epsilon=1e-5))
+    oh = o.reshape(1, 5, 2, 4)
+    want = (oh / np.sqrt((oh ** 2).mean(-1, keepdims=True) + 1e-5)
+            * w).reshape(1, 5, 8) / (1 + np.exp(-g))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+
+
+def test_the_op_trains_and_counts():
+    """Through layers -> Program -> Executor: the loss falls, and
+    telemetry()["kda"] counts the tokens, the chunks and the elements
+    of the in-chunk cumulative log decay under -80 (two steps of one
+    layer over 2 x 100 tokens: chunks of 64 and 36)."""
+    b, s, h, d = 2, 100, 2, 4
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        x = layers.data("x", shape=[s, h * d], dtype="float32")
+        y = layers.data("y", shape=[s, h * d], dtype="float32")
+        proj = lambda n: layers.short_conv(                 # noqa: E731
+            layers.fc(x, h * d, num_flatten_dims=2, bias_attr=False),
+            name="conv_" + n)
+        # A = exp(3) and a step of softplus(2): -40 a token, the floor
+        # after two tokens of every chunk
+        start = lambda n, shape, value: layers.create_parameter(  # noqa: E731
+            shape, "float32", name=n,
+            default_initializer=fluid.initializer.Constant(value))
+        g = layers.kda_gate(
+            layers.fc(x, h * d, num_flatten_dims=2, bias_attr=False),
+            start("gate.A_log", (h,), 3.0),
+            start("gate.dt_bias", (h * d,), 2.0))
+        beta = layers.sigmoid(layers.fc(x, h, num_flatten_dims=2))
+        o = layers.kda_attention(proj("q"), proj("k"), proj("v"), g,
+                                 beta, scale=d ** -0.5)
+        loss = layers.reduce_mean(layers.square(o - y))
+        fluid.optimizer.Adam(learning_rate=0.01).minimize(loss)
+    before = profiler.counter_values().get("kda_lowering.xla_chunked",
+                                           0.0)
+    exe, scope = fluid.Executor(fluid.CPUPlace()), fluid.Scope()
+    r = np.random.RandomState(3)
+    feed = {"x": r.randn(b, s, h * d).astype(np.float32),
+            "y": r.randn(b, s, h * d).astype(np.float32) * 0.1}
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        assert exe.telemetry(scope=scope)["kda"]["tokens_total"] == 0
+        losses = [float(exe.run(main, feed=feed, fetch_list=[loss])[0])
+                  for _ in range(2)]
+        gate = np.asarray(scope.find_var("gate.A_log"))
+    assert losses[1] < losses[0]
+    assert gate.shape == (h,) and np.abs(gate - 3.0).max() > 0  # it trains
+    tel = exe.telemetry(scope=scope)["kda"]
+    assert tel["tokens_total"] == 2 * b * s
+    assert tel["chunks_total"] == 2 * b * 2
+    # every element from a chunk's second or third token on, and none
+    # of the 28 pad positions that fill the second chunk
+    assert 0.95 < tel["decay_floor_hits_total"] \
+        / (2 * b * s * h * d) < 0.99
+    assert profiler.counter_values()["kda_lowering.xla_chunked"] > before

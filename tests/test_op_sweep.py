@@ -400,6 +400,24 @@ def _rotary_ref(ins):
 spec("rotary_embedding", {"X": sgn((1, 2, 5, 4), 133)},
      {"theta": 100.0}, ref=_rotary_ref,
      loss_weight=_rs(203).uniform(0.5, 1.5, (1, 2, 5, 4)))
+spec("gated_rms_norm", {"X": sgn((2, 3, 8), 134),
+                        "Gate": sgn((2, 3, 8), 136),
+                        "Scale": u((4,), 138)},
+     {"epsilon": 1e-5}, max_rel=0.02,
+     ref=lambda ins: [(lambda x: x / np.sqrt(
+         np.mean(np.square(x), -1, keepdims=True) + 1e-5)
+         * ins["Scale"])(ins["X"].reshape(2, 3, 2, 4)).reshape(2, 3, 8)
+         / (1.0 + np.exp(-ins["Gate"]))])
+spec("short_conv", {"X": sgn((2, 6, 3), 140), "W": sgn((3, 4), 142)},
+     max_rel=0.02,
+     ref=lambda ins: [(lambda y: y / (1.0 + np.exp(-y)))(sum(
+         np.pad(ins["X"], ((0, 0), (3, 0), (0, 0)))[:, i:i + 6]
+         * ins["W"][:, i] for i in range(4)))])
+spec("kda_gate", {"X": sgn((2, 5, 6), 144), "ALog": u((2,), 146),
+                  "DtBias": sgn((6,), 148)},
+     grad=["X", "ALog", "DtBias"], max_rel=0.02,
+     ref=lambda ins: [-np.repeat(np.exp(ins["ALog"]), 3)
+                      * np.log1p(np.exp(ins["X"] + ins["DtBias"]))])
 spec("instance_norm", {"X": sgn((2, 2, 3, 3), 104),
                        "Scale": u((2,), 105),
                        "Bias": sgn((2,), 106)}, max_rel=0.02,
@@ -2199,6 +2217,13 @@ spec("fusion_lstm",
      {"use_peepholes": False}, ref=_fusion_lstm_ref, max_rel=0.01)
 
 EXEMPT = {
+    # a chunked scan that writes the step's counters in place; finite
+    # differences over a 64-token chunk's triangular inverse say less
+    # than the recurrence does
+    "kda_attention":
+        "test_kda.py (the chunked form against the token-by-token "
+        "recurrence, forward and every gradient; the counters), "
+        "test_kimi_linear_model.py",
     # discrete routing over persistable buffers (a bias buffer and the
     # step's counters written in place; top-k flips under a finite
     # difference)

@@ -1,6 +1,7 @@
 """The Mosaic kernels of the benchmark's cells (the flash 1k pair at
 transformer-base's and BERT-base's sites, the blocked flash kernels
-and the grouped products of the Trinity-Mini cell), COMPILED for a v5e
+and the grouped products of the Trinity-Mini cell, the blocked flash
+kernels at Kimi-Linear's latent-attention widths), COMPILED for a v5e
 that is described and not attached, at the cells' own shapes: what the
 chip's compiler would refuse (a tile that does not align, more fast
 memory than a kernel may use) is refused here, at no chip time.
@@ -106,6 +107,48 @@ def test_blocked_flash_at_the_cells_site(one_chip, for_the_chip, window):
     assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
     # out, lse, delta and the three gradients: nothing of S x S
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_blocked_flash_at_the_mla_site(one_chip, for_the_chip):
+    """Latent attention as ``kimi_linear_s8k_scan`` runs it: 32 heads,
+    queries and keys 192 wide (128 + the 64 shared lanes) beside
+    128-wide values, 8192 positions, bf16: the same three kernels with
+    q, k, dq, dk blocks at one width and v, o, do, dv at the other."""
+    bf = jnp.bfloat16
+    qk, vv = (1, 32, 8192, 192), (1, 32, 8192, 128)
+
+    def site(q_, k_, v_, g_):
+        seed = jnp.zeros((2,), jnp.float32)
+        out, pull = jax.vjp(
+            lambda a, b, c: A._sdpa_flash(a, b, c, None, seed,
+                                          192 ** -0.5, 0.0, True, 0),
+            q_, k_, v_)
+        return out, pull(g_)
+
+    c = compiled(site, one_chip, (qk, bf), (qk, bf), (vv, bf), (vv, bf))
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_kda_core_at_the_cells_widths(one_chip):
+    """The chunked delta rule as ``kimi_linear_s8k_scan`` runs it (32
+    heads of 128, 8192 positions, q / k / v in bf16 and the log decay
+    in float32), forward and backward: XLA's own lowering, no Mosaic
+    call yet, and temporaries that leave the cell its room (one site's
+    forward + backward: 1.2 GiB on the chip, my chip run, PR 32)."""
+    from paddle_tpu.ops import kda_ops as K
+    bf, wide = jnp.bfloat16, (1, 8192, 32 * 128)
+
+    def site(q, k, v, g, beta, ct):
+        out, pull = jax.vjp(
+            lambda *a: K.kda_chunked(*a, 128 ** -0.5)[0],
+            q, k, v, g, beta)
+        return out, pull(ct)
+
+    c = compiled(site, one_chip, (wide, bf), (wide, bf), (wide, bf),
+                 (wide, jnp.float32), ((1, 8192, 32), bf), (wide, bf))
+    assert 'custom_call_target="tpu_custom_call"' not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 3 << 29
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
