@@ -49,7 +49,16 @@ pass makes the chunk intermediates again (block by block of
 ``_BLOCK_CHUNKS`` chunks for the stateless part, and under the scan
 over chunks only the chunk-start states are kept).
 
-A Pallas kernel for the chunked form is not here yet (ROADMAP).
+**Two lowerings of the chunked form.** On a TPU backend, where a head
+of q, k and v is whole groups of 128 lanes, ``kda_chunked`` takes the
+Mosaic kernels of ``ops/pallas/kda.py``, forward and backward: the
+chunk's G, pairwise decays, T, W, U and the carried state stay in
+VMEM, the arrays are read in place from ``[B, S, H*D]``. Everywhere
+else (the CPU, a head of 64 lanes) it takes the XLA form below. They
+share this module's constants (``_CHUNK``, ``_SUB``, ``_STATE_DTYPE``,
+``_FLOOR``, read when a site is traced) and nothing else;
+``kda_lowering.pallas_chunked`` / ``.xla_chunked`` count which a site
+took.
 """
 
 from __future__ import annotations
@@ -60,7 +69,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas.common import count_lowering
+from .pallas import kda as kda_pallas
+from .pallas.common import count_lowering, interpret_mode
 from .registry import register
 
 _CHUNK = 64           # tokens to a chunk
@@ -290,16 +300,33 @@ def _kda_forward(q, k, v, g, beta, scale):
             jnp.sum(low))
 
 
+def lowering(q, v, beta):
+    """``pallas_chunked`` or ``xla_chunked``: by what the site's shapes
+    and the backend show, as ``grouped_matmul.py`` chooses."""
+    h = beta.shape[-1]
+    if not interpret_mode() and kda_pallas.takes(
+            q.shape[-1] // h, v.shape[-1] // h, _CHUNK, _SUB):
+        return "pallas_chunked"
+    return "xla_chunked"
+
+
+def _chunked(q, k, v, g, beta, scale):
+    if lowering(q, v, beta) == "pallas_chunked":
+        return kda_pallas.kda_fwd(q, k, v, g, beta, scale, _CHUNK, _SUB,
+                                  _STATE_DTYPE, _FLOOR)
+    return _kda_forward(q, k, v, g, beta, scale)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def kda_chunked(q, k, v, g, beta, scale=1.0):
     """The chunked form of ``kda_recurrence`` (same arguments): (the
     output in v's type, the count of ``_kda_forward``)."""
-    return _kda_forward(q, k, v, g, beta, scale)
+    return _chunked(q, k, v, g, beta, scale)
 
 
 def _kda_fwd(q, k, v, g, beta, scale):
     # the inputs are all the backward pass keeps
-    return _kda_forward(q, k, v, g, beta, scale), (q, k, v, g, beta)
+    return _chunked(q, k, v, g, beta, scale), (q, k, v, g, beta)
 
 
 @functools.partial(jax.jit, static_argnums=6)
@@ -310,6 +337,10 @@ def _kda_backward(q, k, v, g, beta, d_out, scale):
 
 
 def _kda_bwd(scale, res, ct):
+    q, _, v, _, beta = res
+    if lowering(q, v, beta) == "pallas_chunked":
+        return kda_pallas.kda_bwd(*res, ct[0], scale, _CHUNK, _SUB,
+                                  _STATE_DTYPE)
     return _kda_backward(*res, ct[0], scale)
 
 
@@ -324,7 +355,9 @@ def kda_attention(q, k, v, g, beta, counters, *, scale=1.0):
     per head, q times ``scale``, then the gated delta rule (the
     module's docstring) in its chunked form. ``Out`` [B,S,H*dv] has V's
     type; ``CountersOut`` is the input's own variable."""
-    count_lowering("kda_lowering.xla_chunked")
+    took = lowering(q, v, beta)
+    for path in ("pallas_chunked", "xla_chunked"):
+        count_lowering("kda_lowering." + path, float(path == took))
     out, low = kda_chunked(q, k, v, g, beta, float(scale))
     b, s, _ = q.shape
     add = jnp.stack([jnp.float32(b * s),

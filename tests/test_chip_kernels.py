@@ -292,6 +292,37 @@ def test_flash_blocked_mla_widths_matches_reference():
            rtol=5e-2, atol=3e-2, atol_of_max=True)
 
 
+def test_kda_kernels_match_the_xla_form():
+    """kimi_linear_s8k_scan's KDA site (32 heads of 128, S=8192, q, k,
+    v in bf16, the log decay in float32 with the model's spread of
+    gates, so some channels pass the floor inside a chunk): the Mosaic
+    kernels against the XLA chunked form, output, floor hits and all
+    five gradients, at the operands' own tolerance."""
+    from paddle_tpu.ops import kda_ops as K
+    b, s, h, d = 1, 8192, 32, 128
+    r = np.random.RandomState(33)
+    mk = lambda *sh: jnp.asarray(r.randn(*sh), jnp.bfloat16)  # noqa: E731
+    q, k, v, ct = (mk(b, s, h * d) for _ in range(4))
+    a = np.repeat(np.exp(r.uniform(0, 2.77, h)), d)
+    step = np.exp(r.uniform(np.log(1e-3), np.log(0.1), h * d))
+    x = r.randn(b, s, h * d) * 0.5 + np.log(np.expm1(step))
+    g = jnp.asarray(-a * np.log1p(np.exp(x)), jnp.float32)
+    beta = jnp.asarray(1 / (1 + np.exp(-r.randn(b, s, h))), jnp.bfloat16)
+    args, scale = (q, k, v, g, beta), d ** -0.5
+    assert K.lowering(q, v, beta) == "pallas_chunked"
+
+    def site(*a):
+        (out, low), pull = jax.vjp(lambda *x: K.kda_chunked(*x, scale),
+                                   *a)
+        return out, low, pull((ct, jnp.zeros_like(low)))
+    out, low, grads = jax.jit(site)(*args)
+    want, low_x = K._kda_forward(*args, scale)
+    grads_x = K._kda_backward(*args, ct, scale)
+    assert float(low) == float(low_x) > 0
+    _close(out, want, rtol=2e-2, atol=2e-2, atol_of_max=True)
+    _close(grads, grads_x, rtol=5e-2, atol=2e-2, atol_of_max=True)
+
+
 # -- grouped matrix product (the held experts' three products) -------------
 
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])

@@ -4,7 +4,11 @@ forward and every input's gradient, at a length that is no multiple of
 the chunk, across several chunks (the carried state), under gates so
 strong that a decay factorised about the chunk's start would leave
 float32 --, the two limits written out by hand, the short convolution's
-causality, what the custom VJP keeps, and the op's counters."""
+causality, what the custom VJP keeps, and the op's counters; then the
+chunked form's second lowering, the Mosaic kernels of
+ops/pallas/kda.py, interpreted here: against the recurrence and the XLA
+form, the rule that chooses between the two, and the module's
+constants as a fault driver sets them."""
 
 import numpy as np
 import pytest
@@ -15,6 +19,7 @@ import jax.numpy as jnp
 import paddle_tpu as fluid
 from paddle_tpu import layers, profiler
 from paddle_tpu.ops import kda_ops as K
+from paddle_tpu.ops.pallas import kda as KP
 
 
 def inputs(seed, b, s, h, dk, dv, g_scale=1.0):
@@ -211,3 +216,147 @@ def test_the_op_trains_and_counts():
     assert 0.95 < tel["decay_floor_hits_total"] \
         / (2 * b * s * h * d) < 0.99
     assert profiler.counter_values()["kda_lowering.xla_chunked"] > before
+
+
+# -- the Mosaic kernels (ops/pallas/kda.py), interpreted -------------------
+
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """``kda_chunked`` chooses the kernels where ``interpret_mode()`` is
+    false; the kernels' own copy of it stays true, so they are
+    interpreted."""
+    monkeypatch.setattr(K, "interpret_mode", lambda: False)
+
+
+def kernel_inputs(seed, s, strong, dtype, h=2, d=128):
+    q, k, v, g, beta = inputs(seed, 1, s, h, d, d, 3.0)
+    if strong:
+        g = g.at[:, 30:100].multiply(16.0 * 2.0)
+        assert float(jnp.min(jnp.cumsum(g[:, :64], axis=1))) < -1000
+    ct = jnp.asarray(np.random.RandomState(1).randn(1, s, h * d), dtype)
+    return tuple(x.astype(dtype) for x in (q, k, v)) + (g, beta), ct
+
+
+def rel(got, want):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("strong", [False, True], ids=["weak", "strong"])
+@pytest.mark.parametrize("s", [150, 256])
+def test_kernels_are_the_recurrence(as_on_the_chip, s, strong, dtype):
+    """Two heads of 128 lanes in one [B,S,H*D] array, 150 = two chunks
+    and 22 tokens: the output and all five gradients through
+    ``kda_chunked`` on the kernels, against the recurrence and against
+    the XLA form; the floor hits equal the XLA form's."""
+    args, ct = kernel_inputs(s, s, strong, dtype)
+    scale = 128 ** -0.5
+    assert K.lowering(args[0], args[2], args[4]) == "pallas_chunked"
+    every = tuple(range(5))
+    loss = lambda f: lambda *a: jnp.sum(                 # noqa: E731
+        f(*a).astype(jnp.float32) * ct.astype(jnp.float32))
+    out, low = K.kda_chunked(*args, scale)
+    got = jax.grad(loss(lambda *a: K.kda_chunked(*a, scale)[0]),
+                   every)(*args)
+    o_xla, low_xla = K._kda_forward(*args, scale)
+    g_xla = K._kda_backward(*args, ct, scale)
+    o_rec = K.kda_recurrence(*args, scale=scale)
+    g_rec = jax.grad(loss(lambda *a: K.kda_recurrence(*a, scale=scale)),
+                     every)(*args)
+    assert out.shape == (1, s, 256) and out.dtype == dtype
+    assert float(low) == float(low_xla) > 0
+    # float32: rounding alone; bfloat16: the operands' own 2^-8
+    tol = 2e-4 if dtype == jnp.float32 else 2e-2
+    assert rel(out, o_rec) < tol and rel(out, o_xla) < tol
+    for name, a, x, w in zip("qkvgb", got, g_xla, g_rec):
+        assert a.dtype == w.dtype and a.shape == w.shape, name
+        assert bool(jnp.all(jnp.isfinite(a.astype(jnp.float32)))), name
+        assert rel(a, w) < 3 * tol, "d%s against the recurrence" % name
+        assert rel(a, x) < 3 * tol, "d%s against the XLA form" % name
+
+
+def test_a_head_reads_its_own_lanes(as_on_the_chip):
+    """Another head's q, k, v, g and beta change nothing in this
+    head's output or gradients, bit for bit."""
+    args, ct = kernel_inputs(11, 150, True, jnp.float32)
+    other = [x.at[..., x.shape[-1] // 2:].multiply(-1.7) for x in args]
+    other[3] = args[3].at[..., 128:].multiply(0.3)
+
+    def run(a):
+        out, pull = jax.vjp(lambda *x: K.kda_chunked(*x, 0.1)[0], *a)
+        return (out,) + pull(ct)
+    for a, b in zip(run(args), run(other)):
+        half = a.shape[-1] // 2
+        assert np.array_equal(a[..., :half], b[..., :half])
+        assert not np.array_equal(a[..., half:], b[..., half:])
+
+
+@pytest.mark.parametrize("setting", ["state_bf16", "chunk_8_sub_4"])
+def test_kernels_read_the_modules_constants(monkeypatch, setting):
+    """As tests/benchmark_suite/fault_driver_kimi_linear.py plants its
+    ``kda_carry_bf16``: ``_STATE_DTYPE`` (and at toy size ``_CHUNK``,
+    ``_SUB``) set on the module before a site is traced."""
+    args, ct = kernel_inputs(12, 150, False, jnp.float32)
+    sound = K.kda_recurrence(*args, scale=0.1)
+    if setting == "state_bf16":
+        monkeypatch.setattr(K, "interpret_mode", lambda: False)
+        plain = K.kda_chunked(*args, 0.1)[0]
+        monkeypatch.setattr(K, "_STATE_DTYPE", jnp.bfloat16)
+        out = K.kda_chunked(*args, 0.1)[0]
+        starts, _ = KP._forward(*args, 0.1, K._CHUNK, K._SUB,
+                                jnp.dtype(K._STATE_DTYPE), 0.0, False)
+        assert starts.dtype == jnp.bfloat16
+        # the carry's rounding shows, and is the XLA form's
+        assert 1e-4 < rel(out, sound) < 2e-2 and rel(plain, sound) < 1e-5
+        xla = K._kda_forward.__wrapped__(*args, 0.1)[0]
+        assert rel(out, xla) < rel(out, sound)
+        grads = jax.grad(lambda *a: jnp.sum(
+            K.kda_chunked(*a, 0.1)[0] * ct), tuple(range(5)))(*args)
+        assert all(bool(jnp.all(jnp.isfinite(x))) for x in grads)
+    else:
+        monkeypatch.setattr(K, "_CHUNK", 8)
+        monkeypatch.setattr(K, "_SUB", 4)
+        out, low = KP.kda_fwd(*args, 0.1, K._CHUNK, K._SUB,
+                              K._STATE_DTYPE, K._FLOOR)
+        _, low_xla = K._kda_forward.__wrapped__(*args, 0.1)
+        assert rel(out, sound) < 2e-4
+        got = KP.kda_bwd(*args, ct, 0.1, K._CHUNK, K._SUB, K._STATE_DTYPE)
+        want = jax.grad(lambda *a: jnp.sum(
+            K.kda_recurrence(*a, scale=0.1) * ct),
+            tuple(range(5)))(*args)
+        for a, w in zip(got, want):
+            assert rel(a, w) < 6e-4
+        # chunks of 8 reach the floor less often than chunks of 64
+        assert float(low) == float(low_xla)
+        assert float(low) < float(KP.kda_fwd(
+            *args, 0.1, 64, 16, K._STATE_DTYPE, K._FLOOR)[1])
+
+
+@pytest.mark.parametrize("d,took", [(128, "pallas_chunked"),
+                                    (64, "xla_chunked"),
+                                    (256, "pallas_chunked")])
+def test_the_lowering_follows_the_head_width(as_on_the_chip, d, took):
+    """Whole groups of 128 lanes a head take the kernels; a head of 64
+    takes the XLA form. ``kda_lowering.<path>`` counts the one taken
+    and lists the other."""
+    op = fluid.ops.get("kda_attention").fn
+    q, k, v, g, beta = inputs(13, 1, 70, 2, d, d)
+    names = ["kda_lowering.pallas_chunked", "kda_lowering.xla_chunked"]
+    before = [profiler.counter_values().get(n, 0.0) for n in names]
+    out, counters = op(q, k, v, g, beta, jnp.zeros((3,), jnp.float32),
+                       scale=d ** -0.5)
+    after = [profiler.counter_values()[n] for n in names]
+    assert K.lowering(q, v, beta) == took
+    assert [a - b for a, b in zip(after, before)] == \
+        [float(took == n.split(".")[1]) for n in names]
+    assert rel(out, K.kda_recurrence(q, k, v, g, beta,
+                                     scale=d ** -0.5)) < 2e-4
+    assert counters.tolist()[:2] == [70.0, 2.0]
+
+
+def test_off_the_chip_the_lowering_is_xla():
+    q, k, v, g, beta = inputs(14, 1, 64, 2, 128, 128)
+    assert K.lowering(q, v, beta) == "xla_chunked"
+    assert not KP.takes(128, 128, 8, 4) and KP.takes(128, 128, 64, 16)

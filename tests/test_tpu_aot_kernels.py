@@ -1,7 +1,8 @@
 """The Mosaic kernels of the benchmark's cells (the flash 1k pair at
 transformer-base's and BERT-base's sites, the blocked flash kernels
 and the grouped products of the Trinity-Mini cell, the blocked flash
-kernels at Kimi-Linear's latent-attention widths), COMPILED for a v5e
+kernels at Kimi-Linear's latent-attention widths and its KDA core's
+three kernels), COMPILED for a v5e
 that is described and not attached, at the cells' own shapes: what the
 chip's compiler would refuse (a tile that does not align, more fast
 memory than a kernel may use) is refused here, at no chip time.
@@ -149,6 +150,30 @@ def test_kda_core_at_the_cells_widths(one_chip):
                  (wide, jnp.float32), ((1, 8192, 32), bf), (wide, bf))
     assert 'custom_call_target="tpu_custom_call"' not in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 3 << 29
+
+
+def test_kda_kernels_at_the_cells_widths(one_chip, monkeypatch):
+    """The same site as the chip lowers it (``interpret_mode()`` false
+    in the op's module and in the kernels'): three Mosaic calls (the
+    forward kernel, its state pass again for the backward pass, the
+    reverse pass), and for temporaries the chunk-start states and T in
+    float32 (256 + 64 MiB) beside the reverse pass's outputs."""
+    from paddle_tpu.ops import kda_ops as K
+    from paddle_tpu.ops.pallas import kda as KP
+    monkeypatch.setattr(K, "interpret_mode", lambda: False)
+    monkeypatch.setattr(KP, "interpret_mode", lambda: False)
+    bf, wide = jnp.bfloat16, (1, 8192, 32 * 128)
+
+    def site(q, k, v, g, beta, ct):
+        out, pull = jax.vjp(
+            lambda *a: K.kda_chunked(*a, 128 ** -0.5)[0],
+            q, k, v, g, beta)
+        return out, pull(ct)
+
+    c = compiled(site, one_chip, (wide, bf), (wide, bf), (wide, bf),
+                 (wide, jnp.float32), ((1, 8192, 32), bf), (wide, bf))
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 29
 
 
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])

@@ -105,9 +105,16 @@ def main():
     print("%d dispatches of %d steps: %.4f s untraced, %.4f s traced "
           "(%.2f%% slower)" % (n, spd, untraced, traced,
                                100.0 * (traced / untraced - 1.0)))
+    # which lowering each kind of site took, counted when the step was
+    # traced (sdpa_lowering.*, moe_lowering.*, kda_lowering.*)
+    lowerings = {k: v for k, v in sorted(profiler.counter_values().items())
+                 if "_lowering." in k}
+    print("lowerings: " + ", ".join("%s %g" % kv
+                                    for kv in lowerings.items()))
     if args.out:
         table = dict(profiler.device_scope_table(), steps=n * spd,
-                     untraced_s=untraced, traced_s=traced)
+                     untraced_s=untraced, traced_s=traced,
+                     lowerings=lowerings)
         os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(table, f, indent=1)
