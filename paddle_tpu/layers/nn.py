@@ -974,11 +974,18 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(x, theta=10000.0, name=None):
-    """Rotary position embedding (rotate-half, the whole head) of
-    [batch, heads, seq, head_dim]; row s is position s."""
-    return _simple("rotary_embedding", x, {"theta": float(theta)},
-                   name=name)
+def rotary_embedding(x, theta=10000.0, start=0, width=None,
+                     interleaved=False, name=None):
+    """Rotary position embedding of [batch, heads, seq, head_dim]; row
+    s is position s. By default the whole head in the rotate-half
+    layout; ``start`` / ``width`` turn lanes ``[start, start + width)``
+    alone and pass the others through (a decoupled rotary part beside
+    plain lanes), ``interleaved`` pairs lanes (2i, 2i + 1) of the part
+    in place of (i, i + width/2)."""
+    return _simple("rotary_embedding", x,
+                   {"theta": float(theta), "start": int(start),
+                    "width": int(width or 0),
+                    "interleaved": bool(interleaved)}, name=name)
 
 
 def moe_ffn(x, num_experts, d_ffn, capacity_factor=1.25, top_k=1,

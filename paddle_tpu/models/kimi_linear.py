@@ -13,7 +13,10 @@ projection. Every fourth layer's mixer is **latent attention without
 positions** (MLA, NoPE; layer kind ``mla``): keys and values from a
 512-wide latent, the keys' last 64 lanes one vector shared by every
 head, queries and keys 192 wide beside 128-wide values, a causal
-softmax over the whole row, no rotary anywhere. After the leading dense
+softmax over the whole row, no rotary anywhere. The mixer is
+``models/mla.py latent_attention``, which ``models/deepseek_v3.py``
+builds too, there with its rotary part on; this model's published
+configuration has none (``mla_use_nope`` true). After the leading dense
 layer the FFN is a mixture of experts: a sigmoid top-k router with a
 selection-bias buffer, routed experts plus a shared one, as
 ``models/afmoe.py`` has it (the same two ops).
@@ -45,6 +48,7 @@ from ..framework import name_scope
 from ..initializer import Constant
 from ..param_attr import ParamAttr
 from .afmoe import _gated_mlp, _linear, _moe, _norm
+from .mla import latent_attention
 
 __all__ = ["KimiLinearConfig", "kimi_linear_lm", "kda_gate_start"]
 
@@ -94,9 +98,12 @@ class KimiLinearConfig:
                                        la["full_attn_layers"],
                                        num_hidden_layers))
         if q_lora_rank is not None or not mla_use_nope:
-            raise ValueError("the latent attention here has no query "
-                             "compression and no rotary (q_lora_rank "
-                             "null, mla_use_nope true)")
+            raise ValueError("this model's latent attention has no "
+                             "query compression and no rotary "
+                             "(q_lora_rank null, mla_use_nope true); "
+                             "models/mla.py latent_attention has the "
+                             "rotary form, which models/deepseek_v3.py "
+                             "builds")
         if moe_router_activation_func != "sigmoid" \
                 or num_expert_group != 1 or topk_group != 1:
             raise ValueError("the router here is the sigmoid one over "
@@ -116,6 +123,7 @@ class KimiLinearConfig:
         self.qk_nope_head_dim = qk_nope_head_dim
         self.qk_rope_head_dim = qk_rope_head_dim
         self.v_head_dim = v_head_dim
+        self.mla_use_nope = mla_use_nope
         self.intermediate_size = intermediate_size
         self.moe_intermediate_size = moe_intermediate_size
         self.num_experts = num_experts
@@ -194,35 +202,6 @@ def _kda(a, cfg, prefix):
     return _linear(o, cfg.hidden_size, prefix + "_out")
 
 
-@name_scope("mla")
-def _mla(a, cfg, prefix):
-    s, h = cfg.seq_len, cfg.num_attention_heads
-    dn, dr, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
-                  cfg.v_head_dim)
-
-    def heads(t, width):              # [b, s, h * width] -> [b, h, s, width]
-        return layers.transpose(layers.reshape(t, (-1, s, h, width)),
-                                (0, 2, 1, 3))
-
-    q = heads(_linear(a, h * (dn + dr), prefix + "_q"), dn + dr)
-    latent, k_shared = layers.split(
-        _linear(a, cfg.kv_lora_rank + dr, prefix + "_kv_a"),
-        [cfg.kv_lora_rank, dr], dim=2)
-    kv = heads(_linear(_norm(latent, cfg, prefix + "_kv_a_norm"),
-                       h * (dn + dv), prefix + "_kv_b"), dn + dv)
-    k_own, v = layers.split(kv, [dn, dv], dim=3)
-    # the 64 "rope" lanes are plain lanes here (NoPE), one vector for
-    # every head
-    k_shared = layers.expand(layers.reshape(k_shared, (-1, 1, s, dr)),
-                             [1, h, 1, 1])
-    k = layers.concat([k_own, k_shared], axis=3)
-    o = layers.scaled_dot_product_attention(
-        q, k, v, scale=(dn + dr) ** -0.5, causal=True)
-    o = layers.reshape(layers.transpose(o, (0, 2, 1, 3)),
-                       (-1, s, h * dv))
-    return _linear(o, cfg.hidden_size, prefix + "_out")
-
-
 def kimi_linear_lm(cfg, is_test=False):
     """Causal-LM training graph. Feeds: ``ids``, ``labels`` [b, s]
     int64; ``mask`` [b, s] float32 (1 where the position's loss
@@ -243,7 +222,7 @@ def kimi_linear_lm(cfg, is_test=False):
         p = "layer%d" % i
         with name_scope("residual_norm"):
             a = _norm(h, cfg, p + "_input_norm")
-        mixed = _mla(a, cfg, p + "_mla") if i + 1 in full \
+        mixed = latent_attention(a, cfg, p + "_mla") if i + 1 in full \
             else _kda(a, cfg, p + "_kda")
         with name_scope("residual_norm"):
             h = layers.elementwise_add(h, mixed)

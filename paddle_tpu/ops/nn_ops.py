@@ -18,6 +18,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..core.enforce import InvalidArgumentError
@@ -614,22 +615,70 @@ def rms_norm(x, scale, *, epsilon=1e-5):
     return (xf * inv * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+_ROTARY_PATHS = ("whole_half", "whole_interleaved", "partial_half",
+                 "partial_interleaved")
+
+
 @register("rotary_embedding", ["X"], ["Out"])
-def rotary_embedding(x, *, theta=10000.0):
-    """Rotary position embedding over the whole head, rotate-half
-    layout: x [B, H, S, Dh], position s of row s; the pair (x[i],
-    x[i + Dh/2]) turns by ``s * theta^(-2i/Dh)``. The angles are
-    float32 constants of the trace; the output keeps x's type."""
+def rotary_embedding(x, *, theta=10000.0, start=0, width=0,
+                     interleaved=False):
+    """Rotary position embedding of lanes ``[start, start + width)`` of
+    the last axis (``width`` 0: all that follow ``start``), the other
+    lanes passed through: x [B, H, S, Dh], position s of row s. Pair i
+    of the ``width // 2`` turns by ``s * theta^(-2i/width)``; it is
+    the lanes (i, i + width/2) of the part in the rotate-half layout
+    and (2i, 2i + 1) with ``interleaved``, each turned in place. The
+    angles are float32 constants of the trace; the output keeps x's
+    type. Each lowering bumps ``rotary_lowering.<whole|partial>_
+    <half|interleaved>`` (the paths not taken listed with 0)."""
+    # the kernel package registers variants of this module's ops
+    from .pallas.common import count_lowering
     s, dh = x.shape[-2], x.shape[-1]
-    half = dh // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32)
-                         * (2.0 / dh))
+    width = width or dh - start
+    if start < 0 or width % 2 or start + width > dh:
+        raise ValueError("rotary_embedding: lanes [%d, %d) of %d in "
+                         "pairs" % (start, start + width, dh))
+    took = "%s_%s" % ("whole" if width == dh else "partial",
+                      "interleaved" if interleaved else "half")
+    for path in _ROTARY_PATHS:
+        count_lowering("rotary_lowering." + path, float(path == took))
+    half = width // 2
+    if took == "whole_half":
+        # the form ``models/afmoe.py``'s sliding layers have lowered to
+        # since PR 28, kept word for word: their executables' keys hold
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32)
+                             * (2.0 / width))
+        ang = jnp.arange(s, dtype=jnp.float32)[:, None] \
+            * inv_freq[None, :]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        x1 = x[..., :half].astype(jnp.float32)
+        x2 = x[..., half:].astype(jnp.float32)
+        return jnp.concatenate([x1 * cos - x2 * sin,
+                                x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+    # any other part or layout: each lane of the part times its pair's
+    # cosine plus its partner times the sine. The partners come from a
+    # product with a constant signed permutation (one +-1 a column, so
+    # exact in x's own type): the lanes are never reshaped into pairs,
+    # which would pad every pair to a tile on the TPU
+    lane = np.arange(width)
+    pair, first, mate = (lane // 2, lane % 2 == 0, lane ^ 1) \
+        if interleaved else (lane % half, lane < half,
+                             (lane + half) % width)
+    swap = np.zeros((width, width), np.float32)
+    swap[mate, lane] = np.where(first, -1.0, 1.0)
+    part = x[..., start:start + width]
+    partner = jnp.matmul(
+        part, jnp.asarray(swap, x.dtype), preferred_element_type=x.dtype,
+        precision=lax.Precision.HIGHEST if x.dtype == jnp.float32
+        else None)
+    inv_freq = theta ** (-jnp.asarray(pair, jnp.float32) * (2.0 / width))
     ang = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1 = x[..., :half].astype(jnp.float32)
-    x2 = x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
+    turned = part.astype(jnp.float32) * jnp.cos(ang) \
+        + partner.astype(jnp.float32) * jnp.sin(ang)
+    pieces = [x[..., :start], turned.astype(x.dtype),
+              x[..., start + width:]]
+    return jnp.concatenate([p for p in pieces if p.shape[-1]], axis=-1)
 
 
 @register("group_norm", ["X", "Scale", "Bias"], ["Y", "Mean", "Variance"])

@@ -43,9 +43,9 @@ def blk(n: int, target: int = 128) -> int:
 
 def count_lowering(name: str, by: float = 1.0) -> None:
     """One bump of the counter ``name`` each time a site is LOWERED
-    (trace time, so nothing in a step): ``sdpa_lowering.<path>`` and
-    ``moe_lowering.<path>`` say, in ``counter_values()``, ``/metrics``
-    and ``obs_dump``, which lowering a program's sites took. The
+    (trace time, so nothing in a step): ``sdpa_lowering.<path>``,
+    ``moe_lowering``, ``kda_lowering`` and ``rotary_lowering.<path>``
+    say, in ``counter_values()``, which lowering a site took. The
     abstract pass that infers shapes while the graph is built is left
     out. ``by`` 0 lists the counter without moving it."""
     from ... import framework, profiler

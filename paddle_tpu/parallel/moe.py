@@ -266,24 +266,24 @@ def balance_bias_update(bias, load, coeff):
     return bias - jnp.mean(bias)
 
 
-def _chunk_rows(n_tokens, capacity):
+def _chunk_rows(n_tokens, capacity, n_held=8):
     """Rows a chunk of the sorted buffer, from the shapes alone: three
-    for every two tokens of the step (the whole buffer where that is
-    less), in whole row tiles.
-
-    Swept on the v5e at the Trinity-Mini cell's shapes (T = 8,192
-    tokens, 65,536 rows, 8 experts of 2048 x 1024; PERF.md section 6,
-    PR 31). A row of slack in a chunk costs 0.18 us of passes, a real
-    row 0.45 us, and a chunk of its own about 1 ms more (the held
+    for every two tokens of the step, or an eighth of the tokens for
+    every expert held where that is more (the whole buffer where it is
+    less), in whole row tiles. Swept on the v5e (PERF.md section 6). A
+    row of slack in a chunk costs 0.15-0.18 us of passes, a real row
+    0.2-0.45 us, and a chunk of its own about 1 ms more (the held
     matrices fetched again, the float32 sums of their gradients read
     and written), so a chunk should take a layer's load whole nearly
-    always and not be much longer: the cell's layers hold 0.7 to 1.5
-    rows a token, and a step read 255.0 / 258.1 ms at 8,192 rows a
-    chunk (two chunks in half the layers), 251.7 / 252.8 at 12,288 and
-    255.6 / 256.6 at 16,384 (two seeds); one layer forward and
-    backward at 7,100 rows 9.3, 7.6 and 8.3 ms at 4,096, 8,192 and
-    12,288, and 12.4, 12.1 and 8.6 at 8,320 rows."""
-    rows = min(n_tokens + n_tokens // 2, capacity)
+    always and not be much longer. PR 31, T = 8,192 tokens and 8
+    experts of 2048 x 1024 holding 0.7 to 1.5 rows a token: a step
+    255.0 / 258.1 ms at 8,192 rows a chunk (two chunks in half the
+    layers), 251.7 / 252.8 at 12,288, 255.6 / 256.6 at 16,384. PR 34,
+    16 experts of 2048 x 768 holding 8,900 to 10,000 rows a layer with
+    a tail past 12,288: 8 steps 2.808 / 2.832 s at 12,288 (two seeds:
+    the pace moved with the seed's second chunks), 2.828 / 2.835 at
+    16,384, 2.844 / 2.851 at 18,432, 2.895 / 2.902 at 24,576."""
+    rows = min(n_tokens * max(12, n_held) // 8, capacity)
     return -(-rows // gmm_lib.TILE_M) * gmm_lib.TILE_M
 
 
@@ -412,7 +412,7 @@ def held_experts_ffn(x, sel, weight, w_gate, w_up, w_down, *,
     n_held = w_gate.shape[0]
     n_assign = T * k
     cap = int(row_capacity) or n_assign
-    chunk = _chunk_rows(T, cap)
+    chunk = _chunk_rows(T, cap, n_held)
     padded = -(-cap // chunk) * chunk           # whole chunks
     local = sel.reshape(-1) - first_held
     held = jnp.logical_and(local >= 0, local < n_held)

@@ -129,6 +129,12 @@ def test_chunk_rows_are_whole_tiles_from_the_shapes_alone():
         assert rows > 0 and rows % gmm.TILE_M == 0
         # never more than the buffer rounded to whole tiles
         assert rows <= -(-cap // gmm.TILE_M) * gmm.TILE_M
+    # the benchmark's three expert layers: 8 held under top-8 (two
+    # cells) keep the chunk they were swept at, 16 held under top-6
+    # take an eighth of the tokens for every expert held
+    assert moe_lib._chunk_rows(8192, 65536, 8) == 12288
+    assert moe_lib._chunk_rows(8192, 49152, 16) == 16384
+    assert moe_lib._chunk_rows(8192, 49152, 12) == 12288
 
 
 def layer_and_pullback(o, row_capacity=0):

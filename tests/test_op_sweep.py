@@ -384,22 +384,39 @@ spec("rms_norm", {"X": sgn((3, 4), 131), "Scale": u((4,), 132)},
          * ins["Scale"]])
 
 
-def _rotary_ref(ins):
-    """Rotate-half over the whole head: pair (i, i + Dh/2) of row s
-    turns by s * theta^(-2i/Dh)."""
-    x = ins["X"]
-    s, dh = x.shape[-2], x.shape[-1]
-    ang = np.arange(s)[:, None] \
-        * 100.0 ** (-np.arange(dh // 2) * 2.0 / dh)[None, :]
-    x1, x2 = x[..., :dh // 2], x[..., dh // 2:]
-    return [np.concatenate([x1 * np.cos(ang) - x2 * np.sin(ang),
-                            x2 * np.cos(ang) + x1 * np.sin(ang)],
-                           -1).astype(np.float32)]
+def _rotary_ref(start=0, width=None, interleaved=False):
+    """Lanes [start, start + width) of the head (all by default): pair
+    i turns by s * theta^(-2i/width) at row s; its lanes are (i, i +
+    width/2) of the part, or (2i, 2i + 1) interleaved."""
+    def ref(ins):
+        x = ins["X"]
+        w = x.shape[-1] - start if width is None else width
+        ang = np.arange(x.shape[-2])[:, None] \
+            * 100.0 ** (-np.arange(w // 2) * 2.0 / w)[None, :]
+        part = x[..., start:start + w]
+        first, second = (slice(0, None, 2), slice(1, None, 2)) \
+            if interleaved else (slice(0, w // 2), slice(w // 2, None))
+        x1, x2 = part[..., first], part[..., second]
+        out = x.copy()
+        out[..., start:start + w][..., first] = \
+            x1 * np.cos(ang) - x2 * np.sin(ang)
+        out[..., start:start + w][..., second] = \
+            x2 * np.cos(ang) + x1 * np.sin(ang)
+        return [out.astype(np.float32)]
+    return ref
 
 
 spec("rotary_embedding", {"X": sgn((1, 2, 5, 4), 133)},
-     {"theta": 100.0}, ref=_rotary_ref,
+     {"theta": 100.0}, ref=_rotary_ref(),
      loss_weight=_rs(203).uniform(0.5, 1.5, (1, 2, 5, 4)))
+# a rotary part beside plain lanes, and the interleaved pair layout
+for start_, width_, inter_ in [(0, None, True), (2, 4, True),
+                               (4, None, False)]:
+    spec("rotary_embedding", {"X": sgn((1, 2, 5, 8), 135)},
+         {"theta": 100.0, "start": start_, "width": width_ or 0,
+          "interleaved": inter_},
+         ref=_rotary_ref(start_, width_, inter_),
+         loss_weight=_rs(205).uniform(0.5, 1.5, (1, 2, 5, 8)))
 spec("gated_rms_norm", {"X": sgn((2, 3, 8), 134),
                         "Gate": sgn((2, 3, 8), 136),
                         "Scale": u((4,), 138)},

@@ -2,7 +2,8 @@
 transformer-base's and BERT-base's sites, the blocked flash kernels
 and the grouped products of the Trinity-Mini cell, the blocked flash
 kernels at Kimi-Linear's latent-attention widths and its KDA core's
-three kernels), COMPILED for a v5e
+three kernels, the grouped products, the expert layer and the rotary
+part at the Kanana-2 cell's), COMPILED for a v5e
 that is described and not attached, at the cells' own shapes: what the
 chip's compiler would refuse (a tile that does not align, more fast
 memory than a kernel may use) is refused here, at no chip time.
@@ -176,37 +177,44 @@ def test_kda_kernels_at_the_cells_widths(one_chip, monkeypatch):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 29
 
 
-@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])
+@pytest.mark.parametrize("e,k,n", [(8, 2048, 1024), (8, 1024, 2048),
+                                   (16, 2048, 768), (16, 768, 2048)])
 def test_grouped_products_at_the_cells_widths(one_chip, for_the_chip,
-                                              k, n):
-    """8 held experts, one chunk of the cell's row buffer: gmm forward,
-    gmm for the rows' gradient, tgmm adding the matrices' to a float32
-    sum (its output block and the sum's, both float32, in VMEM)."""
+                                              e, k, n):
+    """8 held experts of width 1024 (Trinity-Mini's and Kimi-Linear's
+    cells) and 16 of width 768 (Kanana-2's), one chunk of the cell's
+    row buffer (12,288 and 16,384 rows): gmm forward, gmm for the rows' gradient, tgmm adding
+    the matrices' to a float32 sum (its output block and the sum's,
+    both float32, in VMEM)."""
     bf = jnp.bfloat16
-    rows = M._chunk_rows(8192, 65536)
+    rows = M._chunk_rows(8192, 8192 * (8 if e == 8 else 6), e)
 
     def product(lhs, rhs, sizes, g):
         out, pull = jax.vjp(
             lambda a, b: G.grouped_matmul(a, b, sizes), lhs, rhs)
         return out, pull(g)
 
-    c = compiled(product, one_chip, ((rows, k), bf), ((8, k, n), bf),
-                 ((8,), jnp.int32), ((rows, n), bf))
+    c = compiled(product, one_chip, ((rows, k), bf), ((e, k, n), bf),
+                 ((e,), jnp.int32), ((rows, n), bf))
     assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
 
 
-def test_held_experts_layer_at_the_cells_widths(one_chip, for_the_chip):
-    """The cell's expert layer as the executor lowers it (forward op,
-    then the op under ``jax.vjp``): three products in the forward
-    chunk loop, eight in the backward one (two made again, three
-    pullbacks of two), and no third loop: the differentiated forward
-    is gone from what the chip would run."""
-    bf, t, d, f, e, k = jnp.bfloat16, 8192, 2048, 1024, 8, 8
+@pytest.mark.parametrize("f,e,k", [(1024, 8, 8), (768, 16, 6)])
+def test_held_experts_layer_at_the_cells_widths(one_chip, for_the_chip,
+                                                f, e, k):
+    """A cell's expert layer as the executor lowers it (forward op,
+    then the op under ``jax.vjp``), at Trinity-Mini's load (8 held of
+    width 1024 under top-8) and at Kanana-2's (16 of width 768 under
+    top-6): three products in the forward chunk loop, eight in the
+    backward one (two made again, three pullbacks of two), and no
+    third loop: the differentiated forward is gone from what the chip
+    would run."""
+    bf, t, d = jnp.bfloat16, 8192, 2048
 
     def layer(x, sel, w, w_gate, w_up, w_down, g):
         def held(x, w, w_gate, w_up, w_down):
             return M.held_experts_ffn(x, sel, w, w_gate, w_up, w_down,
-                                      first_held=8, row_capacity=65536)[0]
+                                      first_held=e, row_capacity=t * k)[0]
         out = held(x, w, w_gate, w_up, w_down)
         return out, jax.vjp(held, x, w, w_gate, w_up, w_down)[1](g)
 
@@ -218,5 +226,29 @@ def test_held_experts_layer_at_the_cells_widths(one_chip, for_the_chip):
     loops = [ln for ln in text.splitlines() if " while(" in ln
              and "f32[%d,%d]" % (t, d) in ln.split(" while(")[0]]
     assert len(loops) == 2
-    # nothing of the buffer's 65,536 rows times a width
+    # nothing of the buffer's t x k rows times a width
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_rotary_part_at_the_mla_site(one_chip):
+    """The decoupled rotary part as ``kanana2_s8k_scan`` runs it: lanes
+    128..191 of 32 query heads and the ONE 64-lane key vector a token,
+    interleaved pairs, 8192 positions, bf16, forward and gradient: the
+    partners come from a product with a signed permutation fused with
+    the turn, so nothing wider than the operands is kept (a roll of
+    the lanes kept 770 MB of float32 slices here)."""
+    from paddle_tpu.ops import nn_ops
+    bf = jnp.bfloat16
+    q, k = (1, 32, 8192, 192), (1, 1, 8192, 64)
+
+    def site(q_, k_, gq, gk):
+        out, pull = jax.vjp(
+            lambda a, b: (
+                nn_ops.rotary_embedding(a, theta=1e6, start=128, width=64,
+                                        interleaved=True),
+                nn_ops.rotary_embedding(b, theta=1e6, interleaved=True)),
+            q_, k_)
+        return out, pull((gq, gk))
+
+    c = compiled(site, one_chip, (q, bf), (k, bf), (q, bf), (k, bf))
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 27

@@ -106,7 +106,7 @@ def main():
           "(%.2f%% slower)" % (n, spd, untraced, traced,
                                100.0 * (traced / untraced - 1.0)))
     # which lowering each kind of site took, counted when the step was
-    # traced (sdpa_lowering.*, moe_lowering.*, kda_lowering.*)
+    # traced (sdpa_ / moe_ / kda_ / rotary_lowering.*)
     lowerings = {k: v for k, v in sorted(profiler.counter_values().items())
                  if "_lowering." in k}
     print("lowerings: " + ", ".join("%s %g" % kv
