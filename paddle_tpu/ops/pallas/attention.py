@@ -9,21 +9,26 @@ matrix in HBM in either direction:
 
 - **Forward**: k-blocked online softmax. Running max ``m``, normalizer
   ``l`` and the output accumulator live in VMEM scratch; the softmax
-  statistics ``lse = m + log(l)`` are saved for the backward.
-- **Backward**: two pallas kernels with per-block recompute —
-  ``dq`` (scanning k-blocks) and ``dk/dv`` (scanning q-blocks). Each
-  block recomputes ``p = exp(s - lse)`` from q/k and the saved
-  statistics; only O(seq * head_dim) residuals (out, lse) ever hit HBM.
+  statistics ``lse = m + log(l)`` are saved for the backward, a row
+  of lanes a head.
+- **Backward**: per-block recompute of ``p = exp(s - lse)`` from q/k
+  and the saved statistics; only O(seq * head_dim) residuals (out,
+  lse) ever hit HBM. ONE kernel with one recompute wherever K, V, dK
+  and dV of a kv head fit VMEM (the three 8k benchmark cells), else
+  two, ``dq`` (scanning k-blocks) and ``dk/dv`` (scanning q-blocks).
 - **Dropout** runs in-kernel with the TPU PRNG
   (``pltpu.prng_seed``/``prng_random_bits``), seeded per
   (grid cell, q-block, k-block) so the backward regenerates the exact
   forward mask without storing it.
 - **Causal** masking skips fully-masked k-blocks (roughly halves the
   decoder self-attention work).
-- **Short-sequence batching** (blocked kernels): each grid cell
-  processes ``G`` (batch, head) rows at once (batched dot_generals
-  over the leading dim). G divides H, so a cell never straddles a
-  batch row and per-BATCH bias blocks stay well-defined.
+- **The blocked kernels' schedule is read off the site's shape** by a
+  VMEM model (``_blocked_schedule``): K and V of a kv head resident
+  for its whole q sweep, the k-blocks walked by a loop inside the
+  body that masks at the band's edges only; a cell holds one head's
+  q-block, or those of the query heads that share a kv head (batched
+  over the leading dim: G divides the group, so a cell never
+  straddles a batch row and per-BATCH bias blocks stay well-defined).
 - **Single-k-block specialization** (``_1k_applicable``: Sk<=512,
   and Sq at most 256 or a multiple of 256, natural tiling): when the
   whole key range fits one block, the online-softmax machinery is
@@ -49,9 +54,10 @@ matrix in HBM in either direction:
   q-blocks, G=6). The argument for it in-model: XLA's fused chain
   pays RNG mask materialization + probs HBM round-trips at every
   attention site (BERT S=512: 136 ms of a 253 ms step, ledger PR 26).
-  Everything else — Sk > 512 (S=1024 self-attention), a ragged Sq —
-  takes the blocked kernels below under ``FLAGS_op_library=pallas``
-  and XLA's chain by default.
+  Everything else — Sk > 512, grouped queries, a window, two widths —
+  takes the blocked kernels below: with no dropout by default (the
+  three 8k cells' 5 + 5 + 1 sites), with dropout under
+  ``FLAGS_op_library=pallas``; a ragged shape keeps XLA's chain.
 - **Two entry layouts, one pair.** The op takes rank 4
   ``[B,H,S,Dh]`` or rank 3 ``[B,S,H*Dh]`` with ``num_heads``; the
   rank of Q is all the lowering looks at. The 1k pair has the rank-3
@@ -74,8 +80,8 @@ kernel dispatch), operators/fused/.
 
 from __future__ import annotations
 
+import collections
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -329,10 +335,12 @@ def scaled_dot_product_attention(q, k, v, bias, *, scale=1.0,
 
 def _blocked_applicable(Sq, Sk):
     """The envelope in which a site with no dropout takes the blocked
-    kernels by itself: keys past the single-k-block envelope, whole
-    256 x 512 tiles."""
-    return Sk > 512 and Sk % _BLK_K_TARGET == 0 \
-        and Sq % _BLK_Q_TARGET == 0
+    kernels by itself: keys past the single-k-block envelope, in the
+    tiles the schedule takes to the chip (_blocked_tiles): whole
+    128-lane groups to a q-block, whose statistics travel a row of
+    lanes, and to a k-block."""
+    _, blk_q, blk_k = _blocked_tiles(1, Sq, Sk)
+    return Sk > 512 and blk_q % 128 == 0 and blk_k % 128 == 0
 
 
 def _count_lowering(path, by=1.0):
@@ -351,9 +359,9 @@ def _count_lowering(path, by=1.0):
 # online-softmax machinery is pure overhead: no m/l scratch, no alpha
 # rescales, no lane-replicated statistics round-tripping through HBM.
 # The backward is ONE kernel computing dq/dk/dv together from a single
-# exp recompute (the blocked path needs two kernels = two recomputes),
-# with lse and delta = rowsum(dO*O) derived in-kernel so the only HBM
-# residual is the forward output itself.
+# exp recompute (as the blocked path's is wherever a kv head's K, V,
+# dK and dV fit VMEM), with lse and delta = rowsum(dO*O) derived
+# in-kernel so the only HBM residual is the forward output itself.
 #
 # Layout: q / o / do / dq are [B, Sq, H*Dh] and k / v / dk / dv
 # [B, Sk, H*Dh], as the projections produce and consume them: no
@@ -585,16 +593,6 @@ _1K_TEMP_BYTES = 24
 _1K_VMEM_BUDGET = 15 << 20
 _1K_MAX_G = 8
 
-# Blocked-path tile targets, env-tunable for on-chip sweeps
-# (tools/blocked_sweep.py): PALLAS_BLK_Q / PALLAS_BLK_K. The blocked
-# path runs only under FLAGS_op_library=pallas and only outside
-# _1k_applicable (Sk > 512, or a ragged Sq): no default program
-# dispatches it, so any change must be chip-measured in-model at
-# S>=1024 first.
-_BLK_Q_TARGET = int(os.environ.get("PALLAS_BLK_Q", "256"))
-_BLK_K_TARGET = int(os.environ.get("PALLAS_BLK_K", "512"))
-
-
 def _1k_cell_bytes(G, itemsize, Sq, Sk, Dh, n_sq_ops, n_sk_ops,
                    bias_itemsize=0, per_head=False, accumulates=False):
     """Modeled VMEM of one grid cell of G heads; ``bias_itemsize`` 0
@@ -644,28 +642,6 @@ def _1k_fwd_G(H, itemsize, rate, Sq, Sk, Dh, bias_itemsize=0,
                          per_head)
     return _1k_G(H, Dh, itemsize, Sq, Sk, Dh, 2, 2, bias_itemsize,
                  per_head)
-
-
-def _blocked_G(H):
-    """(batch, head) rows per grid cell of the blocked kernels — ONE
-    choice shared by forward and both backward kernels.
-
-    The in-kernel dropout mask is seeded per grid CELL and q-/k-block
-    (_dropout_keep), so the (batch, head) -> cell mapping and the
-    block sizes MUST be identical in the kernels that generate and
-    regenerate it: a fwd G=8 / bwd G=4 split silently regenerates
-    different masks for every head the two groupings assign to
-    different cells. (The single-k-block pair keeps the same
-    invariant through _1k_fwd_G / _1k_bwd_G / _1k_blk_q.)
-
-    2 is what Mosaic accepts on a v5e at the 256x512 tiles (chip runs,
-    PR 21): G=8 ran out of the 16 MB scoped VMEM in the f32 forward,
-    the biased bf16 forward and the bf16 backward (Dh<=64 blocks are
-    lane-padded to 128, the statistics ride 128 lanes wide, and the
-    [G, blk_q, blk_k] f32 score temporaries sit beside them); G=2
-    compiled and matched the reference at f32, of which bf16 is the
-    smaller case in every term."""
-    return blk(H, 2)
 
 
 def _seed_smem(seed_f, G):
@@ -791,26 +767,209 @@ def _flash_bwd_1k(q, k, v, bias, seed_f, o, g, H, scale, rate, causal):
 
 # ---------------------------------------------------------------------------
 # blocked kernels: any Sk, causal k-block skipping, a sliding window,
-# grouped queries
+# grouped queries, a qk width beside a v width
 # ---------------------------------------------------------------------------
 #
-# Geometry shared by the three kernels. A grid cell holds G query heads
-# and the kv rows they read: G kv heads where every query head has its
-# own (group == 1), ONE where ``group`` query heads share a kv head
-# (G divides group, so a cell never straddles two kv heads; the kv
-# block is indexed by ``cell // (group // G)`` and never repeated in
-# HBM). In the shared case the G heads' query rows fold into the
-# matmuls' M dimension ([G*blk_q, Dh] x [Dh, blk_k]), and dk / dv sum
-# over them in the same product.
+# A grid cell holds G query heads and the kv rows they read: G kv heads
+# where every query head has its own (group == 1), ONE where ``group``
+# query heads share a kv head (G divides group, so a cell never
+# straddles two kv heads; the kv block is indexed by
+# ``cell // (group // G)`` and never repeated in HBM). In the shared
+# case the G heads' query rows fold into the matmuls' M dimension
+# ([G*blk_q, Dh] x [Dh, blk_k]), and dk / dv sum over them in the same
+# product.
+#
+# The schedule (_blocked_geometry: ONE function of the site's shape for
+# the forward and every backward kernel, since the in-kernel dropout
+# mask is seeded by (cell, q-block, k-block) of the [blk_q, blk_k]
+# score tile):
+#
+# - A grid step holds one q-block of blk_q rows against a MAJOR block
+#   of keys, ``k_major`` rows of K and V, and walks the blk_k-row
+#   k-blocks of it that the q-block's band touches in a loop INSIDE the
+#   body (``_walk``): a k-block outside the band is no grid step, and a
+#   loop step costs no pipeline bookkeeping. Where K and V of the
+#   cell's kv head(s) fit the VMEM model the major block is the whole
+#   key range (``flash_schedule.kv_resident``): its block index does
+#   not move across the q-blocks of a cell (nor across the cells that
+#   share a kv head), so K and V are fetched once a head, where a
+#   256-row q-block re-read every k-block up to its diagonal, 17 times
+#   a head at 8,192 keys. Where they do not fit, the largest major
+#   block that does is streamed along a third grid axis, clamped to the
+#   band as before (``kv_streamed``).
+# - The walk is cut at the band's edges: the k-blocks wholly inside the
+#   band run a body without ``_causal_mask`` (two iotas, a compare, two
+#   with a window, and a select a score); only the blocks that the
+#   diagonal or, with a window, the trailing edge crosses keep it.
+# - The statistics enter and leave as ``[BH, 1, S]`` float32, a row of
+#   lanes a head, and are spread to the 128-lane columns the score
+#   tile reads them in by one transpose a q-block in VMEM
+#   (``_columns``); no ``[BH, S, 128]`` array exists around the kernels.
+# - The backward is ONE kernel with one recompute of s, p, dp and ds
+#   wherever K, V, dK, dV of a kv head and the float32 sums of dK and
+#   dV fit (``flash_backward.fused``): it walks the q-blocks of the
+#   kv head's query heads, writes dq a block and adds into the
+#   resident sums (832 lanes of products a pair at 192 / 128 and one
+#   exp, where two kernels need 1,152 and two). Where they do not fit
+#   (``split``), a dq kernel scheduled like the forward and a dk / dv
+#   kernel that is its mirror image: a k-block and its sums resident
+#   against a major block of q, dO and the statistics, the q-blocks
+#   walked inside.
 #
 # Which k-blocks a q-block reads: causal, the blocks up to the one
 # holding its last row; with a window, from the block holding key
-# ``first row - window + 1`` on. The k axis of the grid is as long as
-# the most any q-block reads (``_n_steps``), step ``kk`` of q-block
-# ``j`` is k-block ``k_lo(j) + kk``, and the index map clamps it to
-# ``k_hi(j)``: a block outside the band is neither computed (pl.when)
-# nor fetched (an unchanged block index is not fetched again). The
-# dk / dv kernel walks the q-blocks of a k-block the same way.
+# ``first row - window + 1`` on (``_band``, at the tile's and at the
+# major block's size alike).
+
+# The VMEM model of the blocked kernels: what a grid step holds, in the
+# (sublane, 128-lane) tiles VMEM keeps it in.
+#   - streamed blocks, double-buffered: q, o / dO, dq rows of blk_q;
+#     the major block of K and V (and dK, dV where the backward is
+#     fused) at ``_lanes`` of the two widths (192 lanes fill 256);
+#     the statistics, a row of lanes a head (8 sublanes a row);
+#   - scratch, resident once: the float32 accumulators, the lane-wide
+#     m / l or lse / delta columns, fused dK / dV sums of the whole key
+#     range;
+#   - the [G, blk_q, blk_k] score temporaries of ONE loop step
+#     (s, p, dp, ds in float32 and the two casts: 24 bytes a score in
+#     the backward, 16 in the forward, the generator's bits of a
+#     dropout site among them; twice for float32 operands, whose exact
+#     products split each into bf16 parts);
+#   - a bias block beside the scores' in every schedule: blk_q rows of
+#     the major block's keys, double-buffered, and its float32 addend.
+# The kernels ask Mosaic for _BLOCKED_VMEM_LIMIT (of the v5e's 128 MiB;
+# the default scoped limit is 16 MB) and the model plans with
+# _BLOCKED_VMEM_BUDGET of it: the rest is the compiler's own scratch
+# and what the model does not see. tests/test_pallas_vmem.py replays
+# the model at the three 8k cells' sites and at a 32k-key corner and
+# pins each one's schedule; tests/test_tpu_aot_kernels.py has the
+# chip's compiler accept them.
+_BLOCKED_VMEM_LIMIT = 100 << 20
+_BLOCKED_VMEM_BUDGET = 80 << 20
+_BLOCKED_FWD_TEMP_BYTES = 16
+_BLOCKED_BWD_TEMP_BYTES = 24
+# Rows of scores a loop step holds (G x blk_q) and keys a k-block: the
+# tiles the chip ran fastest at the three cells' sites, forward and
+# backward together (tools/blocked_sweep.py; PERF.md section 5, PR 35):
+# one head's 512 rows where every query head has its own kv head
+# (256 or 1,024 rows 3-5% slower, 256 or 1,024 keys 4-16%), four
+# heads' 256 where they share one (a k-block is then the matrix unit's
+# stationary operand for twice the rows: 3-5% faster than two heads').
+_BLOCKED_ROWS = 512
+_BLOCKED_ROWS_SHARED = 1024
+_BLOCKED_BLK_K = 512
+
+_Schedule = collections.namedtuple("_Schedule", [
+    "G", "gk", "reps",          # q heads, kv heads a cell; cells a kv head
+    "blk_q", "blk_k",           # the score tile of a loop step
+    "k_major",                  # keys a forward / dq grid step holds
+    "fused",                    # one backward kernel
+    "q_major",                  # query rows a dk / dv grid step holds
+    "kv_resident"])             # k_major is the whole key range
+
+
+def _tile(n, target):
+    """The largest divisor of ``n`` that is whole 128-lane groups and at
+    most ``target``; of a ragged ``n`` (which the envelope keeps from
+    the chip), common.blk's."""
+    for b in range(min(n, target) // 128 * 128, 0, -128):
+        if n % b == 0:
+            return b
+    return blk(n, target)
+
+
+def _blocked_tiles(group, Sq, Sk):
+    """(G, blk_q, blk_k): one head's _BLOCKED_ROWS rows of scores to a
+    loop step where every query head has its own kv head; where
+    ``group`` of them share one, 256 rows of as many as make
+    _BLOCKED_ROWS_SHARED."""
+    blk_k = _tile(Sk, _BLOCKED_BLK_K)
+    if group == 1:
+        return 1, _tile(Sq, _BLOCKED_ROWS), blk_k
+    blk_q = _tile(Sq, 256)
+    return blk(group, max(1, _BLOCKED_ROWS_SHARED // blk_q)), blk_q, blk_k
+
+
+def _lanes(d):
+    return -(-d // 128) * 128
+
+
+def _blocked_bytes(kernel, G, gk, blk_q, blk_k, major, Sk, Dh, Dv,
+                   itemsize, bias_itemsize=0, per_head=False):
+    """Modeled VMEM of one grid step of ``kernel``: "fwd", "dq" (each
+    against ``major`` keys), "dkv" (a k-block against ``major`` query
+    rows) or "fused" (the whole key range, ``major`` is Sk)."""
+    dh, dv = _lanes(Dh), _lanes(Dv)
+    wide = 2 if itemsize > 2 else 1
+    stat = 2 * G * 8 * 4                 # a double-buffered row, a lane
+    col = G * blk_q * 128 * 4            # a lane-wide column scratch
+    temps = G * blk_q * blk_k * wide * (
+        _BLOCKED_FWD_TEMP_BYTES if kernel == "fwd"
+        else _BLOCKED_BWD_TEMP_BYTES)
+    slabs = G if per_head else 1
+    if kernel == "dkv":
+        total = 2 * G * major * (dh + dv) * itemsize       # q, dO
+        total += 2 * stat * major                          # lse, delta
+        total += 2 * 2 * gk * blk_k * (dh + dv) * itemsize  # k v dk dv
+        total += gk * blk_k * (dh + dv) * 4 + 2 * col
+        bias = 2 * slabs * major * blk_k * bias_itemsize
+    else:
+        total = 2 * G * blk_q * (dh + dv) * itemsize       # q, o / dO
+        total += 2 * gk * major * (dh + dv) * itemsize     # k, v
+        total += 2 * col
+        if kernel == "fwd":
+            total += stat * blk_q + G * blk_q * dv * 4     # lse; acc
+        else:
+            total += 2 * stat * blk_q                      # lse, delta
+            total += 2 * G * blk_q * dh * itemsize + G * blk_q * dh * 4
+        if kernel == "fused":
+            total += 2 * gk * Sk * (dh + dv) * itemsize    # dk, dv
+            total += gk * Sk * (dh + dv) * 4               # their sums
+        bias = 2 * slabs * blk_q * major * bias_itemsize
+    if bias_itemsize:
+        total += bias + G * blk_q * blk_k * 4
+    return total + temps
+
+
+def _largest_major(n, tile, fits):
+    """The most rows (whole tiles, a divisor of ``n``) that ``fits``;
+    one tile where nothing does."""
+    for parts in range(1, n // tile + 1):
+        if (n // tile) % parts == 0 and fits(n // parts):
+            return n // parts
+    return tile
+
+
+def _blocked_schedule(H, Hkv, Sq, Sk, Dh, Dv, itemsize, bias_itemsize=0,
+                      per_head=False):
+    """The schedule of a site, read off its shape alone: key length,
+    the two widths, query heads a kv head, operand and bias types."""
+    group = H // Hkv
+    G, blk_q, blk_k = _blocked_tiles(group, Sq, Sk)
+    gk = G if group == 1 else 1
+
+    def fits(kernel):
+        return lambda major: _blocked_bytes(
+            kernel, G, gk, blk_q, blk_k, major, Sk, Dh, Dv, itemsize,
+            bias_itemsize, per_head) <= _BLOCKED_VMEM_BUDGET
+
+    # the dq kernel holds more beside the major block than the forward:
+    # one size that both fit
+    k_major = _largest_major(Sk, blk_k, lambda m: fits("fwd")(m)
+                             and fits("dq")(m))
+    return _Schedule(
+        G, gk, group // G if group > 1 else 1, blk_q, blk_k, k_major,
+        fits("fused")(Sk), _largest_major(Sq, blk_q, fits("dkv")),
+        k_major == Sk)
+
+
+def _blocked_geometry(q, k, v, bias=None, per_head=False):
+    """The _Schedule of q [B,H,Sq,Dh], k [B,Hkv,Sk,Dh], v [B,Hkv,Sk,Dv]
+    and a bias as _prep_bias left it."""
+    return _blocked_schedule(
+        q.shape[1], k.shape[1], q.shape[2], k.shape[2], q.shape[3],
+        v.shape[3], q.dtype.itemsize,
+        bias.dtype.itemsize if bias is not None else 0, per_head)
 
 
 def _band(blk_q, blk_k, n_q, n_k, causal, window):
@@ -849,26 +1008,87 @@ def _n_steps(blk_a, blk_b, n_b, window):
     return min(n_b, (blk_a + window - 2) // blk_b + 2)
 
 
+def _inside(blk_q, blk_k, causal, window):
+    """(first, end) of the k-blocks wholly inside q-block j's band, and
+    of the q-blocks wholly inside k-block kk's: every score of such a
+    tile is kept, so it needs no mask. ``None`` where the band has no
+    edge on that side."""
+    def k_first(j):             # past the window's trailing edge
+        return (jnp.maximum(j * blk_q + blk_q - 1 - window, -1)
+                + blk_k) // blk_k
+
+    def k_end(j):               # before the diagonal
+        return (j * blk_q + 1) // blk_k
+
+    def j_first(kk):            # past the diagonal
+        return (kk * blk_k + blk_k + blk_q - 2) // blk_q
+
+    def j_end(kk):              # before the window's trailing edge
+        return (jnp.maximum(window + kk * blk_k - blk_q, -1)
+                + blk_q) // blk_q
+
+    return (k_first if window else None, k_end if causal else None,
+            j_first if causal else None, j_end if window else None)
+
+
+def _walk(lo, hi, first_in, end_in, step):
+    """``step(t, masked)`` for the blocks lo..hi of a band, in up to
+    three loops: the edge blocks before ``first_in`` masked, the blocks
+    inside bare, the edge blocks from ``end_in`` on masked; an edge the
+    band does not have (``None``) is no loop. The loops are
+    ``fori_loop``s, not unrolled: one body each in the Mosaic text."""
+    stop = hi + 1
+    a = lo if first_in is None else jnp.clip(first_in, lo, stop)
+    b = stop if end_in is None else jnp.clip(end_in, a, stop)
+
+    def loop(start, end, masked):
+        def body(t, carry):
+            step(t, masked)
+            return carry
+        lax.fori_loop(start, end, body, 0)
+
+    if first_in is not None:
+        loop(lo, a, True)
+    loop(a, b, False)
+    if end_in is not None:
+        loop(b, stop, True)
+
+
+def _rows(ref, start, size):
+    """``size`` rows of a resident block from row ``start`` (a multiple
+    of ``size``), every cell and lane of it."""
+    return ref[:, pl.ds(start, size), :]
+
+
+def _columns(row_ref, col_ref, start=0):
+    """Spread a statistic that arrives a row of lanes a head,
+    ``row_ref`` [G, 1, n], to the lane-wide columns ``col_ref``
+    [G, blk_q, 128] the score tile reads (rows ``start`` on)."""
+    blk_q = col_ref.shape[1]
+    for g in range(col_ref.shape[0]):
+        row = row_ref[g, :, pl.ds(start, blk_q)]            # [1, blk_q]
+        col_ref[g] = jnp.transpose(
+            jnp.broadcast_to(row, (128, blk_q)))
+
+
 def _qk(q, k):
     """[G,bq,Dh] x [Gk,bk,Dh] -> [G,bq,bk] float32; Gk is G or 1."""
-    if k.shape[0] == q.shape[0]:
+    if k.shape[0] == q.shape[0] and k.shape[0] > 1:
         return lax.dot_general(q, k, _QK,
                                preferred_element_type=jnp.float32)
     G, bq, dh = q.shape
-    s = lax.dot_general(q.reshape(G * bq, dh), k[0],
-                        (((1,), (1,)), ((), ())),
+    s = lax.dot_general(q.reshape(G * bq, dh), k[0], _NT,
                         preferred_element_type=jnp.float32)
     return s.reshape(G, bq, s.shape[-1])
 
 
 def _pv(p, v):
     """[G,bq,bk] x [Gk,bk,Dh] -> [G,bq,Dh] float32."""
-    if v.shape[0] == p.shape[0]:
+    if v.shape[0] == p.shape[0] and v.shape[0] > 1:
         return lax.dot_general(p, v, _PV,
                                preferred_element_type=jnp.float32)
     G, bq, bk = p.shape
-    o = lax.dot_general(p.reshape(G * bq, bk), v[0],
-                        (((1,), (0,)), ((), ())),
+    o = lax.dot_general(p.reshape(G * bq, bk), v[0], _NN,
                         preferred_element_type=jnp.float32)
     return o.reshape(G, bq, o.shape[-1])
 
@@ -877,62 +1097,125 @@ def _tt(p, x, gk):
     """[G,bq,bk] x [G,bq,Dh] -> [gk,bk,Dh] float32: per row where gk
     is G, summed over the G rows where gk is 1."""
     G, bq, bk = p.shape
-    if gk == G:
+    if gk == G and G > 1:
         return lax.dot_general(p, x, _TT,
                                preferred_element_type=jnp.float32)
     return lax.dot_general(p.reshape(G * bq, bk),
-                           x.reshape(G * bq, x.shape[-1]),
-                           (((0,), (0,)), ((), ())),
+                           x.reshape(G * bq, x.shape[-1]), _TN,
                            preferred_element_type=jnp.float32)[None]
 
 
+def _scores(q, k, b, j, kk, *, scale, blk_q, blk_k, window, masked):
+    """One tile's float32 scores: scaled, the bias added, and masked
+    where the band's edge crosses the tile."""
+    s = _qk(q, k) * scale                               # [G, bq, bk]
+    if b is not None:
+        # per-head: [G,bq,bk]; per-batch: [1,bq,bk] broadcasts over G
+        s = s + b.astype(jnp.float32)
+    if masked:
+        s = _causal_mask(s, j, kk, blk_q, blk_k, window)
+    return s
+
+
+def _bias_tile(b_ref, rows, cols):
+    """The bias block's [gb, rows, cols] tile; ``rows`` and ``cols``
+    are slices of the block's last two axes."""
+    return None if b_ref is None else b_ref[:, 0, rows, cols]
+
+
+def _k_walk(j, major, *, blk_q, blk_k, k_major, n_q, n_k, causal,
+            window):
+    """(lo, hi, first_in, end_in) of the k-blocks that q-block ``j``
+    walks in major block ``major`` of its keys."""
+    per = k_major // blk_k
+    k_lo, k_hi, _, _ = _band(blk_q, blk_k, n_q, n_k, causal, window)
+    k_first, k_end, _, _ = _inside(blk_q, blk_k, causal, window)
+    return (jnp.maximum(k_lo(j), major * per),
+            jnp.minimum(k_hi(j), major * per + per - 1),
+            k_first and k_first(j), k_end and k_end(j))
+
+
+def _lane_tile(x, n):
+    """``x`` [G, rows, 128], every lane of a row alike, as [G, rows, n]:
+    whole copies side by side where n is whole lane groups (no lane is
+    moved), one lane spread otherwise."""
+    if n % 128 == 0:
+        return x if n == 128 else jnp.concatenate([x] * (n // 128), -1)
+    return jnp.broadcast_to(x[..., :1], x.shape[:-1] + (n,))
+
+
+def _lane_fold(p):
+    """[G, rows, n] -> [G, rows, 128] whose lanes sum to the rows' sums:
+    the n / 128 lane groups added onto each other, vector adds that
+    move no lane; of a ragged n, the rows' sums in lane 0."""
+    n = p.shape[-1]
+    if n % 128 == 0:
+        return functools.reduce(
+            lambda a, b: a + b,
+            [p[..., g:g + 128] for g in range(0, n, 128)])
+    lane = lax.broadcasted_iota(jnp.int32, p.shape[:-1] + (128,), 2)
+    return jnp.where(lane == 0, jnp.sum(p, -1, keepdims=True), 0.0)
+
+
 def _fwd_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, o_ref, lse_ref,
-                acc_ref, m_ref, l_ref, *, scale, blk_q, blk_k, n_q,
-                n_k, n_steps, rate, causal, window):
+                acc_ref, m_ref, l_ref, *, scale, blk_q, blk_k, k_major,
+                n_q, n_k, n_steps, rate, causal, window):
+    """m rides lane-replicated [G, blk_q, 128] and meets the scores by
+    whole copies (_lane_tile); l rides as 128 partial sums a row
+    (_lane_fold) that alpha, lane-replicated, rescales as it would
+    their sum, and is summed over the lanes once a q-block. So a step
+    reduces over the lanes once (its max) and spreads one column.
+    Spreading m, l and alpha from one lane each, every step, cost more
+    than the step's products (13.1 -> 7.3 ms a call at 32 x 8,192 x
+    192 / 128: my chip runs, PR 35, calls 1 and 2)."""
     i = pl.program_id(0)
     j = pl.program_id(1)
-    step = pl.program_id(2)
-    k_lo, k_hi, _, _ = _band(blk_q, blk_k, n_q, n_k, causal, window)
-    kk = k_lo(j) + step
+    st = pl.program_id(2)
+    K_lo, _, _, _ = _band(blk_q, k_major, n_q, n_k * blk_k // k_major,
+                          causal, window)
+    major = K_lo(j) + st
 
-    @pl.when(step == 0)
+    @pl.when(st == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(kk <= k_hi(j))
-    def _step():
-        s = _qk(q_ref[...], k_ref[...]) * scale         # [G, bq, bk]
-        if b_ref is not None:
-            # per-head: [G,1,bq,bk] -> [G,bq,bk]; per-batch:
-            # [1,1,bq,bk] broadcasts over G
-            s = s + b_ref[:, 0].astype(jnp.float32)
-        if causal:
-            s = _causal_mask(s, j, kk, blk_q, blk_k, window)
-        m_prev = m_ref[..., :1]                         # [G, bq, 1]
-        l_prev = l_ref[..., :1]
+    def step(kk, masked):
+        at = pl.multiple_of((kk - major * (k_major // blk_k)) * blk_k,
+                            blk_k)
+        k, v = _rows(k_ref, at, blk_k), _rows(v_ref, at, blk_k)
+        s = _scores(q_ref[...], k,
+                    _bias_tile(b_ref, slice(None), pl.ds(at, blk_k)),
+                    j, kk, scale=scale, blk_q=blk_q, blk_k=blk_k,
+                    window=window, masked=masked)
+        m_prev = m_ref[...]                             # [G, bq, 128]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, -1, keepdims=True)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        p = jnp.exp(s - _lane_tile(m_new, blk_k))
+        l_ref[...] = alpha * l_ref[...] + _lane_fold(p)
+        m_ref[...] = m_new
         if rate > 0.0:
             keep = _dropout_keep(seed_ref, i, j, kk, n_q, n_k,
                                  p.shape, rate)
             p = jnp.where(keep, p / (1.0 - rate), 0.0)
-        acc_ref[...] = acc_ref[...] * alpha \
-            + _pv(p.astype(v_ref.dtype), v_ref[...])
+        acc_ref[...] = acc_ref[...] * _lane_tile(
+            alpha, acc_ref.shape[-1]) + _pv(p.astype(v.dtype), v)
 
-    @pl.when(step == n_steps - 1)
+    _walk(*_k_walk(j, major, blk_q=blk_q, blk_k=blk_k, k_major=k_major,
+                   n_q=n_q, n_k=n_k, causal=causal, window=window),
+          step)
+
+    @pl.when(st == n_steps - 1)
     def _finish():
-        l_safe = jnp.where(l_ref[...] == 0.0, 1.0, l_ref[...])
-        o_ref[...] = (acc_ref[...] / l_safe[..., :1]).astype(
-            o_ref.dtype)
-        # lane-replicated [G, blk_q, 128] (the TPU min-tile layout);
-        # the wrapper slices lane 0 out for the residual
-        lse_ref[...] = m_ref[...] + jnp.log(l_safe)
+        l = jnp.sum(l_ref[...], -1, keepdims=True)      # [G, bq, 1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        # the columns are lane-replicated [G, blk_q, 128]; what leaves
+        # is one row of lanes a head
+        lse = m_ref[...] + jnp.log(l_safe)
+        for g in range(lse.shape[0]):
+            lse_ref[g] = jnp.transpose(lse[g])[:1]
 
 
 def _prep_bias(bias, B, H, Sq, Sk):
@@ -943,24 +1226,67 @@ def _prep_bias(bias, B, H, Sq, Sk):
     directly — both paths are G-consistent because G divides H."""
     if bias is None:
         return None, False
-    if bias.ndim == 4 and bias.shape[1] == H and H > 1:
+    if _per_head(bias, H):
         return (jnp.broadcast_to(bias, (B, H, Sq, Sk))
                 .reshape(B * H, 1, Sq, Sk)), True
     return jnp.broadcast_to(bias, (B, 1, Sq, Sk)), False
 
 
-def _blocked_geometry(q, k):
-    """(G, gk, reps, blk_q, blk_k, n_q, n_k) of the blocked kernels for
-    q [B,H,Sq,Dh] and k [B,Hkv,Sk,Dh]: G query heads and gk kv heads
-    to a cell, ``reps`` cells of query heads to a kv head."""
-    H, Hkv = q.shape[1], k.shape[1]
-    group = H // Hkv
-    G = _blocked_G(H if group == 1 else group)
-    gk = G if group == 1 else 1
-    blk_q = blk(q.shape[2], _BLK_Q_TARGET)
-    blk_k = blk(k.shape[2], _BLK_K_TARGET)
-    return (G, gk, group // G if group > 1 else 1, blk_q, blk_k,
-            q.shape[2] // blk_q, k.shape[2] // blk_k)
+def _per_head(bias, H):
+    """Whether a bias has a slab for every head."""
+    return bias is not None and bias.ndim == 4 and bias.shape[1] == H \
+        and H > 1
+
+
+def _without_bias(kernel, bias):
+    """``kernel`` with ``None`` for its bias block where there is no
+    bias operand (the fifth: after the seed, q, k and v)."""
+    if bias is not None:
+        return kernel
+    return lambda sr, qr, kr, vr, *rest, **kw: kernel(
+        sr, qr, kr, vr, None, *rest, **kw)
+
+
+def _mosaic(*semantics):
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_BLOCKED_VMEM_LIMIT)
+
+
+def _k_major_specs(sched, q, k, v, bias, per_head, causal, window):
+    """What the forward and the dq kernel share: grid (cells, q-blocks,
+    major blocks of a q-block's band), and the specs and operands of
+    the seed's successors q, k, v and the bias. Returns (grid axes'
+    lengths, specs, operands, the index map of a q-side block)."""
+    B, H, Sq, Dh = q.shape
+    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
+    G, gk, reps, blk_q, _, k_major = sched[:6]
+    hb = H // G                    # cells per batch row
+    n_q, n_major = Sq // blk_q, Sk // k_major
+    K_lo, K_hi, _, _ = _band(blk_q, k_major, n_q, n_major, causal,
+                             window)
+    n_steps = _n_steps(blk_q, k_major, n_major, window)
+
+    def mj(j, st):
+        return jnp.minimum(K_lo(j) + st, K_hi(j))
+
+    def qi(i, j, st):
+        return i, j, 0
+
+    def ki(i, j, st):
+        return i // reps, mj(j, st), 0
+
+    specs = [pl.BlockSpec((G, blk_q, Dh), qi),
+             pl.BlockSpec((gk, k_major, Dh), ki),
+             pl.BlockSpec((gk, k_major, Dv), ki)]
+    args = [q.reshape(B * H, Sq, Dh), k.reshape(B * Hkv, Sk, Dh),
+            v.reshape(B * Hkv, Sk, Dv)]
+    if bias is not None:
+        specs.append(pl.BlockSpec(
+            (G if per_head else 1, 1, blk_q, k_major),
+            lambda i, j, st: (i if per_head else i // hb, 0, j,
+                              mj(j, st))))
+        args.append(bias)
+    return (B * H // G, n_q, n_steps), specs, args, qi
 
 
 # Jitted for what the 1k wrappers are jitted for: the sites of one
@@ -968,131 +1294,171 @@ def _blocked_geometry(q, k):
 # lowers twice (once more under jax.vjp) is one call after XLA's CSE.
 @functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
 def _flash_fwd(q, k, v, bias, seed_f, scale, rate, causal, window=0):
-    B, H, Sq, Dh = q.shape
-    Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
-    BH = B * H
+    """q [B,H,Sq,Dh], k [B,Hkv,Sk,Dh], v [B,Hkv,Sk,Dv] -> out
+    [B,H,Sq,Dv] and the rows' lse [B*H,1,Sq] float32."""
+    B, H, Sq, _ = q.shape
+    Sk, Dv = k.shape[2], v.shape[3]
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
-    G, gk, reps, blk_q, blk_k, n_q, n_k = _blocked_geometry(q, k)
-    hb = H // G                    # cells per batch row
-    q3 = q.reshape(BH, Sq, Dh)
-    k3 = k.reshape(B * Hkv, Sk, Dh)
-    v3 = v.reshape(B * Hkv, Sk, Dv)
-    k_lo, k_hi, _, _ = _band(blk_q, blk_k, n_q, n_k, causal, window)
-    n_steps = _n_steps(blk_q, blk_k, n_k, window)
-    grid = (BH // G, n_q, n_steps)
-    seed = _seed_smem(seed_f, G)
-
-    def kb(j, step):
-        return jnp.minimum(k_lo(j) + step, k_hi(j))
-
-    kv_spec = pl.BlockSpec((gk, blk_k, Dh),
-                           lambda i, j, st: (i // reps, kb(j, st), 0))
-    in_specs = [
-        pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec((G, blk_q, Dh), lambda i, j, st: (i, j, 0)),
-        kv_spec, pl.BlockSpec((gk, blk_k, Dv), kv_spec.index_map),
-    ]
-    args = [seed, q3, k3, v3]
-    if bias is not None:
-        if per_head:
-            bspec = pl.BlockSpec(
-                (G, 1, blk_q, blk_k),
-                lambda i, j, st: (i, 0, j, kb(j, st)))
-        else:
-            bspec = pl.BlockSpec(
-                (1, 1, blk_q, blk_k),
-                lambda i, j, st: (i // hb, 0, j, kb(j, st)))
-        in_specs.append(bspec)
-        args.append(bias)
-        kernel = _fwd_kernel
-    else:
-        kernel = (lambda sr, qr, kr, vr, orf, lr, ar, mr, llr, **kw:
-                  _fwd_kernel(sr, qr, kr, vr, None, orf, lr, ar, mr,
-                              llr, **kw))
+    sched = _blocked_geometry(q, k, v, bias, per_head)
+    G, blk_q, blk_k = sched.G, sched.blk_q, sched.blk_k
+    grid, specs, args, qi = _k_major_specs(sched, q, k, v, bias,
+                                           per_head, causal, window)
 
     out, lse = pl.pallas_call(
-        functools.partial(kernel, scale=scale, blk_q=blk_q,
-                          blk_k=blk_k, n_q=n_q, n_k=n_k,
-                          n_steps=n_steps, rate=rate, causal=causal,
-                          window=window),
-        out_shape=[jax.ShapeDtypeStruct((BH, Sq, Dv), q.dtype),
-                   jax.ShapeDtypeStruct((BH, Sq, 128), jnp.float32)],
+        functools.partial(
+            _without_bias(_fwd_kernel, bias), scale=scale, blk_q=blk_q,
+            blk_k=blk_k, k_major=sched.k_major, n_q=Sq // blk_q,
+            n_k=Sk // blk_k, n_steps=grid[2], rate=rate, causal=causal,
+            window=window),
+        out_shape=[jax.ShapeDtypeStruct((B * H, Sq, Dv), q.dtype),
+                   jax.ShapeDtypeStruct((B * H, 1, Sq), jnp.float32)],
         grid=grid,
-        in_specs=in_specs,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + specs,
         out_specs=[
-            pl.BlockSpec((G, blk_q, Dv), lambda i, j, st: (i, j, 0)),
-            pl.BlockSpec((G, blk_q, 128), lambda i, j, st: (i, j, 0)),
+            pl.BlockSpec((G, blk_q, Dv), qi),
+            pl.BlockSpec((G, 1, blk_q), lambda i, j, st: (i, 0, j)),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, blk_q, Dv), jnp.float32),
             pltpu.VMEM((G, blk_q, 128), jnp.float32),
             pltpu.VMEM((G, blk_q, 128), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_mosaic("parallel", "parallel", "arbitrary"),
         interpret=interpret_mode(),
-    )(*args)
-    return out.reshape(B, H, Sq, Dv), lse[:, :, 0]
+    )(_seed_smem(seed_f, G), *args)
+    return out.reshape(B, H, Sq, Dv), lse
 
 
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
 
-def _recompute_p(q_ref, k_ref, b_ref, lse_ref, *, scale, j, kk, blk_q,
-                 blk_k, causal, window):
-    s = _qk(q_ref[...], k_ref[...]) * scale
-    if b_ref is not None:
-        s = s + b_ref[:, 0].astype(jnp.float32)
-    if causal:
-        s = _causal_mask(s, j, kk, blk_q, blk_k, window)
-    return jnp.exp(s - lse_ref[..., :1])          # [G, blk_q, blk_k]
+def _bwd_tile(seed_ref, i, j, kk, q, k, v, b, do, lse, delta, *, scale,
+              blk_q, blk_k, n_q, n_k, rate, window, masked):
+    """The one recompute of a [G, blk_q, blk_k] tile (``lse`` and
+    ``delta`` as wide, by whole copies of their lane-replicated
+    columns): (pd, ds) in the operands' dtype, pd the probabilities as
+    the forward's PV product saw them (dropped and rescaled under
+    dropout) for dV, ds the scores' gradient for dQ and dK."""
+    s = _scores(q, k, b, j, kk, scale=scale, blk_q=blk_q, blk_k=blk_k,
+                window=window, masked=masked)
+    p = jnp.exp(s - lse)                          # [G, blk_q, blk_k]
+    dp = _qk(do, v)
+    pd = p
+    if rate > 0.0:
+        keep = _dropout_keep(seed_ref, i, j, kk, n_q, n_k, p.shape,
+                             rate)
+        pd = jnp.where(keep, p / (1.0 - rate), 0.0)
+        dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
+    ds = (p * (dp - delta) * scale).astype(q.dtype)
+    return pd.astype(do.dtype), ds
+
+
+def _q_block_backward(seed_ref, i, j, major, q_ref, k_ref, v_ref, b_ref,
+                      do_ref, lse_ref, dl_ref, dq_acc, lse_col, dl_col,
+                      sums, *, k_major, **kw):
+    """One q-block against the k-blocks of its band in the major block
+    ``k_ref`` / ``v_ref`` hold: dq into ``dq_acc`` and, where ``sums``
+    = (dk, dv) float32 sums of the major block's rows, dk and dv into
+    them (the fused kernel)."""
+    blk_k = kw["blk_k"]
+    _columns(lse_ref, lse_col)
+    _columns(dl_ref, dl_col)
+    tile = {n: kw[n] for n in ("scale", "blk_q", "blk_k", "n_q", "n_k",
+                               "rate", "window")}
+
+    def step(kk, masked):
+        at = pl.multiple_of((kk - major * (k_major // blk_k)) * blk_k,
+                            blk_k)
+        rows = pl.ds(at, blk_k)
+        k, v = _rows(k_ref, at, blk_k), _rows(v_ref, at, blk_k)
+        q, do = q_ref[...], do_ref[...]
+        pd, ds = _bwd_tile(
+            seed_ref, i, j, kk, q, k, v,
+            _bias_tile(b_ref, slice(None), rows),
+            do, _lane_tile(lse_col[...], blk_k),
+            _lane_tile(dl_col[...], blk_k), masked=masked, **tile)
+        dq_acc[...] += _pv(ds, k)
+        if sums is not None:
+            dk_acc, dv_acc = sums
+            gk = k.shape[0]
+            dv_acc[:, rows, :] += _tt(pd, do, gk)
+            dk_acc[:, rows, :] += _tt(ds, q, gk)
+
+    _walk(*_k_walk(j, major, blk_q=kw["blk_q"], blk_k=blk_k,
+                   k_major=k_major, n_q=kw["n_q"], n_k=kw["n_k"],
+                   causal=kw["causal"], window=kw["window"]), step)
 
 
 def _dq_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
-               dl_ref, dq_ref, dq_acc, *, scale, blk_q, blk_k, n_q,
-               n_k, n_steps, rate, causal, window):
+               dl_ref, dq_ref, dq_acc, lse_col, dl_col, *, n_steps,
+               **kw):
     i = pl.program_id(0)
     j = pl.program_id(1)
-    step = pl.program_id(2)
-    k_lo, k_hi, _, _ = _band(blk_q, blk_k, n_q, n_k, causal, window)
-    kk = k_lo(j) + step
+    st = pl.program_id(2)
+    K_lo, _, _, _ = _band(
+        kw["blk_q"], kw["k_major"], kw["n_q"],
+        kw["n_k"] * kw["blk_k"] // kw["k_major"], kw["causal"],
+        kw["window"])
 
-    @pl.when(step == 0)
+    @pl.when(st == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(kk <= k_hi(j))
-    def _step():
-        p = _recompute_p(q_ref, k_ref, b_ref, lse_ref, scale=scale,
-                         j=j, kk=kk, blk_q=blk_q, blk_k=blk_k,
-                         causal=causal, window=window)
-        dp = _qk(do_ref[...], v_ref[...])             # [G, bq, bk]
-        if rate > 0.0:
-            keep = _dropout_keep(seed_ref, i, j, kk, n_q, n_k,
-                                 dp.shape, rate)
-            dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
-        delta = dl_ref[..., :1]                       # [G, bq, 1]
-        ds = (p * (dp - delta) * scale).astype(k_ref.dtype)
-        dq_acc[...] += _pv(ds, k_ref[...])
+    _q_block_backward(seed_ref, i, j, K_lo(j) + st, q_ref, k_ref, v_ref,
+                      b_ref, do_ref, lse_ref, dl_ref, dq_acc, lse_col,
+                      dl_col, None, **kw)
 
-    @pl.when(step == n_steps - 1)
+    @pl.when(st == n_steps - 1)
     def _finish():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
+def _bwd_fused_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref,
+                      lse_ref, dl_ref, dq_ref, dk_ref, dv_ref, dq_acc,
+                      lse_col, dl_col, dk_acc, dv_acc, *, reps, **kw):
+    """Grid (kv cells, the q-blocks of the kv head's ``reps`` cells of
+    query heads): K, V and the float32 sums of dK and dV of the whole
+    key range stay; each step writes one block of dq."""
+    n_q = kw["n_q"]
+    c = pl.program_id(0)
+    u = pl.program_id(1)
+
+    @pl.when(u == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+    _q_block_backward(seed_ref, c * reps + u // n_q, u % n_q, 0, q_ref,
+                      k_ref, v_ref, b_ref, do_ref, lse_ref, dl_ref,
+                      dq_acc, lse_col, dl_col, (dk_acc, dv_acc), **kw)
+    dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+    @pl.when(u == reps * n_q - 1)
+    def _finish():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
 def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
-                dl_ref, dk_ref, dv_ref, dk_acc, dv_acc, *, scale,
-                blk_q, blk_k, n_q, n_k, n_steps, reps, rate, causal,
-                window):
+                dl_ref, dk_ref, dv_ref, dk_acc, dv_acc, lse_col, dl_col,
+                *, scale, blk_q, blk_k, q_major, n_q, n_k, n_steps,
+                reps, rate, causal, window):
+    """The mirror image: grid (kv cells, k-blocks, the major blocks of
+    query rows of the kv head's ``reps`` cells): a k-block and its
+    sums stay, the q-blocks of its band in the major block are walked
+    inside."""
     c = pl.program_id(0)
     kk = pl.program_id(1)
     u = pl.program_id(2)
-    # the inner axis walks the kv head's ``reps`` cells of query heads,
-    # and in each the q-blocks of this k-block's band
-    i = c * reps + u // n_steps
+    per = q_major // blk_q
+    _, _, J_lo, _ = _band(q_major, blk_k, n_q // per, n_k, causal,
+                          window)
     _, _, j_lo, j_hi = _band(blk_q, blk_k, n_q, n_k, causal, window)
-    j = j_lo(kk) + u % n_steps
+    _, _, j_first, j_end = _inside(blk_q, blk_k, causal, window)
+    i = c * reps + u // n_steps
+    major = J_lo(kk) + u % n_steps
     gk = k_ref.shape[0]
 
     @pl.when(u == 0)
@@ -1100,28 +1466,25 @@ def _dkv_kernel(seed_ref, q_ref, k_ref, v_ref, b_ref, do_ref, lse_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    @pl.when(j <= j_hi(kk))
-    def _step():
-        p = _recompute_p(q_ref, k_ref, b_ref, lse_ref, scale=scale,
-                         j=j, kk=kk, blk_q=blk_q, blk_k=blk_k,
-                         causal=causal, window=window)
-        do = do_ref[...]
-        if rate > 0.0:
-            keep = _dropout_keep(seed_ref, i, j, kk, n_q, n_k,
-                                 p.shape, rate)
-            pd = jnp.where(keep, p / (1.0 - rate), 0.0)
-        else:
-            pd = p
-        # dv += Pd^T @ dO (per row, or over the rows that share the
-        # kv head)
-        dv_acc[...] += _tt(pd.astype(do.dtype), do, gk)
-        dp = _qk(do, v_ref[...])
-        if rate > 0.0:
-            dp = jnp.where(keep, dp / (1.0 - rate), 0.0)
-        delta = dl_ref[..., :1]
-        ds = (p * (dp - delta) * scale).astype(q_ref.dtype)
-        # dk += dS^T @ Q
-        dk_acc[...] += _tt(ds, q_ref[...], gk)
+    def step(j, masked):
+        at = pl.multiple_of((j - major * per) * blk_q, blk_q)
+        rows = pl.ds(at, blk_q)
+        _columns(lse_ref, lse_col, at)
+        _columns(dl_ref, dl_col, at)
+        q, do = q_ref[:, rows, :], do_ref[:, rows, :]
+        pd, ds = _bwd_tile(
+            seed_ref, i, j, kk, q, k_ref[...], v_ref[...],
+            _bias_tile(b_ref, rows, slice(None)), do,
+            _lane_tile(lse_col[...], blk_k),
+            _lane_tile(dl_col[...], blk_k), scale=scale, blk_q=blk_q,
+            blk_k=blk_k, n_q=n_q, n_k=n_k, rate=rate, window=window,
+            masked=masked)
+        dv_acc[...] += _tt(pd, do, gk)
+        dk_acc[...] += _tt(ds, q, gk)
+
+    _walk(jnp.maximum(j_lo(kk), major * per),
+          jnp.minimum(j_hi(kk), major * per + per - 1),
+          j_first and j_first(kk), j_end and j_end(kk), step)
 
     @pl.when(u == reps * n_steps - 1)
     def _finish():
@@ -1136,112 +1499,132 @@ def _flash_bwd(q, k, v, bias, seed_f, o, lse, g, scale, rate, causal,
     Hkv, Sk, Dv = k.shape[1], k.shape[2], v.shape[3]
     BH, BHkv = B * H, B * Hkv
     bias, per_head = _prep_bias(bias, B, H, Sq, Sk)
-    G, gk, reps, blk_q, blk_k, n_q, n_k = _blocked_geometry(q, k)
+    sched = _blocked_geometry(q, k, v, bias, per_head)
+    G, gk, reps, blk_q, blk_k = sched[:5]
     hb = H // G
-    q3 = q.reshape(BH, Sq, Dh)
-    k3 = k.reshape(BHkv, Sk, Dh)
-    v3 = v.reshape(BHkv, Sk, Dv)
-    do3 = g.reshape(BH, Sq, Dv)
-    k_lo, k_hi, j_lo, j_hi = _band(blk_q, blk_k, n_q, n_k, causal,
-                                   window)
-    nk_steps = _n_steps(blk_q, blk_k, n_k, window)
-    nj_steps = _n_steps(blk_k, blk_q, n_q, window)
+    n_q, n_k = Sq // blk_q, Sk // blk_k
     seed = _seed_smem(seed_f, G)
-    # delta_i = rowsum(dO * O): O(S*Dh) elementwise work, XLA fuses it.
-    # lse/delta enter the kernels lane-replicated to the 128-lane
-    # min-tile (the layout the fwd kernel produced them in).
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    do3 = g.reshape(BH, Sq, Dv)
+    # delta_i = rowsum(dO * O): O(S*Dh) elementwise work, XLA fuses
+    # it. lse and delta enter the kernels as they are, a row a head.
     delta = jnp.sum(do3.astype(jnp.float32) * o.reshape(BH, Sq, Dv)
-                    .astype(jnp.float32), axis=-1)
-    lse128 = jnp.broadcast_to(lse[:, :, None], (BH, Sq, 128))
-    delta128 = jnp.broadcast_to(delta[:, :, None], (BH, Sq, 128))
-
-    def specs(order):
-        """order: 'dq' grid (BH/G, n_q, k steps) or 'dkv' (kv cells,
-        n_k, reps x q steps). Each index map goes through (q cell, q
-        block, k block) of the grid point."""
-        if order == "dq":
-            def at(i, j, st):
-                return i, j, jnp.minimum(k_lo(j) + st, k_hi(j))
-        else:
-            def at(c, kk, u):
-                return (c * reps + u // nj_steps,
-                        jnp.minimum(j_lo(kk) + u % nj_steps, j_hi(kk)),
-                        kk)
-
-        def qi(*g3):
-            i, j, _ = at(*g3)
-            return i, j, 0
-
-        def ki(*g3):
-            i, _, kk = at(*g3)
-            return i // reps, kk, 0
-
-        def bi(*g3):
-            i, j, kk = at(*g3)
-            return (i if per_head else i // hb), 0, j, kk
-
-        sp = [pl.BlockSpec(memory_space=pltpu.SMEM),
-              pl.BlockSpec((G, blk_q, Dh), qi),
-              pl.BlockSpec((gk, blk_k, Dh), ki),
-              pl.BlockSpec((gk, blk_k, Dv), ki)]
-        ar = [seed, q3, k3, v3]
-        if bias is not None:
-            gb = G if per_head else 1
-            sp.append(pl.BlockSpec((gb, 1, blk_q, blk_k), bi))
-            ar.append(bias)
-        sp += [pl.BlockSpec((G, blk_q, Dv), qi),
-               pl.BlockSpec((G, blk_q, 128), qi),
-               pl.BlockSpec((G, blk_q, 128), qi)]
-        ar += [do3, lse128, delta128]
-        return sp, ar
-
-    def with_bias(kern):
-        if bias is not None:
-            return kern
-        return functools.partial(
-            lambda f, sr, qr, kr, vr, *rest, **kw:
-            f(sr, qr, kr, vr, None, *rest, **kw), kern)
-
+                    .astype(jnp.float32), axis=-1)[:, None, :]
     common = dict(scale=scale, blk_q=blk_q, blk_k=blk_k, n_q=n_q,
                   n_k=n_k, rate=rate, causal=causal, window=window)
-    sp, ar = specs("dq")
+    cols = [pltpu.VMEM((G, blk_q, 128), jnp.float32)] * 2
+    kv_shapes = [jax.ShapeDtypeStruct((BHkv, Sk, Dh), k.dtype),
+                 jax.ShapeDtypeStruct((BHkv, Sk, Dv), v.dtype)]
+    back = lambda dq, dk, dv: (                         # noqa: E731
+        dq.reshape(B, H, Sq, Dh), dk.reshape(B, Hkv, Sk, Dh),
+        dv.reshape(B, Hkv, Sk, Dv))
+
+    if sched.fused:
+        def at(c, u):
+            return c * reps + u // n_q, u % n_q
+
+        def qi(c, u):
+            return (*at(c, u), 0)
+
+        def si(c, u):
+            i, j = at(c, u)
+            return i, 0, j
+
+        def ki(c, u):
+            return c, 0, 0
+
+        kspec = [pl.BlockSpec((gk, Sk, Dh), ki),
+                 pl.BlockSpec((gk, Sk, Dv), ki)]
+        specs = [smem, pl.BlockSpec((G, blk_q, Dh), qi)] + kspec
+        args = [seed, q.reshape(BH, Sq, Dh), k.reshape(BHkv, Sk, Dh),
+                v.reshape(BHkv, Sk, Dv)]
+        if bias is not None:
+            specs.append(pl.BlockSpec(
+                (G if per_head else 1, 1, blk_q, Sk),
+                lambda c, u: (at(c, u)[0] if per_head
+                              else at(c, u)[0] // hb, 0, at(c, u)[1], 0)))
+            args.append(bias)
+        specs += [pl.BlockSpec((G, blk_q, Dv), qi),
+                  pl.BlockSpec((G, 1, blk_q), si),
+                  pl.BlockSpec((G, 1, blk_q), si)]
+        return back(*pl.pallas_call(
+            functools.partial(_without_bias(_bwd_fused_kernel, bias),
+                              reps=reps, k_major=Sk, **common),
+            out_shape=[jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype)]
+            + kv_shapes,
+            grid=(BHkv // gk, reps * n_q),
+            in_specs=specs,
+            out_specs=[pl.BlockSpec((G, blk_q, Dh), qi)] + kspec,
+            scratch_shapes=[pltpu.VMEM((G, blk_q, Dh), jnp.float32)]
+            + cols + [pltpu.VMEM((gk, Sk, Dh), jnp.float32),
+                      pltpu.VMEM((gk, Sk, Dv), jnp.float32)],
+            compiler_params=_mosaic("parallel", "arbitrary"),
+            interpret=interpret_mode(),
+        )(*args, do3, lse, delta))
+
+    grid, specs, args, qi = _k_major_specs(sched, q, k, v, bias,
+                                           per_head, causal, window)
+    stat = pl.BlockSpec((G, 1, blk_q), lambda i, j, st: (i, 0, j))
     dq = pl.pallas_call(
-        functools.partial(with_bias(_dq_kernel), n_steps=nk_steps,
+        functools.partial(_without_bias(_dq_kernel, bias),
+                          k_major=sched.k_major, n_steps=grid[2],
                           **common),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, Dh), q.dtype),
-        grid=(BH // G, n_q, nk_steps),
-        in_specs=sp,
-        out_specs=pl.BlockSpec((G, blk_q, Dh),
-                               lambda i, j, st: (i, j, 0)),
-        scratch_shapes=[pltpu.VMEM((G, blk_q, Dh), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        grid=grid,
+        in_specs=[smem] + specs
+        + [pl.BlockSpec((G, blk_q, Dv), qi), stat, stat],
+        out_specs=pl.BlockSpec((G, blk_q, Dh), qi),
+        scratch_shapes=[pltpu.VMEM((G, blk_q, Dh), jnp.float32)] + cols,
+        compiler_params=_mosaic("parallel", "parallel", "arbitrary"),
         interpret=interpret_mode(),
-    )(*ar)
+    )(seed, *args, do3, lse, delta)
 
-    sp, ar = specs("dkv")
+    q_major = sched.q_major
+    n_major = Sq // q_major
+    _, _, J_lo, J_hi = _band(q_major, blk_k, n_major, n_k, causal,
+                             window)
+    nj_steps = _n_steps(blk_k, q_major, n_major, window)
+
+    def at(c, kk, u):
+        return (c * reps + u // nj_steps,
+                jnp.minimum(J_lo(kk) + u % nj_steps, J_hi(kk)))
+
+    def qi(c, kk, u):
+        return (*at(c, kk, u), 0)
+
+    def si(c, kk, u):
+        i, mj = at(c, kk, u)
+        return i, 0, mj
+
+    def ki(c, kk, u):
+        return c, kk, 0
+
+    kspec = [pl.BlockSpec((gk, blk_k, Dh), ki),
+             pl.BlockSpec((gk, blk_k, Dv), ki)]
+    specs = [smem, pl.BlockSpec((G, q_major, Dh), qi)] + kspec
+    if bias is not None:
+        specs.append(pl.BlockSpec(
+            (G if per_head else 1, 1, q_major, blk_k),
+            lambda c, kk, u: (at(c, kk, u)[0] if per_head
+                              else at(c, kk, u)[0] // hb, 0,
+                              at(c, kk, u)[1], kk)))
+    specs += [pl.BlockSpec((G, q_major, Dv), qi),
+              pl.BlockSpec((G, 1, q_major), si),
+              pl.BlockSpec((G, 1, q_major), si)]
     dk, dv = pl.pallas_call(
-        functools.partial(with_bias(_dkv_kernel), n_steps=nj_steps,
-                          reps=reps, **common),
-        out_shape=[jax.ShapeDtypeStruct((BHkv, Sk, Dh), k.dtype),
-                   jax.ShapeDtypeStruct((BHkv, Sk, Dv), v.dtype)],
+        functools.partial(_without_bias(_dkv_kernel, bias),
+                          q_major=q_major, n_steps=nj_steps, reps=reps,
+                          **common),
+        out_shape=kv_shapes,
         grid=(BHkv // gk, n_k, reps * nj_steps),
-        in_specs=sp,
-        out_specs=[
-            pl.BlockSpec((gk, blk_k, Dh), lambda c, kk, u: (c, kk, 0)),
-            pl.BlockSpec((gk, blk_k, Dv), lambda c, kk, u: (c, kk, 0)),
-        ],
+        in_specs=specs,
+        out_specs=kspec,
         scratch_shapes=[pltpu.VMEM((gk, blk_k, Dh), jnp.float32),
-                        pltpu.VMEM((gk, blk_k, Dv), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+                        pltpu.VMEM((gk, blk_k, Dv), jnp.float32)] + cols,
+        compiler_params=_mosaic("parallel", "parallel", "arbitrary"),
         interpret=interpret_mode(),
-    )(*ar)
-
-    dq = dq.reshape(B, H, Sq, Dh)
-    dk = dk.reshape(B, Hkv, Sk, Dh)
-    dv = dv.reshape(B, Hkv, Sk, Dv)
-    return dq, dk, dv
+    )(seed, *args, do3, lse, delta)
+    return back(dq, dk, dv)
 
 
 def _takes_1k(H, Hkv, Sq, Sk, window):
@@ -1320,8 +1703,11 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
         _count_lowering("flash_1k_transposed", 0.0 if heads_last else 1.0)
         if not heads_last:
             q, k, v = (_merge_heads(x) for x in (q, k, v))
-    elif heads_last:
-        q, k, v = (_split_heads(x, dh) for x in (q, k, v))
+    else:
+        if heads_last:
+            q, k, v = (_split_heads(x, dh) for x in (q, k, v))
+        _count_schedule(_blocked_geometry(q, k, v, bias,
+                                          _per_head(bias, H)))
     if rate > 0.0:
         # fold the step key into a scalar TPU PRNG seed; float32 carries
         # it through custom_vjp without an int-cotangent (float0) dance
@@ -1393,6 +1779,19 @@ def _flash_over_mesh(mesh, q, k, v, bias, seed, H, Hkv, scale, rate,
 
     return shard_map(body, mesh=mesh, in_specs=tuple(specs),
                      out_specs=spec, check_vma=False)(*args)
+
+
+def _count_schedule(sched):
+    """``flash_schedule.kv_resident`` or ``.kv_streamed`` and
+    ``flash_backward.fused`` or ``.split`` beside a blocked site's
+    ``sdpa_lowering.*``: what _blocked_schedule read off its shape, the
+    one not taken listed with 0."""
+    count_lowering("flash_schedule.kv_resident",
+                   float(sched.kv_resident))
+    count_lowering("flash_schedule.kv_streamed",
+                   float(not sched.kv_resident))
+    count_lowering("flash_backward.fused", float(sched.fused))
+    count_lowering("flash_backward.split", float(not sched.fused))
 
 
 def _same_widths(k, v):

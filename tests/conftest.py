@@ -161,3 +161,19 @@ def fresh_programs():
 @pytest.fixture
 def rng():
     return np.random.RandomState(42)
+
+
+@pytest.fixture
+def fresh_traces():
+    """The blocked flash wrappers are jitted on the shapes alone: a
+    test that replaces their VMEM model's budget must not meet a trace
+    made under another."""
+    from paddle_tpu.ops.pallas import attention
+
+    def clear():
+        attention._flash_fwd.clear_cache()
+        attention._flash_bwd.clear_cache()
+
+    clear()
+    yield
+    clear()

@@ -283,6 +283,77 @@ def test_1k_headline_geometry_pinned():
         assert H % g == 0 and (g == H or g * Dh % 128 == 0), (H, Dh, g)
 
 
+# the blocked kernels' sites: (heads, kv heads, S, qk width, v width,
+# window) of kanana2_s8k_scan's and kimi_linear_s8k_scan's latent
+# attention, trinity_mini_s8k_scan's full and sliding layers, and a
+# 32k-key corner; and the schedule _blocked_schedule reads off each:
+# (G, blk_q, blk_k, kv_resident, fused)
+_BLOCKED_SITES = {
+    "mla_8k": ((32, 32, 8192, 192, 128, 0), (1, 512, 512, True, True)),
+    "gqa_8k": ((32, 4, 8192, 128, 128, 0), (4, 256, 512, True, True)),
+    "gqa_8k_window": ((32, 4, 8192, 128, 128, 2048),
+                      (4, 256, 512, True, True)),
+    "mla_32k": ((32, 32, 32768, 192, 128, 0),
+                (1, 512, 512, True, False)),
+}
+
+
+@pytest.mark.parametrize("site", sorted(_BLOCKED_SITES))
+def test_blocked_schedule_pinned_and_fits(site):
+    """The blocked flash kernels' VMEM model at the three 8k cells'
+    sites and at 32k keys: the schedule each shape takes is pinned
+    (all three cells keep K and V of a kv head for its whole q sweep
+    and run ONE backward kernel; at 32k keys K, V, dK, dV and the
+    float32 sums no longer fit together and the backward splits), and
+    the kernels' declared blocks and scratch, replayed by this file's
+    own tiling rules with the model's count of a step's score
+    temporaries, stay under what the kernels ask Mosaic for and under
+    what the model itself charged."""
+    from paddle_tpu.ops.pallas import attention as A
+    (h, hkv, s, dqk, dv, window), want = _BLOCKED_SITES[site]
+    sched = A._blocked_schedule(h, hkv, s, s, dqk, dv, 2)
+    assert (sched.G, sched.blk_q, sched.blk_k, sched.kv_resident,
+            sched.fused) == want
+    assert sched.k_major == sched.q_major == s
+    assert A._BLOCKED_VMEM_BUDGET < A._BLOCKED_VMEM_LIMIT <= 128 << 20
+    # one kv head and the query heads that share it (two heads where
+    # each has its own): the blocks are a cell's, whatever the count
+    cut = h // hkv if h > hkv else 2
+    q = jnp.zeros((1, cut, s, dqk), jnp.bfloat16)
+    k = jnp.zeros((1, max(1, cut * hkv // h), s, dqk), jnp.bfloat16)
+    v = jnp.zeros(k.shape[:3] + (dv,), jnp.bfloat16)
+    seed = jnp.zeros((2,), jnp.float32)
+
+    def fwd_bwd():
+        out, pull = jax.vjp(
+            lambda a, b, c: A._sdpa_flash(a, b, c, None, seed,
+                                          dqk ** -0.5, 0.0, True,
+                                          window), q, k, v)
+        pull(out)
+
+    orig = A.interpret_mode
+    A.interpret_mode = lambda: False
+    try:
+        calls = _capture_calls(fwd_bwd)
+    finally:
+        A.interpret_mode = orig
+    kernels = ["fwd", "fused"] if sched.fused else ["fwd", "dq", "dkv"]
+    assert len(calls) == len(kernels)
+    for kernel, call in zip(kernels, calls):
+        temps = sched.G * sched.blk_q * sched.blk_k * (
+            A._BLOCKED_FWD_TEMP_BYTES if kernel == "fwd"
+            else A._BLOCKED_BWD_TEMP_BYTES)
+        # less the seed's pair of words, which _footprint charges as a
+        # double-buffered tile and which lives in SMEM
+        total = _footprint(call) + temps - 1024
+        modeled = A._blocked_bytes(
+            kernel, sched.G, sched.gk, sched.blk_q, sched.blk_k, s, s,
+            dqk, dv, 2)
+        assert total <= modeled <= A._BLOCKED_VMEM_BUDGET, (
+            "%s %s: replayed %.1f MB, modeled %.1f MB"
+            % (site, kernel, total / 2**20, modeled / 2**20))
+
+
 def test_layer_norm_flagship_fits_vmem():
     rs = np.random.RandomState(0)
     x = jnp.asarray(rs.rand(_N, _D).astype("float32"))

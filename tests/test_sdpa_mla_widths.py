@@ -31,7 +31,7 @@ def plain(q, k, v, scale):
 
 # (S, heads, qk width, v width): the model's own pair, a toy pair with
 # the wider values, and S past one k-block of 512 so that the online
-# softmax and both backward kernels walk several blocks
+# softmax and the backward walk several blocks
 CASES = [(1024, 2, 192, 128), (1024, 3, 24, 16), (512, 2, 8, 16),
          (2048, 1, 48, 32)]
 
@@ -86,12 +86,14 @@ def test_equal_widths_lower_as_before():
     """A caller with one width gets the kernels it always got: the
     second width is read off v's own shape, so at equal widths every
     block, scratch shape and index map is what it was. At 48 / 32 the
-    same three kernels differ from the equal-width trace in numbers
+    same kernels differ from the equal-width trace in numbers
     alone: no pad, slice or concatenate of a split head joins them
-    (the traces hold the same primitives in the same order)."""
+    (the traces hold the same primitives in the same order). Two
+    kernels: the forward and the one backward (K, V, dK and dV of a
+    head fit the VMEM model at 1,024 keys)."""
     import re
     same, two = _traced(32, 32), _traced(48, 32)
-    assert same.count("pallas_call") == two.count("pallas_call") == 3
+    assert same.count("pallas_call") == two.count("pallas_call") == 2
     shapeless = lambda t: re.sub(r"\d+", "N", t)       # noqa: E731
     assert shapeless(same) == shapeless(two)
     assert A._blocked_name(jnp.zeros((1, 2, 8, 32)),

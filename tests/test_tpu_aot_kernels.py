@@ -90,46 +90,80 @@ def test_flash_1k_pair_at_the_cells_sites(one_chip, for_the_chip, b, s,
     assert c.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-@pytest.mark.parametrize("window", [2048, 0])
-def test_blocked_flash_at_the_cells_site(one_chip, for_the_chip, window):
-    """32 q heads over 4 kv heads of 128, 8192 positions, bf16: the
-    forward and both backward kernels."""
+def _blocked_site(one_chip, q, kv, v, window=0):
+    """One site's forward and backward, compiled: (its Mosaic calls,
+    the bytes of its temporaries)."""
     bf = jnp.bfloat16
-    q, kv = (1, 32, 8192, 128), (1, 4, 8192, 128)
 
     def site(q_, k_, v_, g_):
         seed = jnp.zeros((2,), jnp.float32)
         out, pull = jax.vjp(
             lambda a, b, c: A._sdpa_flash(a, b, c, None, seed,
-                                          128 ** -0.5, 0.0, True,
+                                          q[3] ** -0.5, 0.0, True,
                                           window), q_, k_, v_)
         return out, pull(g_)
 
-    c = compiled(site, one_chip, (q, bf), (kv, bf), (kv, bf), (q, bf))
-    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
-    # out, lse, delta and the three gradients: nothing of S x S
-    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+    c = compiled(site, one_chip, (q, bf), (kv, bf), (v, bf),
+                 (q[:3] + v[3:], bf))
+    return (c.as_text().count('custom_call_target="tpu_custom_call"'),
+            c.memory_analysis().temp_size_in_bytes)
+
+
+@pytest.mark.parametrize("window", [2048, 0])
+def test_blocked_flash_at_the_cells_site(one_chip, for_the_chip, window):
+    """32 q heads over 4 kv heads of 128, 8192 positions, bf16: the
+    forward and the ONE backward kernel, K and V of a kv head (and the
+    float32 sums of its dK and dV) resident in the 100 MB of VMEM the
+    kernels ask for."""
+    calls, temps = _blocked_site(one_chip, (1, 32, 8192, 128),
+                                 (1, 4, 8192, 128), (1, 4, 8192, 128),
+                                 window)
+    assert calls == 2
+    # out, lse, delta and the three gradients: nothing of S x S, and
+    # no [BH, S, 128] statistics
+    assert temps < 1 << 29
 
 
 def test_blocked_flash_at_the_mla_site(one_chip, for_the_chip):
-    """Latent attention as ``kimi_linear_s8k_scan`` runs it: 32 heads,
-    queries and keys 192 wide (128 + the 64 shared lanes) beside
-    128-wide values, 8192 positions, bf16: the same three kernels with
-    q, k, dq, dk blocks at one width and v, o, do, dv at the other."""
-    bf = jnp.bfloat16
-    qk, vv = (1, 32, 8192, 192), (1, 32, 8192, 128)
+    """Latent attention as ``kimi_linear_s8k_scan`` and
+    ``kanana2_s8k_scan`` run it: 32 heads, queries and keys 192 wide
+    (128 + the 64 shared or rotary lanes) beside 128-wide values, 8192
+    positions, bf16: the same two kernels with q, k, dq, dk blocks at
+    one width and v, o, do, dv at the other."""
+    calls, temps = _blocked_site(one_chip, (1, 32, 8192, 192),
+                                 (1, 32, 8192, 192), (1, 32, 8192, 128))
+    assert calls == 2
+    assert temps < 1 << 30
 
-    def site(q_, k_, v_, g_):
-        seed = jnp.zeros((2,), jnp.float32)
-        out, pull = jax.vjp(
-            lambda a, b, c: A._sdpa_flash(a, b, c, None, seed,
-                                          192 ** -0.5, 0.0, True, 0),
-            q_, k_, v_)
-        return out, pull(g_)
 
-    c = compiled(site, one_chip, (qk, bf), (qk, bf), (vv, bf), (vv, bf))
-    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 3
-    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+def test_blocked_flash_at_32k_keys(one_chip, for_the_chip):
+    """The fallback, compiled for the v5e too: at 32,768 keys K, V, dK,
+    dV and the float32 sums of a head no longer fit together, so the
+    backward is the dq kernel (K and V still resident) and its mirror
+    image (a k-block's sums against a head's q, dO and statistics)."""
+    assert not A._blocked_schedule(4, 4, 32768, 32768, 192, 128, 2).fused
+    calls, _ = _blocked_site(one_chip, (1, 4, 32768, 192),
+                             (1, 4, 32768, 192), (1, 4, 32768, 128))
+    assert calls == 3
+
+
+@pytest.mark.parametrize("budget_mb,resident", [(30, True), (8, False)])
+@pytest.mark.parametrize("site", ["mla", "gqa_window"])
+def test_blocked_flash_other_schedules(one_chip, for_the_chip,
+                                       monkeypatch, fresh_traces, site,
+                                       budget_mb, resident):
+    """The schedules the cells' shapes do not take, at the cells'
+    widths, steered by the model's budget alone: the split backward
+    with K and V resident, and everything streamed in major blocks."""
+    monkeypatch.setattr(A, "_BLOCKED_VMEM_BUDGET", budget_mb << 20)
+    h, hkv, dqk, window = {"mla": (32, 32, 192, 0),
+                           "gqa_window": (32, 4, 128, 2048)}[site]
+    sched = A._blocked_schedule(h, hkv, 8192, 8192, dqk, 128, 2)
+    assert not sched.fused and sched.kv_resident == resident
+    calls, _ = _blocked_site(one_chip, (1, h, 8192, dqk),
+                             (1, hkv, 8192, dqk), (1, hkv, 8192, 128),
+                             window)
+    assert calls == 3
 
 
 def test_kda_core_at_the_cells_widths(one_chip):

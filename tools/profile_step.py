@@ -106,9 +106,12 @@ def main():
           "(%.2f%% slower)" % (n, spd, untraced, traced,
                                100.0 * (traced / untraced - 1.0)))
     # which lowering each kind of site took, counted when the step was
-    # traced (sdpa_ / moe_ / kda_ / rotary_lowering.*)
+    # traced (sdpa_ / moe_ / kda_ / rotary_lowering.*), and the
+    # schedule the blocked flash kernels read off each site's shape
+    # (flash_schedule.* / flash_backward.*)
     lowerings = {k: v for k, v in sorted(profiler.counter_values().items())
-                 if "_lowering." in k}
+                 if "_lowering." in k
+                 or k.startswith(("flash_schedule.", "flash_backward."))}
     print("lowerings: " + ", ".join("%s %g" % kv
                                     for kv in lowerings.items()))
     if args.out:
