@@ -65,8 +65,8 @@ from typing import Optional
 from . import observability as _obs
 
 __all__ = ["CompileCache", "CacheHit", "configure", "active",
-           "canonical_fingerprint", "cache_key", "stats",
-           "reset_stats", "resolve_root", "store_dir", "enable"]
+           "canonical_fingerprint", "cache_key", "memory_record",
+           "stats", "reset_stats", "resolve_root", "store_dir", "enable"]
 
 ENV_DIR = "PADDLE_TPU_COMPILE_CACHE_DIR"
 ENV_MAX_BYTES = "PADDLE_TPU_COMPILE_CACHE_MAX_BYTES"
@@ -107,6 +107,39 @@ def cache_key(fingerprint: str, mesh_fp=None) -> str:
         fingerprint, backend, str(jax.device_count()),
         jax.__version__, jaxlib.__version__, repr(mesh_fp)])
     return hashlib.sha256(material.encode()).hexdigest()
+
+
+# memory_record's keys beside the compiler's own field names
+_MEMORY_FIELDS = (("argument_bytes", "argument_size_in_bytes"),
+                  ("output_bytes", "output_size_in_bytes"),
+                  ("alias_bytes", "alias_size_in_bytes"),
+                  ("temp_bytes", "temp_size_in_bytes"),
+                  ("generated_code_bytes", "generated_code_size_in_bytes"))
+
+
+def memory_record(compiled) -> Optional[dict]:
+    """What one executable holds in device memory, per device, as the
+    compiler counted it (``memory_analysis()`` of a
+    jax.stages.Compiled, compiled here or loaded from the store): its
+    arguments, its outputs, the outputs that alias arguments (donated
+    state), its temporaries and its code; ``peak_bytes`` where the
+    runtime fills ``peak_memory_in_bytes``, else None. None where the
+    executable gives no analysis: the executor hands the compiled
+    one's record to ``put`` with the entry's meta, so a process that
+    loads the entry can still read what the compiling one read."""
+    try:
+        ma = compiled.memory_analysis()
+    except Exception:    # a backend without the analysis
+        return None
+    if ma is None:
+        return None
+    rec = {ours: int(getattr(ma, theirs, 0) or 0)
+           for ours, theirs in _MEMORY_FIELDS}
+    if not any(rec.values()):
+        return None
+    rec["peak_bytes"] = int(getattr(ma, "peak_memory_in_bytes", 0)
+                            or 0) or None
+    return rec
 
 
 class CacheHit:

@@ -29,11 +29,22 @@ def device_count() -> int:
     return jax.device_count()
 
 
-def device_properties(device_id: int = 0) -> Dict:
-    """Kind + memory stats of one device (gpu_info.cc
-    GpuMaxAllocSize analog; HBM numbers come straight from PJRT)."""
+# the allocator's reserved side: on the TPU runtime a step program's
+# temporaries are RESERVED when it first runs and stay so, outside
+# ``bytes_in_use``, so the device's peak is ``bytes_in_use +
+# peak_bytes_reserved`` where that is the larger
+_RESERVED_STATS = ("bytes_reserved", "peak_bytes_reserved",
+                   "largest_free_block_bytes")
+
+
+def device_properties(device_id=0) -> Dict:
+    """Kind + memory stats of one device, given as its index in
+    ``jax.devices()`` or as the device itself (gpu_info.cc
+    GpuMaxAllocSize analog; HBM numbers come straight from PJRT).
+    Of ``_RESERVED_STATS`` only what the runtime reports is there."""
     import jax
-    d = jax.devices()[device_id]
+    d = device_id if hasattr(device_id, "memory_stats") \
+        else jax.devices()[device_id]
     props = {
         "device_kind": d.device_kind,
         "platform": d.platform,
@@ -45,6 +56,8 @@ def device_properties(device_id: int = 0) -> Dict:
         props["bytes_limit"] = stats.get("bytes_limit")
         props["bytes_in_use"] = stats.get("bytes_in_use")
         props["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
+        props.update((k, stats[k]) for k in _RESERVED_STATS
+                     if k in stats)
     except Exception:
         pass  # CPU backend has no memory_stats
     return props

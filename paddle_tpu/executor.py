@@ -1072,7 +1072,8 @@ class Executor:
 
     def _note_provenance(self, entry, shape_sig, reason, fingerprint,
                          mesh_fp, seconds, mode="xla",
-                         xla_seconds=None, build_phases=None):
+                         xla_seconds=None, build_phases=None,
+                         memory=None):
         """Registry + journal record for ONE compile — the compile
         plane's provenance ledger (docs/compile.md): every compile is
         an attributable event with a *miss reason*, not a silent perf
@@ -1099,7 +1100,7 @@ class Executor:
                   build_phases={k: round(v, 6)
                                 for k, v in build_phases.items()}
                   if build_phases else None,
-                  mode=mode, nth=nth)
+                  memory=memory, mode=mode, nth=nth)
 
     @contextlib.contextmanager
     def _building(self, ekey):
@@ -1165,7 +1166,8 @@ class Executor:
                 self._artifacts[ekey] = {
                     "entry": entry, "program_uid": program._uid,
                     "shape_key": _shape_key(shape_sig),
-                    "fingerprint": None, "mode": "interpret"}
+                    "fingerprint": None, "mode": "interpret",
+                    "dispatches": 0}
                 self._executables[ekey] = jitfn
                 return jitfn
             ctx = compile_ctx if compile_ctx is not None \
@@ -1177,7 +1179,8 @@ class Executor:
             phases = dict.fromkeys(_BUILD_PHASES, 0.0)
             with _profiler.RecordEvent("executor_trace_compile"), \
                     ctx():
-                lowered = jitfn.lower(*lower_args())
+                args = lower_args()
+                lowered = jitfn.lower(*args)
                 t_lowered = time.perf_counter()
                 phases["trace_lower_seconds"] = t_lowered - t0
                 fp = _ccache.canonical_fingerprint(lowered.as_text())
@@ -1194,6 +1197,12 @@ class Executor:
                         time.perf_counter() - t_keyed
                     if hit is not None:
                         loaded = hit.loaded
+                        # a warm run reports the bytes the cold one
+                        # did: where the loaded executable gives no
+                        # analysis, what the compiling process put
+                        # into the entry's meta
+                        memory = _ccache.memory_record(loaded) \
+                            or hit.meta.get("memory")
                         self._book_prog_sig(cache_key, program,
                                             shape_sig, mesh_fp)
                         with self._lock:
@@ -1210,7 +1219,8 @@ class Executor:
                             compile_seconds_saved=hit.meta.get(
                                 "compile_seconds"),
                             build_phases={k: round(v, 6)
-                                          for k, v in phases.items()})
+                                          for k, v in phases.items()},
+                            memory=memory)
                 if loaded is None:
                     reason = self._classify_miss(cache_key, program,
                                                  shape_sig, mesh_fp,
@@ -1222,18 +1232,21 @@ class Executor:
                     t_compiled = time.perf_counter()
                     xla_s = t_compiled - t1
                     phases["xla_compile_seconds"] = xla_s
+                    memory = _ccache.memory_record(compiled)
                     if cache is not None:
                         cache.put(disk_key, compiled, {
                             "entry": entry, "fingerprint": fp,
                             "shape_key": _shape_key(shape_sig),
                             "mesh": _mesh_tag(mesh_fp),
-                            "compile_seconds": xla_s})
+                            "compile_seconds": xla_s,
+                            "memory": memory})
                         phases["store_put_seconds"] = \
                             time.perf_counter() - t_compiled
                     self._note_provenance(
                         entry, shape_sig, reason, fp, mesh_fp,
                         t_compiled - t0, mode="xla",
-                        xla_seconds=xla_s, build_phases=phases)
+                        xla_seconds=xla_s, build_phases=phases,
+                        memory=memory)
                     loaded = compiled
                 # memoize INSIDE the compile_ctx window: the ctx's
                 # __exit__ may legitimately raise (run_pipelined's
@@ -1248,6 +1261,15 @@ class Executor:
                     "fingerprint": fp, "mode": "xla",
                     "from_cache": compiled is None,
                     "build_phases": phases,
+                    # the memory plane (telemetry()["memory"]): what
+                    # the compiler says this executable holds, the
+                    # state and feed it takes by kind, per device,
+                    # and the dispatches that went through it
+                    "memory": memory,
+                    "state": _state_by_kind(program.global_block(),
+                                            *args[:2]),
+                    "device_ids": _device_ids(loaded),
+                    "dispatches": 0,
                     "build_seconds": time.perf_counter() - t0}
                 with self._lock:
                     for k, v in phases.items():
@@ -1303,7 +1325,10 @@ class Executor:
         readback or a device trace gives); ``build_phases``, the
         seconds of every executable built by phase (trace+lower, key,
         store load, XLA compile, store put); input-pipeline stall
-        stats of the last *_from_dataset pass; anomaly-guard skip
+        stats of the last *_from_dataset pass; ``memory``, what every
+        executable built holds in device memory and what the devices'
+        allocators report (_memory_plane; read at phase boundaries,
+        it asks each device for its stats); anomaly-guard skip
         counters read from ``scope``; and (when a distributed
         ``program`` is passed) the estimated gradient-sync
         bytes-on-wire per step."""
@@ -1324,6 +1349,8 @@ class Executor:
                 "build_phases": {k: round(v, 6) for k, v
                                  in self._build_phases.items()},
             }
+            built = [dict(rec) for rec in self._artifacts.values()]
+        out["memory"] = _memory_plane(built)
         out["compile_cache"] = _ccache.stats()
         ps = self._last_pipeline_stats
         out["input_pipeline"] = dict(ps) if ps else None
@@ -1813,6 +1840,9 @@ class Executor:
                 counter = self._run_counter
                 self._run_counter += entry.steps
                 self._dispatch_count += 1
+                built = self._artifacts.get((cache_key, shape_sig))
+                if built is not None:
+                    built["dispatches"] += 1
             self._m_dispatch.inc()
             self._m_steps.inc(entry.steps)
             # the failed-settlement guard covers EVERYTHING after the
@@ -1879,3 +1909,66 @@ def _step_counters(scope):
     from .ops import kda_ops
     from .parallel import moe
     return moe.read_counters(scope), kda_ops.read_counters(scope)
+
+
+_STATE_KINDS = ("parameters", "optimizer_state", "other")
+
+
+def _state_by_kind(block, persist=None, feed=None):
+    """The state one executable takes as arguments, by kind, and its
+    feed: bytes and leaves PER DEVICE (a sharded leaf counts its
+    shard), counted where the executable is built. ``parameters`` are
+    the block's Parameters; ``optimizer_state`` what an update op (one
+    with a ``Param`` slot) takes beside its parameter, gradient and
+    learning rate: the optimizer's accumulators; ``other`` the rest
+    (AMP's scale, the guard's and the layers' counters, the learning
+    rate)."""
+    accumulators = set()
+    for op in block.ops:
+        if "Param" in op.inputs:
+            for slot, names in op.inputs.items():
+                if slot not in ("Param", "Grad", "LearningRate"):
+                    accumulators.update(_var_names(names))
+
+    def nbytes(v):
+        sharding = getattr(v, "sharding", None)
+        shape = sharding.shard_shape(v.shape) if sharding is not None \
+            else np.shape(v)
+        return int(np.prod(shape, dtype=np.int64)) * v.dtype.itemsize
+
+    out = {k: {"bytes": 0, "leaves": 0} for k in _STATE_KINDS}
+    for name, v in (persist or {}).items():
+        kind = "parameters" \
+            if isinstance(block.vars.get(name), framework.Parameter) \
+            else "optimizer_state" if name in accumulators else "other"
+        out[kind]["bytes"] += nbytes(v)
+        out[kind]["leaves"] += 1
+    out["feed_bytes"] = sum(nbytes(v) for v in (feed or {}).values())
+    return out
+
+
+def _device_ids(loaded):
+    """Ids of the devices an executable was built for."""
+    try:
+        return sorted(d.id for d in
+                      loaded.runtime_executable().local_devices())
+    except Exception:       # a backend whose executables do not say
+        return None
+
+
+def _memory_plane(built):
+    """``telemetry()["memory"]``: one record per executable built
+    (what the compiler says it holds, the state it takes by kind, the
+    dispatches that went through it), and the allocator's account of
+    this executor's devices: those its executables were built for,
+    else the one JAX places arrays on."""
+    from .core import device_info
+    keys = ("entry", "program_uid", "shape_key", "from_cache",
+            "dispatches", "memory", "state")
+    ids = {i for rec in built for i in rec.get("device_ids") or ()}
+    local = jax.local_devices()
+    return {"executables": [{k: rec.get(k) for k in keys}
+                            for rec in built],
+            "devices": [device_info.device_properties(d)
+                        for d in local if d.id in ids]
+            or [device_info.device_properties(local[0])]}

@@ -44,7 +44,9 @@ from jax.profiler import TraceAnnotation
 __all__ = ["RecordEvent", "record_event", "start_profiler",
            "stop_profiler", "reset_profiler", "reset_counters",
            "profiler", "export_chrome_tracing", "scope_table",
-           "device_scope_table", "device_summary_table", "bump_counter", "counter_values",
+           "device_scope_table", "device_summary_table", "memory_table",
+           "format_memory_table", "device_memory_table", "bump_counter",
+           "counter_values",
            "cuda_profiler", "npu_profiler"]
 
 _state = threading.local()
@@ -345,13 +347,22 @@ _XLA_MADE = "(xla) "
 
 
 def _opcode(instruction):
-    """``copy-done.66`` -> ``copy-done``."""
-    return re.sub(r"[.\d]+$", "", instruction)
+    """``copy-done.66`` -> ``copy-done``, ``broadcast.7.clone.2.clone``
+    -> ``broadcast``."""
+    return re.sub(r"(?:\.clone|[.\d])+$", "", instruction)
 
 
 def _is_collective(ev):
     head = ev["name"].split(" = ", 1)[0]
     return any(w in head for w in _COLLECTIVES)
+
+
+# one row of HLO text: an instruction, a computation's head, the names
+# an instruction's operands and attributes mention (hlo_op_names and
+# memory_table read the same text)
+_HLO_INSTR = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
+_HLO_COMP = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_HLO_OPERAND = re.compile(r"%([\w.\-]+)")
 
 
 def hlo_op_names(optimized_hlo):
@@ -367,11 +378,8 @@ def hlo_op_names(optimized_hlo):
     texts = [optimized_hlo] if isinstance(optimized_hlo, str) \
         else list(optimized_hlo or ())
     out = {}
-    instr = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
-    comp = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
     op_name = re.compile(r'op_name="([^"]*)"')
     calls = re.compile(r"calls=%?([\w.\-]+)")
-    operand = re.compile(r"%([\w.\-]+)")
     for text in texts:
         if not text:
             continue
@@ -380,20 +388,20 @@ def hlo_op_names(optimized_hlo):
         by_comp, called, current = {}, {}, None
         bare, users = [], {}
         for row in text.splitlines():
-            m = instr.match(row)
+            m = _HLO_INSTR.match(row)
             if m is None:
-                c = comp.match(row)
+                c = _HLO_COMP.match(row)
                 if c is not None:
-                    current = by_comp.setdefault(c.group(1), [])
+                    current = by_comp.setdefault(c.group(2), [])
                     users = {}      # names are a computation's own
                 continue
-            name = m.group(1)
+            name = m.group(2)
             n = op_name.search(row)
             n = n.group(1) if n else ""
             names.setdefault(name, n)
             if current is not None:
                 current.append(n)
-            for used in operand.findall(row[m.end():].split(
+            for used in _HLO_OPERAND.findall(row[m.end():].split(
                     ", metadata=", 1)[0]):
                 users.setdefault(used, []).append(name)
             if not _SCOPE.search(n):
@@ -579,6 +587,24 @@ def scope_table(events, optimized_hlo=None):
                 idle_by_cause=idle, longest_gaps=longest, note=note)
 
 
+def _ranked_rows(title, unit, share, table, limit, per, whole):
+    """One table's rows, largest first, as lines of text: each value
+    over ``per`` and as a share of ``whole``; past ``limit`` the rest
+    in one row. Nothing for an empty table."""
+    rows = sorted(table.items(), key=lambda kv: -kv[1])
+    if not rows:
+        return []
+    lines = ["", "%-52s %12s %8s" % (title, unit, share)]
+    lines += ["%-52s %12.4f %7.2f%%" % (name[:52], v / per,
+                                        100.0 * v / whole)
+              for name, v in rows[:limit]]
+    if limit and len(rows) > limit:
+        lines.append("%-52s %12.4f" % (
+            "... %d more" % (len(rows) - limit),
+            sum(v for _, v in rows[limit:]) / per))
+    return lines
+
+
 def format_scope_table(table, steps=1) -> str:
     """``scope_table``'s result as text; ``steps`` divides every time
     (the steps the capture holds)."""
@@ -606,17 +632,8 @@ def format_scope_table(table, steps=1) -> str:
             ("Asynchronous collectives (overlap the rows above)",
              "async_collectives_by_layer", None),
             ("Idle, by cause", "idle_by_cause", None)):
-        rows = sorted(table[key].items(), key=lambda kv: -kv[1])
-        if not rows:
-            continue
-        lines += ["", "%-52s %12s %8s" % (title, unit, "of busy")]
-        for name, ms in rows[:limit]:
-            lines.append("%-52s %12.4f %7.2f%%"
-                         % (name[:52], ms / steps, 100.0 * ms / busy))
-        if limit and len(rows) > limit:
-            lines.append("%-52s %12.4f" % (
-                "... %d more" % (len(rows) - limit),
-                sum(ms for _, ms in rows[limit:]) / steps))
+        lines += _ranked_rows(title, unit, "of busy", table[key], limit,
+                              steps, busy)
     if table["longest_gaps"]:
         lines += ["", "Longest idle gaps (ms, whole capture)"]
         lines += ["%-52s %12.4f" % (w[:52], ms)
@@ -624,10 +641,320 @@ def format_scope_table(table, steps=1) -> str:
     return "\n".join(lines)
 
 
+# -- from scheduled HLO text to the step's fullest moment by scope -----
+
+_ELEMENT_BYTES = {"pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1,
+                  "s16": 2, "u16": 2, "f16": 2, "bf16": 2, "s32": 4,
+                  "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8,
+                  "c64": 8, "c128": 16}
+_ARRAY = re.compile(r"([a-z]\w*)\[([^\]]*)\](?:\{([^}]*)\})?")
+# ops that own no bytes: a value of nothing ...
+_OWNS_NOTHING = ("parameter", "constant", "after-all", "partition-id",
+                 "replica-id")
+# ... and ops whose value IS their first operand's bytes (a ``-done``
+# stands for its ``-start`` the same way)
+_STANDS_FOR_OPERAND = ("bitcast", "while", "optimization-barrier",
+                       "add-dependency")
+_CALLED = re.compile(r"(?:body|to_apply|true_computation|"
+                     r"false_computation)=%?([\w.\-]+)"
+                     r"|branch_computations=\{([^}]*)\}")
+
+
+@dataclass
+class _Row:
+    """One instruction of a computation's text."""
+    name: str
+    shape: str
+    opcode: str
+    operands: List[str]     # every %name its operands and attributes say
+    called: List[str]       # computations a while / call / conditional runs
+    root: bool
+    index: Optional[int]    # a get-tuple-element's
+
+
+def _split_shape(text):
+    """The shape an instruction's row starts with, and the rest."""
+    if not text.startswith("("):
+        shape, _, rest = text.partition(" ")
+        return shape, rest
+    depth = 0
+    for i, ch in enumerate(text):
+        depth += (ch == "(") - (ch == ")")
+        if depth == 0:
+            return text[:i + 1], text[i + 2:]
+    return text, ""
+
+
+def _hbm_bytes(shape):
+    """(bytes in HBM, the array's own text) of every array of a
+    shape's text, in order (a tuple gives one entry an element, nested
+    ones flattened): the dimensions padded to the layout's tiles, each
+    tile laid over the minor dimensions the tile before it left
+    (``T(8,128)(2,1)``), times the element's bytes (``E(n)`` bits where
+    the layout packs them). An array the layout puts in another memory
+    space (``S(n)``: VMEM, SMEM, the sync flags) holds none."""
+    out = []
+    for m in _ARRAY.finditer(shape):
+        dtype, dims, layout = m.group(1), m.group(2), m.group(3) or ""
+        if re.search(r"S\([1-9]", layout) or dtype not in _ELEMENT_BYTES \
+                and not dtype.startswith("f8"):
+            out.append((0, m.group(0)))
+            continue
+        dims = [int(d.lstrip("<=")) for d in dims.split(",") if d]
+        order, _, tiling = layout.partition(":")
+        order = [int(i) for i in order.split(",") if i]
+        if len(order) == len(dims):
+            dims = [dims[i] for i in reversed(order)]   # major first
+        for tile in re.findall(r"\((\d[\d,]*)\)",
+                               tiling.split("S(")[0].split("E(")[0]):
+            tile = [int(t) for t in tile.split(",")]
+            dims = [1] * (len(tile) - len(dims)) + dims
+            k = len(dims) - len(tile)
+            dims = dims[:k] + [-(-d // t) for d, t in zip(dims[k:], tile)] \
+                + tile
+        n = 1
+        for d in dims:
+            n *= d
+        bits = re.search(r"E\((\d+)\)", layout)
+        out.append((n * int(bits.group(1)) // 8 if bits
+                    else n * _ELEMENT_BYTES.get(dtype, 1), m.group(0)))
+    return out
+
+
+def _hlo_computations(text):
+    """({computation: [_Row, ...] in schedule order}, the entry's name)
+    of one module's text."""
+    comps, entry, current = {}, None, None
+    for line in text.splitlines():
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            c = _HLO_COMP.match(line)
+            if c is not None:
+                current = comps.setdefault(c.group(2), [])
+                if c.group(1):
+                    entry = c.group(2)
+            continue
+        if current is None:
+            continue
+        shape, rest = _split_shape(line[m.end():])
+        rest = rest.split(", metadata=", 1)[0]
+        opcode = rest.split("(", 1)[0]
+        called = [n.lstrip("%") for a, b in _CALLED.findall(rest)
+                  for n in ([a] if a else re.split(r",\s*", b))] \
+            if opcode in ("while", "call", "conditional") else []
+        index = re.search(r"index=(\d+)", rest) \
+            if opcode == "get-tuple-element" else None
+        current.append(_Row(m.group(2), shape, opcode,
+                            _HLO_OPERAND.findall(rest), called,
+                            bool(m.group(1)),
+                            int(index.group(1)) if index else None))
+    return comps, entry
+
+
+def _fullest_moment(comp, comps, walked):
+    """(peak bytes, the buffers live at it as (bytes, instruction, array
+    text), the innermost instruction it falls at as (computation, name,
+    opcode)) of one computation and whatever it runs."""
+    if comp in walked:
+        return walked[comp]
+    nothing = walked[comp] = (0, [], None)
+    rows = comps.get(comp, ())
+    users = {}
+    for i, row in enumerate(rows):
+        for used in row.operands:
+            users.setdefault(used, []).append(i)
+    done_of = {row.operands[0]: row for row in rows
+               if row.opcode.endswith("-done") and row.operands}
+    # refs[value]: the buffers it stands for, a tuple's element by
+    # element; sized[buffer], born[buffer]; a buffer is (owner, element)
+    refs, sized, born, returned = {}, {}, {}, set()
+    for i, row in enumerate(rows):
+        first = refs.get(row.operands[0]) if row.operands else None
+        if row.opcode in _OWNS_NOTHING:
+            refs[row.name] = set()
+        elif row.opcode == "tuple":
+            refs[row.name] = [_flat(refs.get(o)) for o in row.operands]
+        elif row.opcode == "get-tuple-element":
+            refs[row.name] = first[row.index] \
+                if isinstance(first, list) and row.index < len(first) \
+                else _flat(first)
+        elif row.opcode in _STANDS_FOR_OPERAND \
+                or row.opcode.endswith("-done"):
+            refs[row.name] = first if first is not None else set()
+        else:
+            # an asynchronous pair's result exists from its start on
+            owned = done_of[row.name].shape \
+                if row.opcode.endswith("-start") and row.name in done_of \
+                else row.shape
+            keys = []
+            for k, size in enumerate(_hbm_bytes(owned)):
+                keys.append((row.name, k))
+                sized[keys[-1]], born[keys[-1]] = size, i
+            refs[row.name] = [{k} for k in keys] \
+                if owned.startswith("(") else set(keys)
+        if row.root:
+            returned = _flat(refs[row.name])
+    dies = dict(born)
+    for name, r in refs.items():
+        last = max(users.get(name, (0,)))
+        for key in _flat(r):
+            dies[key] = max(dies[key], last)
+    counted = [key for key in born
+               if key not in returned and sized[key][0]]
+    delta = [0] * (len(rows) + 1)
+    for key in counted:
+        delta[born[key]] += sized[key][0]
+        delta[dies[key] + 1] -= sized[key][0]
+    best, at, held, below = 0, None, 0, nothing
+    for i, row in enumerate(rows):
+        held += delta[i]
+        inner = max((_fullest_moment(c, comps, walked)
+                     for c in row.called),
+                    key=lambda w: w[0], default=nothing)
+        if held + inner[0] > best:
+            best, at, below = held + inner[0], i, inner
+    if at is not None:
+        walked[comp] = (
+            best,
+            [(sized[key][0], key[0], sized[key][1])
+             for key in counted if born[key] <= at <= dies[key]]
+            + below[1],
+            below[2] or (comp, rows[at].name, rows[at].opcode))
+    return walked[comp]
+
+
+def memory_table(optimized_hlo, temp_bytes=None):
+    """The step's fullest moment in HBM by the program's own scopes,
+    MODELLED from one executable's optimized HLO text (what
+    ``Executor.aot_artifacts()`` gives as ``optimized_hlo``: it is
+    scheduled, so the order of a computation's rows is the order they
+    run in). ``temp_bytes`` is the compiler's own count of the
+    executable's temporaries (the artifact's ``memory`` record).
+
+    The walk starts at the entry and goes down into every ``while``,
+    ``call`` and ``conditional`` (a scan's step lies in its ``while``
+    body). A buffer is born at its instruction (an asynchronous pair's
+    at the ``-start``) and dies after the last instruction that reads
+    it, through whatever stands for it meanwhile (``bitcast``,
+    ``tuple``, ``get-tuple-element``, a ``-done``, a ``while``'s
+    result). Its bytes come from the shape, the element type and the
+    layout's tiles (``_hbm_bytes``). No bytes of their own: parameters
+    (the executable's arguments, a loop's carried values), constants,
+    the values a computation returns (outputs, and what a loop body
+    writes into the carried buffers), and arrays the layout puts
+    outside HBM. While a ``while`` runs, its body's own peak lies on
+    top of what the caller holds.
+
+    It is a model and not the compiler's buffer assignment: it shares
+    no buffer between two values (no in-place update, no reuse of a
+    dead buffer's space mid-fusion), counts nothing a fusion or a
+    Mosaic call holds inside itself, and knows no fragmentation.
+    ``coverage`` is the modelled peak over ``temp_bytes``: near 1 the
+    tables can be read as the compiler's, far from 1 they say which
+    scopes are live together and no more.
+
+    Returns ``peak_bytes``; ``instruction``, ``opcode``, ``scope``
+    (``phase/layer/op type``) and ``computation`` of the innermost
+    instruction the peak falls at; the live bytes there ``by_phase``,
+    ``by_layer`` and ``by_layer_op`` (each sums to ``peak_bytes``);
+    ``largest``, the twenty largest live buffers; ``buffers``, how many
+    were live; ``temp_bytes`` and ``coverage``."""
+    head = re.match(r"HloModule ([\w.\-]+)", optimized_hlo or "")
+    names = hlo_op_names(optimized_hlo).get(
+        head.group(1) if head else "", {})
+    comps, entry = _hlo_computations(optimized_hlo or "")
+    peak, live, at = _fullest_moment(entry, comps, {})
+    opcodes = {row.name: row.opcode
+               for rows in comps.values() for row in rows}
+
+    def scope_of(name):
+        m = _SCOPE.search(names.get(name, ""))
+        return m.groups() if m \
+            else (UNSCOPED, UNSCOPED, _opcode(opcodes[name]))
+
+    tables = {"by_phase": {}, "by_layer": {}, "by_layer_op": {}}
+    for size, name, _array in live:
+        phase, layer, op = scope_of(name)
+        for table, key in (("by_phase", phase), ("by_layer", layer),
+                           ("by_layer_op", "%s %s" % (layer, op))):
+            tables[table][key] = tables[table].get(key, 0) + size
+    comp, name, opcode = at or (None, None, None)
+    return dict(
+        tables, peak_bytes=peak, computation=comp, instruction=name,
+        opcode=opcode, scope="/".join(scope_of(name)) if name else None,
+        buffers=len(live),
+        largest=[{"bytes": size, "instruction": n, "shape": array,
+                  "scope": "/".join(scope_of(n))}
+                 for size, n, array in sorted(live, reverse=True)[:20]],
+        temp_bytes=temp_bytes,
+        coverage=peak / temp_bytes if temp_bytes else None)
+
+
+def _flat(refs):
+    """Every buffer a value stands for, a tuple's elements together."""
+    if isinstance(refs, list):
+        return set().union(*refs) if refs else set()
+    return set(refs or ())
+
+
+def format_memory_table(table) -> str:
+    """``memory_table``'s result as text."""
+    gib = 2.0 ** 30
+    peak = table["peak_bytes"] or 1
+    cover = "coverage unknown (no temp_bytes given)" \
+        if table["coverage"] is None else \
+        "coverage %.3f of the compiler's temp_bytes %.3f GiB" % (
+            table["coverage"], table["temp_bytes"] / gib)
+    lines = ["-------------------->   HBM at the fullest moment (a MODEL "
+             "of the scheduled HLO)   <--------------------",
+             "!! a model, not the buffer assignment (no in-place reuse, "
+             "nothing inside a fusion or kernel): %s" % cover,
+             "modelled peak %.3f GiB in %d live buffers, at %s (%s) in %s"
+             % (table["peak_bytes"] / gib, table["buffers"],
+                table["instruction"], table["scope"],
+                table["computation"])]
+    for title, key, limit in (("Phase", "by_phase", None),
+                              ("Layer", "by_layer", None),
+                              ("Layer, op type", "by_layer_op", 20)):
+        lines += _ranked_rows(title, "GiB", "of peak", table[key], limit,
+                              gib, peak)
+    lines += ["", "Largest live buffers (GiB)"]
+    lines += ["%-30s %-40s %9.4f  %s" % (r["instruction"][:30],
+                                         r["scope"][:40],
+                                         r["bytes"] / gib, r["shape"][:60])
+              for r in table["largest"]]
+    return "\n".join(lines)
+
+
+def _registered_artifacts():
+    """The record of every executable the live Executors hold whose
+    backend gives its optimized HLO."""
+    return [rec for exe in list(_executors)
+            for rec in exe.aot_artifacts() if rec.get("optimized_hlo")]
+
+
 def _registered_hlo():
     """Optimized HLO of every executable the live Executors hold."""
-    return [rec["optimized_hlo"] for exe in list(_executors)
-            for rec in exe.aot_artifacts() if rec.get("optimized_hlo")]
+    return [rec["optimized_hlo"] for rec in _registered_artifacts()]
+
+
+def device_memory_table():
+    """``memory_table`` of the executable the live Executors dispatched
+    most (the step; of equals the one built last, so a startup
+    program's does not stand in), against the compiler's own count of its
+    temporaries, with the artifact's ``entry``, ``shape_key``,
+    ``memory`` and ``state`` records beside; None before any
+    executable was built."""
+    built = _registered_artifacts()
+    if not built:
+        return None
+    step = max(reversed(built),
+               key=lambda rec: rec.get("dispatches") or 0)
+    table = memory_table(step["optimized_hlo"],
+                         (step.get("memory") or {}).get("temp_bytes"))
+    return dict(table, **{k: step.get(k) for k in (
+        "entry", "shape_key", "from_cache", "dispatches", "memory",
+        "state")})
 
 
 def device_scope_table():

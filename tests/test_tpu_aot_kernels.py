@@ -286,3 +286,58 @@ def test_rotary_part_at_the_mla_site(one_chip):
 
     c = compiled(site, one_chip, (q, bf), (k, bf), (q, bf), (k, bf))
     assert c.memory_analysis().temp_size_in_bytes < 1 << 27
+
+
+def test_memory_plane_of_a_small_step(one_chip):
+    """The memory plane against the TPU's compiler: a small AMP + Adam
+    step, built as the executor builds it (``engine.build_step`` under
+    ``run_repeated``'s scan, the state donated), compiled for the
+    described v5e. Its record counts temporaries, and
+    ``profiler.memory_table`` reads the scheduled text the TPU compiler
+    prints (tiles, memory spaces, asynchronous copies) into a peak that
+    can be set against them."""
+    import numpy as np
+    import paddle_tpu as fluid
+    from paddle_tpu import compile_cache, profiler
+    from paddle_tpu.contrib import mixed_precision as amp
+    from paddle_tpu.engine import build_repeat_fn, build_step
+
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard():
+        with fluid.program_guard(main, startup):
+            x = fluid.layers.data("x", shape=[1024], dtype="float32")
+            y = fluid.layers.data("y", shape=[1], dtype="float32")
+            # activations of 128 MB and more: past what VMEM holds
+            h = fluid.layers.fc(x, 4096, act="relu")
+            h = fluid.layers.fc(h, 4096, act="relu")
+            loss = fluid.layers.mean(fluid.layers.square_error_cost(
+                fluid.layers.fc(h, 1), y))
+            amp.decorate(fluid.optimizer.AdamOptimizer(1e-3)).minimize(
+                loss)
+    block = main.global_block()
+    made = {n for op in startup.global_block().ops
+            for names in op.outputs.values() for n in names}
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(
+        tuple(shape), dtype, sharding=one_chip)
+    persist = {n: sds(v.shape, np.dtype(v.dtype))
+               for n, v in block.vars.items()
+               if v.persistable and n in made}
+    feed = {"x": sds((16384, 1024), jnp.float32),
+            "y": sds((16384, 1), jnp.float32)}
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    step = build_step(main, block, [loss.name],
+                      carried=frozenset(persist))
+    with jax.default_matmul_precision("default"):
+        c = jax.jit(build_repeat_fn(step, 4), donate_argnums=(0,)).lower(
+            persist, feed, sds(key.shape, key.dtype)).compile()
+    record = compile_cache.memory_record(c)
+    assert record["temp_bytes"] > 0
+    state = sum(int(np.prod(v.shape)) * v.dtype.itemsize
+                for v in persist.values())
+    assert record["alias_bytes"] >= state       # the state is donated
+    table = profiler.memory_table(c.as_text(), record["temp_bytes"])
+    assert table["peak_bytes"] > 0 and table["scope"]
+    assert 0.0 < table["coverage"] < float("inf")
+    for by in ("by_phase", "by_layer", "by_layer_op"):
+        assert sum(table[by].values()) == table["peak_bytes"]
+    print(profiler.format_memory_table(table))

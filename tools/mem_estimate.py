@@ -8,8 +8,8 @@ real steps until one runs out of memory.
     python tools/mem_estimate.py transformer 64 96
 
 Prints one JSON line per batch with the compiler's memory_analysis
-(no step is ever launched; only the startup program runs, which
-allocates just the parameters).
+(``compile_cache.memory_record``: no step is ever launched; only the
+startup program runs, which allocates just the parameters).
 """
 
 import json
@@ -92,18 +92,12 @@ def estimate(model, batch):
         key = jax.random.key(0)
         lowered = jax.jit(step, donate_argnums=(0,)).lower(
             persist, feed_dev, key)
-        compiled = lowered.compile()
-        ma = compiled.memory_analysis()
-        row = {"model": model, "batch": batch}
-        for field in ("temp_size_in_bytes", "argument_size_in_bytes",
-                      "output_size_in_bytes",
-                      "alias_size_in_bytes",
-                      "peak_memory_in_bytes"):
-            v = getattr(ma, field, None)
-            if v is not None:
-                row[field.replace("_in_bytes", "_gb")] = round(
-                    v / 2**30, 3)
-        return row
+        # the record every executable of an Executor carries
+        # (telemetry()["memory"]), in GiB
+        record = compile_cache.memory_record(lowered.compile()) or {}
+        return dict({"model": model, "batch": batch},
+                    **{k.replace("_bytes", "_gib"): round(v / 2**30, 3)
+                       for k, v in record.items() if v is not None})
 
 
 def main():

@@ -6,10 +6,16 @@ same dispatches traced and untraced.
 
     python tools/profile_step.py [--iters 20] [--batch 64]
     python tools/profile_step.py --workload tfm_base_scan [--out t.json]
+    python tools/profile_step.py --workload tfm_base_scan --memory
 
 Without ``--workload`` the flagship transformer-base step at
 ``--batch``; with it, one cell of ``BENCHMARK.json`` exactly as the
 benchmark builds it (its adapter, weights and batch from ``--seed``).
+``--memory`` traces nothing: it builds the step with one dispatch and
+prints what its executable holds in HBM (the compiler's count, the
+state it takes by kind) and ``profiler.memory_table``: the scheduled
+HLO's fullest moment by the same scopes, a model whose first line says
+how much of the compiler's temporaries it covers.
 A table is only as new as the executable: start from an empty compile
 cache (``JAX_COMPILATION_CACHE_DIR`` to a fresh directory) after a
 change to the scopes, or the store hands back an executable that
@@ -76,6 +82,35 @@ def timed(dispatch, n):
     return time.perf_counter() - t0
 
 
+def memory_report(dispatch, out):
+    """The step's executable, built by one dispatch: its memory and
+    state records and the scheduled HLO's fullest moment by scope."""
+    from paddle_tpu import profiler
+    print("building the step...", file=sys.stderr, flush=True)
+    np.asarray(dispatch())
+    table = profiler.device_memory_table()
+    if table is None:
+        raise SystemExit("no executable gives its optimized HLO here")
+    gib = 2.0 ** 30
+    print("executable %s (%s), %s: %s" % (
+        table["entry"], table["shape_key"],
+        "loaded from the store" if table["from_cache"] else "compiled",
+        ", ".join("%s %.4f GiB" % (k[:-6], v / gib)
+                  for k, v in (table["memory"] or {}).items()
+                  if v is not None) or "no memory analysis"))
+    state = table["state"]
+    print("state it takes, per device: " + ", ".join(
+        "%s %.4f GiB in %d leaves" % (k, state[k]["bytes"] / gib,
+                                      state[k]["leaves"])
+        for k in ("parameters", "optimizer_state", "other"))
+        + "; feed %.4f GiB" % (state["feed_bytes"] / gib))
+    print(profiler.format_memory_table(table))
+    if out:
+        os.makedirs(os.path.dirname(out) or ".", exist_ok=True)
+        with open(out, "w") as f:
+            json.dump(table, f, indent=1)
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--iters", type=int, default=20)
@@ -88,11 +123,15 @@ def main():
     # finds under it, so two runs sharing one read as one capture
     ap.add_argument("--trace-dir",
                     default="/tmp/flagship_trace.%d" % os.getpid())
+    ap.add_argument("--memory", action="store_true",
+                    help="no trace: the step's HBM by scope")
     ap.add_argument("--out", help="write the table as JSON here too")
     args = ap.parse_args()
 
     from paddle_tpu import profiler
     dispatch, spd = cell(args) if args.workload else flagship(args)
+    if args.memory:
+        return memory_report(dispatch, args.out)
     n = args.dispatches
     print("compiling + warmup...", file=sys.stderr, flush=True)
     timed(dispatch, 2)
