@@ -44,10 +44,10 @@ float32 whatever the inputs' type; the matrix products take their
 operands in q's type (bf16 under AMP) and accumulate in float32; T is
 made and applied at full precision.
 
-**Memory.** One custom VJP keeps the op's inputs alone; the backward
-pass makes the chunk intermediates again (block by block of
-``_BLOCK_CHUNKS`` chunks for the stateless part, and under the scan
-over chunks only the chunk-start states are kept).
+**Memory.** Every custom VJP here keeps its op's inputs alone and the
+backward pass makes the intermediates again: the core's chunk by chunk
+(blocks of ``_BLOCK_CHUNKS`` for the stateless part, the chunk-start
+states under the scan), the two small ops' float32 insides in one pass.
 
 **Two lowerings of the chunked form.** On a TPU backend, where a head
 of q, k and v is whole groups of 128 lanes, ``kda_chunked`` takes the
@@ -376,12 +376,28 @@ def kda_gate(x, a_log, dt_bias):
                                 + dt_bias.astype(jnp.float32))
 
 
-@register("short_conv", ["X", "W"], ["Out"])
-def short_conv(x, w):
-    """Causal depthwise convolution over positions, then SiLU: x
-    [B,S,C], w [C,K] (no bias), ``y_t = silu(sum_i w[:, i]
-    x_{t-(K-1)+i})`` with nought before the row's start. Sums in
-    float32, the output in x's type."""
+# ---- the two small ops beside the core. Below it in the file: the
+# core's Mosaic bodies carry this file's line numbers in their text
+from .pallas import kda_small  # noqa: E402
+
+
+def _small_lowering(takes):
+    """``pallas`` (ops/pallas/kda_small.py) on a TPU backend where the
+    kernels take the shapes, else ``xla``: read off the site, as
+    ``lowering`` chooses for the core."""
+    return "pallas" if not interpret_mode() and takes else "xla"
+
+
+def _count_small(name, took):
+    for path in ("pallas", "xla"):
+        count_lowering("%s_lowering.%s" % (name, path), float(path == took))
+
+
+def short_conv_definition(x, w):
+    """What ``short_conv`` computes, as plain ``jax.numpy``: x [B,S,C],
+    w [C,K] (no bias), ``y_t = silu(sum_i w[:, i] x_{t-(K-1)+i})`` with
+    nought before the row's start. Sums in float32, the output in x's
+    type."""
     K = w.shape[1]
     s = x.shape[1]
     xf = jnp.pad(x.astype(jnp.float32), ((0, 0), (K - 1, 0), (0, 0)))
@@ -390,13 +406,92 @@ def short_conv(x, w):
     return jax.nn.silu(y).astype(x.dtype)
 
 
-@register("gated_rms_norm", ["X", "Gate", "Scale"], ["Y"])
-def gated_rms_norm(x, gate, scale, *, epsilon=1e-5):
-    """RMSNorm over each group of ``len(scale)`` lanes of the last axis
-    (a head), times the weight, times ``sigmoid(gate)``: x and gate
-    [..., H*D], scale [D]. Float32 inside, x's type out."""
+def _conv_lowering(x, w):
+    return _small_lowering(kda_small.conv_takes(x, w))
+
+
+@jax.custom_vjp
+def _short_conv(x, w):
+    if _conv_lowering(x, w) == "pallas":
+        return kda_small.conv_fwd(x, w)
+    return short_conv_definition(x, w)
+
+
+def _short_conv_fwd(x, w):
+    return _short_conv(x, w), (x, w)
+
+
+def _short_conv_bwd(res, dy):
+    if _conv_lowering(*res) == "pallas":
+        return kda_small.conv_bwd(*res, dy)
+    return jax.vjp(short_conv_definition, *res)[1](dy)
+
+
+_short_conv.defvjp(_short_conv_fwd, _short_conv_bwd)
+
+
+@register("short_conv", ["X", "W"], ["Out"])
+def short_conv(x, w):
+    """Causal depthwise convolution over positions, then SiLU
+    (``short_conv_definition``; the same values). Its own VJP keeps
+    (x, w) as they came: the backward pass reads x, w and dy and makes
+    the float32 pre-activation again, then ``g = dy silu'(pre)`` in
+    float32, ``dx`` as the taps of g shifted the other way, ``dw[:, i]``
+    as the sum over positions of ``x_{t-(K-1)+i} g_t``. On a TPU the
+    Mosaic kernels of ops/pallas/kda_small.py, one reading each way;
+    elsewhere the definition and its autodiff, made again from the
+    inputs. ``short_conv_lowering.pallas`` / ``.xla`` count which a
+    site took."""
+    _count_small("short_conv", _conv_lowering(x, w))
+    return _short_conv(x, w)
+
+
+def gated_rms_norm_definition(x, gate, scale, epsilon=1e-5):
+    """What ``gated_rms_norm`` computes, as plain ``jax.numpy``: RMSNorm
+    over each group of ``len(scale)`` lanes of the last axis (a head),
+    times the weight, times ``sigmoid(gate)``: x and gate [..., H*D],
+    scale [D]. Float32 inside, x's type out."""
     d = scale.shape[0]
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, d))
     inv = lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + epsilon)
     y = (xf * inv * scale.astype(jnp.float32)).reshape(x.shape)
     return (y * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
+
+
+def _norm_lowering(x, scale):
+    return _small_lowering(kda_small.norm_takes(x, scale))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gated_rms_norm(x, gate, scale, epsilon):
+    if _norm_lowering(x, scale) == "pallas":
+        return kda_small.norm_fwd(x, gate, scale, epsilon)
+    return gated_rms_norm_definition(x, gate, scale, epsilon)
+
+
+def _gated_norm_fwd(x, gate, scale, epsilon):
+    return _gated_rms_norm(x, gate, scale, epsilon), (x, gate, scale)
+
+
+def _gated_norm_bwd(epsilon, res, dy):
+    if _norm_lowering(res[0], res[2]) == "pallas":
+        return kda_small.norm_bwd(*res, epsilon, dy)
+    return jax.vjp(functools.partial(gated_rms_norm_definition,
+                                     epsilon=epsilon), *res)[1](dy)
+
+
+_gated_rms_norm.defvjp(_gated_norm_fwd, _gated_norm_bwd)
+
+
+@register("gated_rms_norm", ["X", "Gate", "Scale"], ["Y"])
+def gated_rms_norm(x, gate, scale, *, epsilon=1e-5):
+    """RMSNorm a head times the weight times ``sigmoid(gate)``
+    (``gated_rms_norm_definition``). Its own VJP keeps (x, gate, scale)
+    as they came: the backward pass reads them and dy, makes ``inv``,
+    the normalised rows and ``sigmoid(gate)`` again in float32 and
+    gives ``dx``, ``dgate`` and ``dscale``. On a TPU the Mosaic kernels
+    of ops/pallas/kda_small.py, one reading each way; elsewhere the
+    definition and its autodiff, made again from the inputs.
+    ``gated_rms_norm_lowering.pallas`` / ``.xla`` count which."""
+    _count_small("gated_rms_norm", _norm_lowering(x, scale))
+    return _gated_rms_norm(x, gate, scale, float(epsilon))

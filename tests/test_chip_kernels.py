@@ -323,6 +323,39 @@ def test_kda_kernels_match_the_xla_form():
     _close(grads, grads_x, rtol=5e-2, atol=2e-2, atol_of_max=True)
 
 
+@pytest.mark.parametrize("op", ["short_conv", "gated_rms_norm"])
+def test_kda_small_kernels_match_plain_autodiff(op):
+    """kimi_linear_s8k_scan's short convolution (4 taps) and gated norm
+    (32 heads of 128) at ``[1,8192,4096]`` in bf16: the Mosaic kernels
+    of ops/pallas/kda_small.py through the op, against ``jax.vjp`` of
+    the op's definition, the output and every gradient."""
+    from paddle_tpu.ops import kda_ops as K
+    r = np.random.RandomState(37)
+    mk = lambda *sh: jnp.asarray(r.randn(*sh), jnp.bfloat16)  # noqa: E731
+    x, dy = mk(1, 8192, 4096), mk(1, 8192, 4096)
+    if op == "short_conv":
+        fn, definition, args = K.short_conv, K.short_conv_definition, \
+            (x, mk(4096, 4) * 0.3)
+        assert K._conv_lowering(*args) == "pallas"
+    else:
+        fn = lambda *a: K.gated_rms_norm(*a, epsilon=1e-5)  # noqa: E731
+        definition = lambda *a: K.gated_rms_norm_definition(  # noqa: E731
+            *a, 1e-5)
+        args = (x, mk(1, 8192, 4096),
+                jnp.asarray(r.uniform(0.5, 1.5, (128,)), jnp.bfloat16))
+        assert K._norm_lowering(x, args[2]) == "pallas"
+
+    def site(f):
+        def run(*a):
+            out, pull = jax.vjp(f, *a)
+            return (out,) + pull(dy)
+        return jax.jit(run)(*args)
+    # one bf16 ulp of an element; the sums over 8,192 positions (dw,
+    # dscale) in another order
+    _close(site(fn), site(definition), rtol=2.0 ** -7, atol=1e-3,
+           atol_of_max=True)
+
+
 # -- grouped matrix product (the held experts' three products) -------------
 
 @pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048)])

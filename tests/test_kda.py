@@ -167,6 +167,75 @@ def test_gate_and_gated_norm_by_hand():
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
+def _small_op_case(op, seed, dtype, b, s, c, k=None, d=64):
+    """(the op under test, its definition, the inputs, dy) in ``dtype``."""
+    r = np.random.RandomState(seed)
+    f = lambda *sh: jnp.asarray(r.randn(*sh), dtype)    # noqa: E731
+    if op == "short_conv":
+        return (fluid.ops.get(op).fn, K.short_conv_definition,
+                (f(b, s, c), f(c, k)), f(b, s, c))
+    eps = 1e-5
+    return (lambda *a: fluid.ops.get(op).fn(*a, epsilon=eps),
+            lambda *a: K.gated_rms_norm_definition(*a, eps),
+            (f(b, s, c), f(b, s, c),
+             jnp.asarray(r.uniform(0.5, 1.5, (d,)), dtype)), f(b, s, c))
+
+
+def _matches_plain_autodiff(fn, definition, args, dy):
+    """The output and every gradient against ``jax.vjp`` of the
+    definition: float32 rounding at float32 inputs (an element, or for
+    a sum the array's largest), one ulp of the result at bfloat16."""
+    got_y, pull = jax.vjp(fn, *args)
+    want_y, want_pull = jax.vjp(definition, *args)
+    rtol = 1e-6 if dy.dtype == jnp.float32 else 2.0 ** -7
+    for got, want in zip((got_y,) + pull(dy), (want_y,) + want_pull(dy)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        want = np.asarray(want, np.float32)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   rtol=rtol,
+                                   atol=1e-6 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("c", [128, 384])
+@pytest.mark.parametrize("k", [2, 4])
+@pytest.mark.parametrize("s", [5, 64, 200])
+@pytest.mark.parametrize("b", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_short_conv_vjp_is_plain_autodiff(dtype, b, s, k, c):
+    """y, dx and dw of the op's own VJP against autodiff of
+    ``short_conv_definition``; 5 and 200 are no multiple of a tile's
+    rows, and 5 rows with 4 taps are little more than the halo."""
+    _matches_plain_autodiff(*_small_op_case(
+        "short_conv", 31 + s + k, jnp.dtype(dtype), b, s, c, k))
+
+
+@pytest.mark.parametrize("c", [128, 384])
+@pytest.mark.parametrize("s", [5, 64, 200])
+@pytest.mark.parametrize("b", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gated_rms_norm_vjp_is_plain_autodiff(dtype, b, s, c):
+    """y, dx, dgate and dscale against autodiff of
+    ``gated_rms_norm_definition``, two and six heads of 64 lanes."""
+    _matches_plain_autodiff(*_small_op_case(
+        "gated_rms_norm", 37 + s, jnp.dtype(dtype), b, s, c))
+
+
+@pytest.mark.parametrize("op", ["short_conv", "gated_rms_norm"])
+def test_small_ops_keep_their_inputs_alone(op):
+    """What the pullback closes over is the op's inputs as they came
+    (bfloat16 under AMP): no float32 array of x's size waits for the
+    backward pass, as the definition's autodiff keeps several."""
+    fn, definition, args, _ = _small_op_case(op, 41, jnp.bfloat16,
+                                             2, 70, 128, 4)
+
+    def kept(f):
+        pull = jax.eval_shape(lambda *a: jax.vjp(f, *a)[1], *args)
+        return sorted((v.shape, str(v.dtype))
+                      for v in jax.tree_util.tree_leaves(pull))
+    assert kept(fn) == sorted((a.shape, str(a.dtype)) for a in args)
+    assert (args[0].shape, "float32") in kept(definition)
+
+
 def test_the_op_trains_and_counts():
     """Through layers -> Program -> Executor: the loss falls, and
     telemetry()["kda"] counts the tokens, the chunks and the elements
@@ -226,6 +295,27 @@ def as_on_the_chip(monkeypatch):
     false; the kernels' own copy of it stays true, so they are
     interpreted."""
     monkeypatch.setattr(K, "interpret_mode", lambda: False)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """ops/pallas/kda_small.py's tiles made small, so that a few
+    thousand rows are several row tiles and 384 lanes several lane
+    tiles. Its wrappers are jitted on the shapes alone: no trace made
+    under other tiles may be met, here or afterwards."""
+    from paddle_tpu.ops.pallas import kda_small as KS
+    wrappers = (KS.conv_fwd, KS.conv_bwd, KS.norm_fwd, KS.norm_bwd)
+
+    def tiles(lanes):
+        monkeypatch.setattr(KS, "_TILE_LANES", lanes)
+        monkeypatch.setattr(KS, "_TILE_ELEMENTS", 512 * lanes)
+        for f in wrappers:
+            f.clear_cache()
+        return KS
+    yield tiles
+    monkeypatch.undo()
+    for f in wrappers:
+        f.clear_cache()
 
 
 def kernel_inputs(seed, s, strong, dtype, h=2, d=128):
@@ -334,6 +424,65 @@ def test_kernels_read_the_modules_constants(monkeypatch, setting):
             *args, 0.1, 64, 16, K._STATE_DTYPE, K._FLOOR)[1])
 
 
+@pytest.mark.parametrize("c", [128, 384])
+@pytest.mark.parametrize("k", [2, 4])
+@pytest.mark.parametrize("b,s", [(1, 16), (2, 64), (1, 2080)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_short_conv_kernels_are_plain_autodiff(as_on_the_chip, small_tiles,
+                                               dtype, b, s, k, c):
+    """ops/pallas/kda_small.py's pair, interpreted, through the op, its
+    tiles made small: one chunk of 16 rows, a tile of four, and 2,080
+    rows = five tiles of 416 (a tile's first taps and last ``g`` come
+    from its neighbours), one and three lane tiles; two rows of a batch
+    do not meet."""
+    KS = small_tiles(128)
+    fn, definition, args, dy = _small_op_case(
+        "short_conv", 43 + s + k, jnp.dtype(dtype), b, s, c, k)
+    assert K._conv_lowering(*args) == "pallas"
+    assert KS._conv_specs(args[0])[2] == (c // 128, b, -(-s // 512))
+    _matches_plain_autodiff(fn, definition, args, dy)
+
+
+@pytest.mark.parametrize("c,d", [(128, 128), (768, 128), (512, 256)])
+@pytest.mark.parametrize("b,s", [(1, 16), (2, 64), (1, 2080)])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_gated_rms_norm_kernels_are_plain_autodiff(as_on_the_chip,
+                                                   small_tiles, dtype, b,
+                                                   s, c, d):
+    """The norm's pair, interpreted, its tiles made small: one head,
+    six heads in two lane tiles of three, two heads of 256 lanes in a
+    tile each; ``dscale`` summed over 2,080 rows' five row tiles."""
+    small_tiles(384)
+    fn, definition, args, dy = _small_op_case(
+        "gated_rms_norm", 47 + s, jnp.dtype(dtype), b, s, c, d=d)
+    assert K._norm_lowering(args[0], args[2]) == "pallas"
+    _matches_plain_autodiff(fn, definition, args, dy)
+
+
+@pytest.mark.parametrize("op,b,s,c,k,d,took", [
+    ("short_conv", 1, 64, 256, 4, None, "pallas"),
+    ("short_conv", 1, 200, 256, 4, None, "xla"),    # no whole row tiles
+    ("short_conv", 1, 64, 192, 4, None, "xla"),     # no whole lane groups
+    ("short_conv", 1, 64, 256, 5, None, "xla"),     # more taps than tested
+    ("gated_rms_norm", 2, 32, 256, None, 128, "pallas"),
+    ("gated_rms_norm", 2, 32, 256, None, 64, "xla"),    # a head of 64 lanes
+    ("gated_rms_norm", 2, 32, 768, None, 384, "xla"),   # a head of 384
+    ("gated_rms_norm", 1, 40, 256, None, 128, "xla")])
+def test_small_ops_lowering_follows_the_shapes(as_on_the_chip, op, b, s,
+                                               c, k, d, took):
+    """``<op>_lowering.pallas`` / ``.xla`` count the one a site took and
+    list the other, as ``kda_lowering.*``; off the chip it is XLA's.
+    The kernels take what the tests lower for the chip and no more."""
+    fn, _, args, _ = _small_op_case(op, 53, jnp.float32, b, s, c, k,
+                                    d=d or 64)
+    names = ["%s_lowering.%s" % (op, path) for path in ("pallas", "xla")]
+    before = [profiler.counter_values().get(n, 0.0) for n in names]
+    fn(*args)
+    after = [profiler.counter_values()[n] for n in names]
+    assert [a - b_ for a, b_ in zip(after, before)] == \
+        [float(took == "pallas"), float(took == "xla")]
+
+
 @pytest.mark.parametrize("d,took", [(128, "pallas_chunked"),
                                     (64, "xla_chunked"),
                                     (256, "pallas_chunked")])
@@ -359,4 +508,6 @@ def test_the_lowering_follows_the_head_width(as_on_the_chip, d, took):
 def test_off_the_chip_the_lowering_is_xla():
     q, k, v, g, beta = inputs(14, 1, 64, 2, 128, 128)
     assert K.lowering(q, v, beta) == "xla_chunked"
+    assert K._conv_lowering(q, jnp.ones((256, 4))) == "xla"
+    assert K._norm_lowering(q, jnp.ones((128,))) == "xla"
     assert not KP.takes(128, 128, 8, 4) and KP.takes(128, 128, 64, 16)

@@ -2,8 +2,8 @@
 transformer-base's and BERT-base's sites, the blocked flash kernels
 and the grouped products of the Trinity-Mini cell, the blocked flash
 kernels at Kimi-Linear's latent-attention widths and its KDA core's
-three kernels, the grouped products, the expert layer and the rotary
-part at the Kanana-2 cell's), COMPILED for a v5e
+three kernels and two elementwise ops, the grouped products, the
+expert layer and the rotary part at the Kanana-2 cell's), COMPILED for a v5e
 that is described and not attached, at the cells' own shapes: what the
 chip's compiler would refuse (a tile that does not align, more fast
 memory than a kernel may use) is refused here, at no chip time.
@@ -286,6 +286,47 @@ def test_rotary_part_at_the_mla_site(one_chip):
 
     c = compiled(site, one_chip, (q, bf), (k, bf), (q, bf), (k, bf))
     assert c.memory_analysis().temp_size_in_bytes < 1 << 27
+
+
+def _kda_small_site(one_chip, op):
+    """A KDA site's short convolution (4 taps) or gated norm (32 heads
+    of 128 lanes) at the cell's ``[1,8192,4096]`` in bf16, forward +
+    ``jax.vjp`` backward, compiled."""
+    from paddle_tpu.ops import kda_ops as K
+    bf, wide = jnp.bfloat16, (1, 8192, 4096)
+    if op == "short_conv":
+        fn, extra = K.short_conv, [((4096, 4), bf)]
+    else:
+        fn = lambda *a: K.gated_rms_norm(*a, epsilon=1e-5)  # noqa: E731
+        extra = [(wide, bf), ((128,), bf)]
+
+    def site(dy, *ins):
+        out, pull = jax.vjp(fn, *ins)
+        return out, pull(dy)
+
+    return compiled(site, one_chip, (wide, bf), (wide, bf), *extra)
+
+
+@pytest.mark.parametrize("op", ["short_conv", "gated_rms_norm"])
+def test_kda_small_kernels_at_the_cells_widths(one_chip, monkeypatch, op):
+    """As the chip lowers them (``interpret_mode()`` false in the op's
+    module and in the kernels'): one Mosaic call each way, and no
+    temporary of x's size (``dw`` / ``dscale`` as eight sublanes of
+    partial sums a lane)."""
+    from paddle_tpu.ops import kda_ops as K
+    from paddle_tpu.ops.pallas import kda_small as KS
+    monkeypatch.setattr(K, "interpret_mode", lambda: False)
+    monkeypatch.setattr(KS, "interpret_mode", lambda: False)
+    wrappers = (KS.conv_fwd, KS.conv_bwd, KS.norm_fwd, KS.norm_bwd)
+    for f in wrappers:          # jitted on the shapes alone
+        f.clear_cache()
+    try:
+        c = _kda_small_site(one_chip, op)
+    finally:
+        for f in wrappers:
+            f.clear_cache()
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') == 2
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_memory_plane_of_a_small_step(one_chip):
