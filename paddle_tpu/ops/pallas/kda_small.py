@@ -68,11 +68,11 @@ def _sigmoid(x):
     return 1.0 / (1.0 + jnp.exp(-x))
 
 
-def _fold(v):
-    """[R, L] -> [8, L]: the sublane tiles added."""
-    out = v[:8]
-    for at in range(8, v.shape[0], 8):
-        out = out + v[at:at + 8]
+def _fold(v, rows=8):
+    """[R, L] -> [rows, L]: the tiles of ``rows`` sublanes added."""
+    out = v[:rows]
+    for at in range(rows, v.shape[0], rows):
+        out = out + v[at:at + rows]
     return out
 
 

@@ -47,8 +47,18 @@ backward pass is ``jax.vjp`` of the chunked form made again from them.
 The in-chunk decay matrices of a layer (64 chunks x 64 heads x 128 x
 128 float32: 268 MB at the Nemotron-H cell's size) are never kept.
 
-``ssd_lowering.xla_chunked`` counts the sites; ``.pallas_chunked`` is
-listed at 0 (no Mosaic form yet: ROADMAP's Speed queue).
+**Two lowerings of the chunked form.** On a TPU backend, where a
+group's heads and the state are whole groups of 128 lanes and the chunk
+is too (``ops/pallas/ssd.py takes``), ``ssd_chunked`` takes the Mosaic
+kernels of ``ops/pallas/ssd.py``, forward and backward: a grid step is
+one chunk of one group of B / C and its heads, the decays and the
+decay-weighted matrices are made in VMEM and never written, the state
+is carried in VMEM. Everywhere else (the CPU, toy widths) it takes the
+XLA form below. The step ``softplus(dt + dt_bias)``, its log decay
+``step * A`` and their chain rule stay in XLA around the kernels
+(``[B,S,H]`` arrays). ``ssd_lowering.pallas_chunked`` / ``.xla_chunked``
+count which a site took, and ``mosaic_tokens_total`` the tokens whose
+core ran on the kernels.
 """
 
 from __future__ import annotations
@@ -59,7 +69,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .pallas.common import count_lowering
+from .pallas import ssd as ssd_pallas
+from .pallas.common import count_lowering, interpret_mode
 from .registry import register
 
 _BLOCK_CHUNKS = 8     # chunks to a block of the stateless part
@@ -211,16 +222,59 @@ def _ssd_forward(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk):
     return y.astype(cd), jnp.sum(low)
 
 
+def lowering(x, a_log, b, n_groups, chunk):
+    """``pallas_chunked`` or ``xla_chunked``: by what the site's shapes
+    and the backend show, as ``kda_ops.lowering`` chooses."""
+    h = a_log.shape[0]
+    if not interpret_mode() and ssd_pallas.takes(
+            h // n_groups, x.shape[-1] // h, b.shape[-1] // n_groups, chunk):
+        return "pallas_chunked"
+    return "xla_chunked"
+
+
+def _steps(dt, a_log, dt_bias):
+    """The step and its log decay ``step * A`` [B,S,H], float32."""
+    step = _step(dt, dt_bias)
+    return step, step * -jnp.exp(a_log.astype(jnp.float32))
+
+
+# Jitted as the XLA form is: a model's sites of one signature share ONE
+# trace of the kernels, forward and backward.
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _kernels_forward(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk):
+    step, la = _steps(dt, a_log, dt_bias)
+    return ssd_pallas.ssd_fwd(x, step, la, b, c, d, n_groups, chunk, _FLOOR)
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _kernels_backward(x, dt, a_log, b, c, d, dt_bias, d_out, n_groups,
+                      chunk):
+    (step, la), pull = jax.vjp(_steps, dt, a_log, dt_bias)
+    dx, dstep, dla, db, dc, dd = ssd_pallas.ssd_bwd(
+        x, step, la, b, c, d, d_out, n_groups, chunk)
+    ddt, da_log, ddt_bias = pull((dstep, dla))
+    return dx, ddt, da_log, db, dc, dd, ddt_bias
+
+
+def _chunked(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk):
+    if lowering(x, a_log, b, n_groups, chunk) == "pallas_chunked":
+        return _kernels_forward(x, dt, a_log, b, c, d, dt_bias, n_groups,
+                                chunk)
+    return _ssd_forward(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
 def ssd_chunked(x, dt, a_log, b, c, d, dt_bias, n_groups=1, chunk=128):
     """The chunked form of ``ssd_recurrence`` (same arguments, dt_bias
-    may be None): (y in x's type, the count of ``_ssd_forward``)."""
-    return _ssd_forward(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk)
+    may be None): (y in x's type, the elements of the in-chunk
+    cumulative log decay below ``_FLOOR``), on the lowering
+    ``lowering`` picks."""
+    return _chunked(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk)
 
 
 def _ssd_fwd(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk):
     # the inputs are all the backward pass keeps
-    return (_ssd_forward(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk),
+    return (_chunked(x, dt, a_log, b, c, d, dt_bias, n_groups, chunk),
             (x, dt, a_log, b, c, d, dt_bias))
 
 
@@ -233,6 +287,9 @@ def _ssd_backward(x, dt, a_log, b, c, d, dt_bias, d_out, n_groups, chunk):
 
 
 def _ssd_bwd(n_groups, chunk, res, ct):
+    x, _, a_log, b = res[:4]
+    if lowering(x, a_log, b, n_groups, chunk) == "pallas_chunked":
+        return _kernels_backward(*res, ct[0], n_groups, chunk)
     return _ssd_backward(*res, ct[0], n_groups, chunk)
 
 
@@ -249,12 +306,14 @@ def ssd_scan(x, dt, a_log, b, c, d, dt_bias, counters, *, n_groups=1,
     ``DtBias`` [H] is given), ``A_log`` and ``D`` [H], B and C
     [B,S,G*N]: chunks of ``chunk_size``. ``Out`` [B,S,H*P] has x's
     type; ``CountersOut`` is the input's own variable."""
+    n_groups, chunk_size = int(n_groups), int(chunk_size)
+    took = lowering(x, a_log, b, n_groups, chunk_size)
     for path in ("pallas_chunked", "xla_chunked"):
-        count_lowering("ssd_lowering." + path, float(path == "xla_chunked"))
-    out, low = ssd_chunked(x, dt, a_log, b, c, d, dt_bias, int(n_groups),
-                           int(chunk_size))
+        count_lowering("ssd_lowering." + path, float(path == took))
+    out, low = ssd_chunked(x, dt, a_log, b, c, d, dt_bias, n_groups,
+                           chunk_size)
     bn, s, _ = x.shape
     add = jnp.stack([jnp.float32(bn * s),
-                     jnp.float32(bn * (-(-s // int(chunk_size)))), low,
-                     jnp.float32(0.0)])
+                     jnp.float32(bn * (-(-s // chunk_size))), low,
+                     jnp.float32(bn * s * (took == "pallas_chunked"))])
     return out, counters + add

@@ -323,6 +323,42 @@ def test_kda_kernels_match_the_xla_form():
     _close(grads, grads_x, rtol=5e-2, atol=2e-2, atol_of_max=True)
 
 
+def test_ssd_kernels_match_the_xla_form():
+    """nemotron3_nano_s8k_scan's SSD site (64 heads of 64 over 8 groups
+    of B / C at state 128, chunks of 128, S=8192, x, B, C in bf16, the
+    step and its bias float32 at the model's start: A = 1..64 and steps
+    0.001..0.1, so the strong heads pass the floor inside a chunk): the
+    Mosaic kernels of ops/pallas/ssd.py against the XLA chunked form,
+    output, floor hits and all seven gradients, at the KDA core's
+    tolerances."""
+    from paddle_tpu.models import nemotron_h as NH
+    from paddle_tpu.ops import ssd_ops as S
+    b, s, h, p, g, n = 1, 8192, 64, 64, 8, 128
+    r = np.random.RandomState(39)
+    mk = lambda *sh: jnp.asarray(r.randn(*sh) * 0.5,  # noqa: E731
+                                 jnp.bfloat16)
+    x, ct, bm, cm = (mk(b, s, w) for w in (h * p, h * p, g * n, g * n))
+    a_log, dt_bias = (jnp.asarray(t)
+                      for t in NH.ssd_start(NH.NemotronHConfig()))
+    dt = jnp.asarray(r.randn(b, s, h) * 0.5, jnp.float32)
+    d = jnp.asarray(r.uniform(0.5, 1.5, h), jnp.float32)
+    args = (x, dt, a_log, bm, cm, d, dt_bias)
+    assert S.lowering(x, a_log, bm, g, 128) == "pallas_chunked"
+
+    def site(*a):
+        (out, low), pull = jax.vjp(lambda *t: S.ssd_chunked(*t, g, 128),
+                                   *a)
+        return out, low, pull((ct, jnp.zeros_like(low)))
+    out, low, grads = jax.jit(site)(*args)
+    want, low_x = S._ssd_forward(*args, g, 128)
+    grads_x = S._ssd_backward(*args, ct, g, 128)
+    # the two cumulative sums may round an element at -80 apart
+    assert abs(float(low) - float(low_x)) <= 1e-4 * float(low_x)
+    assert float(low_x) > 0
+    _close(out, want, rtol=2e-2, atol=2e-2, atol_of_max=True)
+    _close(grads, grads_x, rtol=5e-2, atol=2e-2, atol_of_max=True)
+
+
 @pytest.mark.parametrize("op", ["short_conv", "gated_rms_norm"])
 def test_kda_small_kernels_match_plain_autodiff(op):
     """kimi_linear_s8k_scan's short convolution (4 taps) and gated norm

@@ -2238,8 +2238,9 @@ EXEMPT = {
     # finite differences through 128-token chunks of exp(-800) decays
     # say less than the recurrence does
     "ssd_scan":
-        "test_ssd.py (the chunked form against the token-by-token "
-        "recurrence, forward and every gradient; the counters), "
+        "test_ssd.py (the chunked form and, interpreted, its Mosaic "
+        "kernels against the token-by-token recurrence, forward and "
+        "every gradient; the counters), "
         "test_nemotron_h_model.py",
     # a chunked scan that writes the step's counters in place; finite
     # differences over a 64-token chunk's triangular inverse say less

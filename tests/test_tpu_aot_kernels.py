@@ -6,7 +6,7 @@ three kernels and two elementwise ops, the grouped products, the
 expert layer and the rotary part at the Kanana-2 cell's, and at the
 Nemotron-H cell's the relu^2 expert layer at a width of 14.5 x 128
 lanes, GQA at 16 query heads a kv head, the convolution with a bias and
-the SSD core's XLA form), COMPILED for a v5e
+the SSD core on either lowering), COMPILED for a v5e
 that is described and not attached, at the cells' own shapes: what the
 chip's compiler would refuse (a tile that does not align, more fast
 memory than a kernel may use) is refused here, at no chip time.
@@ -308,14 +308,29 @@ def test_blocked_flash_at_the_nemotron_site(one_chip, for_the_chip):
     assert temps < 1 << 29
 
 
-def test_ssd_core_at_the_nemotron_cells_widths(one_chip):
+@pytest.mark.parametrize("lowering,calls,temps", [
+    ("xla_chunked", 0, 3 << 29),
+    ("pallas_chunked", 3, 3 << 26),
+])
+def test_ssd_core_at_the_nemotron_cells_widths(one_chip, monkeypatch,
+                                               lowering, calls, temps):
     """The chunked SSD core as ``nemotron3_nano_s8k_scan`` runs it: 64
     heads of 64 over 8 groups of B / C at state 128, chunks of 128,
     8,192 positions, x / B / C in bf16, the step and its bias float32,
-    forward and backward: XLA's own lowering (no Mosaic form yet), and
-    temporaries of a few blocks of chunks, not the 268 MB of a layer's
-    in-chunk decays several times over."""
+    forward and backward, on either lowering (``ssd_ops.lowering``
+    steered here): XLA's own, whose temporaries are a few blocks of
+    chunks and not the 268 MB of a layer's in-chunk decays several times
+    over; and the Mosaic kernels (``interpret_mode()`` false in the
+    kernels' module: the forward, its state pass again and the reverse
+    pass), whose temporaries are the chunk-start states in float32 (128
+    MiB) and hardly more."""
     from paddle_tpu.ops import ssd_ops as S
+    from paddle_tpu.ops.pallas import ssd as SP
+    monkeypatch.setattr(S, "lowering", lambda *a: lowering)
+    monkeypatch.setattr(SP, "interpret_mode", lambda: False)
+    wrappers = (S._kernels_forward, S._kernels_backward)
+    for f in wrappers:          # jitted on the shapes alone
+        f.clear_cache()
     bf, f32 = jnp.bfloat16, jnp.float32
 
     def site(x, dt, a_log, b, c, d, dt_bias, ct):
@@ -324,11 +339,16 @@ def test_ssd_core_at_the_nemotron_cells_widths(one_chip):
         return out, pull(ct)
 
     wide, gn = (1, 8192, 4096), (1, 8192, 1024)
-    c = compiled(site, one_chip, (wide, bf), ((1, 8192, 64), f32),
-                 ((64,), f32), (gn, bf), (gn, bf), ((64,), f32),
-                 ((64,), f32), (wide, bf))
-    assert 'custom_call_target="tpu_custom_call"' not in c.as_text()
-    assert c.memory_analysis().temp_size_in_bytes < 3 << 29
+    try:
+        c = compiled(site, one_chip, (wide, bf), ((1, 8192, 64), f32),
+                     ((64,), f32), (gn, bf), (gn, bf), ((64,), f32),
+                     ((64,), f32), (wide, bf))
+    finally:
+        for f in wrappers:
+            f.clear_cache()
+    assert c.as_text().count('custom_call_target="tpu_custom_call"') \
+        == calls
+    assert c.memory_analysis().temp_size_in_bytes < temps
 
 
 def test_rotary_part_at_the_mla_site(one_chip):
