@@ -730,16 +730,66 @@ def dropout(x, *, dropout_prob=0.5, is_test=False,
     while a saved mask costs a full HBM round-trip per dropout site
     (~30 sites x [16k, 512]+ on transformer-base). The Mask output is
     still emitted for API parity; XLA CSEs it against the forward's
-    in-register mask and dead-codes it when nothing consumes it."""
+    in-register mask and dead-codes it when nothing consumes it.
+
+    Under a data-parallel mesh whose key XLA cannot draw by shard (an
+    ``rbg`` key: ``mesh.per_shard_batch_axis`` decides) the whole body
+    runs per shard under ``shard_map`` over ``dp`` alone: each device
+    draws its own rows' bits from the key folded with its ``dp``
+    index, where the GSPMD program would draw the global batch's bits
+    on every device and slice them. The custom VJP runs inside the
+    body, so the backward recomputes the shard's own mask. Masks
+    differ by shard, as the reference's ParallelExecutor drew them per
+    device; ``dropout_lowering.per_shard`` / ``.global`` count the
+    sites (trace time) by the path taken."""
+    from ..parallel import mesh as mesh_lib
+    from .pallas.common import count_lowering
+
     if is_test:
         if dropout_implementation == "upscale_in_train":
             return x, jnp.ones_like(x)
         return x * (1.0 - dropout_prob), jnp.ones_like(x)
     key = jax.random.key(seed) if seed else rng
+    rate = float(dropout_prob)
     upscale = dropout_implementation == "upscale_in_train"
-    out = _dropout_train(float(dropout_prob), upscale)(x, key)
-    mask = _keep_mask(key, dropout_prob, x.shape).astype(x.dtype)
-    return out, mask
+    ax = mesh_lib.per_shard_batch_axis(x.shape[0], key) if x.ndim \
+        else None
+    count_lowering("dropout_lowering.per_shard", float(ax is not None))
+    count_lowering("dropout_lowering.global", float(ax is None))
+    if ax is None:
+        return _dropout_draw(rate, upscale, x, key)
+    return _per_shard_dropout(rate, upscale, mesh_lib.current_mesh(), ax,
+                              x.ndim)(x, key)
+
+
+@functools.lru_cache(maxsize=None)
+def _per_shard_dropout(rate, upscale, mesh, ax, ndim):
+    """``_dropout_draw`` per shard of ``ax``, from the key folded by the
+    shard's index. Manual over ``ax`` alone: an array the other axes
+    shard (tp's hidden columns) stays theirs, with no gather into the
+    body. Jitted, so that the sites of one shape share one trace:
+    traced one by one, transformer-base's 64 per-shard bodies (32
+    sites, forward and backward) added about half a second to tracing
+    its data-parallel step."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec
+
+    spec = PartitionSpec(ax, *([None] * (ndim - 1)))
+
+    def body(x, key):
+        return _dropout_draw(rate, upscale, x,
+                             jax.random.fold_in(key, lax.axis_index(ax)))
+
+    return jax.jit(shard_map(body, mesh=mesh,
+                             in_specs=(spec, PartitionSpec()),
+                             out_specs=(spec, spec),
+                             axis_names=frozenset({ax}), check_vma=False))
+
+
+def _dropout_draw(rate, upscale, x, key):
+    """Dropout's output and its ``Mask`` from one key."""
+    out = _dropout_train(rate, upscale)(x, key)
+    return out, _keep_mask(key, rate, x.shape).astype(x.dtype)
 
 
 @functools.lru_cache(maxsize=None)
@@ -773,8 +823,9 @@ def _keep_mask(key, rate, shape):
     a measured win. (A u16-halves variant — one generated u32 serving
     two elements — was chip-measured in round 4 and did NOT win: the
     bitcast+reshape breaks the generator's fusion with the consumer,
-    and the rbg generator is not bit-count-bound.) RNG impl is
-    whatever jax.random.bits uses (rbg on TPU via bench.py)."""
+    and the rbg generator is not bit-count-bound.) The generator is
+    the key's: ``rbg`` where the benchmark's configs set ``prng_impl``,
+    jax's default (partitionable threefry) elsewhere."""
     bits = jax.random.bits(key, shape, jnp.uint32)
     thresh = min(int(rate * (1 << 32)), (1 << 32) - 1)
     return bits >= jnp.uint32(thresh)

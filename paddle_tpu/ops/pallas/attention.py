@@ -1716,9 +1716,9 @@ def sdpa_pallas(q, k, v, bias, *, scale=1.0, dropout_rate=0.0,
     else:
         seed = jnp.float32(0)
     from ...parallel import mesh as mesh_lib
-    from ...parallel.ulysses import in_sp_body
     mesh = mesh_lib.current_mesh()
-    if mesh is not None and mesh.size > 1 and not in_sp_body():
+    if mesh is not None and mesh.size > 1 \
+            and not mesh_lib.in_manual_body():
         out = _flash_over_mesh(mesh, q, k, v, bias, seed, H, hkv,
                                float(scale), rate, bool(causal), window)
     else:
@@ -1737,7 +1737,8 @@ def _flash_over_mesh(mesh, q, k, v, bias, seed, H, Hkv, scale, rate,
     a multi-device jit (jax/_src/tpu_custom_call.py) — so it runs per
     shard under shard_map: batch over ``dp`` and heads over ``tp``
     where the mesh has those axes and they divide (the kv heads too,
-    where there are fewer of them), every other axis computing
+    where there are fewer of them; the batch axis is
+    ``mesh.per_shard_batch_axis``'s), every other axis computing
     replicated. Heads are axis 1 of the blocked kernels' rank 4 and
     contiguous in the last axis of the pair's rank 3. Each shard
     numbers its dropout cells from its first (batch, head) row, so
@@ -1745,6 +1746,8 @@ def _flash_over_mesh(mesh, q, k, v, bias, seed, H, Hkv, scale, rate,
     trace is the single-device one."""
     from jax import shard_map
     from jax.sharding import PartitionSpec
+
+    from ...parallel.mesh import per_shard_batch_axis
 
     B = q.shape[0]
 
@@ -1754,7 +1757,7 @@ def _flash_over_mesh(mesh, q, k, v, bias, seed, H, Hkv, scale, rate,
             return name
         return None
 
-    b_ax, h_ax = axis("dp", B), axis("tp", H, Hkv)
+    b_ax, h_ax = per_shard_batch_axis(B), axis("tp", H, Hkv)
     h_loc = H // mesh.shape[h_ax] if h_ax else H
     spec = PartitionSpec(b_ax, None, h_ax) if q.ndim == 3 \
         else PartitionSpec(b_ax, h_ax, None, None)

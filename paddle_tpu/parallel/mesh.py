@@ -75,6 +75,42 @@ def current_mesh() -> Optional[Mesh]:
     return getattr(_mesh_tls, "mesh", None)
 
 
+def in_manual_body() -> bool:
+    """Whether tracing is inside a ``shard_map`` body (the pipeline's,
+    sequence parallelism's, a kernel's per-shard wrapper, a user's):
+    there the arrays are already one shard's, and building another
+    ``shard_map`` would nest one."""
+    return bool(jax.sharding.get_abstract_mesh().manual_axes)
+
+
+def _key_partitions(key) -> bool:
+    """Whether XLA's partitioner splits ``key``'s bit generator by
+    shard: threefry with ``jax_threefry_partitionable`` on computes
+    each element's bits from its index alone. An ``rbg`` key lowers to
+    one ``rng-bit-generator`` whose stream depends on the whole shape,
+    so a GSPMD program draws the global array on every device and
+    slices it."""
+    return (jax.random.key_impl(key) == "threefry2x32"
+            and jax.config.jax_threefry_partitionable)
+
+
+def per_shard_batch_axis(batch: int, key=None) -> Optional[str]:
+    """The mesh axis an op's per-shard body splits a leading axis of
+    ``batch`` rows over, or None where the op stays one global program.
+    "dp" under a multi-device ambient mesh whose ``dp`` axis (of more
+    than one device) divides ``batch``, outside any manual body. With
+    ``key``, an op drawing random bits from it: only where the key's
+    generator does not partition by itself (``_key_partitions``), so
+    that drawing per shard saves the global draw."""
+    mesh = current_mesh()
+    if mesh is None or mesh.size <= 1 or in_manual_body():
+        return None
+    if key is not None and _key_partitions(key):
+        return None
+    n = dict(mesh.shape).get("dp", 1)
+    return "dp" if n > 1 and batch % n == 0 else None
+
+
 @contextlib.contextmanager
 def mesh_guard(mesh: Mesh):
     prev = current_mesh()
